@@ -73,14 +73,15 @@ def normalize_device_kind(kind) -> str:
 
 def current_device_kind() -> str:
     """The attached device's kind string (the cache-key leg that keeps a
-    CPU-tuned winner off a TPU run), normalized. "unknown" when no
-    backend — an unknown kind still caches consistently within one
-    environment."""
-    try:
+    CPU-tuned winner off a TPU run), normalized. A process that has not
+    opened a backend asks a CHILD: the search's trials are children that
+    need the chip, and a parent that had looked for itself would hold it
+    (context.py, "one process per chip")."""
+    from .. import context
+    if context.backend_opened():
         import jax
         return normalize_device_kind(jax.devices()[0].device_kind)
-    except Exception:  # noqa: BLE001
-        return "unknown"
+    return normalize_device_kind(context.devices_seen_by_a_child()[1])
 
 
 def _count_reject():
@@ -196,10 +197,10 @@ class TuningCache:
 
     # -- sweep ingestion --------------------------------------------------
     def ingest(self, results, fp: str, mesh, device_kind: str):
-        """Adopt the best OK trial of a manual sweep
-        (tools/perf_sweep.py) as this key's winner — sweep rows and
-        tuner trials are the same record shape by construction, so the
-        manual protocol feeds the same cache the tuner reads. Returns
+        """Adopt the best OK trial of a manual sweep as this key's
+        winner — sweep rows and tuner trials are the same record shape
+        by construction, so a manual protocol feeds the same cache the
+        tuner reads. Returns
         the stored entry, or None when no usable trial."""
         from .trial import score as _score
         ok = [r for r in results if getattr(r, "ok", False)

@@ -1,8 +1,6 @@
 """mxtpu.autotune.trial — ONE way to measure a knob config.
 
-Every trial — the tuner's, and tools/perf_sweep.py's manual rows, which
-rebased onto this runner so the two can never disagree on how a config
-is measured — executes a short steady-state bench.py window **in a
+Every trial executes a short steady-state bench.py window **in a
 subprocess** and reads the measurement out of the emitted BENCH json.
 
 Subprocess isolation is a design requirement, not a nicety:
@@ -14,6 +12,12 @@ Subprocess isolation is a design requirement, not a nicety:
   corrupt deserialization in trial 3 cannot poison trial 4) and makes a
   trial death a counted skip instead of a tuner crash;
 * the measured numbers come from the exact code path the driver runs.
+
+A chip belongs to one process at a time, so a trial can only have the
+device while its parent has NOT opened a jax backend: bench.py runs the
+search before it touches jax, and :func:`run_trial` refuses — a counted
+failed trial, with that reason — from a process that already holds an
+accelerator (context.py, "one process per chip").
 
 The measurement a trial yields (:func:`measurement_from_artifact`):
 measured devicescope busy fraction + idle-gap taxonomy (score
@@ -207,8 +211,8 @@ def trial_env(config=None, model=None, batch=None, dtype=None,
               scrub_ambient=True) -> dict:
     """Build the subprocess environment for one trial: the parent's env
     with every BENCH_*/knob spelling scrubbed (driver parity — a stray
-    BENCH_MODEL would silently mislabel every trial; the perf_sweep
-    lesson), the config's canonical spellings exported, and — with
+    BENCH_MODEL would silently mislabel every trial), the config's
+    canonical spellings exported, and — with
     ``measure=True`` — the measurement arming: one devicescope window
     (measured busy provenance), k=1 control off, Chrome trace off.
     ``extra_env`` applies LAST (the sweep's non-knob BENCH_K/BENCH_S2D
@@ -261,6 +265,13 @@ def run_trial(config=None, *, model=None, batch=None, dtype=None,
     ``config=None`` exports NO knob env at all (bench resolves its own
     defaults) — the sweep's driver-parity warm run; a search trial
     always passes an explicit config so the trial is fully pinned."""
+    from .. import context as _context
+    if _context.holds_accelerator():
+        return TrialResult(
+            config, "failed", knob=knob, value=value,
+            error="this process holds the accelerator, and a chip belongs "
+                  "to one process at a time: the trial's child could not "
+                  "have it (run the search before touching jax)")
     env = trial_env(config, model=model, batch=batch, dtype=dtype,
                     steps=steps, measure=measure, extra_env=extra_env,
                     scrub_ambient=scrub_ambient)

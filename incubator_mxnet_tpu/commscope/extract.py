@@ -113,8 +113,10 @@ def ici_peaks(device=None) -> dict:
     the table for new hardware without a code change."""
     from ..perfscope import cost as _pcost
     base = _pcost.device_peaks(device)
-    row = base.get("table_row", "cpu")
-    bw = ICI_TABLE.get(row, ICI_TABLE["cpu"])
+    # perfscope has no row for an unknown device; the link-time estimate
+    # (always flagged "estimated") keeps its round CPU stand-in
+    row = base.get("table_row") or "cpu"
+    bw = ICI_TABLE[row]
     env = _env_float("MXTPU_PEAK_ICI_BW")
     if env:
         bw = env

@@ -54,6 +54,11 @@ DTYPE_BYTES = {
     "f16": 2, "bf16": 2, "f32": 4, "f64": 8, "c64": 8, "c128": 16,
 }
 
+# XLA marks every fifth element of a long tuple shape with a comment,
+# "/*index=5*/" — it holds the "=" the tuple patterns below stop at, so
+# comments are dropped from a line before it is matched
+_COMMENT_RE = re.compile(r"/\*.*?\*/")
+
 _SHAPE_RE = re.compile(r"([a-z][a-z0-9]*)\[([0-9,]*)\](?:\{[^}]*\})?")
 
 # one collective instruction: "%name = <shape> <op>(" with the op drawn
@@ -191,6 +196,7 @@ def parse_instructions(text) -> dict:
     provenance chase needs. Malformed lines are skipped."""
     defs = {}
     for line in (text or "").splitlines():
+        line = _COMMENT_RE.sub("", line)
         m = _DEF_RE.match(line)
         if not m:
             continue
@@ -253,6 +259,7 @@ def parse_collectives(text) -> list:
         return out
     for line in lines:
         try:
+            line = _COMMENT_RE.sub("", line)
             m = _COLL_RE.search(line)
             if not m:
                 continue

@@ -145,7 +145,7 @@ class CaptureWindow:
 
         ``sync``: optional zero-arg barrier called ONLY when this mark
         triggers the stop, BEFORE the trace closes. Through an async
-        dispatch path the host mark runs ahead of the device (a relay
+        dispatch path the host mark runs ahead of the device (dispatch
         returns at enqueue), so without a barrier the window could
         close with its own steps still in flight and under-count busy
         time. Pass a host value fetch of the step's result (bench

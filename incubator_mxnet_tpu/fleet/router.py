@@ -129,6 +129,30 @@ class ReplicaSet:
             spec.setdefault("host", self.host)
         return spec
 
+    def _check_workers_can_have_devices(self):
+        """A spawned worker opens the accelerator for itself, and a TPU
+        chip belongs to one process at a time — the first process takes
+        every local chip. So on a TPU host at most ONE spawned worker can
+        ever become ready, and none while this process holds the chips:
+        say so now, instead of hanging on a ready line. Asked of a child
+        process, so that this parent stays off the device its workers
+        need. In-process replicas (`spawn=False`) are the form for one
+        chip, and for several: one process can drive them all."""
+        from .. import context
+        if os.environ.get("JAX_PLATFORMS") == "cpu":
+            return                      # CPU workers share nothing
+        refusal = ("spawned fleet workers each need the accelerator to "
+                   "themselves, and a TPU chip belongs to one process at "
+                   "a time: {why}. Use in-process replicas (spawn=False)")
+        if context.holds_accelerator():
+            raise RuntimeError(refusal.format(
+                why="this process already holds the chips"))
+        platform, kind, count = context.devices_seen_by_a_child()
+        if platform == "tpu" and self.n > 1:
+            raise RuntimeError(refusal.format(
+                why=f"{self.n} workers on a host where the first takes "
+                    f"all {count} chip(s) ({kind})"))
+
     def _spawn_one(self, name, timeout=600.0):
         """Spawn one worker process and block on its readiness
         handshake (model freeze + warmup happen before the ready line,
@@ -178,6 +202,7 @@ class ReplicaSet:
     def start(self):
         """Freeze + start every replica; returns the replica list."""
         if self.spawn:
+            self._check_workers_can_have_devices()
             # replica 0 alone first: its cache stores must land before
             # the rest warm up, or every replica pays the compile
             self.replicas.append(self._spawn_one(f"{self.name}0"))

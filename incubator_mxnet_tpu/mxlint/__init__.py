@@ -10,7 +10,7 @@ Two halves, one contract (docs/mxlint.md):
   resolution order, counter names drifting from the family tables,
   raises inside never-raise parsers, raw device-kind comparisons,
   unlocked writes to thread-shared module state, and duplicated default
-  tables. ``tools/mxlint.py --check`` gates auto_guard/auto_sweep on a
+  tables. ``tools/mxlint.py --check`` gates on a
   clean tree; ``mxdiag.py lint`` renders the findings report.
 * **runtime** (:mod:`.runtime`, armed by ``MXTPU_STRICT=1``) — a
   strict-mode auditor over the steady train/serve loop:

@@ -74,6 +74,9 @@ RAW_ENV_ALLOWLIST = {
     "MXTPU_MEMSCOPE": {
         "reason": "import-time arming knob (memscope enable_from_env)",
         "files": ("memscope/__init__.py",)},
+    "MXTPU_FLEETSCOPE": {
+        "reason": "import-time arming knob (fleetscope enable_from_env)",
+        "files": ("fleetscope/__init__.py",)},
     "MXTPU_STRICT": {
         "reason": "import-time arming knob (mxlint.runtime "
                   "enable_from_env)",
@@ -462,9 +465,9 @@ class ThreadSharedMutationRule(Rule):
 
 class DuplicatedDefaultTableRule(Rule):
     id = "duplicated-default-table"
-    hint = ("keep ONE home for the table and import it (the PR 13 "
-            "perf_sweep/bench DEFAULT_BATCH drift is the cautionary "
-            "tale); if the copies are genuinely independent, suppress "
+    hint = ("keep ONE home for the table and import it (PR 13's "
+            "DEFAULT_BATCH, copied into a tool, drifted from bench.py's); "
+            "if the copies are genuinely independent, suppress "
             "with a reason")
 
     MIN_ENTRIES = 4

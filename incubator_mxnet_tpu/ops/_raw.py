@@ -12,6 +12,7 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 
 # ---------------------------------------------------------------------------
@@ -313,23 +314,6 @@ def upsampling(x, scale=2, sample_type="nearest", layout="NCHW"):
 # pooling
 # ---------------------------------------------------------------------------
 
-def _maxpool_ncs(x, kernel, stride, pad, hi_extra=None):
-    """Max pool on (N, C, *spatial) via dilated patches (jit-differentiable)."""
-    import numpy as _np
-    nsp = x.ndim - 2
-    hi_extra = hi_extra or [0] * nsp
-    if any(pad) or any(hi_extra):
-        neg = jnp.asarray(jnp.finfo(x.dtype).min if jnp.issubdtype(x.dtype, jnp.floating)
-                          else jnp.iinfo(x.dtype).min, x.dtype)
-        pw = [(0, 0), (0, 0)] + [(p, p + h) for p, h in zip(pad, hi_extra)]
-        x = jnp.pad(x, pw, constant_values=neg)
-    patches = lax.conv_general_dilated_patches(x, tuple(kernel), tuple(stride), "VALID")
-    c = x.shape[1]
-    k = int(_np.prod(kernel))
-    out_sp = patches.shape[2:]
-    return patches.reshape((x.shape[0], c, k) + out_sp).max(axis=2)
-
-
 def pooling(x, pool_type="max", kernel=(2, 2), stride=None, pad=None,
             global_pool=False, count_include_pad=True, layout="NCHW",
             ceil_mode=False):
@@ -368,15 +352,15 @@ def pooling(x, pool_type="max", kernel=(2, 2), stride=None, pad=None,
         strides[a] = stride[i]
         pads[a] = (pad[i], pad[i] + hi_extra[i])
     if pool_type == "max":
-        # Patch-extraction + max: reduce_window(max) has no linearization
-        # rule under jit in this jax, and patches feed the same XLA fusion.
-        if channels_last:
-            perm = (0, x.ndim - 1) + tuple(range(1, x.ndim - 1))
-            xc = jnp.transpose(x, perm)
-            y = _maxpool_ncs(xc, kernel, stride, pad, hi_extra)
-            back = (0,) + tuple(range(2, x.ndim)) + (1,)
-            return jnp.transpose(y, back)
-        return _maxpool_ncs(x, kernel, stride, pad, hi_extra)
+        # reduce_window, NOT patch extraction: patches are a convolution,
+        # and on the TPU a float32 convolution takes bfloat16 operands —
+        # the pooled values came back rounded, and the padding value
+        # (finfo.min, -inf in bfloat16) times the patch kernel's zeros was
+        # NaN (first chip run of a float32 ResNet-50, PR 22)
+        lowest = -np.inf if jnp.issubdtype(x.dtype, jnp.floating) \
+            else np.iinfo(x.dtype).min
+        return lax.reduce_window(x, np.array(lowest, x.dtype), lax.max,
+                                 window, strides, pads)
     if pool_type in ("avg", "sum"):
         s = lax.reduce_window(x, jnp.asarray(0, x.dtype), lax.add,
                               window, strides, pads)

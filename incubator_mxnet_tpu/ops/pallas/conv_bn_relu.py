@@ -31,21 +31,13 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:
-    from jax.experimental.pallas import tpu as pltpu
-    _VMEM = pltpu.VMEM
-except Exception:  # pragma: no cover
-    pltpu = None
-    _VMEM = None
+from jax.experimental.pallas import tpu as pltpu
 
 __all__ = ["scale_shift_act", "conv_bn_relu", "fold_bn"]
 
 
 def _vspec(shape, index_map):
-    if _VMEM is None:
-        return pl.BlockSpec(shape, index_map)
-    return pl.BlockSpec(shape, index_map, memory_space=_VMEM)
+    return pl.BlockSpec(shape, index_map, memory_space=pltpu.VMEM)
 
 
 def _apply_act(y, act):
@@ -184,8 +176,6 @@ def _mm_epilogue(x2, w2, scale, shift, act, interpret):
     nk = k // bk
     s2 = scale.reshape(1, n).astype(jnp.float32)
     b2 = shift.reshape(1, n).astype(jnp.float32)
-    if pltpu is None:  # pragma: no cover — no pallas TPU support built in
-        raise NotImplementedError("pallas TPU backend unavailable")
     scratch = [pltpu.VMEM((bm, bn), jnp.float32)]
     out = pl.pallas_call(
         functools.partial(_mm_kernel, nk=nk, act=act),

@@ -12,20 +12,13 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:
-    from jax.experimental.pallas import tpu as pltpu
-    _VMEM = pltpu.VMEM
-except Exception:  # pragma: no cover
-    _VMEM = None
+from jax.experimental.pallas import tpu as pltpu
 
 __all__ = ["layer_norm"]
 
 
 def _vspec(shape, index_map):
-    if _VMEM is None:
-        return pl.BlockSpec(shape, index_map)
-    return pl.BlockSpec(shape, index_map, memory_space=_VMEM)
+    return pl.BlockSpec(shape, index_map, memory_space=pltpu.VMEM)
 
 
 def _ln_kernel(x_ref, g_ref, b_ref, o_ref, *, eps):

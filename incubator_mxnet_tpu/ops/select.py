@@ -44,6 +44,9 @@ conv_bn_relu     pallas enabled; inference-style BN (moving stats);
                  matmul+epilogue kernel, any other geometry keeps the
                  XLA conv and fuses only the epilogue
 ===============  =========================================================
+
+Every row also needs a program on ONE device: inside a program that
+GSPMD partitions over a mesh (:class:`partitioned`) no kernel qualifies.
 """
 from __future__ import annotations
 
@@ -52,7 +55,8 @@ import threading
 from .. import profiler as _prof
 
 __all__ = ["flash_attention", "layer_norm", "scale_shift_act",
-           "conv_bn_relu", "capture", "quiet", "selection_table"]
+           "conv_bn_relu", "capture", "quiet", "partitioned",
+           "selection_table"]
 
 _tls = threading.local()
 
@@ -89,6 +93,29 @@ class quiet:
         return False
 
 
+class partitioned:
+    """Mark what this thread traces inside the scope as ONE program that
+    GSPMD partitions over `mesh` (FusedTrainStep(mesh=...), FrozenModel(
+    mesh=...)). jax cannot partition a Mosaic kernel by itself — the
+    lowering raises "Mosaic kernels cannot be automatically partitioned.
+    Please wrap the call in a shard_map" — so over more than one device
+    every kernel is rejected (counted, with this reason) and the XLA
+    formulation, which GSPMD can split, is traced instead. `mesh=None`
+    and a one-device mesh change nothing."""
+
+    def __init__(self, mesh):
+        self._size = int(mesh.size) if mesh is not None else 1
+
+    def __enter__(self):
+        self._prev = getattr(_tls, "mesh_size", 1)
+        _tls.mesh_size = self._size
+        return self
+
+    def __exit__(self, *exc):
+        _tls.mesh_size = self._prev
+        return False
+
+
 def _decide(kernel: str, ok: bool, reason: str) -> bool:
     if not getattr(_tls, "quiet", False):
         _prof.counter(
@@ -101,9 +128,15 @@ def _decide(kernel: str, ok: bool, reason: str) -> bool:
     return ok
 
 
-def _enabled():
+def _open(kernel: str) -> bool:
+    """May a kernel be selected here at all? The master switch, then the
+    program being traced (see :class:`partitioned`)."""
     from . import pallas as _pallas
-    return _pallas.enabled()
+    if not _pallas.enabled():
+        return False
+    if getattr(_tls, "mesh_size", 1) > 1:
+        return _decide(kernel, False, "multi-device GSPMD program")
+    return True
 
 
 def _on_tpu():
@@ -114,7 +147,7 @@ def _on_tpu():
 def flash_attention(mask, dropout_active: bool) -> bool:
     """Qualify the pallas flash-attention kernel for a multihead-attention
     call (O(L) memory, scores stay in VMEM)."""
-    if not _enabled():
+    if not _open("flash_attention"):
         return False
     if mask is not None:
         return _decide("flash_attention", False, "explicit mask")
@@ -125,7 +158,7 @@ def flash_attention(mask, dropout_active: bool) -> bool:
 
 def layer_norm(x, gamma, axis) -> bool:
     """Qualify the fused pallas layernorm (one HBM pass, f32 stats)."""
-    if not _enabled():
+    if not _open("layer_norm"):
         return False
     if axis not in (-1, x.ndim - 1) or gamma.ndim != 1:
         return _decide("layer_norm", False, "non-last-axis")
@@ -145,7 +178,7 @@ def scale_shift_act(x, channel_axis, act=None) -> bool:
     BatchNorm[+ReLU] tail as one HBM pass) — channels-last layouts only;
     the per-channel scale/shift broadcast along the last axis maps onto
     lanes."""
-    if not _enabled():
+    if not _open("scale_shift_act"):
         return False
     if act not in _EPILOGUE_ACTS:
         return _decide("scale_shift_act", False, f"act {act!r}")
@@ -162,7 +195,7 @@ def conv_bn_relu(x, weight, stride, pad, dilate, num_group,
     """Qualify the fused conv+BN+relu path (inference hot path: the conv
     epilogue applies the folded BN scale/shift + relu in one pass; 1x1
     convs run entirely as a fused pallas matmul)."""
-    if not _enabled():
+    if not _open("conv_bn_relu"):
         return False
     if act not in _EPILOGUE_ACTS:
         return _decide("conv_bn_relu", False, f"act {act!r}")

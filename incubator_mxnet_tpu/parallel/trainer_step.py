@@ -25,6 +25,7 @@ from ..io.pipeline import TRANSFER_GATE as _TRANSFER_GATE
 from ..io.pipeline import _defer_put_needed as _cpu_serial_client
 from ..ndarray import NDArray
 from ..ndarray import random as ndrandom
+from ..ops import select as _select
 from .. import optimizer as opt_mod
 from . import fsdp as _fsdp
 from . import sharding as _sharding
@@ -188,7 +189,7 @@ class FusedTrainStep:
     def _f32(self, name, v):
         """Device scalar for a hyperparameter, one slot per name: lr/wd/
         rescale rarely change, and re-uploading three host scalars every
-        step is measurable latency through a remote dispatch relay. A
+        step is measurable host latency on the dispatch path. A
         per-step-varying scheduler just overwrites its slot (O(1) memory,
         never evicts the constant hyperparameters)."""
         v = float(v)
@@ -247,7 +248,8 @@ class FusedTrainStep:
                 for j, i in enumerate(aux_idx):
                     sub[ids[i]] = aux_raws[j]
                 with _ParamTraceScope(sub), autograd._Scope(False, True), \
-                        ndrandom._TraceKeyScope(key):
+                        ndrandom._TraceKeyScope(key), \
+                        _select.partitioned(self.mesh):
                     out = net.forward(NDArray(xb))
                     loss = loss_fn(out, NDArray(yb))
                     loss_raw = jnp.mean(loss._data)
@@ -348,8 +350,8 @@ class FusedTrainStep:
     def _build_k(self):
         """Wrap the same step_fn in a lax.scan over a leading micro-step
         axis: k fwd+bwd+collective+update iterations inside ONE XLA
-        program. Through a remote dispatch relay (or any host-limited
-        launch path) this amortizes per-step latency by k — the chip runs
+        program. On a host-limited launch path this amortizes per-step
+        latency by k — the chip runs
         micro-steps back-to-back instead of idling between dispatches.
 
         lr is PER MICRO-STEP: either computed in-program from the step
@@ -437,6 +439,24 @@ class FusedTrainStep:
         if self._jitted is None:
             self._resolve(x, y)
         return self
+
+    def lower(self, x, y):
+        """The single-step program lowered for this batch signature, for
+        inspection: ``.compile()`` gives ``as_text()`` (which kernels and
+        collectives the compiler kept) and ``memory_analysis()`` (bytes
+        per device). Reads shapes only — no update is consumed and no
+        buffer is donated."""
+        self.ensure_built(x, y)
+        f32, i32 = jnp.float32(0), jnp.int32(0)
+        args = ([self.params[i].data()._data for i in self.train_idx],
+                [self.params[i].data()._data for i in self.aux_idx],
+                self._states, jax.random.PRNGKey(0), f32, f32, i32, f32,
+                x._data if isinstance(x, NDArray) else x,
+                y._data if isinstance(y, NDArray) else y)
+        specs = jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), args)
+        with _select.quiet():  # inspection must not count as a selection
+            return self._jitted.lower(*specs)
 
     # -- execution --------------------------------------------------------
     def __call__(self, x, y):
@@ -527,8 +547,7 @@ class FusedTrainStep:
     def run_k(self, xs, ys):
         """Run k optimizer micro-steps as ONE compiled XLA program (a
         lax.scan over the leading axis) — k× fewer host dispatches, so a
-        slow launch path (e.g. a remote device relay) no longer bounds
-        step time. xs/ys: stacked (k, batch, ...) arrays, or lists of k
+        slow launch path no longer bounds step time. xs/ys: stacked (k, batch, ...) arrays, or lists of k
         per-step batches. lr is per micro-step (host-sampled table, or
         computed in-program under schedule_in_program), so schedulers
         advance step-for-step exactly like a sequential loop. Returns the

@@ -9,9 +9,9 @@ slow and what fixing it would buy**:
   (HybridBlock jit cache, FusedTrainStep, TrainLoop chunks, FrozenModel
   serving buckets) captures XLA ``cost_analysis()`` FLOPs/bytes per
   executable and derives an analytic roofline verdict — compute-bound,
-  HBM-bound, trivially small, or unknown — against per-device peak
-  tables (v5e/v4/v5p/CPU fallback, ``MXTPU_PEAK_FLOPS``/``MXTPU_PEAK_BW``
-  overrides). Verdicts land in the flight recorder's compile spans and
+  HBM-bound, trivially small, or unknown — against the per-device peak
+  table (v5e/v4/v5p; a device that is not in it has no peaks and gets
+  "unknown"). Verdicts land in the flight recorder's compile spans and
   the ``perfscope.*`` counter family.
 * **step-time decomposition** (:mod:`.decomp`) — the per-step budget
   ``step_ms = device_compute + collective + input_wait + host_gap +

@@ -4,9 +4,10 @@ of the reference's src/engine + src/storage + src/io prefetcher for
 host-side work (device compute is scheduled by XLA's async dispatch).
 
 The .so is built on first import with g++ (no pybind11 — plain C API via
-ctypes). If the toolchain is unavailable everything degrades to functional
+ctypes). On a machine WITHOUT a compiler everything degrades to functional
 pure-Python equivalents, so the framework never hard-depends on the native
-layer. `native_available()` reports which path is live.
+layer; a compiler that is there and FAILS raises with its stderr.
+`native_available()` reports which path is live.
 """
 from __future__ import annotations
 
@@ -77,7 +78,9 @@ def _build_so(src_name, so_path, extra_flags=()):
     """First-use g++ build of a native component: compiles to a pid-unique
     temp file and os.replace()s it into place (atomic on POSIX), so
     concurrent importers (pytest-xdist, DataLoader workers) never observe
-    a partially written .so. Returns the loaded CDLL or None.
+    a partially written .so. Returns the loaded CDLL, or None where there
+    is no g++ to run (the pure-Python engine takes over); a g++ that runs
+    and fails is a broken build, and raises with the compiler's stderr.
 
     Two passes: a concurrent process sharing the cache dir (e.g. a
     different package version doing its stale-sibling cleanup) can unlink
@@ -88,16 +91,20 @@ def _build_so(src_name, so_path, extra_flags=()):
             src = os.path.join(_DIR, "src", src_name)
             tmp = f"{so_path}.tmp.{os.getpid()}"
             try:
-                subprocess.run(["g++", "-O2", "-std=c++17", "-fPIC",
-                                "-shared", "-o", tmp, src, *extra_flags],
-                               check=True, capture_output=True, timeout=120)
+                r = subprocess.run(["g++", "-O2", "-std=c++17", "-fPIC",
+                                    "-shared", "-o", tmp, src, *extra_flags],
+                                   capture_output=True, text=True,
+                                   timeout=120)
+                if r.returncode:
+                    raise RuntimeError(
+                        f"g++ failed (exit {r.returncode}) building "
+                        f"{src_name}:\n{r.stderr[-2000:]}")
                 os.replace(tmp, so_path)
-            except Exception:
-                try:
-                    os.unlink(tmp)
-                except OSError:
-                    pass
+            except FileNotFoundError:       # no compiler on this machine
                 return None
+            finally:
+                if os.path.exists(tmp):
+                    os.unlink(tmp)
         try:
             return ctypes.CDLL(so_path)
         except OSError:
@@ -530,8 +537,9 @@ class TokenQueue:
 # ---------------------------------------------------------------------------
 # native JPEG decode (src/imgdec.cc, its own .so linked against libjpeg):
 # GIL-free decompression for the record-IO pipeline — the rebuild of the
-# reference's opencv decode in src/io/iter_image_recordio_2.cc. Missing
-# toolchain/libjpeg only disables this path; callers fall back to PIL.
+# reference's opencv decode in src/io/iter_image_recordio_2.cc. A machine
+# without g++ only disables this path (callers fall back to PIL); a build
+# that fails — a missing libjpeg included — raises, as _build_so says.
 # ---------------------------------------------------------------------------
 
 _IMG_SO = _so_path("libmxtpu_imgdec", "imgdec.cc")

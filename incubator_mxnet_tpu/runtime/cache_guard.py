@@ -22,12 +22,16 @@ and the guard disables the persistent cache for the process (with a
 warning and a `compile_cache.guard_tripped` counter) instead of letting
 training proceed on a broken executable.
 
-`FusedTrainStep` runs the check before its first build; bench.py arms it
-right after backend init. MXTPU_CACHE_GUARD=0 skips the check (trust the
-cache).
+`FusedTrainStep` runs the check before its first build; bench.py and
+chip_smoke.py arm it right after backend init. MXTPU_CACHE_GUARD=0 skips
+the check (trust the cache).
+
+:func:`use_compile_cache` is the one place an entry point (bench.py,
+chip_smoke.py) chooses where the cache lives.
 """
 from __future__ import annotations
 
+import os
 import threading
 import warnings
 from contextlib import contextmanager
@@ -35,10 +39,27 @@ from contextlib import contextmanager
 import numpy as np
 
 __all__ = ["check", "verdict", "donated_read_quarantine",
-           "_reset_for_tests"]
+           "use_compile_cache", "_reset_for_tests"]
 
 # None = not yet checked; True = cache ok (or not in use); False = tripped
 _VERDICT = None
+
+
+def use_compile_cache(checkout: str) -> str:
+    """Turn the persistent compile cache on and return its directory.
+
+    ``JAX_COMPILATION_CACHE_DIR``, when set, is jax's own setting and the
+    only one: nothing is set in code. Otherwise the cache lives at the
+    fixed ``<checkout>/.jax_cache`` — the path is part of the cache key,
+    so a directory named after a pid, a time or a temporary name would
+    never hit."""
+    import jax
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        jax.config.update("jax_compilation_cache_dir",
+                          os.path.join(checkout, ".jax_cache"))
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
+    return jax.config.jax_compilation_cache_dir
 
 # -- donated-executable read quarantine (PR 17) ---------------------------
 #
@@ -65,10 +86,12 @@ def _install_read_filter():
         return
     real_get = cc.get_executable_and_time
 
-    def _filtered_get(cache_key, compile_options, backend):
+    def _filtered_get(cache_key, compile_options, backend,
+                      executable_devices):
         if getattr(_READ_QUARANTINE, "on", False):
             return None, None        # forced miss -> fresh backend compile
-        return real_get(cache_key, compile_options, backend)
+        return real_get(cache_key, compile_options, backend,
+                        executable_devices)
 
     cc.get_executable_and_time = _filtered_get
     cc._mxtpu_donated_read_filter = real_get
@@ -111,12 +134,8 @@ def _disabled_by_env():
 
 def _cache_active():
     import jax
-    try:
-        enabled = bool(jax.config.jax_enable_compilation_cache)
-        cache_dir = jax.config.jax_compilation_cache_dir
-    except AttributeError:          # much older jax: no persistent cache
-        return False
-    return enabled and bool(cache_dir)
+    return bool(jax.config.jax_enable_compilation_cache
+                and jax.config.jax_compilation_cache_dir)
 
 
 def _run() -> bool:
@@ -133,13 +152,9 @@ def _run() -> bool:
     # the size/time thresholds for its one tiny compile, restore after
     overrides = {"jax_persistent_cache_min_entry_size_bytes": -1,
                  "jax_persistent_cache_min_compile_time_secs": 0.0}
-    old = {}
+    old = {k: getattr(jax.config, k) for k in overrides}
     for k, v in overrides.items():
-        try:
-            old[k] = getattr(jax.config, k)
-            jax.config.update(k, v)
-        except Exception:  # noqa: BLE001 — knob absent on this jax
-            pass
+        jax.config.update(k, v)
     try:
         got_c, got_s = _canary_values()
         exp = _expected()
@@ -157,10 +172,7 @@ def _run() -> bool:
         return False
     finally:
         for k, v in old.items():
-            try:
-                jax.config.update(k, v)
-            except Exception:  # noqa: BLE001
-                pass
+            jax.config.update(k, v)
 
 
 def _canary_values():
@@ -208,12 +220,9 @@ def _trip(why):
         f"fresh). Detail: {why}. Delete the cache directory "
         f"({getattr(jax.config, 'jax_compilation_cache_dir', '?')}) to "
         "clear the corrupt entries.", RuntimeWarning, stacklevel=3)
-    try:
-        jax.config.update("jax_enable_compilation_cache", False)
-        from jax._src import compilation_cache as cc
-        cc.reset_cache()            # drop the already-initialized object
-    except Exception:  # noqa: BLE001 — best effort; worst case slow, not wrong
-        pass
+    jax.config.update("jax_enable_compilation_cache", False)
+    from jax._src import compilation_cache as cc
+    cc.reset_cache()                # drop the already-initialized object
     _prof.counter("compile_cache.guard_tripped").increment()
     _prof.set_gauge("compile_cache.canary_ok", 0)
 

@@ -17,24 +17,15 @@ class Feature:
 
 
 def _detect():
+    from ..ops import pallas as _pallas
     backend = jax.default_backend()
-    try:
-        from ..ops import pallas as _pallas
-        pallas_ok = _pallas.enabled()
-    except Exception:
-        pallas_ok = False
-    try:
-        from ..ops.pallas import is_tpu as _is_tpu
-        on_tpu = _is_tpu()
-    except Exception:  # noqa: BLE001
-        on_tpu = backend == "tpu"
     return {
-        "TPU": on_tpu,
+        "TPU": _pallas.is_tpu(),
         "CPU": True,
         "CUDA": backend == "gpu",          # reference flag name; XLA:GPU here
         "BF16": True,                       # native MXU dtype
         "F16C": True,
-        "PALLAS": pallas_ok,                # custom TPU kernels
+        "PALLAS": _pallas.enabled(),        # custom TPU kernels
         "DIST_MESH": len(jax.devices()) > 1,  # multi-device collectives
         "OPENCV": False,
         "BLAS_OPEN": True,                  # XLA handles BLAS

@@ -40,6 +40,7 @@ from ..gluon.block import HybridBlock, _flatten_out, _unflatten_out
 from ..gluon.parameter import DeferredInitializationError, _ParamTraceScope
 from ..ndarray import NDArray
 from ..ndarray import random as ndrandom
+from ..ops import select as _select
 from .errors import InvalidInputError, ReshardingGateError
 
 __all__ = ["FrozenModel", "default_buckets"]
@@ -218,7 +219,8 @@ class FrozenModel:
             # BN running stats are read, never written; dropout passes
             # through; nothing lands on any autograd tape
             with _ParamTraceScope(sub), autograd._Scope(False, False), \
-                    ndrandom._TraceKeyScope(key_raw):
+                    ndrandom._TraceKeyScope(key_raw), \
+                    _select.partitioned(self._mesh):
                 out = block.forward(NDArray(x_raw))
                 leaves, tree = _flatten_out(out)
             info["tree"] = tree

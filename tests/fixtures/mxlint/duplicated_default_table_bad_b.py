@@ -1,5 +1,5 @@
 """BAD fixture (pair half B): a structurally equal copy in a second
-module — the PR 13 perf_sweep/bench drift, re-enacted."""
+module — the PR 13 DEFAULT_BATCH drift, re-enacted."""
 
 MY_BATCH_TABLE = {
     "lenet": 512,

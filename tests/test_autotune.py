@@ -6,7 +6,7 @@ best-so-far, cache hit skipping the search, corrupt/stale cache entries
 rejected and counted, subprocess trial death as a counted skip (never a
 crash), and the tooling satellites (trace_check AUTOTUNE_FAMILIES +
 check_autotune_extra, perf_regress knob-diff context notes, mxdiag tune
-rendering, perf_sweep knob splitting). Search logic runs against
+rendering). Search logic runs against
 DETERMINISTIC fake measurement fixtures — no real training."""
 import importlib.util
 import json
@@ -404,10 +404,22 @@ class TestTrial:
     def test_env_failure_artifact_is_failure(self, tmp_path):
         stub = self._stub(tmp_path, (
             'print(\'{"metric": "m", "value": 0.0, '
-            '"status": "env_failure", "error": "wedged tunnel"}\')\n'))
+            '"status": "env_failure", "error": "no backend"}\')\n'))
         r = trial_mod.run_trial(KnobConfig(), bench_path=stub, timeout=30)
         assert r.status == "failed"
-        assert "wedged tunnel" in r.error
+        assert "no backend" in r.error
+
+    def test_parent_that_holds_the_chip_cannot_run_a_trial(
+            self, tmp_path, monkeypatch):
+        """One process per chip: a parent that has opened a TPU backend
+        keeps the chip from its trial's child — refused with that
+        reason, as a counted failed trial, before anything is spawned."""
+        stub = self._stub(tmp_path, "raise SystemExit('must not run')\n")
+        from incubator_mxnet_tpu import context
+        monkeypatch.setattr(context, "holds_accelerator", lambda: True)
+        r = trial_mod.run_trial(KnobConfig(), bench_path=stub, timeout=30)
+        assert r.status == "failed"
+        assert "one process at a time" in r.error
 
     def test_ok_stub_yields_measurement(self, tmp_path):
         doc = {"metric": "m", "value": 200.0, "unit": "img/s",
@@ -836,18 +848,3 @@ class TestMxdiagTune:
                "extra": {"autotune": extra}}
         md.print_tune(doc)
         assert "OVERRODE" in capsys.readouterr().out
-
-
-class TestPerfSweepSplit:
-    def test_split_knobs(self):
-        ps = _load_tool("perf_sweep")
-        cfg, extras = ps._split_knobs({"BENCH_LOOP_CHUNK": "8",
-                                       "BENCH_REMAT": "1",
-                                       "BENCH_BATCH": "256",
-                                       "BENCH_K": "1",
-                                       "BENCH_S2D": "1"})
-        assert cfg.loop_chunk == 8 and cfg.remat and cfg.batch == 256
-        assert extras == {"BENCH_K": "1", "BENCH_S2D": "1"}
-        cfg2, extras2 = ps._split_knobs({"BENCH_STEPS": "20"})
-        assert cfg2 is None                # warm run: NO knob env
-        assert extras2 == {"BENCH_STEPS": "20"}

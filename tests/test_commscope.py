@@ -195,6 +195,19 @@ class TestParseCollectives:
         colls = {c["name"]: c for c in hlo.parse_collectives(_HLO_FIXTURE)}
         assert colls["all-to-all.3"]["result_bytes"] == 2 * 4 * 4
 
+    def test_long_tuple_with_index_comments(self):
+        # XLA marks every fifth element of a long tuple "/*index=5*/":
+        # the combined gradient all-reduce of a dp step is such a tuple
+        txt = ("  %all-reduce.49 = (f32[], f32[8]{0}, f32[8]{0}, f32[8]{0},"
+               " f32[8]{0}, /*index=5*/f32[4]{0}) all-reduce(%a, %b, %c, "
+               "%d, %e, %f), channel_id=1, replica_groups=[1,4]<=[4], "
+               "to_apply=%add\n")
+        colls = hlo.parse_collectives(txt)
+        assert [c["kind"] for c in colls] == ["all-reduce"]
+        assert colls[0]["result_bytes"] == 4 + 4 * 8 * 4 + 4 * 4
+        assert hlo.parse_instructions(txt)["all-reduce.49"][0] == \
+            "all-reduce"
+
     def test_collective_broadcast_buckets_as_other(self):
         txt = ("  %collective-broadcast = f32[8]{0} "
                "collective-broadcast(f32[8]{0} %x), channel_id=9\n")

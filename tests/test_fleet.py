@@ -376,6 +376,27 @@ def test_deploy_swaps_every_replica_with_zero_drops(fleet, tmp_path):
     assert c.get("fleet/fleet.readmits", 0) == 2
 
 
+@pytest.mark.parametrize("holds,why", [
+    (False, "2 workers on a host where the first takes all 1 chip"),
+    (True, "this process already holds the chips")])
+def test_spawned_workers_refused_where_they_cannot_have_a_chip(
+        monkeypatch, holds, why):
+    """One process per chip: on a TPU host spawned replicas would hang on
+    their ready line for ten minutes — refused before anything spawns."""
+    from incubator_mxnet_tpu import context
+    monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+    monkeypatch.setattr(context, "holds_accelerator", lambda: holds)
+    monkeypatch.setattr(context, "devices_seen_by_a_child",
+                        lambda: ("tpu", "TPU v5 lite", 1))
+    rset = ReplicaSet({"model": "lenet", "input_shape": [1, 28, 28]},
+                      n=2, spawn=True)
+    monkeypatch.setattr(rset, "_spawn_one", lambda *a, **k: pytest.fail(
+        "a worker was spawned"))
+    with pytest.raises(RuntimeError, match="one process at a time") as ei:
+        rset.start()
+    assert why in str(ei.value) and "spawn=False" in str(ei.value)
+
+
 def test_model_server_continuous_batcher_knob(frozen):
     srv = ModelServer(frozen, batcher="continuous")
     assert isinstance(srv.batcher, ContinuousBatcher)

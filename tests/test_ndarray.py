@@ -234,6 +234,18 @@ def test_copy_context():
     assert_close(d.asnumpy(), np.ones((2, 2)))
 
 
+def test_tpu_context_means_a_tpu():
+    """`mx.tpu()` is platform 'tpu' and nothing else: on this CPU it
+    raises where it is resolved — no stand-in device, no CPU fallback —
+    and the default context says what the backend really is."""
+    assert mx.context.num_tpus() == 0
+    assert mx.current_context().device_type == "cpu"
+    with pytest.raises(ValueError, match="No device of type 'tpu'"):
+        nd.ones((2, 2), ctx=mx.tpu(0))
+    with pytest.raises(ValueError, match="No device of type 'tpu'"):
+        nd.ones((2, 2)).as_in_context(mx.tpu())
+
+
 def test_sequence_mask():
     data = nd.ones((4, 2, 3))  # (seq, batch, feat)
     out = nd.sequence_mask(data, nd.array([2, 3]), use_sequence_length=True, value=0)

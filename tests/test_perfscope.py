@@ -45,17 +45,26 @@ def _counters(prefix="perfscope/"):
 # roofline classification
 # ---------------------------------------------------------------------------
 
+@pytest.fixture
+def v5e_table(monkeypatch):
+    """Steer the code that looks the device up itself (record_program,
+    StepBudget.finish, bench_extra) to the v5e row for one test."""
+    from incubator_mxnet_tpu.perfscope import cost
+    monkeypatch.setattr(cost, "device_peaks", lambda device=None: dict(V5E))
+    monkeypatch.setattr(ps, "device_peaks", lambda device=None: dict(V5E))
+
+
 class TestClassify:
     def test_compute_bound(self):
         # AI far above any ridge
-        r = ps.classify(1e12, 1e6)
+        r = ps.classify(1e12, 1e6, V5E)
         assert r["verdict"] == "compute_bound"
         assert r["ai"] == pytest.approx(1e6)
         assert r["est_compute_ms"] > 0
 
     def test_hbm_bound(self):
         # 1 FLOP per byte is below every ridge in the table
-        r = ps.classify(1e9, 1e9)
+        r = ps.classify(1e9, 1e9, V5E)
         assert r["verdict"] == "hbm_bound"
         assert r["ai"] == pytest.approx(1.0)
 
@@ -77,13 +86,13 @@ class TestClassify:
 
     def test_flops_without_bytes_is_compute_bound(self):
         # real FLOPs, zero reported traffic -> compute is the only ceiling
-        r = ps.classify(1e10, 0)
+        r = ps.classify(1e10, 0, V5E)
         assert r["verdict"] == "compute_bound"
         assert r["ai"] is None
 
     def test_trivial_threshold_env_override(self, monkeypatch):
         monkeypatch.setenv("MXTPU_PERFSCOPE_TRIVIAL_FLOPS", "1")
-        assert ps.classify(100.0, 1e12)["verdict"] == "hbm_bound"
+        assert ps.classify(100.0, 1e12, V5E)["verdict"] == "hbm_bound"
 
     def test_verdict_taxonomy_is_closed(self):
         for args in ((1e12, 1e6), (1e9, 1e9), (0, 0), (None, None)):
@@ -95,11 +104,24 @@ class _FakeDevice:
         self.device_kind = kind
 
 
+# a table for verdict tests that run on the CPU, which has none of its own
+V5E = {"device_kind": "tpu v5 lite", "table_row": "v5e",
+       "peak_flops_f32": 99e12, "peak_flops_bf16": 197e12,
+       "hbm_bytes_per_s": 819e9}
+
+
 class TestPeaks:
-    def test_cpu_fallback(self):
+    def test_unknown_device_has_no_peaks(self):
+        """The CPU is not in the table: no peaks, no ridge, no verdict
+        beyond "trivial" — never a stand-in row."""
         p = ps.device_peaks()
-        assert p["table_row"] == "cpu"
-        assert p["peak_flops_f32"] > 0 and p["hbm_bytes_per_s"] > 0
+        assert p["device_kind"] == "cpu" and p["table_row"] is None
+        assert p["peak_flops_f32"] is None and p["peak_flops_bf16"] is None
+        assert p["hbm_bytes_per_s"] is None
+        rec = ps.classify(1e12, 1e6)
+        assert rec["verdict"] == "unknown" and rec["ridge"] is None
+        assert rec["est_compute_ms"] is None and rec["peak_flops"] is None
+        assert ps.classify(10.0, 10.0)["verdict"] == "trivial"
 
     @pytest.mark.parametrize("kind,row", [
         ("TPU v5 lite", "v5e"),       # what jax reports for a v5e
@@ -107,7 +129,7 @@ class TestPeaks:
         ("TPU v5e", "v5e"),
         ("TPU v4", "v4"),
         ("TPU v5p", "v5p"),           # must not fall into the v5e row
-        ("weird accelerator", "cpu"),
+        ("weird accelerator", None),
     ])
     def test_device_kind_matching(self, kind, row):
         p = ps.device_peaks(_FakeDevice(kind))
@@ -120,21 +142,18 @@ class TestPeaks:
         assert p["peak_flops_bf16"] == pytest.approx(197e12)
         assert p["peak_flops_f32"] == pytest.approx(99e12)
 
-    def test_env_overrides(self, monkeypatch):
+    def test_environment_cannot_invent_peaks(self, monkeypatch):
+        """The retired MXTPU_PEAK_* overrides let any backend print an
+        "MFU"; they are gone, so setting them changes nothing."""
         monkeypatch.setenv("MXTPU_PEAK_FLOPS", "123e12")
         monkeypatch.setenv("MXTPU_PEAK_BW", "456e9")
-        p = ps.device_peaks()
-        assert p["peak_flops_f32"] == pytest.approx(123e12)
-        assert p["peak_flops_bf16"] == pytest.approx(123e12)
-        assert p["hbm_bytes_per_s"] == pytest.approx(456e9)
+        assert ps.device_peaks()["peak_flops_f32"] is None
+        assert ps.device_peaks(_FakeDevice("TPU v4"))["peak_flops_bf16"] \
+            == pytest.approx(275e12)
 
-    def test_malformed_env_overrides_never_raise(self, monkeypatch):
-        monkeypatch.setenv("MXTPU_PEAK_FLOPS", "197 Tf")
-        monkeypatch.setenv("MXTPU_PEAK_BW", "lots")
+    def test_malformed_trivial_threshold_never_raises(self, monkeypatch):
         monkeypatch.setenv("MXTPU_PERFSCOPE_TRIVIAL_FLOPS", "tiny")
-        p = ps.device_peaks()                       # table kept
-        assert p["peak_flops_f32"] > 0
-        assert ps.classify(1e12, 1e6)["verdict"] == "compute_bound"
+        assert ps.classify(1e12, 1e6, V5E)["verdict"] == "compute_bound"
         ps.record_program("t_env", 1e12, 1e6)       # never raises
 
     def test_bf16_uses_doubled_peak(self):
@@ -149,7 +168,7 @@ class TestPeaks:
 # ---------------------------------------------------------------------------
 
 class TestAnalyze:
-    def test_matmul_lowered(self):
+    def test_matmul_lowered(self, v5e_table):
         ps.enable()
         lowered = jax.jit(lambda a, b: (a @ b).sum()).lower(
             jax.ShapeDtypeStruct((256, 256), jnp.float32),
@@ -206,7 +225,7 @@ class TestAnalyze:
         mxdiag = _load_tool("mxdiag")
         mxdiag.print_flight(doc, 10)
 
-    def test_last_analysis_wins_per_name(self):
+    def test_last_analysis_wins_per_name(self, v5e_table):
         ps.enable()
         ps.record_program("t_dup", 1e12, 1e6)
         ps.record_program("t_dup", 1e9, 1e9)
@@ -335,7 +354,7 @@ class TestCompileSites:
 # ---------------------------------------------------------------------------
 
 class TestStepBudget:
-    def test_components_sum_to_step(self):
+    def test_components_sum_to_step(self, v5e_table):
         ps.enable()
         f = jax.jit(lambda a: a @ a)
         x = jnp.ones((64, 64))
@@ -398,7 +417,7 @@ class TestStepBudget:
         h = prof.counters()["perfscope/perfscope.device_step_ms"]
         assert h["count"] == 4
 
-    def test_mfu_counterfactuals(self):
+    def test_mfu_counterfactuals(self, v5e_table):
         ps.enable()
         budget = ps.StepBudget().begin()
         prof.counter("io.wait_ms", "io").increment(200.0)  # 50 ms/step
@@ -407,6 +426,18 @@ class TestStepBudget:
         # removing 50 ms of input wait from a 100 ms step doubles MFU
         assert d["mfu_if_removed"]["input_wait"] == \
             pytest.approx(2 * d["mfu"], rel=1e-3)
+
+    def test_no_mfu_on_a_device_without_peaks(self):
+        """This CPU has no row in the peak table: the budget still
+        decomposes the step, and reports no utilisation at all."""
+        ps.enable()
+        budget = ps.StepBudget().begin()
+        budget.end(steps=4, steady_s=0.4)
+        d = budget.finish(model_flops_per_step=1e9)
+        assert d["step_ms"] == pytest.approx(100.0)
+        assert d["mfu"] is None and d["peak_flops"] is None
+        assert d["mfu_device_only"] is None
+        assert set(d["mfu_if_removed"].values()) == {None}
 
 
 class TestKVStoreCollectiveCounter:
@@ -688,7 +719,7 @@ class TestMxdiagPerf:
 # ---------------------------------------------------------------------------
 
 class TestBenchExtra:
-    def test_payload_shape_validates(self):
+    def test_payload_shape_validates(self, v5e_table):
         ps.enable()
         lowered = jax.jit(lambda a, b: a @ b).lower(
             jax.ShapeDtypeStruct((64, 64), jnp.float32),

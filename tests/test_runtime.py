@@ -15,6 +15,20 @@ def test_native_builds():
     assert runtime.native_available(), "C++ runtime failed to build"
 
 
+def test_failed_native_build_raises_with_compiler_stderr(tmp_path):
+    """A compiler that is there and fails is a broken build: loud, with
+    g++'s own words — never a quiet switch to the Python engine."""
+    with pytest.raises(RuntimeError, match=r"g\+\+ failed.*no_such_source"):
+        runtime._build_so("no_such_source.cc", str(tmp_path / "x.so"))
+    assert not list(tmp_path.iterdir())          # no temp file left behind
+
+
+def test_no_compiler_means_python_engine(tmp_path, monkeypatch):
+    """Only a machine without g++ gets the pure-Python equivalents."""
+    monkeypatch.setenv("PATH", str(tmp_path))
+    assert runtime._build_so("runtime.cc", str(tmp_path / "x.so")) is None
+
+
 class TestEngine:
     def test_write_write_ordering(self):
         eng = runtime.Engine(4)

@@ -47,6 +47,13 @@ def frozen():
 # FrozenModel
 # ---------------------------------------------------------------------------
 
+def assert_same_program_output(got, ref):
+    """Two DIFFERENT XLA programs over the same weights (another batch
+    size, another fusion): what the compiler can promise is float32
+    resolution, not the same bits (seen on jax 0.9.0: 1.5e-8 absolute)."""
+    np.testing.assert_allclose(got, ref, rtol=1e-6, atol=1e-7)
+
+
 def test_frozen_precompiles_every_bucket_and_matches_eager(frozen):
     net = _mlp()          # same seeded params as the fixture's source
     net_h = _mlp()
@@ -55,13 +62,10 @@ def test_frozen_precompiles_every_bucket_and_matches_eager(frozen):
     for n in (1, 3, 5, 8):
         x = np.random.RandomState(n).randn(n, 6).astype(np.float32)
         out = frozen(x).asnumpy()
-        # BIT-exact vs the hybridized forward: freezing runs the same
-        # whole-graph XLA program as the CachedOp. Per-op eager can
-        # legitimately differ by 1 ULP from any compiled path (fusion),
-        # so that comparison is allclose at float32 resolution.
-        np.testing.assert_array_equal(out, net_h(nd.array(x)).asnumpy())
-        np.testing.assert_allclose(out, net(nd.array(x)).asnumpy(),
-                                   rtol=1e-6, atol=1e-7)
+        # the bucket's program pads the batch, the hybridized forward and
+        # per-op eager do not: three programs, float32 resolution
+        assert_same_program_output(out, net_h(nd.array(x)).asnumpy())
+        assert_same_program_output(out, net(nd.array(x)).asnumpy())
 
 
 def test_frozen_padding_rows_do_not_leak_into_real_rows(frozen):
@@ -136,7 +140,7 @@ def test_batcher_coalesces_concurrent_requests(frozen):
     net = _mlp()
     for i in range(12):
         ref = net(nd.array(xs[i:i + 1])).asnumpy()[0]
-        np.testing.assert_array_equal(results[i][0], ref)
+        assert_same_program_output(results[i][0], ref)
 
 
 def test_deadline_expired_requests_rejected_not_dropped(frozen):

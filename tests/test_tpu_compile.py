@@ -1,0 +1,190 @@
+"""The Pallas kernels of the main path, compiled for a TPU v5e that is
+described and not attached — what interpret mode cannot see: tiling,
+alignment, fast-memory limits. Nothing runs, so nothing here says a result
+is right or fast; `python chip_smoke.py` on the chip says that.
+
+This is the ONLY file that describes a topology, and it does so inside a
+fixture: the process that describes one loads the TPU library and keeps it
+until it exits, so no import, `skipif`, `parametrize` argument or conftest
+hook may do it (the other xdist workers would fail on the library's lock).
+"""
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from incubator_mxnet_tpu.ops.pallas import (conv_bn_relu, flash_attention,
+                                            layer_norm, scale_shift_act)
+
+BF16 = jnp.bfloat16
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def no_compile_cache():
+    """The persistent cache is off around these compiles: an entry written
+    for a described chip cannot be read back without one, and the next
+    run would warn about it."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def compile_for_chip(one_chip, no_compile_cache):
+    """compile_for_chip(fn, (shape, dtype), ...) -> optimized HLO text."""
+    def compile_(fn, *shapes):
+        specs = [jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+                 for s, d in shapes]
+        return jax.jit(fn).lower(*specs).compile().as_text()
+    return compile_
+
+
+def _with_grads(fn, n):
+    """fn's output and its gradients wrt the first n arguments, as a
+    training step uses them (a backward pass alone would let the
+    compiler drop a forward kernel whose VJP is written in XLA)."""
+    def fwd_bwd(*args):
+        def scalar(*d):
+            out = fn(*d, *args[n:])
+            return jnp.sum(out.astype(jnp.float32)), out
+        (_, out), grads = jax.value_and_grad(
+            scalar, argnums=tuple(range(n)), has_aux=True)(*args[:n])
+        return out, grads
+    return fwd_bwd
+
+
+# the causal LM of chip_smoke.py: GPT-2-base, batch 16, sequence 512
+QKV = ((16, 12, 512, 64), BF16)
+
+
+@pytest.mark.parametrize("causal", [False, True], ids=["full", "causal"])
+@pytest.mark.parametrize("direction", ["fwd", "bwd"])
+def test_flash_attention(compile_for_chip, causal, direction):
+    def fn(q, k, v):
+        return flash_attention(q, k, v, causal=causal, interpret=False)
+    if direction == "bwd":
+        fn = _with_grads(fn, 3)
+    text = compile_for_chip(fn, QKV, QKV, QKV)
+    # forward: one kernel; backward: forward again plus dq and dk/dv
+    assert text.count("tpu_custom_call") >= (1 if direction == "fwd" else 3)
+
+
+def test_flash_attention_pads_a_ragged_sequence(compile_for_chip):
+    """L=300, D=80: neither is a multiple of the 128-lane tile, so the
+    wrapper pads both before the kernel sees them."""
+    shape = ((2, 4, 300, 80), BF16)
+    text = compile_for_chip(
+        lambda q, k, v: flash_attention(q, k, v, causal=True,
+                                        interpret=False),
+        shape, shape, shape)
+    assert "tpu_custom_call" in text
+
+
+LN = (((8192, 768), BF16), ((768,), BF16), ((768,), BF16))
+
+
+@pytest.mark.parametrize("direction", ["fwd", "bwd"])
+def test_layer_norm(compile_for_chip, direction):
+    def fn(x, g, b):
+        return layer_norm(x, g, b, interpret=False)
+    if direction == "bwd":
+        fn = _with_grads(fn, 3)
+    assert "tpu_custom_call" in compile_for_chip(fn, *LN)
+
+
+# ResNet-50 NHWC, batch 128: the first and the last bottleneck stage
+@pytest.mark.parametrize("shape", [(128, 56, 56, 256), (128, 7, 7, 2048)],
+                         ids=["56x56x256", "7x7x2048"])
+@pytest.mark.parametrize("direction", ["fwd", "bwd"])
+def test_scale_shift_act(compile_for_chip, shape, direction):
+    def fn(x, s, b):
+        return scale_shift_act(x, s, b, act="relu", interpret=False)
+    if direction == "bwd":
+        fn = _with_grads(fn, 3)
+    c = shape[-1]
+    text = compile_for_chip(fn, (shape, BF16), ((c,), jnp.float32),
+                            ((c,), jnp.float32))
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("kernel,pad", [(1, 0), (3, 1)], ids=["1x1", "3x3"])
+def test_conv_bn_relu(compile_for_chip, kernel, pad):
+    """1x1 is the fused matmul+epilogue kernel; 3x3 is XLA's convolution
+    with the Pallas epilogue."""
+    cin, cout = 256, 128
+
+    def fn(x, w, g, b, m, v):
+        return conv_bn_relu(x, w, g, b, m, v, pad=(pad, pad),
+                            interpret=False)
+    stat = ((cout,), jnp.float32)
+    text = compile_for_chip(fn, ((128, 56, 56, cin), BF16),
+                            ((kernel, kernel, cin, cout), BF16),
+                            stat, stat, stat, stat)
+    assert "tpu_custom_call" in text
+    assert (" convolution(" in text) == (kernel == 3)
+
+
+def test_max_pool_is_a_reduce_window_not_a_convolution(compile_for_chip):
+    """ResNet-50's stem pool in float32. Pooling by patch extraction is a
+    convolution, and the TPU feeds a float32 convolution bfloat16 operands:
+    the first chip run returned rounded maxima and, from the padding value
+    times the patch kernel's zeros, NaN."""
+    from incubator_mxnet_tpu.ops import _raw
+    text = compile_for_chip(
+        lambda x: _raw.pooling(x, "max", (3, 3), (2, 2), (1, 1),
+                               layout="NHWC"),
+        ((8, 112, 112, 64), jnp.float32))
+    # opcodes, "name(": the text also quotes this test's own name
+    assert " reduce-window(" in text and " convolution(" not in text
+
+
+@pytest.mark.parametrize("axes,mode", [({"dp": 1}, "dp"), ({"dp": 4}, "dp"),
+                                       ({"dp": 2, "mp": 2}, "auto")],
+                         ids=["one-chip", "dp4", "dp2xmp2"])
+def test_lm_train_step(topo, no_compile_cache, monkeypatch, axes, mode):
+    """The whole FusedTrainStep program of a two-layer causal LM. On one
+    chip it holds the kernels; over four, where jax refuses to partition a
+    Mosaic kernel ("wrap the call in a shard_map"), the selection layer
+    keeps them out and the compiler's all-reduce is there instead."""
+    import numpy as np
+
+    import incubator_mxnet_tpu as mx
+    from incubator_mxnet_tpu import nd
+    from incubator_mxnet_tpu.models import TransformerLM
+    from incubator_mxnet_tpu.models.transformer_lm import lm_loss
+    from incubator_mxnet_tpu.parallel import FusedTrainStep, make_mesh
+
+    # the trace asks the platform, and the platform here is the CPU
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    net = TransformerLM(512, num_layers=2, units=128, hidden_size=512,
+                        num_heads=2, max_length=128, dropout=0.0)
+    net.initialize(init=mx.init.Normal(0.02))
+    net.cast("bfloat16")
+    n = int(np.prod(list(axes.values())))
+    step = FusedTrainStep(net, lambda out, y: lm_loss(out, y).mean(),
+                          mx.optimizer.create("adam", multi_precision=True),
+                          mesh=make_mesh(axes, topo.devices[:n]),
+                          sharding=mode)
+    tokens = nd.array(np.zeros((8, 128), np.int32))
+    text = step.lower(tokens, tokens).compile().as_text()
+    assert ("tpu_custom_call" in text) == (n == 1)
+    assert ("all-reduce(" in text) == (n > 1)

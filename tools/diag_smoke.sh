@@ -3,7 +3,7 @@
 # observability stack armed (memory ledger + 100ms metrics sampler +
 # flight recorder), then validate every artifact with tools/trace_check
 # and assert the BENCH json carries the memory/counters sections.
-# No TPU, no tunnel — safe to run anywhere, cheap enough for CI.
+# No TPU — safe to run anywhere, cheap enough for CI.
 # Exit 0 iff the whole pipeline (record -> export -> validate) is healthy.
 set -u
 cd "$(dirname "$0")/.." || exit 1

@@ -14,8 +14,7 @@
 #  3. perf_regress accepts that artifact self-vs-self (a resilient run
 #     is a usable perf number, not an env failure).
 #
-# Wired into tools/auto_guard.sh / tools/auto_sweep.sh like every other
-# subsystem smoke. Exit 0 = all good.
+# Exit 0 = all good.
 set -u
 cd "$(dirname "$0")/.." || exit 1
 export JAX_PLATFORMS=cpu

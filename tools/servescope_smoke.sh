@@ -13,7 +13,7 @@
 #   * mxdiag.py serve renders the report,
 #   * perf_regress.py accepts the artifact self-vs-self and FLAGS an
 #     injected 20% p99 degradation at the serving threshold (0.15).
-# No TPU, no tunnel - safe anywhere, CI-cheap.
+# No TPU - safe anywhere, CI-cheap.
 set -u
 cd "$(dirname "$0")/.." || exit 1
 

@@ -625,8 +625,13 @@ def check_perfscope_extra(ps) -> list:
     if not isinstance(peaks, dict):
         errors.append("needs a 'peaks' object")
     else:
+        # a device_kind outside the peak table has no row and no peaks
+        # (all null); a known one has all three
+        unknown = peaks.get("table_row") is None
         for key in ("peak_flops_f32", "peak_flops_bf16", "hbm_bytes_per_s"):
             v = peaks.get(key)
+            if unknown and v is None:
+                continue
             if not _is_num(v) or v <= 0:
                 errors.append(f"peaks[{key!r}] must be positive, got {v!r}")
     progs = ps.get("programs")
@@ -1835,14 +1840,15 @@ def check_bench_json(path: str) -> list:
         errors.append(f"needs numeric 'value', got {doc.get('value')!r}")
     extra = doc.get("extra") or {}
     # training benches must carry MFU (ROADMAP item 1: regressions visible
-    # per-PR). Serving benches and error results are exempt.
+    # per-PR) — null on a device outside the peak table (a CPU run has no
+    # utilisation to report). Serving benches and error results are exempt.
     if (isinstance(extra, dict) and extra
             and "serving" not in extra and "error" not in doc):
         mfu = extra.get("mfu")
-        if not _is_num(mfu):
-            errors.append(f"training bench extra needs numeric 'mfu', "
-                          f"got {mfu!r}")
-        elif not (0.0 <= mfu <= 1.5):
+        if "mfu" not in extra or (mfu is not None and not _is_num(mfu)):
+            errors.append(f"training bench extra needs 'mfu' (numeric, or "
+                          f"null without device peaks), got {mfu!r}")
+        elif mfu is not None and not (0.0 <= mfu <= 1.5):
             errors.append(f"extra.mfu={mfu} outside [0, 1.5] — wrong "
                           f"peak-FLOPs or flops-per-sample accounting")
     errors += [f"extra.perfscope: {e}"
