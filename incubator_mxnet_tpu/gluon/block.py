@@ -12,6 +12,7 @@ the reference's CachedOp forward/backward graph pair.
 """
 from __future__ import annotations
 
+import contextlib
 from collections import OrderedDict
 
 import jax
@@ -28,6 +29,8 @@ from .parameter import (DeferredInitializationError, Parameter, ParameterDict,
                         _ParamTraceScope, _trace)
 
 __all__ = ["Block", "HybridBlock", "SymbolBlock"]
+
+_NO_SCOPE = contextlib.nullcontext()
 
 
 class _NameScope:
@@ -237,16 +240,23 @@ class Block:
     def __call__(self, *args, **kwargs):
         for hook in self._forward_pre_hooks:
             hook(self, args)
-        if _dmem._ACTIVE:
-            # attribute arrays created during this forward to this block
-            # (innermost scope wins) for memory_summary()'s by-block view
-            _dmem.push_block(self.name)
-            try:
+        # while a program is traced (a fused step, a hybridized or frozen
+        # forward) the block's name goes into every operation's `op_name`
+        # (docs/profiler.md, "Names in a device trace"). Not in eager
+        # mode: an eager op is compiled once, under whichever block
+        # called it first, so a name there would be another block's.
+        with jax.named_scope(self.name) if _trace.active else _NO_SCOPE:
+            if _dmem._ACTIVE:
+                # attribute arrays created during this forward to this
+                # block (innermost scope wins) for memory_summary()'s
+                # by-block view
+                _dmem.push_block(self.name)
+                try:
+                    out = self._invoke(*args, **kwargs)
+                finally:
+                    _dmem.pop_block()
+            else:
                 out = self._invoke(*args, **kwargs)
-            finally:
-                _dmem.pop_block()
-        else:
-            out = self._invoke(*args, **kwargs)
         for hook in self._forward_hooks:
             hook(self, args, out)
         return out

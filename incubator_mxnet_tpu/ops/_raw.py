@@ -7,6 +7,11 @@ All functions are pure (state in, state out) so they compose with jit/grad/
 shard_map. Layouts: MXNet's default NCHW is supported everywhere, NHWC is
 offered because it is the faster layout on TPU (channels-last feeds the MXU
 without relayout); model zoo defaults to NHWC on TPU.
+
+The three ops where ops/select.py chooses between a Pallas kernel and XLA
+(`batch_norm`, `layer_norm`, `multihead_attention`) run under a
+`jax.named_scope` named for the op, not the implementation: both branches
+have the same owner in a device trace (docs/profiler.md).
 """
 from __future__ import annotations
 
@@ -382,6 +387,7 @@ def pooling(x, pool_type="max", kernel=(2, 2), stride=None, pad=None,
 # normalization
 # ---------------------------------------------------------------------------
 
+@jax.named_scope("batch_norm")
 def batch_norm(x, gamma, beta, moving_mean, moving_var, *, axis=1, eps=1e-5,
                momentum=0.9, training=True, use_global_stats=False,
                fix_gamma=False, act=None):
@@ -450,6 +456,7 @@ def conv_bn_relu(x, weight, gamma, beta, moving_mean, moving_var, *,
     return y
 
 
+@jax.named_scope("layer_norm")
 def layer_norm(x, gamma, beta, axis=-1, eps=1e-5):
     """LayerNorm (reference src/operator/nn/layer_norm.cc). Stats in f32 for
     bf16 stability, one fused XLA chain. Qualifying shapes dispatch to the
@@ -577,6 +584,7 @@ def smooth_l1(x, scalar=1.0):
 # attention (XLA path; pallas kernel in ops/pallas/ for the TPU fast path)
 # ---------------------------------------------------------------------------
 
+@jax.named_scope("attention")
 def multihead_attention(q, k, v, num_heads, mask=None, dropout_rate=0.0,
                         key=None, training=False, scale=None, causal=False):
     """Batched MHA on (B, L, D) inputs already projected; splits heads,

@@ -73,6 +73,7 @@ def _ssa_fwd_impl(x2, scale, shift, act, interpret, block_r):
         out_specs=_vspec((block_r, d), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((rows, d), x2.dtype),
         interpret=interpret,
+        name="scale_shift_act_fwd",
     )(x2, s2, b2)
 
 
@@ -188,6 +189,7 @@ def _mm_epilogue(x2, w2, scale, shift, act, interpret):
         out_shape=jax.ShapeDtypeStruct((mp, n), x2.dtype),
         scratch_shapes=scratch,
         interpret=interpret,
+        name="matmul_scale_shift_act_fwd",
     )(x2, w2, s2, b2)
     return out[:m]
 

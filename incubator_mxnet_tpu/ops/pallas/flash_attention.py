@@ -120,6 +120,7 @@ def _fwd(q, k, v, cfg):
                         pltpu.VMEM((bq, _LANES), jnp.float32),
                         pltpu.VMEM((bq, d), jnp.float32)],
         interpret=interpret,
+        name="flash_attention_fwd",
     )(q, k, v)
 
 
@@ -249,6 +250,7 @@ def _bwd(cfg, res, dout):
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
         interpret=interpret,
+        name="flash_attention_dq",
     )(q, k, v, do, lse, delta)
 
     dk, dv = pl.pallas_call(
@@ -269,6 +271,7 @@ def _bwd(cfg, res, dout):
         scratch_shapes=[pltpu.VMEM((bk, d), jnp.float32),
                         pltpu.VMEM((bk, d), jnp.float32)],
         interpret=interpret,
+        name="flash_attention_dkv",
     )(q, k, v, do, lse, delta)
     return dq, dk, dv
 

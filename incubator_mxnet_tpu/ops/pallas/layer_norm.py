@@ -43,6 +43,7 @@ def _ln_fwd_impl(x2, gamma, beta, eps, interpret, block_r):
         out_specs=_vspec((block_r, d), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((rows, d), x2.dtype),
         interpret=interpret,
+        name="layer_norm_fwd",
     )(x2, g2, b2)
 
 

@@ -240,7 +240,13 @@ class FusedTrainStep:
         ids = [id(p) for p in params]
         aux_ids = [id(params[i]) for i in aux_idx]
 
-        def step_fn(train_raws, aux_raws, states, key, lr, wd, t, rescale, xb, yb):
+        # Named `train_step`, not `step_fn` as before the step's operations
+        # had owners: the compile cache's key leaves metadata out, so under
+        # the old name a cache that holds the same program without names
+        # (written by an older checkout) would serve it, and a profile of
+        # this step would show no owner at all.
+        def train_step(train_raws, aux_raws, states, key, lr, wd, t, rescale,
+                       xb, yb):
             def loss_of(train_raws_):
                 sub = {}
                 for j, i in enumerate(train_idx):
@@ -250,9 +256,15 @@ class FusedTrainStep:
                 with _ParamTraceScope(sub), autograd._Scope(False, True), \
                         ndrandom._TraceKeyScope(key), \
                         _select.partitioned(self.mesh):
-                    out = net.forward(NDArray(xb))
-                    loss = loss_fn(out, NDArray(yb))
-                    loss_raw = jnp.mean(loss._data)
+                    # owners of the step's operations in a device trace
+                    # (docs/profiler.md): the net by its name (its
+                    # children add their own in Block.__call__), then
+                    # `loss`; jax itself marks forward and backward
+                    with jax.named_scope(net.name):
+                        out = net.forward(NDArray(xb))
+                    with jax.named_scope("loss"):
+                        loss = loss_fn(out, NDArray(yb))
+                        loss_raw = jnp.mean(loss._data)
                     aux_new = [ _trace.aux_updates.get(aid, aux_raws[j])
                                 for j, aid in enumerate(aux_ids)]
                 return loss_raw, aux_new
@@ -277,17 +289,18 @@ class FusedTrainStep:
             (loss, aux_new), grads = jax.value_and_grad(
                 loss_of, has_aux=True)(train_raws)
             new_train, new_states = [], []
-            for j in range(len(train_raws)):
-                nw, ns = optimizer.update_step(
-                    train_raws[j], grads[j], states[j],
-                    lr * lr_mults[j], wd * wd_mults[j], t,
-                    rescale=rescale,
-                    clip=optimizer.clip_gradient)
-                new_train.append(nw)
-                new_states.append(ns)
+            with jax.named_scope("optimizer"):
+                for j in range(len(train_raws)):
+                    nw, ns = optimizer.update_step(
+                        train_raws[j], grads[j], states[j],
+                        lr * lr_mults[j], wd * wd_mults[j], t,
+                        rescale=rescale,
+                        clip=optimizer.clip_gradient)
+                    new_train.append(nw)
+                    new_states.append(ns)
             return loss, new_train, aux_new, new_states
 
-        self._step_fn = step_fn
+        self._step_fn = train_step
         kwargs = {}
         self._sharding_info = None
         if self.mesh is not None:
@@ -345,7 +358,7 @@ class FusedTrainStep:
                                    batch_sharding)
         if self.donate:
             kwargs["donate_argnums"] = (0, 1, 2)
-        self._jitted = jax.jit(step_fn, **kwargs)
+        self._jitted = jax.jit(train_step, **kwargs)
 
     def _build_k(self):
         """Wrap the same step_fn in a lax.scan over a leading micro-step
@@ -459,6 +472,11 @@ class FusedTrainStep:
             return self._jitted.lower(*specs)
 
     # -- execution --------------------------------------------------------
+    # The host's side of a step, as spans on the device trace's clock
+    # (docs/profiler.md, "Names in a device trace"): `mxtpu.step` is the
+    # whole call, a step annotation numbered by the update; its children
+    # `.args` (scalars, key, gathering the buffers), `.enqueue` (the jitted
+    # call inside the gate) and `.rebind` (the new buffers written back).
     def __call__(self, x, y):
         if not isinstance(x, NDArray):
             x = NDArray(x)
@@ -468,65 +486,84 @@ class FusedTrainStep:
             self._resolve(x, y)
         self._num_update += 1
         self.optimizer.num_update = self._num_update
-        lr = self._f32("lr", self.optimizer.learning_rate)
-        wd = self._f32("wd", self.optimizer.wd)
-        t = jnp.int32(self._num_update)
-        key = ndrandom._key()
-        xb, yb = x._data, y._data
-        if self._sharding_info is not None:
-            batch_sharding = self._sharding_info[4]   # resolved in _build
-            with _TRANSFER_GATE:
-                xb = jax.device_put(xb, batch_sharding)
-                yb = jax.device_put(yb, batch_sharding)
-        train_raws = [self.params[i].data()._data for i in self.train_idx]
-        aux_raws = [self.params[i].data()._data for i in self.aux_idx]
-        rescale = self._f32("rescale", self.optimizer.rescale_grad)
-        sig = (tuple(xb.shape), str(xb.dtype), tuple(yb.shape),
-               str(yb.dtype))
-        if _ps._PS is not None and \
-                self._cost_analyzed.get("fused_step") != sig:
-            # roofline capture BEFORE dispatch: analyze_jit only reads
-            # shapes/dtypes, so it is safe against the donated buffers.
-            # Keyed on the batch signature: a shape-driven recompile gets
-            # re-analyzed so the table describes the program being timed.
-            # mesh/mode flow through to commscope, which (when armed)
-            # walks the compiled HLO for the program's collective
-            # inventory — the thing the step budget's `collective`
-            # component is estimated from under GSPMD (docs/commscope.md)
-            self._cost_analyzed["fused_step"] = sig
-            _ps.analyze_jit(
-                self._jitted,
-                (train_raws, aux_raws, self._states, key, lr, wd, t,
-                 rescale, xb, yb),
-                name="fused_step", dtype=xb.dtype, kind="train_step",
-                mesh=self.mesh, mode=self.sharding)
-        # the donating dispatch ENQUEUE is serialized against any
-        # in-flight prefetcher device_put (io.pipeline.TRANSFER_GATE) —
-        # the enqueue-ordering half of the PR 14 flake fix; the other
-        # half is the pipeline's consumer-thread put on XLA:CPU. The
-        # guarded region is the async enqueue, not the step execution.
-        try:
-            with _TRANSFER_GATE, _donated_cache_quarantine(self):
-                loss, new_train, new_aux, new_states = self._jitted(
-                    train_raws, aux_raws, self._states, key, lr, wd, t,
-                    rescale, xb, yb)
-                if _cpu_serial_client():
-                    # XLA:CPU (io/pipeline.py safety model): retire the
-                    # donating execution before ANY other client call —
-                    # this client races the donated-buffer handoff of a
-                    # still-running execution against concurrent client
-                    # work regardless of which Python thread issues it.
-                    # INSIDE the gate: the donation window and the gate
-                    # window coincide, so gate holders (async checkpoint
-                    # saves, prefetcher puts) are mutually excluded from
-                    # it. Compute∥decode overlap is unaffected (the decode
-                    # pool is host-side); only async dispatch depth is
-                    # forfeited, on the backend where it buys nothing.
-                    jax.block_until_ready(
-                        (loss, new_train, new_aux, new_states))
-        except Exception as e:  # noqa: BLE001 — re-raised unchanged
-            _memscope_oom(e, "fused_step", self._num_update)
-            raise
+        with _prof.Scope("mxtpu.step", "trainer", sync=False,
+                         step_num=self._num_update):
+            with _prof.Scope("mxtpu.step.args", "trainer", sync=False):
+                lr = self._f32("lr", self.optimizer.learning_rate)
+                wd = self._f32("wd", self.optimizer.wd)
+                t = jnp.int32(self._num_update)
+                key = ndrandom._key()
+                xb, yb = x._data, y._data
+                if self._sharding_info is not None:
+                    batch_sharding = self._sharding_info[4]  # from _build
+                    with _TRANSFER_GATE:
+                        xb = jax.device_put(xb, batch_sharding)
+                        yb = jax.device_put(yb, batch_sharding)
+                train_raws = [self.params[i].data()._data
+                              for i in self.train_idx]
+                aux_raws = [self.params[i].data()._data
+                            for i in self.aux_idx]
+                rescale = self._f32("rescale", self.optimizer.rescale_grad)
+                sig = (tuple(xb.shape), str(xb.dtype), tuple(yb.shape),
+                       str(yb.dtype))
+                if _ps._PS is not None and \
+                        self._cost_analyzed.get("fused_step") != sig:
+                    # roofline capture BEFORE dispatch: analyze_jit only
+                    # reads shapes/dtypes, so it is safe against the
+                    # donated buffers. Keyed on the batch signature: a
+                    # shape-driven recompile gets re-analyzed so the table
+                    # describes the program being timed. mesh/mode flow
+                    # through to commscope, which (when armed) walks the
+                    # compiled HLO for the program's collective inventory —
+                    # the thing the step budget's `collective` component is
+                    # estimated from under GSPMD (docs/commscope.md)
+                    self._cost_analyzed["fused_step"] = sig
+                    _ps.analyze_jit(
+                        self._jitted,
+                        (train_raws, aux_raws, self._states, key, lr, wd, t,
+                         rescale, xb, yb),
+                        name="fused_step", dtype=xb.dtype, kind="train_step",
+                        mesh=self.mesh, mode=self.sharding)
+            # the donating dispatch ENQUEUE is serialized against any
+            # in-flight prefetcher device_put (io.pipeline.TRANSFER_GATE) —
+            # the enqueue-ordering half of the PR 14 flake fix; the other
+            # half is the pipeline's consumer-thread put on XLA:CPU. The
+            # guarded region is the async enqueue, not the step execution.
+            try:
+                with _prof.Scope("mxtpu.step.enqueue", "trainer",
+                                 sync=False), \
+                        _TRANSFER_GATE, _donated_cache_quarantine(self):
+                    loss, new_train, new_aux, new_states = self._jitted(
+                        train_raws, aux_raws, self._states, key, lr, wd, t,
+                        rescale, xb, yb)
+                    if _cpu_serial_client():
+                        # XLA:CPU (io/pipeline.py safety model): retire the
+                        # donating execution before ANY other client call —
+                        # this client races the donated-buffer handoff of a
+                        # still-running execution against concurrent client
+                        # work regardless of which Python thread issues it.
+                        # INSIDE the gate: the donation window and the gate
+                        # window coincide, so gate holders (async checkpoint
+                        # saves, prefetcher puts) are mutually excluded from
+                        # it. Compute∥decode overlap is unaffected (the
+                        # decode pool is host-side); only async dispatch
+                        # depth is forfeited, on the backend where it buys
+                        # nothing.
+                        jax.block_until_ready(
+                            (loss, new_train, new_aux, new_states))
+            except Exception as e:  # noqa: BLE001 — re-raised unchanged
+                _memscope_oom(e, "fused_step", self._num_update)
+                raise
+            with _prof.Scope("mxtpu.step.rebind", "trainer", sync=False):
+                self._rebind(new_train, new_aux, new_states)
+        # fully-fused path: forward+backward+collective+update is ONE call
+        # of the jitted step per step (bench.py surfaces this in
+        # BENCH_*.json); what the device sees is programs_per_step.train
+        _prof.set_gauge("trainer.dispatches_per_step", 1)
+        return NDArray(loss)
+
+    def _rebind(self, new_train, new_aux, new_states):
+        """Write a dispatch's new buffers back into the parameters."""
         for j, i in enumerate(self.train_idx):
             self.params[i]._data._data = new_train[j]
         for j, i in enumerate(self.aux_idx):
@@ -539,10 +576,6 @@ class FusedTrainStep:
             _sharding.publish_param_stats(self.params, self._states,
                                           self.mesh, self.sharding)
             _memscope_analytic(self)
-        # fully-fused path: forward+backward+collective+update is ONE XLA
-        # dispatch per step (bench.py surfaces this in BENCH_*.json)
-        _prof.set_gauge("trainer.dispatches_per_step", 1)
-        return NDArray(loss)
 
     def run_k(self, xs, ys):
         """Run k optimizer micro-steps as ONE compiled XLA program (a
@@ -570,54 +603,56 @@ class FusedTrainStep:
             self._resolve(NDArray(xs[0]), NDArray(ys[0]))
         if self._jitted_k is None:
             self._build_k()
-        lrs = self._chunk_lrs(k)
-        wd = self._f32("wd", self.optimizer.wd)
-        t0 = jnp.int32(self._num_update + 1)
-        key = ndrandom._key()
-        if self._stacked_sharding is not None:
-            with _TRANSFER_GATE:
-                xs = jax.device_put(xs, self._stacked_sharding)
-                ys = jax.device_put(ys, self._stacked_sharding)
-        train_raws = [self.params[i].data()._data for i in self.train_idx]
-        aux_raws = [self.params[i].data()._data for i in self.aux_idx]
-        rescale = self._f32("rescale", self.optimizer.rescale_grad)
-        sig = (tuple(xs.shape), str(xs.dtype), tuple(ys.shape),
-               str(ys.dtype))
-        if _ps._PS is not None and \
-                self._cost_analyzed.get(f"fused_step_k{k}") != sig:
-            self._cost_analyzed[f"fused_step_k{k}"] = sig
-            _ps.analyze_jit(
-                self._jitted_k,
-                (train_raws, aux_raws, self._states, key, lrs, wd, t0,
-                 rescale, xs, ys),
-                name=f"fused_step_k{k}", dtype=xs.dtype, kind="train_step",
-                extra={"k": k}, mesh=self.mesh, mode=self.sharding)
-        # donation-vs-transfer serialization, same contract as __call__
-        try:
-            with _TRANSFER_GATE, _donated_cache_quarantine(self):
-                losses, new_train, new_aux, new_states = self._jitted_k(
-                    train_raws, aux_raws, self._states, key, lrs, wd, t0,
-                    rescale, xs, ys)
-                if _cpu_serial_client():
-                    # XLA:CPU donating dispatch retires inside the gate —
-                    # see the matching __call__ block and io/pipeline.py
-                    jax.block_until_ready((losses, new_train, new_aux,
-                                           new_states))
-        except Exception as e:  # noqa: BLE001 — re-raised unchanged
-            _memscope_oom(e, f"fused_step_k{k}", self._num_update)
-            raise
-        self._num_update += k
-        self.optimizer.num_update = self._num_update
-        for j, i in enumerate(self.train_idx):
-            self.params[i]._data._data = new_train[j]
-        for j, i in enumerate(self.aux_idx):
-            self.params[i]._data._data = new_aux[j]
-        self._states = new_states
-        if not self._stats_published and self.mesh is not None:
-            self._stats_published = True
-            _sharding.publish_param_stats(self.params, self._states,
-                                          self.mesh, self.sharding)
-            _memscope_analytic(self)
+        # the same four spans as __call__, around k micro-steps
+        with _prof.Scope("mxtpu.step", "trainer", sync=False,
+                         step_num=self._num_update + 1):
+            with _prof.Scope("mxtpu.step.args", "trainer", sync=False):
+                lrs = self._chunk_lrs(k)
+                wd = self._f32("wd", self.optimizer.wd)
+                t0 = jnp.int32(self._num_update + 1)
+                key = ndrandom._key()
+                if self._stacked_sharding is not None:
+                    with _TRANSFER_GATE:
+                        xs = jax.device_put(xs, self._stacked_sharding)
+                        ys = jax.device_put(ys, self._stacked_sharding)
+                train_raws = [self.params[i].data()._data
+                              for i in self.train_idx]
+                aux_raws = [self.params[i].data()._data
+                            for i in self.aux_idx]
+                rescale = self._f32("rescale", self.optimizer.rescale_grad)
+                sig = (tuple(xs.shape), str(xs.dtype), tuple(ys.shape),
+                       str(ys.dtype))
+                if _ps._PS is not None and \
+                        self._cost_analyzed.get(f"fused_step_k{k}") != sig:
+                    self._cost_analyzed[f"fused_step_k{k}"] = sig
+                    _ps.analyze_jit(
+                        self._jitted_k,
+                        (train_raws, aux_raws, self._states, key, lrs, wd,
+                         t0, rescale, xs, ys),
+                        name=f"fused_step_k{k}", dtype=xs.dtype,
+                        kind="train_step", extra={"k": k}, mesh=self.mesh,
+                        mode=self.sharding)
+            # donation-vs-transfer serialization, same contract as __call__
+            try:
+                with _prof.Scope("mxtpu.step.enqueue", "trainer",
+                                 sync=False), \
+                        _TRANSFER_GATE, _donated_cache_quarantine(self):
+                    losses, new_train, new_aux, new_states = self._jitted_k(
+                        train_raws, aux_raws, self._states, key, lrs, wd, t0,
+                        rescale, xs, ys)
+                    if _cpu_serial_client():
+                        # XLA:CPU donating dispatch retires inside the gate
+                        # — see the matching __call__ block and
+                        # io/pipeline.py
+                        jax.block_until_ready((losses, new_train, new_aux,
+                                               new_states))
+            except Exception as e:  # noqa: BLE001 — re-raised unchanged
+                _memscope_oom(e, f"fused_step_k{k}", self._num_update)
+                raise
+            self._num_update += k
+            self.optimizer.num_update = self._num_update
+            with _prof.Scope("mxtpu.step.rebind", "trainer", sync=False):
+                self._rebind(new_train, new_aux, new_states)
         # one dispatch drives k micro-steps
         _prof.set_gauge("trainer.dispatches_per_step", round(1.0 / k, 4))
         return NDArray(losses)

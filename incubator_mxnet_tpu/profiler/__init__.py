@@ -24,8 +24,8 @@ Three event sources feed one recorder:
 
 TPU bridge: when the default backend is TPU (see
 :mod:`incubator_mxnet_tpu.profiler.tpu`), every scope additionally enters
-``jax.profiler.TraceAnnotation`` so host-side regions line up with the XLA
-device trace, and ``set_config(profile_xla=True)`` drives
+``jax.profiler.TraceAnnotation``, running profiler or not, so host-side
+regions line up with the XLA device trace of any jax trace session, and ``set_config(profile_xla=True)`` drives
 ``jax.profiler.start_trace`` for a full TensorBoard/Perfetto capture.
 
 Off-path contract: when profiling is disabled the ndarray funnel checks
@@ -228,16 +228,21 @@ class Scope:
 
     ``sync=True`` (the default for user code) drains device work on exit so
     the duration is wall-true; internal layer hooks pass ``sync=False`` to
-    avoid perturbing the async pipeline. Inert (near-zero cost) when
-    profiling is off or paused, so scopes can stay in production loops."""
+    avoid perturbing the async pipeline. Records nothing when profiling is
+    off or paused, so scopes can stay in production loops: all that is
+    left then is the TPU bridge's TraceAnnotation, which a jax trace
+    session picks up and which otherwise costs under a microsecond.
+    ``step_num`` makes that annotation a StepTraceAnnotation."""
 
-    __slots__ = ("name", "cat", "sync", "_start", "_active", "_depth", "_ann")
+    __slots__ = ("name", "cat", "sync", "step_num", "_start", "_active",
+                 "_depth", "_ann")
 
     def __init__(self, name: str = "<unk>", cat: str = "scope",
-                 sync: bool = True):
+                 sync: bool = True, step_num: int | None = None):
         self.name = name
         self.cat = cat
         self.sync = sync
+        self.step_num = step_num
         self._active = False
         self._ann = None
 
@@ -246,9 +251,10 @@ class Scope:
         if self._active:
             self._depth = getattr(_tls, "depth", 0)
             _tls.depth = self._depth + 1
-            self._ann = _tpu.annotation(self.name)
-            if self._ann is not None:
-                self._ann.__enter__()
+        self._ann = _tpu.annotation(self.name, self.step_num)
+        if self._ann is not None:
+            self._ann.__enter__()
+        if self._active:
             self._start = time.perf_counter()
         return self
 
@@ -258,9 +264,10 @@ class Scope:
                 from .. import ndarray as _nd
                 _nd.waitall()
             dur = time.perf_counter() - self._start
-            if self._ann is not None:
-                self._ann.__exit__(*exc)
-                self._ann = None
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+            self._ann = None
+        if self._active:
             _tls.depth = self._depth
             _emit(self.name, self.cat, (self._start - _t0) * 1e6, dur * 1e6,
                   args={"depth": self._depth})

@@ -6,9 +6,12 @@ Two jobs:
   :class:`~incubator_mxnet_tpu.profiler.Scope` regions in
   ``jax.profiler.TraceAnnotation`` so they line up with the XLA device
   trace (TensorBoard/Perfetto shows the host scope spanning the device
-  ops it dispatched). On CPU/GPU backends this returns None — the host
-  Chrome trace is the single source and the annotation would be dead
-  weight in the hot path.
+  ops it dispatched), whether or not the mx profiler is running: any
+  ``jax.profiler.start_trace`` session then holds the program's spans on
+  the device operations' clock, and with no session on a TraceMe costs
+  well under a microsecond. On CPU/GPU backends this returns None — the
+  host Chrome trace is the single source and the annotation would be
+  dead weight in the hot path.
 * :func:`start_device_trace` / :func:`stop_device_trace` — drive
   ``jax.profiler`` for a full XLA capture when
   ``set_config(profile_xla=True)`` — and for mxtpu.devicescope's
@@ -37,12 +40,16 @@ def on_tpu() -> bool:
     return _is_tpu
 
 
-def annotation(name: str):
-    """A TraceAnnotation context manager for `name` on TPU, else None."""
+def annotation(name: str, step_num: int | None = None):
+    """A TraceAnnotation context manager for `name` on TPU, else None.
+    With a `step_num` it is a StepTraceAnnotation: the device plane of a
+    jax trace then groups its operations by the program's own steps."""
     if not on_tpu():
         return None
     try:
         import jax
+        if step_num is not None:
+            return jax.profiler.StepTraceAnnotation(name, step_num=step_num)
         return jax.profiler.TraceAnnotation(name)
     except Exception:
         return None

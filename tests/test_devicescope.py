@@ -478,7 +478,7 @@ class TestProgramJoin:
         x = nd.array(np.random.rand(4, 8).astype(np.float32))
         y = nd.array(np.random.rand(4, 4).astype(np.float32))
         float(step(x, y))
-        assert ds.program_map().get("jit_step_fn") == "fused_step"
+        assert ds.program_map().get("jit_train_step") == "fused_step"
 
     def test_disabled_no_registration(self):
         ps.enable()

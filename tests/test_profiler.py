@@ -287,6 +287,96 @@ def test_engine_push_wait_all_scopes():
 
 
 # -------------------------------------------------------------------------
+# The fused step's spans (docs/profiler.md, "Names in a device trace")
+# -------------------------------------------------------------------------
+
+STEP_SPANS = ["mxtpu.step.args", "mxtpu.step.enqueue", "mxtpu.step.rebind"]
+
+
+def _fused_step():
+    from incubator_mxnet_tpu.parallel import FusedTrainStep
+    net = gluon.nn.Dense(4, in_units=8)
+    net.initialize()
+    step = FusedTrainStep(net, gluon.loss.L2Loss(),
+                          mx.optimizer.create("sgd", learning_rate=0.01))
+    x = nd.array(np.ones((4, 8), np.float32))
+    y = nd.array(np.ones((4, 4), np.float32))
+    step(x, y).wait_to_read()          # builds the step
+    return step, x, y
+
+
+@pytest.mark.parametrize("call", ["__call__", "run_k"])
+def test_fused_step_records_its_four_spans_when_profiling(call):
+    step, x, y = _fused_step()
+    profiler.reset()
+    if call == "run_k":
+        step.run_k([x, x], [y, y])      # builds the k-step program
+        profiler.reset()
+    profiler.start()
+    if call == "run_k":
+        step.run_k([x, x], [y, y]).wait_to_read()
+    else:
+        step(x, y).wait_to_read()
+    profiler.stop()
+    spans = {e["name"]: e for e in profiler._records
+             if e["name"].startswith("mxtpu.")}
+    assert sorted(spans) == sorted(["mxtpu.step"] + STEP_SPANS)
+    whole = spans["mxtpu.step"]
+    assert whole["args"]["depth"] == 0
+    cursor = whole["ts"]
+    for name in STEP_SPANS:             # in this order, inside the step
+        child = spans[name]
+        assert child["args"]["depth"] == 1
+        assert child["ts"] >= cursor
+        cursor = child["ts"] + child["dur"]
+    assert cursor <= whole["ts"] + whole["dur"] + 1e-3
+
+
+def test_fused_step_records_nothing_when_off():
+    step, x, y = _fused_step()
+    profiler.reset()
+    step(x, y).wait_to_read()
+    step.run_k([x], [y]).wait_to_read()
+    assert profiler._records == []
+    assert profiler.aggregate_stats() == {}
+
+
+def test_scope_enters_the_bridge_annotation_with_the_profiler_off(
+        monkeypatch):
+    """On a TPU a Scope is a TraceAnnotation whether or not the mx
+    profiler runs (a step annotation where it has a step number), so a
+    jax trace session holds the program's spans; nothing is recorded."""
+    import jax
+    from incubator_mxnet_tpu.profiler import tpu
+    made = []
+
+    class Fake:
+        def __init__(self, name, **kw):
+            made.append((type(self).__name__, name, kw))
+
+        def __enter__(self):
+            made.append("enter")
+
+        def __exit__(self, *exc):
+            made.append("exit")
+
+    class FakeStep(Fake):
+        pass
+
+    monkeypatch.setattr(tpu, "_is_tpu", True)
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", Fake)
+    monkeypatch.setattr(jax.profiler, "StepTraceAnnotation", FakeStep)
+    assert profiler._ACTIVE is False
+    with profiler.Scope("mxtpu.step", sync=False, step_num=7):
+        with profiler.Scope("mxtpu.step.args", sync=False):
+            pass
+    assert made == [("FakeStep", "mxtpu.step", {"step_num": 7}), "enter",
+                    ("Fake", "mxtpu.step.args", {}), "enter", "exit",
+                    "exit"]
+    assert profiler._records == []
+
+
+# -------------------------------------------------------------------------
 # Counters registry
 # -------------------------------------------------------------------------
 

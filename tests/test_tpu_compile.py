@@ -157,14 +157,9 @@ def test_max_pool_is_a_reduce_window_not_a_convolution(compile_for_chip):
     assert " reduce-window(" in text and " convolution(" not in text
 
 
-@pytest.mark.parametrize("axes,mode", [({"dp": 1}, "dp"), ({"dp": 4}, "dp"),
-                                       ({"dp": 2, "mp": 2}, "auto")],
-                         ids=["one-chip", "dp4", "dp2xmp2"])
-def test_lm_train_step(topo, no_compile_cache, monkeypatch, axes, mode):
-    """The whole FusedTrainStep program of a two-layer causal LM. On one
-    chip it holds the kernels; over four, where jax refuses to partition a
-    Mosaic kernel ("wrap the call in a shard_map"), the selection layer
-    keeps them out and the compiler's all-reduce is there instead."""
+def _lm_step_text(topo, monkeypatch, axes, mode):
+    """The optimized HLO text of the whole FusedTrainStep program of a
+    two-layer causal LM, compiled for the described chips."""
     import numpy as np
 
     import incubator_mxnet_tpu as mx
@@ -185,6 +180,55 @@ def test_lm_train_step(topo, no_compile_cache, monkeypatch, axes, mode):
                           mesh=make_mesh(axes, topo.devices[:n]),
                           sharding=mode)
     tokens = nd.array(np.zeros((8, 128), np.int32))
-    text = step.lower(tokens, tokens).compile().as_text()
-    assert ("tpu_custom_call" in text) == (n == 1)
-    assert ("all-reduce(" in text) == (n > 1)
+    return step.lower(tokens, tokens).compile().as_text()
+
+
+@pytest.mark.parametrize("axes,mode", [({"dp": 1}, "dp"), ({"dp": 4}, "dp"),
+                                       ({"dp": 2, "mp": 2}, "auto")],
+                         ids=["one-chip", "dp4", "dp2xmp2"])
+def test_lm_train_step(topo, no_compile_cache, monkeypatch, axes, mode):
+    """The whole FusedTrainStep program of a two-layer causal LM. On one
+    chip it holds the kernels; over four, where jax refuses to partition a
+    Mosaic kernel ("wrap the call in a shard_map"), the selection layer
+    keeps them out and the compiler's all-reduce is there instead."""
+    text = _lm_step_text(topo, monkeypatch, axes, mode)
+    one_chip = all(size == 1 for size in axes.values())
+    assert ("tpu_custom_call" in text) == one_chip
+    assert ("all-reduce(" in text) == (not one_chip)
+
+
+KERNEL_NAMES = {"flash_attention_fwd": "attention",
+                "flash_attention_dq": "attention",
+                "flash_attention_dkv": "attention",
+                "layer_norm_fwd": "layer_norm"}
+
+
+def test_lm_train_step_kernels_are_named_and_owned(topo, no_compile_cache,
+                                                   monkeypatch):
+    """Every Mosaic kernel of the one-chip LM step carries its
+    `pl.pallas_call(name=)` as the instruction's name and in its
+    `op_name`, under the op scope of ops/_raw.py (`attention`,
+    `layer_norm`) and the blocks that called it: a device trace names the
+    kernel and its owner, no shape needed (docs/profiler.md)."""
+    import re
+    text = _lm_step_text(topo, monkeypatch, {"dp": 1}, "dp")
+    calls = [line for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    # 2 layers: attention forward, dq, dkv; 2 x 2 + 1 layer norms
+    assert len(calls) == 2 * 3 + 5
+    seen = set()
+    for line in calls:
+        instruction = re.match(r"\s*(?:ROOT )?%([\w\-]+?)(?:\.\d+)? = ",
+                               line).group(1)
+        op_name = re.search(r'op_name="([^"]*)"', line).group(1)
+        assert instruction in KERNEL_NAMES, line[:120]
+        path = op_name.split("/")
+        assert path[-2:] == [instruction, "pallas_call"]
+        assert path[-3] == KERNEL_NAMES[instruction]
+        assert path[0] == "jit(train_step)"
+        assert re.fullmatch(r"(transpose\()?jvp\(transformer_lm_\d+\)\)?",
+                            path[1])
+        assert ("transpose(" in op_name) == instruction.endswith(
+            ("_dq", "_dkv"))
+        seen.add(instruction)
+    assert seen == set(KERNEL_NAMES)
