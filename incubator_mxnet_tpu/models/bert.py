@@ -8,8 +8,9 @@ TPU-first design decisions:
   MXU sees a single large GEMM per attention block.
 - The attention core dispatches to the pallas flash-attention kernel when no
   padding mask is needed (ops/pallas/flash_attention.py): O(L) memory,
-  scores never hit HBM. With a valid_length mask it falls back to the fused
-  XLA softmax path.
+  scores never hit HBM; at 128 or 512 tokens a head is one or two grid
+  steps, its head size of 64 unpadded. With a valid_length mask it falls
+  back to the fused XLA softmax path.
 - Everything is a HybridBlock: `hybridize()` compiles the whole encoder into
   one XLA computation; FusedTrainStep fuses fwd+bwd+AdamW into one program.
 - Long sequences: two exact sequence-parallel cores via

@@ -5,7 +5,8 @@ scripts — re-shaped as the modern causal-LM architecture).
 TPU-first design decisions:
 - Training forward is one causal pass: fused (D,3D) QKV GEMM per layer
   and the causal pallas flash-attention kernel (ops/pallas/
-  flash_attention.py) — O(L) memory, no (L,L) score tensor in HBM.
+  flash_attention.py) — O(L) memory, no (L,L) score tensor in HBM; K and
+  V of a head stay in VMEM and the key loop stops at the diagonal.
 - Pre-LN blocks + final LN (the stable deep-transformer variant); the
   output head can tie to the input embedding table (tie_weights) — one
   (D,V) GEMM either way, MXU-friendly.

@@ -3,20 +3,59 @@
 Replaces the reference's interleaved_matmul_selfatt_* / cuDNN attention
 (src/operator/contrib/transformer.cc) with a FlashAttention-2 style tiled
 kernel: online softmax over K/V blocks, O(L) memory, scores never hit HBM.
-Forward saves the per-row logsumexp; backward recomputes scores blockwise in
-two kernels (dq; dk/dv).
+Forward saves the per-row logsumexp; backward recomputes scores blockwise,
+in one kernel while a head's queries fit VMEM (`flash_attention_bwd`) and in
+two beyond (dq; dk/dv). Operands keep their dtype (bf16 on the chip);
+scores, softmax statistics and accumulators are float32.
 
-Layout notes (TPU tiling wants the last two block dims ∈ {(8k, 128m), full}):
-- q/k/v/o are (batch*heads, seq, head_dim) with head_dim padded to 128 lanes;
-- lse/delta ride as (batch*heads, 1, seq) with full-seq blocks, written via
-  dynamic slices (the (1, block_q) layout is not tileable);
-- the online-softmax m/l scratch is (block_q, 128) lanes-broadcast.
+Tiling. A grid step costs ~0.4 us whatever it does, so a step does a
+step's worth of work: one OUTER block of one sequence against an INNER
+loop (`lax.fori_loop`) over sub-blocks of the other, whose operands stay
+in VMEM.
+
+- forward, dq: grid (batch*heads, lq / block_q, lk / k_major); the inner
+  loop walks `block_k` keys at a time and, when causal, stops at the
+  diagonal, so a block above it costs neither a grid step nor a DMA.
+  m / l / acc are loop carries; they see scratch only between the steps
+  of a streamed key axis.
+- dk/dv: the mirror image. Grid (batch*heads, lk / block_k, lq / q_major);
+  the inner loop walks query sub-blocks from the diagonal on. It computes
+  the TRANSPOSED scores k q^T, so dv += p^T do and dk += ds^T q are plain
+  products and lse / delta are used as the rows they are stored as. With
+  the head's queries resident it adds each tile's ds k to a float32 dq
+  held in VMEM across the key blocks: backward recomputes the scores and
+  their exp once, and the dq kernel does not run.
+- `k_major` (`q_major`) is the whole padded sequence while two operands of
+  that length, double buffered, fit a third of `_VMEM_BUDGET`: the last
+  grid axis then has ONE step and K and V (Q and dO) are fetched once a
+  head. Longer sequences stream major blocks of at least 512 along that
+  axis, with index maps clamped to the diagonal so that a skipped step
+  re-uses the block it holds. Which regime runs depends on the shape alone.
+- only the sub-blocks the diagonal (or the padding of the keys) crosses
+  build a mask; the ones below it run a loop body without one.
+
+`_plan` derives every block from (lq, lk, d, dtype) under the budget;
+`block_q=` / `block_k=` override it for the tests.
+
+Layout (what Mosaic accepted, tests/test_tpu_compile.py):
+- q/k/v/o are (batch*heads, seq, head_dim). A block's last dimension is the
+  array's FULL last dimension, so head_dim is left as it is when it is a
+  multiple of 128 or one of 64 / 32 / 16 / 8 (an even split of the 128
+  lanes), and padded to 128 lanes otherwise (d = 80);
+- the sequences are padded to the block in use only: a multiple of 128 on
+  the chip (lane-dense score tiles, 128-aligned lane slices), of 16 when
+  interpreted; a long sequence to the largest block that wastes under an
+  eighth of it;
+- lse/delta ride as (batch*heads, 1, seq) rows. The forward writes a
+  (1, 1, block_q) block; dq turns its block into a column once a grid
+  step; dk/dv slices the resident row at 128-aligned lane offsets.
 
 Off-TPU the same kernels run with interpret=True (tests/conftest sets CPU).
 """
 from __future__ import annotations
 
 import functools
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -28,99 +67,274 @@ __all__ = ["flash_attention"]
 _NEG = -1e30
 _LANES = 128
 
+# What one kernel may hold in VMEM: 12 of the 16 MiB Mosaic grants a kernel
+# on a v5e by default (the core has 128 MiB). Split in three:
+# - the operands of the inner loop (K and V, or Q and dO), 2 arrays x 2
+#   pipeline buffers x length x 128 lanes x itemsize: 4 MiB holds 4096
+#   positions in bf16 at any head size up to 128;
+# - the score tiles, ~4 live float32 (outer x inner) arrays (s, p, dp, ds):
+#   4 MiB holds 512 x 512;
+# - the outer block's operands and outputs (double buffered), the float32
+#   accumulators, the lse / delta rows.
+_VMEM_BUDGET = 12 * 1024 * 1024
+_OUTER, _INNER = 512, 512     # largest outer block and inner sub-block
+_MIN_MAJOR = 512              # least a streamed major block may hold
+
+_NT = (((1,), (1,)), ((), ()))      # a @ b.T
+_TN = (((0,), (0,)), ((), ()))      # a.T @ b
+
 
 def _ru(x, m):
     return (x + m - 1) // m * m
+
+
+def _divisor(length, align, most):
+    """Largest multiple of `align` that divides `length` and is <= `most`
+    (`align` itself if there is none)."""
+    n = length // align
+    return align * max(c for c in range(1, n + 1)
+                       if n % c == 0 and (c * align <= most or c == 1))
+
+
+class _Plan(NamedTuple):
+    """Block sizes of the three kernels; static, part of the jit key."""
+    lqp: int        # padded lengths
+    lkp: int
+    dp: int         # head size the kernels see
+    bq: int         # forward / dq: outer query block, inner key sub-block,
+    bk: int         # and the major key block held in VMEM
+    k_major: int
+    dkv_bk: int     # dk/dv: outer key block, inner query sub-block, major
+    dkv_bq: int     # query block
+    q_major: int
+
+
+def _plan(lq, lk, d, itemsize, interpret, block_q=None, block_k=None,
+          vmem_budget=_VMEM_BUDGET):
+    align = 16 if interpret else _LANES
+    dp = d if d % _LANES == 0 or d in (8, 16, 32, 64) else _ru(d, _LANES)
+    lanes = _ru(dp, _LANES)
+
+    def blocks(length, override):
+        if override is not None:
+            blk = _ru(min(override, _ru(length, align)), align)
+            return _ru(length, blk), blk, blk
+        # one block if the sequence is short; else the largest block whose
+        # padding costs at most an eighth (6000 -> 12 x 512, not 47 x 128)
+        outer = _ru(length, align)
+        if outer > _OUTER:
+            outer = max((c for c in range(align, _OUTER + 1, align)
+                         if _ru(length, c) - length <= length // 8),
+                        default=align)
+        return _ru(length, outer), outer, _divisor(outer, align, _INNER)
+
+    def major(lp, sub):
+        # positions of two double-buffered operands in a third of the budget
+        fit = vmem_budget // 3 // (4 * lanes * itemsize)
+        if lp <= fit:
+            return lp
+        return _divisor(lp, sub, max(fit, _MIN_MAJOR, sub))
+
+    lqp, bq_outer, bq_inner = blocks(lq, block_q)
+    lkp, bk_outer, bk_inner = blocks(lk, block_k)
+    # the score tile of a step: ~4 live float32 arrays in a third of the
+    # budget; shrink the inner sub-block until it fits
+    most = max(vmem_budget // 3 // 16, align * align)
+    while bq_outer * bk_inner > most and bk_inner > align:
+        bk_inner = _divisor(bk_outer, align, bk_inner - align)
+    while bk_outer * bq_inner > most and bq_inner > align:
+        bq_inner = _divisor(bq_outer, align, bq_inner - align)
+    return _Plan(lqp, lkp, dp, bq_outer, bk_inner, major(lkp, bk_inner),
+                 bk_outer, bq_inner, major(lqp, bq_inner))
+
+
+class _Cfg(NamedTuple):
+    scale: float
+    causal: bool
+    kv_len: int      # keys that are not padding
+    offset: int      # lk - lq: query row r sees key columns <= r + offset
+    interpret: bool
+    plan: _Plan
 
 
 def _vspec(shape, index_map):
     return pl.BlockSpec(shape, index_map, memory_space=pltpu.VMEM)
 
 
+def _call(kernel, cfg, name, carried=(2,), **kw):
+    """pallas_call of a three-axis grid; `carried` are the axes along
+    which a step hands something to the next."""
+    params = {} if cfg.interpret else {"compiler_params": pltpu.CompilerParams(
+        dimension_semantics=tuple("arbitrary" if axis in carried else
+                                  "parallel" for axis in range(3)))}
+    return pl.pallas_call(kernel, interpret=cfg.interpret, name=name,
+                          **params, **kw)
+
+
+def _masker(cfg, shape, rows_axis):
+    """mask(row0, col0) -> which entries of a score tile count, for the tile
+    whose first query row is row0 and first key column col0; queries run
+    along `rows_axis`. The iotas are built here, once a grid step, outside
+    the loops: a masked tile then costs a compare and a select an entry
+    (and nothing at all tests the padding where the keys have none)."""
+    padded = cfg.kv_len != cfg.plan.lkp
+    if cfg.causal:
+        diff = (jax.lax.broadcasted_iota(jnp.int32, shape, rows_axis)
+                - jax.lax.broadcasted_iota(jnp.int32, shape, 1 - rows_axis))
+    if padded:
+        col = jax.lax.broadcasted_iota(jnp.int32, shape, 1 - rows_axis)
+
+    def mask(row0, col0):
+        m = None
+        if cfg.causal:      # row + offset >= col
+            m = diff >= col0 - row0 - cfg.offset
+        if padded:
+            inside = col < cfg.kv_len - col0
+            m = inside if m is None else jnp.logical_and(m, inside)
+        return m
+    return mask
+
+
+def _where(mask, x, other):
+    return x if mask is None else jnp.where(mask, x, other)
+
+
+def _two_loops(lo, split, hi, body, carry, masked_first):
+    """Run body(masked)(i, carry) over [lo, hi): one side of `split` with
+    the mask, the other without."""
+    carry = jax.lax.fori_loop(lo, split, body(masked_first), carry)
+    return jax.lax.fori_loop(split, hi, body(not masked_first), carry)
+
+
+def _carry(scratch, init, step, loop):
+    """loop(carry) over the steps of the last grid axis. With ONE step
+    (operands resident) the carry never leaves the loop; with more it is
+    parked between steps in `scratch`, whose float32 (rows, 128) buffers
+    hold a (rows, 1) statistic broadcast along the lanes (a one-lane
+    scratch costs a relayout a step: +0.7 ms a layer on the chip)."""
+    if not scratch:
+        return loop(init)
+
+    @pl.when(step == 0)
+    def _():
+        for ref, x in zip(scratch, init):
+            ref[...] = jnp.broadcast_to(x, ref.shape)
+
+    carry = loop(tuple(ref[:, :x.shape[1]] for ref, x in zip(scratch, init)))
+    for ref, x in zip(scratch, carry):
+        ref[...] = jnp.broadcast_to(x, ref.shape)
+    return carry
+
+
+def _scratch(steps, *shapes):
+    """Scratch of `_carry`: none when the last grid axis has one step."""
+    if steps == 1:
+        return []
+    return [pltpu.VMEM((rows, _ru(cols, _LANES) if cols == 1 else cols),
+                       jnp.float32) for rows, cols in shapes]
+
+
+def _at_last(steps, step, fn):
+    if steps == 1:
+        fn()
+    else:
+        pl.when(step == steps - 1)(fn)
+
+
+def _key_range(cfg, qi, kj, bq, bk, k_major):
+    """Sub-blocks of major key block kj that query block qi runs: local
+    indices [0, full) need no mask, [full, end) do."""
+    subs = k_major // bk
+    lo = kj * subs
+    full = cfg.kv_len // bk                   # before the padding
+    end = -(-cfg.kv_len // bk)
+    if cfg.causal:
+        # columns <= row + offset; the first row decides what is full
+        full = jnp.minimum(full, (qi * bq + cfg.offset + 1) // bk)
+        end = jnp.minimum(end, ((qi + 1) * bq + cfg.offset + bk - 1) // bk)
+    end = jnp.clip(end - lo, 0, subs)
+    return jnp.clip(full - lo, 0, end), end
+
+
+def _kv_map(cfg, num):
+    """Index map of K and V for the forward and dq grids, clamped to the
+    last major key block query block i reads: a step beyond the diagonal
+    re-uses the block it holds."""
+    bq, k_major = cfg.plan.bq, cfg.plan.k_major
+
+    def kv_map(b, i, j):
+        if cfg.causal:
+            last = ((i + 1) * bq - 1 + cfg.offset) // k_major
+            j = jnp.minimum(j, jnp.clip(last, 0, num - 1))
+        return (b, j, 0)
+    return kv_map
+
+
 # ---------------------------------------------------------------------------
 # forward
 # ---------------------------------------------------------------------------
 
-def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr, *,
-                scale, causal, block_q, block_k, kv_len, num_kv, offset):
-    qi, ki = pl.program_id(1), pl.program_id(2)
+def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch, cfg):
+    p_ = cfg.plan
+    bq, bk = p_.bq, p_.bk
+    qi, kj = pl.program_id(1), pl.program_id(2)
+    q = q_ref[0]
+    full, end = _key_range(cfg, qi, kj, bq, bk, p_.k_major)
+    mask = _masker(cfg, (bq, bk), 0)
 
-    @pl.when(ki == 0)
-    def _():
-        m_scr[:] = jnp.full_like(m_scr, _NEG)
-        l_scr[:] = jnp.zeros_like(l_scr)
-        acc_scr[:] = jnp.zeros_like(acc_scr)
+    def body(masked):
+        def step(kb, carry):
+            m, l, acc = carry
+            start = pl.multiple_of(kb * bk, bk)
+            k = k_ref[0, pl.ds(start, bk), :]
+            v = v_ref[0, pl.ds(start, bk), :]
+            s = jax.lax.dot_general(
+                q, k, _NT, preferred_element_type=jnp.float32) * cfg.scale
+            if masked:
+                s = _where(mask(qi * bq, kj * p_.k_major + start), s, _NEG)
+            m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
+            alpha = jnp.exp(m - m_new)
+            p = jnp.exp(s - m_new)
+            l = alpha * l + jnp.sum(p, axis=1, keepdims=True)
+            acc = acc * alpha + jax.lax.dot(
+                p.astype(v.dtype), v, preferred_element_type=jnp.float32)
+            return m_new, l, acc
+        return step
 
-    run = ((ki * block_k < (qi + 1) * block_q + offset) if causal
-           else (ki >= 0))
+    init = (jnp.full((bq, 1), _NEG, jnp.float32),
+            jnp.zeros((bq, 1), jnp.float32),
+            jnp.zeros((bq, q.shape[1]), jnp.float32))
+    m, l, acc = _carry(scratch, init, kj, lambda carry: _two_loops(
+        0, full, end, body, carry, False))
 
-    @pl.when(run)
-    def _():
-        q = q_ref[0]
-        k = k_ref[0]
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
-        col = ki * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 1)
-        mask = col < kv_len
-        if causal:
-            row = qi * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
-            mask = jnp.logical_and(mask, row + offset >= col)
-        s = jnp.where(mask, s, _NEG)
+    def store():
+        l1 = jnp.where(l == 0.0, 1.0, l)
+        o_ref[0] = (acc / l1).astype(o_ref.dtype)
+        # the column as a row: broadcast along the lanes, transposed on the
+        # XLU (a reshape costs four times as much, 0.19 ms a layer)
+        lse = jnp.broadcast_to(m + jnp.log(l1), (bq, _LANES))
+        lse_ref[0] = lse.T[:1]
 
-        m_prev = m_scr[:, 0:1]
-        l_prev = l_scr[:, 0:1]
-        m_cur = jnp.max(s, axis=1, keepdims=True)
-        m_new = jnp.maximum(m_prev, m_cur)
-        alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new)
-        l_new = alpha * l_prev + jnp.sum(p, axis=1, keepdims=True)
-        m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
-        l_scr[:] = jnp.broadcast_to(l_new, l_scr.shape)
-        acc_scr[:] = acc_scr[:] * alpha + jax.lax.dot(
-            p.astype(v_ref.dtype), v_ref[0],
-            preferred_element_type=jnp.float32)
-
-    if causal:
-        last = jnp.clip(((qi + 1) * block_q - 1 + offset) // block_k,
-                        0, num_kv - 1)
-    else:
-        last = num_kv - 1
-
-    @pl.when(ki == last)
-    def _():
-        l = l_scr[:, 0:1]
-        l = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0] = (acc_scr[:] / l).astype(o_ref.dtype)
-        lse = (m_scr[:, 0:1] + jnp.log(l)).reshape(1, block_q)
-        lse_ref[0, 0:1, pl.ds(pl.multiple_of(qi * block_q, block_q),
-                              block_q)] = lse
+    _at_last(p_.lkp // p_.k_major, kj, store)
 
 
 def _fwd(q, k, v, cfg):
-    scale, causal, bq, bk, kv_len, offset, interpret = cfg
+    p_ = cfg.plan
     bh, lq, d = q.shape
-    lk = k.shape[1]
-    num_q, num_kv = lq // bq, lk // bk
-    kern = functools.partial(_fwd_kernel, scale=scale, causal=causal,
-                             block_q=bq, block_k=bk, kv_len=kv_len,
-                             num_kv=num_kv, offset=offset)
-    return pl.pallas_call(
-        kern,
-        grid=(bh, num_q, num_kv),
+    bq, km = p_.bq, p_.k_major
+    num_q, num_k = lq // bq, k.shape[1] // km
+    kv_map = _kv_map(cfg, num_k)
+    return _call(
+        functools.partial(_fwd_kernel, cfg=cfg), cfg, "flash_attention_fwd",
+        grid=(bh, num_q, num_k),
         in_specs=[_vspec((1, bq, d), lambda b, i, j: (b, i, 0)),
-                  _vspec((1, bk, d), lambda b, i, j: (b, j, 0)),
-                  _vspec((1, bk, d), lambda b, i, j: (b, j, 0))],
+                  _vspec((1, km, d), kv_map),
+                  _vspec((1, km, d), kv_map)],
         out_specs=[_vspec((1, bq, d), lambda b, i, j: (b, i, 0)),
-                   _vspec((1, 1, lq), lambda b, i, j: (b, 0, 0))],
+                   _vspec((1, 1, bq), lambda b, i, j: (b, 0, i))],
         out_shape=[jax.ShapeDtypeStruct((bh, lq, d), q.dtype),
                    jax.ShapeDtypeStruct((bh, 1, lq), jnp.float32)],
-        scratch_shapes=[pltpu.VMEM((bq, _LANES), jnp.float32),
-                        pltpu.VMEM((bq, _LANES), jnp.float32),
-                        pltpu.VMEM((bq, d), jnp.float32)],
-        interpret=interpret,
-        name="flash_attention_fwd",
+        scratch_shapes=_scratch(num_k, (bq, 1), (bq, 1), (bq, d)),
     )(q, k, v)
 
 
@@ -128,152 +342,187 @@ def _fwd(q, k, v, cfg):
 # backward
 # ---------------------------------------------------------------------------
 
-def _row(ref, start, size):
-    """Read (1, size) slice of a (1, 1, L) block as (size, 1)."""
-    return ref[0, 0:1, pl.ds(pl.multiple_of(start, size),
-                             size)].reshape(size, 1)
+def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref, dq_ref, *scratch,
+               cfg):
+    p_ = cfg.plan
+    bq, bk = p_.bq, p_.bk
+    qi, kj = pl.program_id(1), pl.program_id(2)
+    q, do = q_ref[0], do_ref[0]
+    lse = lse_ref[0].reshape(bq, 1)
+    dl = dl_ref[0].reshape(bq, 1)
+    full, end = _key_range(cfg, qi, kj, bq, bk, p_.k_major)
+    mask = _masker(cfg, (bq, bk), 0)
+
+    def body(masked):
+        def step(kb, carry):
+            dq, = carry
+            start = pl.multiple_of(kb * bk, bk)
+            k = k_ref[0, pl.ds(start, bk), :]
+            v = v_ref[0, pl.ds(start, bk), :]
+            s = jax.lax.dot_general(
+                q, k, _NT, preferred_element_type=jnp.float32) * cfg.scale
+            p = jnp.exp(s - lse)
+            if masked:
+                p = _where(mask(qi * bq, kj * p_.k_major + start), p, 0.0)
+            dp = jax.lax.dot_general(do, v, _NT,
+                                     preferred_element_type=jnp.float32)
+            ds = p * (dp - dl)       # x scale: once, on the sum
+            return dq + jax.lax.dot(ds.astype(k.dtype), k,
+                                    preferred_element_type=jnp.float32),
+        return step
+
+    dq, = _carry(scratch, (jnp.zeros(q.shape, jnp.float32),), kj,
+                 lambda carry: _two_loops(0, full, end, body, carry, False))
+
+    def store():
+        dq_ref[0] = (dq * cfg.scale).astype(dq_ref.dtype)
+
+    _at_last(p_.lkp // p_.k_major, kj, store)
 
 
-def _masked_p(q, k, lse_col, scale, causal, qi, ki, block_q, block_k, kv_len,
-              offset):
-    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32) * scale
-    p = jnp.exp(s - lse_col)
-    col = ki * block_k + jax.lax.broadcasted_iota(
-        jnp.int32, (block_q, block_k), 1)
-    mask = col < kv_len
-    if causal:
-        row = qi * block_q + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 0)
-        mask = jnp.logical_and(mask, row + offset >= col)
-    return jnp.where(mask, p, 0.0)
-
-
-def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref, dq_ref, dq_scr, *,
-               scale, causal, block_q, block_k, kv_len, num_kv, offset):
-    qi, ki = pl.program_id(1), pl.program_id(2)
-
-    @pl.when(ki == 0)
-    def _():
-        dq_scr[:] = jnp.zeros_like(dq_scr)
-
-    run = ((ki * block_k < (qi + 1) * block_q + offset) if causal
-           else (ki >= 0))
-
-    @pl.when(run)
-    def _():
-        k, v, do = k_ref[0], v_ref[0], do_ref[0]
-        lse = _row(lse_ref, qi * block_q, block_q)
-        dl = _row(dl_ref, qi * block_q, block_q)
-        p = _masked_p(q_ref[0], k, lse, scale, causal, qi, ki,
-                      block_q, block_k, kv_len, offset)
-        dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        ds = p * (dp - dl) * scale
-        dq_scr[:] = dq_scr[:] + jax.lax.dot(
-            ds.astype(k.dtype), k, preferred_element_type=jnp.float32)
-
-    if causal:
-        last = jnp.clip(((qi + 1) * block_q - 1 + offset) // block_k,
-                        0, num_kv - 1)
-    else:
-        last = num_kv - 1
-
-    @pl.when(ki == last)
-    def _():
-        dq_ref[0] = dq_scr[:].astype(dq_ref.dtype)
+def _merged(plan):
+    """One backward kernel, not two: when the queries of a head are
+    resident for dk/dv anyway."""
+    return plan.q_major == plan.lqp
 
 
 def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref, dk_ref, dv_ref,
-                dk_scr, dv_scr, *, scale, causal, block_q, block_k, kv_len,
-                num_q, offset):
-    ki, qi = pl.program_id(1), pl.program_id(2)
-    if causal:
-        first = jnp.clip((ki * block_k - offset) // block_q, 0, num_q - 1)
-    else:
-        first = 0
+                *rest, cfg):
+    """dk and dv of one key block. With Q and dO resident (`_merged`) the
+    same pass gives dq as well: every tile adds its ds k to the head's
+    float32 dq, kept in VMEM across the key blocks, so the scores and
+    their exp are recomputed once in backward, not twice."""
+    p_ = cfg.plan
+    bk, bq = p_.dkv_bk, p_.dkv_bq
+    subs, num_q = p_.q_major // bq, p_.lqp // bq
+    kj, qm = pl.program_id(1), pl.program_id(2)
+    k, v = k_ref[0], v_ref[0]
+    scratch = rest
+    if _merged(p_):
+        (dq_ref, dq_scr), scratch = rest, ()
 
-    @pl.when(qi == first)
-    def _():
-        dk_scr[:] = jnp.zeros_like(dk_scr)
-        dv_scr[:] = jnp.zeros_like(dv_scr)
+        @pl.when(kj == 0)
+        def _():
+            dq_scr[...] = jnp.zeros_like(dq_scr)
+    # query sub-blocks this key block meets: [first, num_q); the diagonal
+    # crosses [first, full), the padding of the keys every one of them
+    first, full = 0, 0
+    if cfg.causal:
+        first = jnp.clip((kj * bk - cfg.offset) // bq, 0, num_q)
+        full = jnp.clip(((kj + 1) * bk - 1 - cfg.offset + bq - 1) // bq,
+                        first, num_q)
+    if cfg.kv_len % bk:
+        full = jnp.where((kj + 1) * bk > cfg.kv_len, num_q, full)
+    lo = qm * subs
+    first = jnp.clip(first - lo, 0, subs)
+    full = jnp.clip(full - lo, first, subs)
+    mask = _masker(cfg, (bk, bq), 1)
 
-    run = ((ki * block_k < (qi + 1) * block_q + offset) if causal
-           else (qi >= 0))
+    def body(masked):
+        def step(qb, carry):
+            dk, dv = carry
+            start = pl.multiple_of(qb * bq, bq)
+            q = q_ref[0, pl.ds(start, bq), :]
+            do = do_ref[0, pl.ds(start, bq), :]
+            lse = lse_ref[0, :, pl.ds(start, bq)]
+            dl = dl_ref[0, :, pl.ds(start, bq)]
+            # transposed tiles: keys along rows, queries along lanes
+            s = jax.lax.dot_general(
+                k, q, _NT, preferred_element_type=jnp.float32) * cfg.scale
+            p = jnp.exp(s - lse)
+            if masked:
+                p = _where(mask(qm * p_.q_major + start, kj * bk), p, 0.0)
+            dv = dv + jax.lax.dot(p.astype(do.dtype), do,
+                                  preferred_element_type=jnp.float32)
+            dp = jax.lax.dot_general(v, do, _NT,
+                                     preferred_element_type=jnp.float32)
+            ds = (p * (dp - dl)).astype(q.dtype)  # x scale: once, on the sum
+            dk = dk + jax.lax.dot(ds, q, preferred_element_type=jnp.float32)
+            if _merged(p_):
+                dq_scr[pl.ds(start, bq), :] += jax.lax.dot_general(
+                    ds, k, _TN, preferred_element_type=jnp.float32)
+            return dk, dv
+        return step
 
-    @pl.when(run)
-    def _():
-        q, v, do = q_ref[0], v_ref[0], do_ref[0]
-        lse = _row(lse_ref, qi * block_q, block_q)
-        dl = _row(dl_ref, qi * block_q, block_q)
-        p = _masked_p(q, k_ref[0], lse, scale, causal, qi, ki,
-                      block_q, block_k, kv_len, offset)
-        pt = p.astype(do.dtype)
-        dv_scr[:] = dv_scr[:] + jax.lax.dot_general(
-            pt, do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        ds = (p * (dp - dl) * scale).astype(q.dtype)
-        dk_scr[:] = dk_scr[:] + jax.lax.dot_general(
-            ds, q, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+    zeros = jnp.zeros(k.shape, jnp.float32)
+    dk, dv = _carry(scratch, (zeros, zeros), qm, lambda carry: _two_loops(
+        first, full, subs, body, carry, True))
 
-    @pl.when(qi == num_q - 1)
-    def _():
-        dk_ref[0] = dk_scr[:].astype(dk_ref.dtype)
-        dv_ref[0] = dv_scr[:].astype(dv_ref.dtype)
+    def store():
+        dk_ref[0] = (dk * cfg.scale).astype(dk_ref.dtype)
+        dv_ref[0] = dv.astype(dv_ref.dtype)
+
+    _at_last(p_.lqp // p_.q_major, qm, store)
+    if _merged(p_):
+        @pl.when(kj == p_.lkp // bk - 1)
+        def _():
+            dq_ref[0] = (dq_scr[...] * cfg.scale).astype(dq_ref.dtype)
 
 
 def _bwd(cfg, res, dout):
-    scale, causal, bq, bk, kv_len, offset, interpret = cfg
+    p_ = cfg.plan
     q, k, v, out, lse = res
     do, _ = dout
     bh, lq, d = q.shape
     lk = k.shape[1]
-    num_q, num_kv = lq // bq, lk // bk
     delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32),
                     axis=-1).reshape(bh, 1, lq)
 
-    dq = pl.pallas_call(
-        functools.partial(_dq_kernel, scale=scale, causal=causal, block_q=bq,
-                          block_k=bk, kv_len=kv_len, num_kv=num_kv,
-                          offset=offset),
-        grid=(bh, num_q, num_kv),
+    bq, km = p_.bq, p_.k_major
+    num_q, num_k = lq // bq, lk // km
+    kv_map = _kv_map(cfg, num_k)
+    merged = _merged(p_)
+    dq = None if merged else _call(
+        functools.partial(_dq_kernel, cfg=cfg), cfg, "flash_attention_dq",
+        grid=(bh, num_q, num_k),
         in_specs=[_vspec((1, bq, d), lambda b, i, j: (b, i, 0)),
-                  _vspec((1, bk, d), lambda b, i, j: (b, j, 0)),
-                  _vspec((1, bk, d), lambda b, i, j: (b, j, 0)),
+                  _vspec((1, km, d), kv_map),
+                  _vspec((1, km, d), kv_map),
                   _vspec((1, bq, d), lambda b, i, j: (b, i, 0)),
-                  _vspec((1, 1, lq), lambda b, i, j: (b, 0, 0)),
-                  _vspec((1, 1, lq), lambda b, i, j: (b, 0, 0))],
+                  _vspec((1, 1, bq), lambda b, i, j: (b, 0, i)),
+                  _vspec((1, 1, bq), lambda b, i, j: (b, 0, i))],
         out_specs=_vspec((1, bq, d), lambda b, i, j: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
-        scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
-        interpret=interpret,
-        name="flash_attention_dq",
+        scratch_shapes=_scratch(num_k, (bq, d)),
     )(q, k, v, do, lse, delta)
 
-    dk, dv = pl.pallas_call(
-        functools.partial(_dkv_kernel, scale=scale, causal=causal, block_q=bq,
-                          block_k=bk, kv_len=kv_len, num_q=num_q,
-                          offset=offset),
-        grid=(bh, num_kv, num_q),
-        in_specs=[_vspec((1, bq, d), lambda b, j, i: (b, i, 0)),
+    bk, qm = p_.dkv_bk, p_.q_major
+    num_qm = lq // qm
+
+    def first_major(j):
+        # index map clamp: the first major query block key block j meets
+        if not cfg.causal:
+            return 0
+        return jnp.clip((j * bk - cfg.offset) // qm, 0, num_qm - 1)
+
+    def q_map(b, j, i):
+        return (b, jnp.maximum(i, first_major(j)), 0)
+
+    def row_map(b, j, i):
+        return (b, 0, jnp.maximum(i, first_major(j)))
+
+    whole = [_vspec((1, lq, d), lambda b, j, i: (b, 0, 0))]
+    dk, dv, *dq_merged = _call(
+        functools.partial(_dkv_kernel, cfg=cfg), cfg,
+        "flash_attention_bwd" if merged else "flash_attention_dkv",
+        carried=(1, 2) if merged else (2,),
+        grid=(bh, lk // bk, num_qm),
+        in_specs=[_vspec((1, qm, d), q_map),
                   _vspec((1, bk, d), lambda b, j, i: (b, j, 0)),
                   _vspec((1, bk, d), lambda b, j, i: (b, j, 0)),
-                  _vspec((1, bq, d), lambda b, j, i: (b, i, 0)),
-                  _vspec((1, 1, lq), lambda b, j, i: (b, 0, 0)),
-                  _vspec((1, 1, lq), lambda b, j, i: (b, 0, 0))],
+                  _vspec((1, qm, d), q_map),
+                  _vspec((1, 1, qm), row_map),
+                  _vspec((1, 1, qm), row_map)],
         out_specs=[_vspec((1, bk, d), lambda b, j, i: (b, j, 0)),
-                   _vspec((1, bk, d), lambda b, j, i: (b, j, 0))],
+                   _vspec((1, bk, d), lambda b, j, i: (b, j, 0))]
+        + whole * merged,
         out_shape=[jax.ShapeDtypeStruct(k.shape, k.dtype),
-                   jax.ShapeDtypeStruct(v.shape, v.dtype)],
-        scratch_shapes=[pltpu.VMEM((bk, d), jnp.float32),
-                        pltpu.VMEM((bk, d), jnp.float32)],
-        interpret=interpret,
-        name="flash_attention_dkv",
+                   jax.ShapeDtypeStruct(v.shape, v.dtype)]
+        + [jax.ShapeDtypeStruct(q.shape, q.dtype)] * merged,
+        scratch_shapes=([pltpu.VMEM((lq, d), jnp.float32)] if merged else
+                        _scratch(num_qm, (bk, d), (bk, d))),
     )(q, k, v, do, lse, delta)
-    return dq, dk, dv
+    return (dq_merged[0] if merged else dq), dk, dv
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
@@ -294,42 +543,42 @@ _flash.defvjp(_flash_fwd, _bwd)
 # public entry
 # ---------------------------------------------------------------------------
 
-def flash_attention(q, k, v, *, causal=False, scale=None, block_q=128,
-                    block_k=128, interpret=None):
+def _attention(q, k, v, causal, scale, block_q, block_k, interpret,
+               vmem_budget=_VMEM_BUDGET):
+    b, h, lq, d = q.shape
+    lk = k.shape[2]
+    if causal and lq > lk:
+        raise ValueError("flash_attention: causal with more queries than keys "
+                         "is undefined (use an explicit mask)")
+    plan = _plan(lq, lk, d, q.dtype.itemsize, interpret, block_q, block_k,
+                 vmem_budget)
+
+    def prep(x, lp):
+        x = x.reshape(b * h, x.shape[2], d)
+        if lp == x.shape[1] and plan.dp == d:
+            return x
+        return jnp.pad(x, ((0, 0), (0, lp - x.shape[1]), (0, plan.dp - d)))
+
+    cfg = _Cfg(float(scale) if scale is not None else 1.0 / (d ** 0.5),
+               bool(causal), lk, lk - lq, bool(interpret), plan)
+    out, _ = _flash(prep(q, plan.lqp), prep(k, plan.lkp), prep(v, plan.lkp),
+                    cfg)
+    if (plan.lqp, plan.dp) != (lq, d):
+        out = out[:, :lq, :d]
+    return out.reshape(b, h, lq, d)
+
+
+def flash_attention(q, k, v, *, causal=False, scale=None, block_q=None,
+                    block_k=None, interpret=None):
     """Tiled attention on (B, H, L, D) tensors; returns (B, H, Lq, D).
 
-    Differentiable (custom VJP with blockwise recompute). Padding of L and D
-    to block multiples is handled here; padded KV positions are masked inside
-    the kernel, padded Q rows are sliced off (their grads vanish since the
-    incoming cotangent there is zero).
+    Differentiable (custom VJP with blockwise recompute). The block sizes
+    come from the shape (`_plan`); `block_q` / `block_k` override them for
+    the tests. Padding, where the blocks need any, is handled here; padded
+    KV positions are masked inside the kernel, padded Q rows are sliced off
+    (their grads vanish since the incoming cotangent there is zero).
     """
     if interpret is None:
         from . import is_tpu
         interpret = not is_tpu()
-    b, h, lq, d = q.shape
-    lk = k.shape[2]
-    scale = float(scale) if scale is not None else 1.0 / (d ** 0.5)
-
-    if interpret:
-        block_q = min(block_q, _ru(lq, 16))
-        block_k = min(block_k, _ru(lk, 16))
-    else:
-        # Mosaic needs the lse dynamic-slice lane index provably 128-aligned,
-        # so q/k blocks are 128-multiples on hardware (lq/lk get padded up).
-        block_q = _ru(min(block_q, _ru(lq, _LANES)), _LANES)
-        block_k = _ru(min(block_k, _ru(lk, _LANES)), _LANES)
-    lqp, lkp = _ru(lq, block_q), _ru(lk, block_k)
-    dp = d if interpret else _ru(d, _LANES)
-
-    def prep(x, lp):
-        x = x.reshape(b * h, x.shape[2], d)
-        return jnp.pad(x, ((0, 0), (0, lp - x.shape[1]), (0, dp - d)))
-
-    q3, k3, v3 = prep(q, lqp), prep(k, lkp), prep(v, lkp)
-    if causal and lq > lk:
-        raise ValueError("flash_attention: causal with more queries than keys "
-                         "is undefined (use an explicit mask)")
-    cfg = (scale, bool(causal), block_q, block_k, lk, lk - lq,
-           bool(interpret))
-    out, _ = _flash(q3, k3, v3, cfg)
-    return out[:, :lq, :d].reshape(b, h, lq, d)
+    return _attention(q, k, v, causal, scale, block_q, block_k, interpret)

@@ -33,7 +33,10 @@ kernel           qualifies when
 ===============  =========================================================
 flash_attention  pallas enabled; no additive mask; no attention-weight
                  dropout in training mode (the kernel keeps scores in
-                 VMEM and applies no dropout)
+                 VMEM and applies no dropout). Shape never disqualifies:
+                 the kernel derives its blocks from (lq, lk, d), keeps
+                 K/V resident while a head fits VMEM and streams them
+                 beyond, and leaves a head size of 64 unpadded
 layer_norm       pallas enabled; normalized axis is the LAST axis;
                  1-D gamma; on real TPU the width is 128-lane aligned
 scale_shift_act  pallas enabled; channels-last input (the BatchNorm+ReLU

@@ -3,12 +3,17 @@
 Mirrors the reference's operator tests for the hand-written attention
 kernels (tests/python/unittest/test_operator.py multihead attention cases).
 """
+import importlib
+
 import numpy as np
 import jax
 import jax.numpy as jnp
 import pytest
 
 from incubator_mxnet_tpu.ops.pallas import flash_attention, layer_norm
+
+# the module; the package attribute of that name is the function
+fa = importlib.import_module("incubator_mxnet_tpu.ops.pallas.flash_attention")
 
 
 def naive_attention(q, k, v, scale, causal):
@@ -121,6 +126,129 @@ def test_flash_bf16():
     np.testing.assert_allclose(np.asarray(out, dtype=np.float32),
                                np.asarray(ref, dtype=np.float32),
                                rtol=3e-2, atol=3e-2)
+
+
+# (lq, lk, d, causal, block_q, block_k, vmem_budget); None = derived
+# from the shape. A budget of _TIGHT holds 64 resident positions, so 1024
+# keys stream in major blocks of 512, the least one may hold.
+_TIGHT = 3 * 4 * 128 * 4 * 64
+PARITY = {
+    "diagonal-several-query-blocks": (128, 128, 16, True, 32, 16, None),
+    "diagonal-inner-wider-than-outer": (96, 96, 16, True, 16, 48, None),
+    "offset-lq-below-lk": (48, 112, 16, True, 16, 16, None),
+    "offset-derived": (40, 100, 32, True, None, None, None),
+    "decode-one-query": (1, 33, 8, True, None, None, None),
+    "decode-one-query-blocks-16": (1, 70, 8, True, 16, 16, None),
+    "ragged-300-derived": (300, 300, 64, True, None, None, None),
+    "ragged-300-blocks-64": (300, 300, 64, True, 64, 64, None),
+    "ragged-300-full": (300, 300, 16, False, 64, 32, None),
+    "d64-unpadded": (64, 64, 64, False, None, None, None),
+    "d80-padded": (40, 40, 80, True, None, None, None),
+    "cross-lq-above-lk-full": (80, 48, 16, False, 16, 16, None),
+    "streamed-causal": (1024, 1024, 16, True, 128, 128, _TIGHT),
+    "streamed-full": (1024, 1024, 16, False, 128, 128, _TIGHT),
+    "streamed-offset": (512, 1024, 16, True, 128, 128, _TIGHT),
+    "streamed-ragged-derived": (520, 1100, 16, True, None, None, _TIGHT),
+}
+
+
+@pytest.mark.parametrize("case", PARITY)
+def test_flash_parity(case):
+    """Output and the three gradients against the XLA formulation in
+    float32, over the shapes that steer the tiling."""
+    lq, lk, d, causal, block_q, block_k, budget = PARITY[case]
+    rng = np.random.RandomState(11)
+    q, w = (jnp.asarray(rng.randn(1, 2, lq, d).astype(np.float32))
+            for _ in range(2))
+    k, v = (jnp.asarray(rng.randn(1, 2, lk, d).astype(np.float32))
+            for _ in range(2))
+    scale = 1.0 / np.sqrt(d)
+    kw = {} if budget is None else {"vmem_budget": budget}
+
+    def f_flash(q, k, v):
+        out = fa._attention(q, k, v, causal, None, block_q, block_k, True,
+                            **kw)
+        return jnp.sum(out * w), out
+
+    def f_ref(q, k, v):
+        out = naive_attention(q, k, v, scale, causal)
+        return jnp.sum(out * w), out
+
+    (_, out), grads = jax.value_and_grad(f_flash, (0, 1, 2),
+                                         has_aux=True)(q, k, v)
+    (_, ref), want = jax.value_and_grad(f_ref, (0, 1, 2),
+                                        has_aux=True)(q, k, v)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               rtol=2e-5, atol=2e-5)
+    for a, b in zip(grads, want):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=2e-4, atol=2e-4)
+
+
+# (lq, lk, d, itemsize, interpret, block_q, block_k, budget) -> what the
+# plan must say; a name of None is not checked
+PLANS = {
+    # the benchmark's cell on the chip: K and V resident, 512 x 512 tiles
+    "gpt2-cell": ((1024, 1024, 64, 2, False, None, None, None),
+                  dict(lqp=1024, lkp=1024, dp=64, bq=512, bk=512,
+                       k_major=1024, dkv_bk=512, dkv_bq=512, q_major=1024)),
+    # BERT at 128 tokens: one grid step a head
+    "bert-128": ((128, 128, 64, 2, False, None, None, None),
+                 dict(lqp=128, lkp=128, dp=64, bq=128, bk=128, k_major=128,
+                      q_major=128)),
+    # ragged on the chip: padded to the 128 lanes, d = 80 to 128
+    "ragged-chip": ((300, 300, 80, 2, False, None, None, None),
+                    dict(lqp=384, lkp=384, dp=128, bq=384, k_major=384)),
+    # interpreted: 16-row alignment, no lane padding of a split of 128
+    "ragged-interpreted": ((300, 300, 32, 4, True, None, None, None),
+                           dict(lqp=304, lkp=304, dp=32)),
+    "d128": ((256, 256, 128, 2, False, None, None, None), dict(dp=128)),
+    "d256": ((256, 256, 256, 2, False, None, None, None), dict(dp=256)),
+    "decode": ((1, 1000, 64, 2, False, None, None, None),
+               dict(lqp=128, lkp=1024, bq=128, k_major=1024)),
+    # 9 x 128 keys: blocks divide the padded length, nothing is padded
+    # up to a power of two
+    "odd-multiple": ((1152, 1152, 64, 2, False, None, None, None),
+                     dict(lqp=1152, lkp=1152, bq=384, bk=384)),
+    # 47 x 128 tokens: padded to 12 x 512 (2% more), not cut into 128s
+    "awkward-long": ((6000, 6000, 64, 2, False, None, None, None),
+                     dict(lqp=6144, lkp=6144, bq=512, bk=512, k_major=3072,
+                          dkv_bk=512, q_major=3072)),
+    # just over one block: nothing divides it but 128
+    "just-over-a-block": ((520, 520, 64, 2, False, None, None, None),
+                          dict(lqp=640, bq=128, k_major=640)),
+    # beyond the budget the keys stream, in major blocks of >= 512
+    "long": ((16384, 16384, 128, 2, False, None, None, None),
+             dict(lqp=16384, k_major=4096, q_major=4096)),
+    "overrides": ((100, 100, 16, 4, True, 16, 16, None),
+                  dict(lqp=112, lkp=112, bq=16, bk=16, dkv_bk=16,
+                       dkv_bq=16, k_major=112)),
+    "overrides-on-chip-round-up": ((100, 100, 16, 4, False, 16, 16, None),
+                                   dict(lqp=128, bq=128, bk=128)),
+    "tight-budget": ((1024, 1024, 16, 4, True, 128, 128, _TIGHT),
+                     dict(k_major=512, q_major=512, bq=128)),
+}
+
+
+@pytest.mark.parametrize("case", PLANS)
+def test_flash_plan_follows_the_shape(case):
+    (lq, lk, d, itemsize, interpret, bq, bk, budget), want = PLANS[case]
+    kw = {} if budget is None else {"vmem_budget": budget}
+    plan = fa._plan(lq, lk, d, itemsize, interpret, bq, bk, **kw)
+    got = {name: getattr(plan, name) for name in want}
+    assert got == want
+    # every block divides what it tiles
+    assert plan.lqp % plan.bq == 0 and plan.lkp % plan.k_major == 0
+    assert plan.k_major % plan.bk == 0 and plan.lkp % plan.dkv_bk == 0
+    assert plan.lqp % plan.q_major == 0 and plan.q_major % plan.dkv_bq == 0
+    assert plan.lqp >= lq and plan.lkp >= lk
+
+
+def test_flash_causal_needs_keys_for_every_query():
+    q = jnp.zeros((1, 1, 32, 8))
+    with pytest.raises(ValueError, match="more queries than keys"):
+        flash_attention(q, q[:, :, :16], q[:, :, :16], causal=True,
+                        interpret=True)
 
 
 def test_layer_norm_kernel():

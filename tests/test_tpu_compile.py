@@ -84,8 +84,9 @@ def test_flash_attention(compile_for_chip, causal, direction):
     if direction == "bwd":
         fn = _with_grads(fn, 3)
     text = compile_for_chip(fn, QKV, QKV, QKV)
-    # forward: one kernel; backward: forward again plus dq and dk/dv
-    assert text.count("tpu_custom_call") >= (1 if direction == "fwd" else 3)
+    # forward: one kernel; backward: forward again plus the one that
+    # gives dq, dk and dv (a head's queries are resident at 512 tokens)
+    assert text.count("tpu_custom_call") >= (1 if direction == "fwd" else 2)
 
 
 def test_flash_attention_pads_a_ragged_sequence(compile_for_chip):
@@ -97,6 +98,79 @@ def test_flash_attention_pads_a_ragged_sequence(compile_for_chip):
                                         interpret=False),
         shape, shape, shape)
     assert "tpu_custom_call" in text
+
+
+def _custom_calls(text):
+    return [line for line in text.splitlines()
+            if 'custom_call_target="tpu_custom_call"' in line]
+
+
+# the benchmark's cell gpt2_train_b16_s1024, and BERT-base at 128 tokens
+GPT2_CELL = ((16, 12, 1024, 64), BF16)
+BERT_128 = ((32, 12, 128, 64), BF16)
+
+
+@pytest.mark.parametrize("direction", ["fwd", "bwd"])
+def test_flash_attention_keeps_head_size_64(compile_for_chip, direction):
+    """At the cell's own shape the kernels see the head size as it is: no
+    operand or result of a custom-call ends in 128, and nothing in the
+    program pads (the sequence is a multiple of the blocks too)."""
+    import re
+
+    def fn(q, k, v):
+        return flash_attention(q, k, v, causal=True, interpret=False)
+    if direction == "bwd":
+        fn = _with_grads(fn, 3)
+    text = compile_for_chip(fn, GPT2_CELL, GPT2_CELL, GPT2_CELL)
+    calls = _custom_calls(text)
+    assert len(calls) == (1 if direction == "fwd" else 2)
+    for line in calls:
+        # the shapes of the call itself, before its metadata
+        shapes = re.findall(r"(?:bf16|f32)\[([\d,]+)\]",
+                            line.split("custom_call_target")[0])
+        assert shapes and not [s for s in shapes if s.endswith(",128")], line
+        assert any(s == "192,1024,64" for s in shapes)
+    assert " pad(" not in text
+
+
+@pytest.mark.parametrize("direction", ["fwd", "bwd"])
+def test_flash_attention_short_sequence(compile_for_chip, direction):
+    """BERT's shape when no padding mask is given: not causal, one block a
+    head, two kernels with gradients."""
+    def fn(q, k, v):
+        return flash_attention(q, k, v, causal=False, interpret=False)
+    if direction == "bwd":
+        fn = _with_grads(fn, 3)
+    text = compile_for_chip(fn, BERT_128, BERT_128, BERT_128)
+    assert len(_custom_calls(text)) == (1 if direction == "fwd" else 2)
+    assert " pad(" not in text
+
+
+def test_flash_attention_decode_step(compile_for_chip):
+    """TransformerLM's cached decode where it has no mask: one query
+    against a longer cache, offset > 0."""
+    text = compile_for_chip(
+        lambda q, k, v: flash_attention(q, k, v, causal=True,
+                                        interpret=False),
+        ((8, 12, 1, 64), BF16), ((8, 12, 1000, 64), BF16),
+        ((8, 12, 1000, 64), BF16))
+    assert len(_custom_calls(text)) == 1
+
+
+def test_flash_attention_streams_a_long_sequence(compile_for_chip):
+    """16384 keys at d = 128 are beyond the VMEM budget: the key axis
+    stays a grid axis, with scratch between its steps, and backward is
+    two kernels (dq; dk and dv), each streaming the other sequence."""
+    shape = ((1, 4, 16384, 128), BF16)
+    text = compile_for_chip(
+        _with_grads(lambda q, k, v: flash_attention(
+            q, k, v, causal=True, interpret=False), 3),
+        shape, shape, shape)
+    calls = _custom_calls(text)
+    assert len(calls) == 3
+    for name in ("flash_attention_fwd)", "flash_attention_dq)",
+                 "flash_attention_dkv)"):
+        assert sum(name in line for line in calls) == 1, name
 
 
 LN = (((8192, 768), BF16), ((768,), BF16), ((768,), BF16))
@@ -198,8 +272,7 @@ def test_lm_train_step(topo, no_compile_cache, monkeypatch, axes, mode):
 
 
 KERNEL_NAMES = {"flash_attention_fwd": "attention",
-                "flash_attention_dq": "attention",
-                "flash_attention_dkv": "attention",
+                "flash_attention_bwd": "attention",
                 "layer_norm_fwd": "layer_norm"}
 
 
@@ -214,8 +287,8 @@ def test_lm_train_step_kernels_are_named_and_owned(topo, no_compile_cache,
     text = _lm_step_text(topo, monkeypatch, {"dp": 1}, "dp")
     calls = [line for line in text.splitlines()
              if 'custom_call_target="tpu_custom_call"' in line]
-    # 2 layers: attention forward, dq, dkv; 2 x 2 + 1 layer norms
-    assert len(calls) == 2 * 3 + 5
+    # 2 layers: attention forward and backward; 2 x 2 + 1 layer norms
+    assert len(calls) == 2 * 2 + 5
     seen = set()
     for line in calls:
         instruction = re.match(r"\s*(?:ROOT )?%([\w\-]+?)(?:\.\d+)? = ",
@@ -228,7 +301,11 @@ def test_lm_train_step_kernels_are_named_and_owned(topo, no_compile_cache,
         assert path[0] == "jit(train_step)"
         assert re.fullmatch(r"(transpose\()?jvp\(transformer_lm_\d+\)\)?",
                             path[1])
-        assert ("transpose(" in op_name) == instruction.endswith(
-            ("_dq", "_dkv"))
+        assert ("transpose(" in op_name) == instruction.endswith("_bwd")
         seen.add(instruction)
     assert seen == set(KERNEL_NAMES)
+    # head size 64: the attention scope pads nothing and its kernels see 64
+    assert not [line for line in text.splitlines()
+                if " pad(" in line and "/attention" in line]
+    assert all("[16,128,64]" in line for line in calls
+               if "flash_attention" in line)
