@@ -20,7 +20,7 @@ from ..block import Block, HybridBlock
 __all__ = ["Sequential", "HybridSequential", "Dense", "Dropout", "Flatten",
            "Activation", "LeakyReLU", "PReLU", "ELU", "SELU", "GELU", "Swish",
            "SiLU", "Embedding", "BatchNorm", "BatchNormReLU", "LayerNorm", "InstanceNorm",
-           "GroupNorm", "Conv1D", "Conv2D", "Conv3D", "Conv1DTranspose",
+           "GroupNorm", "RMSNorm", "SparseExperts", "Conv1D", "Conv2D", "Conv3D", "Conv1DTranspose",
            "Conv2DTranspose", "Conv3DTranspose", "MaxPool1D", "MaxPool2D",
            "MaxPool3D", "AvgPool1D", "AvgPool2D", "AvgPool3D",
            "GlobalMaxPool1D", "GlobalMaxPool2D", "GlobalMaxPool3D",
@@ -351,6 +351,90 @@ class LayerNorm(HybridBlock):
     def forward(self, x):
         return ops.LayerNorm(x, self.gamma.data(), self.beta.data(),
                              axis=self._axis, eps=self._eps)
+
+
+class RMSNorm(HybridBlock):
+    """x / sqrt(mean(x^2) + epsilon) * gamma over the last axis, the
+    statistic in float32 (ops/_raw.py `rms_norm`)."""
+
+    def __init__(self, epsilon=1e-6, gamma_initializer="ones", in_channels=0,
+                 prefix=None, params=None):
+        super().__init__(prefix, params)
+        self._eps = epsilon
+        self.gamma = self.params.get("gamma", shape=(in_channels,),
+                                     init=gamma_initializer)
+
+    def infer_shape(self, x):
+        self.gamma.shape = (x.shape[-1],)
+
+    def forward(self, x):
+        return ops.RMSNorm(x, self.gamma.data(), eps=self._eps)
+
+
+class SparseExperts(HybridBlock):
+    """Sparse-expert feed-forward layer, as ONE holder of an expert-parallel
+    deployment runs it: the router scores all `num_experts`, every token
+    takes its `top_k`, and this block computes the part of the result that
+    the experts it holds give, `held=(first, count)` (default: all of them).
+    Dropless: no assignment to a held expert is lost, whatever the routing
+    (ops/_raw.py `sparse_experts`). Expert e is (silu(x gate_e) * (x up_e))
+    down_e, `units` -> `hidden_size` -> `units`, no bias.
+
+    `load` (num_experts int32, `grad_req="null"`) holds the assignments each
+    expert got in the last training step; it is updated as BatchNorm's
+    running statistics are, keeps its type under `cast`, and `read_load()`
+    puts it on the profiler's counters."""
+
+    def __init__(self, units, hidden_size, num_experts, top_k, held=None,
+                 norm_topk_prob=True, weight_initializer=None, prefix=None,
+                 params=None):
+        super().__init__(prefix, params)
+        first, count = held if held is not None else (0, num_experts)
+        if not 0 <= first <= first + count <= num_experts or count < 1:
+            raise ValueError(f"held={held!r} of {num_experts} experts")
+        self._top_k = top_k
+        self._first = first
+        self._norm = norm_topk_prob
+        get = self.params.get
+        self.router = get("router", shape=(num_experts, units),
+                          init=weight_initializer)
+        self.gate = get("gate", shape=(count, units, hidden_size),
+                        init=weight_initializer)
+        self.up = get("up", shape=(count, units, hidden_size),
+                      init=weight_initializer)
+        self.down = get("down", shape=(count, hidden_size, units),
+                        init=weight_initializer)
+        self.load = get("load", shape=(num_experts,), dtype="int32",
+                        init="zeros", grad_req="null")
+
+    def cast(self, dtype):
+        # `load` counts: it stays int32 (bfloat16 cannot count past 256)
+        for p in (self.router, self.gate, self.up, self.down):
+            p.cast(dtype)
+        self._dtype = dtype
+
+    def forward(self, x):
+        y, load = ops.sparse_experts(
+            x, self.router.data(), self.gate.data(), self.up.data(),
+            self.down.data(), self._top_k, self._first, self._norm)
+        if autograd.is_training():
+            self.load.update_aux(load._data)
+        return y
+
+    def read_load(self):
+        """{live_rows, load_max_over_mean} of the last training step: the
+        assignments to the experts held here and the largest expert's load
+        over the mean, read from `load` (a device-to-host copy) and set as
+        the counters `moe.live_rows` and `moe.load_max_over_mean`."""
+        from ... import profiler as _prof
+        load = np.asarray(self.load.data().asnumpy(), np.int64)
+        count = self.gate.shape[0]
+        got = {"live_rows": int(load[self._first:self._first + count].sum()),
+               "load_max_over_mean": float(load.max() / max(load.mean(),
+                                                            1e-9))}
+        for name, value in got.items():
+            _prof.set_gauge("moe." + name, value)
+        return got
 
 
 class InstanceNorm(HybridBlock):

@@ -23,7 +23,8 @@ __all__ = ["FullyConnected", "Convolution", "Deconvolution", "Pooling",
            "ConvBNReLU",
            "BatchNorm", "LayerNorm", "InstanceNorm", "GroupNorm", "Activation",
            "Dropout", "L2Normalization", "softmax_cross_entropy", "smooth_l1",
-           "UpSampling", "multihead_attention", "box_iou", "box_nms",
+           "UpSampling", "multihead_attention", "RMSNorm", "rope",
+           "sparse_experts", "box_iou", "box_nms",
            "MultiBoxPrior", "MultiBoxTarget", "MultiBoxDetection",
            "ROIPooling", "ROIAlign", "BilinearResize2D",
            "AdaptiveAvgPooling2D", "im2col", "SliceChannel",
@@ -293,8 +294,18 @@ def SliceChannel(data, num_outputs, axis=1, squeeze_axis=False):
 
 
 def multihead_attention(q, k, v, num_heads, mask=None, dropout_rate=0.0,
-                        scale=None, causal=False):
+                        scale=None, causal=False, num_kv_heads=None,
+                        window=None):
+    """Attention on projected (B, L, heads * head_dim) tensors; `num_kv_heads`
+    gives k and v fewer heads than q (query head h reads key/value head
+    h // group), `window` (with `causal`) the keys a row sees up to its own.
+    Causal, window and grouped heads stay in the flash kernel
+    (ops/_raw.py `multihead_attention`, ops/select.py)."""
     if _symbolic(q):
+        if num_kv_heads is not None or window is not None:
+            raise NotImplementedError(
+                "symbol trace of multihead_attention has no num_kv_heads= "
+                "or window=")
         if dropout_rate and dropout_rate > 0.0:
             import warnings
             warnings.warn(
@@ -312,8 +323,37 @@ def multihead_attention(q, k, v, num_heads, mask=None, dropout_rate=0.0,
     def f(qq, kk, vv, *rest):
         m = rest[0] if rest else None
         return _raw.multihead_attention(qq, kk, vv, num_heads, m, dropout_rate,
-                                        key, training, scale, causal)
+                                        key, training, scale, causal,
+                                        num_kv_heads, window)
     return _apply(f, inputs, name="multihead_attention")
+
+
+def RMSNorm(data, gamma, eps=1e-6):
+    """x / sqrt(mean(x^2) + eps) * gamma over the last axis, float32
+    statistic (ops/_raw.py `rms_norm`)."""
+    return _apply(lambda x, g: _raw.rms_norm(x, g, eps), [data, gamma],
+                  name="RMSNorm")
+
+
+def rope(data, inv_freq, num_heads, factor=1.0):
+    """Rotary positions on (B, L, heads * head_dim); `inv_freq` and `factor`
+    as `ops._raw.rope_frequencies` gives them."""
+    return _apply(lambda x: _raw.rope(x, inv_freq, num_heads, factor),
+                  [data], name="rope")
+
+
+def sparse_experts(data, router, gate, up, down, top_k, first=0,
+                   norm_topk_prob=True):
+    """(y, load) of `ops._raw.sparse_experts`: the held experts' part of a
+    dropless top-k expert layer, and the assignments each expert got."""
+    def f(x, r, g, u, d):
+        y, load = _raw.sparse_experts(x, r, g, u, d, top_k, first,
+                                      norm_topk_prob)
+        # the tape wants a cotangent of every output's own dtype
+        return y, load.astype(jnp.float32)
+    y, load = _apply(f, [data, router, gate, up, down], n_out=2,
+                     name="sparse_experts")
+    return y, NDArray(load._data.astype(jnp.int32))
 
 
 def SequenceMask(data, sequence_length=None, use_sequence_length=False,

@@ -15,6 +15,8 @@ have the same owner in a device trace (docs/profiler.md).
 """
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -586,37 +588,59 @@ def smooth_l1(x, scalar=1.0):
 
 @jax.named_scope("attention")
 def multihead_attention(q, k, v, num_heads, mask=None, dropout_rate=0.0,
-                        key=None, training=False, scale=None, causal=False):
+                        key=None, training=False, scale=None, causal=False,
+                        num_kv_heads=None, window=None):
     """Batched MHA on (B, L, D) inputs already projected; splits heads,
     scaled-dot-product, merges heads. Reference: src/operator/contrib/
     transformer.cc (interleaved_matmul_*).
 
-    Fast path: qualifying calls (no custom mask/dropout — see
-    ops/select.py) dispatch to the pallas flash-attention kernel
-    (ops/pallas/) — O(L) memory, scores stay in VMEM."""
+    `num_kv_heads` (grouped heads): k and v hold that many heads of q's
+    head size, and query head h reads key/value head h // (num_heads /
+    num_kv_heads). `window` (with `causal`): a row sees the `window` keys
+    up to its own.
+
+    Fast path: the pallas flash-attention kernel (ops/pallas/) — O(L)
+    memory, scores stay in VMEM; causal, window and grouped heads all stay
+    in it. What leaves it for the XLA formulation below (ops/select.py): an
+    explicit mask, attention-weight dropout in training, a program
+    partitioned over a mesh."""
     from . import select as _sel
 
     b, lq, d = q.shape
     lk = k.shape[1]
     hd = d // num_heads
+    kv_heads = num_heads if num_kv_heads is None else num_kv_heads
+    if num_heads % kv_heads or k.shape[2] != kv_heads * hd:
+        raise ValueError(f"multihead_attention: {num_heads} heads of {hd} over "
+                         f"{kv_heads} key/value heads need k of width "
+                         f"{kv_heads * hd}, got {k.shape[2]}")
+    if window is not None and not causal:
+        raise ValueError("multihead_attention: window= needs causal=True")
     scale = scale if scale is not None else 1.0 / (hd ** 0.5)
 
-    def split(x, l):
-        return x.reshape(b, l, num_heads, hd).transpose(0, 2, 1, 3)
+    def split(x, l, heads):
+        return x.reshape(b, l, heads, hd).transpose(0, 2, 1, 3)
 
+    qh = split(q, lq, num_heads)
+    kh, vh = split(k, lk, kv_heads), split(v, lk, kv_heads)
     if _sel.flash_attention(mask, dropout_rate > 0.0 and training):
         from . import pallas as _pallas
-        out = _pallas.flash_attention(split(q, lq), split(k, lk), split(v, lk),
-                                      causal=causal, scale=scale)
+        out = _pallas.flash_attention(qh, kh, vh, causal=causal,
+                                      window=window, scale=scale)
         return out.transpose(0, 2, 1, 3).reshape(b, lq, d)
 
-    qh, kh, vh = split(q, lq), split(k, lk), split(v, lk)
+    if kv_heads != num_heads:
+        kh, vh = (jnp.repeat(x, num_heads // kv_heads, axis=1)
+                  for x in (kh, vh))
     scores = jnp.einsum("bhqd,bhkd->bhqk", qh, kh) * scale
     if causal:
         if lq > lk:
             raise ValueError("causal attention with more queries than keys is "
                              "undefined (use an explicit mask)")
         tri = jnp.tril(jnp.ones((lq, lk), dtype=bool), k=lk - lq)
+        if window is not None:      # the band: row - col < window
+            tri = jnp.logical_and(tri, ~jnp.tril(
+                jnp.ones((lq, lk), dtype=bool), k=lk - lq - window))
         mask = tri if mask is None else jnp.logical_and(mask, tri)
     if mask is not None:
         scores = jnp.where(mask, scores, jnp.asarray(-1e9, scores.dtype))
@@ -625,6 +649,176 @@ def multihead_attention(q, k, v, num_heads, mask=None, dropout_rate=0.0,
         w = dropout(w, key, dropout_rate, training)
     out = jnp.einsum("bhqk,bhkd->bhqd", w, vh)
     return out.transpose(0, 2, 1, 3).reshape(b, lq, d)
+
+
+# ---------------------------------------------------------------------------
+# RMS norm, rotary positions
+# ---------------------------------------------------------------------------
+
+@jax.named_scope("rms_norm")
+def rms_norm(x, gamma, eps=1e-6):
+    """x / sqrt(mean(x^2) + eps) * gamma over the last axis; the statistic
+    and the scaling in float32, the result in x's dtype."""
+    xf = x.astype(jnp.float32)
+    y = xf * lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True) + eps)
+    return (y * gamma.astype(jnp.float32)).astype(x.dtype)
+
+
+def rope_frequencies(head_dim, rope_type="default", rope_theta=10000.0,
+                     factor=1.0, original_max_position_embeddings=None,
+                     beta_fast=32.0, beta_slow=1.0, attention_factor=None):
+    """(inv_freq, attention_factor) of a rotary embedding from the keys of a
+    published `rope_parameters` section: head_dim / 2 float64 frequencies
+    and the factor cos and sin are multiplied by.
+
+    "default": inv_freq_j = theta^(-2j / head_dim), factor 1.
+    "yarn" (Peng et al. 2023, as HF's `_compute_yarn_parameters`): the
+    frequencies that turn fewer than `beta_slow` times over the original
+    context are divided by `factor`, those that turn more than `beta_fast`
+    times are kept, with a linear ramp over the dimensions between; the
+    attention factor defaults to 0.1 ln(factor) + 1."""
+    half = head_dim // 2
+    inv_freq = rope_theta ** (-np.arange(half, dtype=np.float64) / half)
+    if rope_type == "default":
+        return inv_freq, 1.0
+    if rope_type != "yarn":
+        raise ValueError(f"rope_frequencies: rope_type {rope_type!r} "
+                         f"(known: 'default', 'yarn')")
+
+    def dimension(turns):       # the dimension that turns `turns` times
+        return (head_dim * np.log(original_max_position_embeddings
+                                  / (turns * 2 * np.pi))
+                / (2 * np.log(rope_theta)))
+    low = max(np.floor(dimension(beta_fast)), 0)
+    high = min(np.ceil(dimension(beta_slow)), head_dim - 1)
+    ramp = np.clip((np.arange(half) - low) / max(high - low, 1e-3), 0, 1)
+    inv_freq = inv_freq / factor * ramp + inv_freq * (1 - ramp)
+    if attention_factor is None:
+        attention_factor = 0.1 * np.log(factor) + 1.0 if factor > 1 else 1.0
+    return inv_freq, float(attention_factor)
+
+
+@jax.named_scope("rope")
+def rope(x, inv_freq, num_heads, factor=1.0):
+    """Rotary positions on (B, L, H * head_dim), HF's rotate-half form on
+    each head: x cos + rotate_half(x) sin, with cos and sin of
+    position * inv_freq repeated over both halves and times `factor`.
+    Angles and the rotation in float32, the result in x's dtype."""
+    b, l, d = x.shape
+    hd = d // num_heads
+    angle = (jnp.arange(l, dtype=jnp.float32)[:, None]
+             * jnp.asarray(inv_freq, jnp.float32)[None, :])
+    cos = (jnp.cos(angle) * factor)[None, :, None, :]
+    sin = (jnp.sin(angle) * factor)[None, :, None, :]
+    xf = x.astype(jnp.float32).reshape(b, l, num_heads, hd)
+    x1, x2 = xf[..., :hd // 2], xf[..., hd // 2:]
+    out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
+    return out.reshape(b, l, d).astype(x.dtype)
+
+
+# ---------------------------------------------------------------------------
+# sparse experts (dropless top-k routing over the experts held here)
+# ---------------------------------------------------------------------------
+
+def grouped_matmul(lhs, rhs, group_sizes):
+    """lhs[rows of group g] @ rhs[g] for consecutive row groups: lhs (M, K),
+    rhs (G, K, N), group_sizes (G,) int32 -> (M, N). Rows beyond the groups
+    are UNSPECIFIED, in the result and in lhs's gradient (zeros from
+    `jax.lax.ragged_dot`, whatever the buffer held from the Mosaic grouped
+    matmul of ops/pallas/, which runs where ops/select.py qualifies it)."""
+    from . import select as _sel
+    if _sel.grouped_matmul(lhs, rhs):
+        from . import pallas as _pallas
+        return _pallas.grouped_matmul(lhs, rhs, group_sizes)
+    return lax.ragged_dot(lhs, rhs, group_sizes)
+
+
+def _held_rows(rows, held, top_k):
+    """(tokens, top_k, D) of the rows gathered for every assignment, zero
+    where the assignment's expert is not held: those rows lie beyond the
+    groups, where `grouped_matmul` writes nothing."""
+    rows = rows.reshape(-1, top_k, rows.shape[-1])
+    return jnp.where(held.reshape(-1, top_k, 1), rows,
+                     jnp.zeros((), rows.dtype))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
+def _dispatch_rows(x, order, slot, held, top_k):
+    """Row r of the result is token order[r] // top_k: the tokens, one copy
+    an assignment, in the order of their experts."""
+    return x[order // top_k]
+
+
+def _dispatch_bwd(top_k, res, g):
+    # `slot` is the inverse of `order`: a token's gradient is a gather of
+    # its top_k rows, where autodiff would scatter-add
+    slot, held = res
+    picked = _held_rows(g[slot], held, top_k)
+    return (jnp.sum(picked, axis=1, dtype=jnp.float32).astype(g.dtype),
+            None, None, None)
+
+
+_dispatch_rows.defvjp(
+    lambda x, order, slot, held, top_k: (x[order // top_k], (slot, held)),
+    _dispatch_bwd)
+
+
+@jax.custom_vjp
+def _unsort_rows(y, order, slot):
+    """Row a of the result is row slot[a] of y: the experts' rows back in
+    the order of the assignments."""
+    return y[slot]
+
+
+_unsort_rows.defvjp(lambda y, order, slot: (y[slot], order),
+                    lambda order, g: (g[order], None, None))
+
+
+@jax.named_scope("moe")
+def sparse_experts(x, router, gate, up, down, top_k, first=0,
+                   norm_topk_prob=True):
+    """The part that the experts held here add to a sparse-expert layer.
+
+    x (..., D); router (E, D) over ALL E experts; gate and up (C, D, F),
+    down (C, F, D): the C experts [first, first + C) held here. Every token
+    takes its `top_k` largest of softmax(x router^T) (float32), with weights
+    normalised over the top_k when `norm_topk_prob`; expert e gives
+    (silu(x gate_e) * (x up_e)) down_e. The result sums, for each token, the
+    weighted outputs of its experts that are held here; what the others
+    would add is left out. No assignment to a held expert is dropped,
+    whatever the routing: the row buffers hold tokens x top_k rows, the
+    assignments to held experts sorted by expert in front, and the grouped
+    products compute those rows and no other.
+
+    Returns (y like x, load (E,) int32: assignments to each expert)."""
+    lead, d = x.shape[:-1], x.shape[-1]
+    x2 = x.reshape(-1, d)
+    tokens, experts, count = x2.shape[0], router.shape[0], gate.shape[0]
+    with jax.named_scope("router"):
+        logits = jnp.dot(x2, router.T, preferred_element_type=jnp.float32)
+        prob, chosen = lax.top_k(jax.nn.softmax(logits, axis=-1), top_k)
+        if norm_topk_prob:
+            prob = prob / jnp.sum(prob, axis=-1, keepdims=True)
+        chosen = chosen.reshape(-1)
+        # counted by comparison: a scatter-add of every assignment is slow
+        load = jnp.sum(chosen[:, None] == jnp.arange(experts)[None, :],
+                       axis=0, dtype=jnp.int32)
+    with jax.named_scope("dispatch"):
+        local = chosen - first
+        held = jnp.logical_and(local >= 0, local < count)
+        # assignments to held experts first, by expert; the rest behind
+        order = jnp.argsort(jnp.where(held, local, count), stable=True)
+        slot = jnp.argsort(order)       # the inverse permutation
+        sizes = lax.dynamic_slice(load, (first,), (count,))
+        rows = _dispatch_rows(x2, order, slot, held, top_k)
+    with jax.named_scope("experts"):
+        inner = (jax.nn.silu(grouped_matmul(rows, gate, sizes))
+                 * grouped_matmul(rows, up, sizes))
+        out = grouped_matmul(inner.astype(x.dtype), down, sizes)
+    with jax.named_scope("combine"):
+        mine = _held_rows(_unsort_rows(out, order, slot), held, top_k)
+        y = jnp.sum(mine.astype(jnp.float32) * prob[..., None], axis=1)
+    return y.astype(x.dtype).reshape(*lead, d), load
 
 
 # ---------------------------------------------------------------------------

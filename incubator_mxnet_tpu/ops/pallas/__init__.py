@@ -11,11 +11,13 @@ tests/test_tpu_compile.py compiles them for a described v5e).
 from .flash_attention import flash_attention
 from .layer_norm import layer_norm
 from .conv_bn_relu import conv_bn_relu, scale_shift_act, fold_bn
+from .grouped_matmul import grouped_matmul
 
 import jax
 
 __all__ = ["flash_attention", "layer_norm", "conv_bn_relu",
-           "scale_shift_act", "fold_bn", "enabled", "is_tpu"]
+           "scale_shift_act", "fold_bn", "grouped_matmul", "enabled",
+           "is_tpu"]
 
 
 def enabled() -> bool:

@@ -33,6 +33,15 @@ in VMEM.
   re-uses the block it holds. Which regime runs depends on the shape alone.
 - only the sub-blocks the diagonal (or the padding of the keys) crosses
   build a mask; the ones below it run a loop body without one.
+- `window=w` (with `causal`): row r sees the w keys up to its own. The
+  window is a BOUND, not a mask: the key loop of forward and dq starts at
+  the first sub-block the window reaches, the query loop of dk/dv ends at
+  the last one, and the streamed index maps are clamped on both sides, so
+  a window layer costs window / length of a full one. Only the sub-blocks
+  either edge crosses build a mask. `window=None` builds today's kernels.
+- grouped heads: K and V may hold fewer heads than Q (`group` query heads
+  to one). Forward and dq read them through the index map (head b // group);
+  dk/dv are written per QUERY head and summed over the group outside.
 
 `_plan` derives every block from (lq, lk, d, dtype) under the budget;
 `block_q=` / `block_k=` override it for the tests.
@@ -155,6 +164,8 @@ class _Cfg(NamedTuple):
     offset: int      # lk - lq: query row r sees key columns <= r + offset
     interpret: bool
     plan: _Plan
+    window: int | None = None   # ... and > r + offset - window
+    group: int = 1              # query heads to one key/value head
 
 
 def _vspec(shape, index_map):
@@ -188,6 +199,9 @@ def _masker(cfg, shape, rows_axis):
         m = None
         if cfg.causal:      # row + offset >= col
             m = diff >= col0 - row0 - cfg.offset
+        if cfg.window is not None:      # row + offset - col < window
+            m = jnp.logical_and(
+                m, diff < cfg.window + col0 - row0 - cfg.offset)
         if padded:
             inside = col < cfg.kv_len - col0
             m = inside if m is None else jnp.logical_and(m, inside)
@@ -199,11 +213,14 @@ def _where(mask, x, other):
     return x if mask is None else jnp.where(mask, x, other)
 
 
-def _two_loops(lo, split, hi, body, carry, masked_first):
-    """Run body(masked)(i, carry) over [lo, hi): one side of `split` with
-    the mask, the other without."""
-    carry = jax.lax.fori_loop(lo, split, body(masked_first), carry)
-    return jax.lax.fori_loop(split, hi, body(not masked_first), carry)
+def _loops(bounds, body, carry, masked_first):
+    """Run body(masked)(i, carry) over consecutive ranges: `bounds` are
+    their edges, and the ranges take turns with and without the mask."""
+    masked = masked_first
+    for lo, hi in zip(bounds, bounds[1:]):
+        carry = jax.lax.fori_loop(lo, hi, body(masked), carry)
+        masked = not masked
+    return carry
 
 
 def _carry(scratch, init, step, loop):
@@ -242,8 +259,11 @@ def _at_last(steps, step, fn):
 
 
 def _key_range(cfg, qi, kj, bq, bk, k_major):
-    """Sub-blocks of major key block kj that query block qi runs: local
-    indices [0, full) need no mask, [full, end) do."""
+    """Sub-blocks of major key block kj that query block qi runs, as the
+    edges of `_loops`: without a window (0, full, end), local indices
+    [0, full) need no mask and [full, end) do; with one (first, inside, full,
+    end), where [first, inside) are the sub-blocks the window's edge crosses
+    and take the mask too."""
     subs = k_major // bk
     lo = kj * subs
     full = cfg.kv_len // bk                   # before the padding
@@ -253,20 +273,32 @@ def _key_range(cfg, qi, kj, bq, bk, k_major):
         full = jnp.minimum(full, (qi * bq + cfg.offset + 1) // bk)
         end = jnp.minimum(end, ((qi + 1) * bq + cfg.offset + bk - 1) // bk)
     end = jnp.clip(end - lo, 0, subs)
-    return jnp.clip(full - lo, 0, end), end
+    if cfg.window is None:
+        return 0, jnp.clip(full - lo, 0, end), end
+    # columns > row + offset - window: the first row decides where the keys
+    # start, the last row which sub-block is inside for every row
+    reach = qi * bq + cfg.offset - cfg.window + 1
+    first = jnp.clip(jnp.maximum(reach, 0) // bk - lo, 0, end)
+    inside = jnp.clip((jnp.maximum(reach + bq - 1, 0) + bk - 1) // bk - lo,
+                      first, end)
+    return first, inside, jnp.clip(full - lo, inside, end), end
 
 
 def _kv_map(cfg, num):
     """Index map of K and V for the forward and dq grids, clamped to the
-    last major key block query block i reads: a step beyond the diagonal
-    re-uses the block it holds."""
+    major key blocks query block i reads (down to the diagonal, up from
+    where the window starts): a step beyond either re-uses the block it
+    holds. Query head b reads key/value head b // group."""
     bq, k_major = cfg.plan.bq, cfg.plan.k_major
 
     def kv_map(b, i, j):
         if cfg.causal:
             last = ((i + 1) * bq - 1 + cfg.offset) // k_major
             j = jnp.minimum(j, jnp.clip(last, 0, num - 1))
-        return (b, j, 0)
+        if cfg.window is not None:
+            reach = i * bq + cfg.offset - cfg.window + 1
+            j = jnp.maximum(j, jnp.clip(reach // k_major, 0, num - 1))
+        return (b // cfg.group if cfg.group > 1 else b, j, 0)
     return kv_map
 
 
@@ -279,7 +311,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch, cfg):
     bq, bk = p_.bq, p_.bk
     qi, kj = pl.program_id(1), pl.program_id(2)
     q = q_ref[0]
-    full, end = _key_range(cfg, qi, kj, bq, bk, p_.k_major)
+    edges = _key_range(cfg, qi, kj, bq, bk, p_.k_major)
     mask = _masker(cfg, (bq, bk), 0)
 
     def body(masked):
@@ -304,8 +336,8 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch, cfg):
     init = (jnp.full((bq, 1), _NEG, jnp.float32),
             jnp.zeros((bq, 1), jnp.float32),
             jnp.zeros((bq, q.shape[1]), jnp.float32))
-    m, l, acc = _carry(scratch, init, kj, lambda carry: _two_loops(
-        0, full, end, body, carry, False))
+    m, l, acc = _carry(scratch, init, kj, lambda carry: _loops(
+        edges, body, carry, cfg.window is not None))
 
     def store():
         l1 = jnp.where(l == 0.0, 1.0, l)
@@ -350,7 +382,7 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref, dq_ref, *scratch,
     q, do = q_ref[0], do_ref[0]
     lse = lse_ref[0].reshape(bq, 1)
     dl = dl_ref[0].reshape(bq, 1)
-    full, end = _key_range(cfg, qi, kj, bq, bk, p_.k_major)
+    edges = _key_range(cfg, qi, kj, bq, bk, p_.k_major)
     mask = _masker(cfg, (bq, bk), 0)
 
     def body(masked):
@@ -372,7 +404,8 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref, dq_ref, *scratch,
         return step
 
     dq, = _carry(scratch, (jnp.zeros(q.shape, jnp.float32),), kj,
-                 lambda carry: _two_loops(0, full, end, body, carry, False))
+                 lambda carry: _loops(edges, body, carry,
+                                       cfg.window is not None))
 
     def store():
         dq_ref[0] = (dq * cfg.scale).astype(dq_ref.dtype)
@@ -404,18 +437,29 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref, dk_ref, dv_ref,
         @pl.when(kj == 0)
         def _():
             dq_scr[...] = jnp.zeros_like(dq_scr)
-    # query sub-blocks this key block meets: [first, num_q); the diagonal
-    # crosses [first, full), the padding of the keys every one of them
-    first, full = 0, 0
+    # query sub-blocks this key block meets: [first, last); the diagonal
+    # crosses [first, full), the window's edge [inside, last), the padding
+    # of the keys every one of them
+    first, full, last = 0, 0, num_q
     if cfg.causal:
         first = jnp.clip((kj * bk - cfg.offset) // bq, 0, num_q)
         full = jnp.clip(((kj + 1) * bk - 1 - cfg.offset + bq - 1) // bq,
                         first, num_q)
+    if cfg.window is not None:
+        # rows < col + window - offset: the last column decides where the
+        # queries end, the first which sub-block is inside for every row
+        reach = cfg.window - cfg.offset + kj * bk
+        last = jnp.clip((reach + bk - 2) // bq + 1, full, num_q)
     if cfg.kv_len % bk:
-        full = jnp.where((kj + 1) * bk > cfg.kv_len, num_q, full)
+        full = jnp.where((kj + 1) * bk > cfg.kv_len, last, full)
     lo = qm * subs
-    first = jnp.clip(first - lo, 0, subs)
-    full = jnp.clip(full - lo, first, subs)
+    edges = [jnp.clip(first - lo, 0, subs)]
+    edges.append(jnp.clip(full - lo, edges[0], subs))
+    if cfg.window is None:
+        edges.append(subs)
+    else:
+        edges.append(jnp.clip(reach // bq - lo, edges[1], subs))
+        edges.append(jnp.clip(last - lo, edges[2], subs))
     mask = _masker(cfg, (bk, bq), 1)
 
     def body(masked):
@@ -445,8 +489,8 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref, dk_ref, dv_ref,
         return step
 
     zeros = jnp.zeros(k.shape, jnp.float32)
-    dk, dv = _carry(scratch, (zeros, zeros), qm, lambda carry: _two_loops(
-        first, full, subs, body, carry, True))
+    dk, dv = _carry(scratch, (zeros, zeros), qm, lambda carry: _loops(
+        edges, body, carry, True))
 
     def store():
         dk_ref[0] = (dk * cfg.scale).astype(dk_ref.dtype)
@@ -489,17 +533,25 @@ def _bwd(cfg, res, dout):
     bk, qm = p_.dkv_bk, p_.q_major
     num_qm = lq // qm
 
-    def first_major(j):
-        # index map clamp: the first major query block key block j meets
-        if not cfg.causal:
-            return 0
-        return jnp.clip((j * bk - cfg.offset) // qm, 0, num_qm - 1)
+    def met(j, i):
+        # index map clamp: the major query blocks key block j meets, from
+        # the diagonal to where the window ends
+        if cfg.causal:
+            i = jnp.maximum(i, jnp.clip((j * bk - cfg.offset) // qm,
+                                        0, num_qm - 1))
+        if cfg.window is not None:
+            end = (j + 1) * bk - 2 + cfg.window - cfg.offset
+            i = jnp.minimum(i, jnp.clip(end // qm, 0, num_qm - 1))
+        return i
 
     def q_map(b, j, i):
-        return (b, jnp.maximum(i, first_major(j)), 0)
+        return (b, met(j, i), 0)
 
     def row_map(b, j, i):
-        return (b, 0, jnp.maximum(i, first_major(j)))
+        return (b, 0, met(j, i))
+
+    def kv_head(b, j, i):
+        return (b // cfg.group if cfg.group > 1 else b, j, 0)
 
     whole = [_vspec((1, lq, d), lambda b, j, i: (b, 0, 0))]
     dk, dv, *dq_merged = _call(
@@ -508,20 +560,25 @@ def _bwd(cfg, res, dout):
         carried=(1, 2) if merged else (2,),
         grid=(bh, lk // bk, num_qm),
         in_specs=[_vspec((1, qm, d), q_map),
-                  _vspec((1, bk, d), lambda b, j, i: (b, j, 0)),
-                  _vspec((1, bk, d), lambda b, j, i: (b, j, 0)),
+                  _vspec((1, bk, d), kv_head),
+                  _vspec((1, bk, d), kv_head),
                   _vspec((1, qm, d), q_map),
                   _vspec((1, 1, qm), row_map),
                   _vspec((1, 1, qm), row_map)],
         out_specs=[_vspec((1, bk, d), lambda b, j, i: (b, j, 0)),
                    _vspec((1, bk, d), lambda b, j, i: (b, j, 0))]
         + whole * merged,
-        out_shape=[jax.ShapeDtypeStruct(k.shape, k.dtype),
-                   jax.ShapeDtypeStruct(v.shape, v.dtype)]
+        # a group's dk and dv are summed from float32, rounded once
+        out_shape=[jax.ShapeDtypeStruct(
+            (bh, lk, d), x.dtype if cfg.group == 1 else jnp.float32)
+            for x in (k, v)]
         + [jax.ShapeDtypeStruct(q.shape, q.dtype)] * merged,
         scratch_shapes=([pltpu.VMEM((lq, d), jnp.float32)] if merged else
                         _scratch(num_qm, (bk, d), (bk, d))),
     )(q, k, v, do, lse, delta)
+    if cfg.group > 1:       # one dk, dv a query head: sum each group's
+        dk, dv = (x.reshape(-1, cfg.group, lk, d).sum(1).astype(k.dtype)
+                  for x in (dk, dv))
     return (dq_merged[0] if merged else dq), dk, dv
 
 
@@ -544,23 +601,32 @@ _flash.defvjp(_flash_fwd, _bwd)
 # ---------------------------------------------------------------------------
 
 def _attention(q, k, v, causal, scale, block_q, block_k, interpret,
-               vmem_budget=_VMEM_BUDGET):
+               vmem_budget=_VMEM_BUDGET, window=None):
     b, h, lq, d = q.shape
     lk = k.shape[2]
     if causal and lq > lk:
         raise ValueError("flash_attention: causal with more queries than keys "
                          "is undefined (use an explicit mask)")
+    if window is not None and not (causal and window > 0):
+        raise ValueError("flash_attention: window= counts the keys up to a "
+                         "row's own, so it needs causal=True and window > 0")
+    if h % k.shape[1] or k.shape != v.shape:
+        raise ValueError(f"flash_attention: {h} query heads over key/value "
+                         f"shapes {k.shape} / {v.shape}")
     plan = _plan(lq, lk, d, q.dtype.itemsize, interpret, block_q, block_k,
                  vmem_budget)
 
     def prep(x, lp):
-        x = x.reshape(b * h, x.shape[2], d)
+        x = x.reshape(-1, x.shape[2], d)
         if lp == x.shape[1] and plan.dp == d:
             return x
         return jnp.pad(x, ((0, 0), (0, lp - x.shape[1]), (0, plan.dp - d)))
 
+    if window is not None and window >= lk:
+        window = None           # every key up to a row's own: plain causal
     cfg = _Cfg(float(scale) if scale is not None else 1.0 / (d ** 0.5),
-               bool(causal), lk, lk - lq, bool(interpret), plan)
+               bool(causal), lk, lk - lq, bool(interpret), plan,
+               window, h // k.shape[1])
     out, _ = _flash(prep(q, plan.lqp), prep(k, plan.lkp), prep(v, plan.lkp),
                     cfg)
     if (plan.lqp, plan.dp) != (lq, d):
@@ -568,9 +634,13 @@ def _attention(q, k, v, causal, scale, block_q, block_k, interpret,
     return out.reshape(b, h, lq, d)
 
 
-def flash_attention(q, k, v, *, causal=False, scale=None, block_q=None,
-                    block_k=None, interpret=None):
+def flash_attention(q, k, v, *, causal=False, window=None, scale=None,
+                    block_q=None, block_k=None, interpret=None):
     """Tiled attention on (B, H, L, D) tensors; returns (B, H, Lq, D).
+
+    `window=w` (causal only): a row sees the w keys up to its own. K and V
+    may hold fewer heads than Q, (B, H / group, Lk, D): query head h reads
+    key/value head h // group.
 
     Differentiable (custom VJP with blockwise recompute). The block sizes
     come from the shape (`_plan`); `block_q` / `block_k` override them for
@@ -581,4 +651,5 @@ def flash_attention(q, k, v, *, causal=False, scale=None, block_q=None,
     if interpret is None:
         from . import is_tpu
         interpret = not is_tpu()
-    return _attention(q, k, v, causal, scale, block_q, block_k, interpret)
+    return _attention(q, k, v, causal, scale, block_q, block_k, interpret,
+                      window=window)

@@ -31,12 +31,17 @@ Selection table (docs/trainloop.md renders this):
 ===============  =========================================================
 kernel           qualifies when
 ===============  =========================================================
-flash_attention  pallas enabled; no additive mask; no attention-weight
+flash_attention  pallas enabled; no explicit mask; no attention-weight
                  dropout in training mode (the kernel keeps scores in
-                 VMEM and applies no dropout). Shape never disqualifies:
-                 the kernel derives its blocks from (lq, lk, d), keeps
-                 K/V resident while a head fits VMEM and streams them
-                 beyond, and leaves a head size of 64 unpadded
+                 VMEM and applies no dropout). Causal, a window and
+                 grouped key/value heads stay in the kernel, and shape
+                 never disqualifies: the kernel derives its blocks from
+                 (lq, lk, d), keeps K/V resident while a head fits VMEM
+                 and streams them beyond, and leaves a head size of 64
+                 unpadded
+grouped_matmul   pallas enabled; rows, contraction and columns all
+                 multiples of 128 (the Mosaic grouped matmul's tiles);
+                 else `jax.lax.ragged_dot`
 layer_norm       pallas enabled; normalized axis is the LAST axis;
                  1-D gamma; on real TPU the width is 128-lane aligned
 scale_shift_act  pallas enabled; channels-last input (the BatchNorm+ReLU
@@ -57,9 +62,9 @@ import threading
 
 from .. import profiler as _prof
 
-__all__ = ["flash_attention", "layer_norm", "scale_shift_act",
-           "conv_bn_relu", "capture", "quiet", "partitioned",
-           "selection_table"]
+__all__ = ["flash_attention", "grouped_matmul", "layer_norm",
+           "scale_shift_act", "conv_bn_relu", "capture", "quiet",
+           "partitioned", "selection_table"]
 
 _tls = threading.local()
 
@@ -149,14 +154,31 @@ def _on_tpu():
 
 def flash_attention(mask, dropout_active: bool) -> bool:
     """Qualify the pallas flash-attention kernel for a multihead-attention
-    call (O(L) memory, scores stay in VMEM)."""
+    call (O(L) memory, scores stay in VMEM). An explicit mask, attention
+    dropout and a mesh program leave it; causal, a window and grouped
+    key/value heads do not."""
     if not _open("flash_attention"):
         return False
     if mask is not None:
-        return _decide("flash_attention", False, "explicit mask")
+        return _decide("flash_attention", False,
+                       "explicit mask (causal and window stay in the kernel)")
     if dropout_active:
-        return _decide("flash_attention", False, "attention dropout")
+        return _decide("flash_attention", False,
+                       "attention-weight dropout in training")
     return _decide("flash_attention", True, "ok")
+
+
+def grouped_matmul(lhs, rhs) -> bool:
+    """Qualify the Mosaic grouped matmul (jax's megablox kernels behind
+    ops/pallas/grouped_matmul.py) for the sparse experts' products: lhs
+    (M, K) in row groups against rhs (G, K, N)."""
+    if not _open("grouped_matmul"):
+        return False
+    m, k, n = lhs.shape[0], lhs.shape[1], rhs.shape[2]
+    if m % 128 or k % 128 or n % 128:
+        return _decide("grouped_matmul", False,
+                       f"({m}, {k}, {n}) not all multiples of 128")
+    return _decide("grouped_matmul", True, "ok")
 
 
 def layer_norm(x, gamma, axis) -> bool:
@@ -222,7 +244,10 @@ def conv_bn_relu(x, weight, stride, pad, dilate, num_group,
 def selection_table():
     """The qualification rules as data (docs/tests): kernel -> rule."""
     return {
-        "flash_attention": "no mask, no attention-weight dropout",
+        "flash_attention": ("no explicit mask, no attention-weight "
+                            "dropout; causal, window and grouped heads "
+                            "stay"),
+        "grouped_matmul": "rows, contraction and columns % 128 == 0",
         "layer_norm": "last-axis, 1-D gamma; TPU: width % 128 == 0",
         "scale_shift_act": "channels-last; TPU: channels % 128 == 0",
         "conv_bn_relu": ("inference BN, NHWC, ungrouped/undilated; "

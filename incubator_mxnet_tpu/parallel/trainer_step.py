@@ -471,6 +471,15 @@ class FusedTrainStep:
         with _select.quiet():  # inspection must not count as a selection
             return self._jitted.lower(*specs)
 
+    def states(self):
+        """The optimizer's state of every trained parameter after the last
+        step, for inspection, in the order of `train_idx` (positions in
+        `collect_params()`): the tuples that
+        `Optimizer.create_state_multi_precision` made, so with master
+        weights `(float32 master, *state)`. These are the live buffers,
+        which the next step donates: copy what has to outlive it."""
+        return list(self._states or ())
+
     # -- execution --------------------------------------------------------
     # The host's side of a step, as spans on the device trace's clock
     # (docs/profiler.md, "Names in a device trace"): `mxtpu.step` is the

@@ -173,6 +173,57 @@ def test_flash_attention_streams_a_long_sequence(compile_for_chip):
         assert sum(name in line for line in calls) == 1, name
 
 
+# the Mellum2 cell's attention: 32 query heads over 4 key/value heads of
+# 128, 8192 tokens, a window of 1024 on three layers in four
+MELLUM_Q = ((1, 32, 8192, 128), BF16)
+MELLUM_KV = ((1, 4, 8192, 128), BF16)
+
+
+@pytest.mark.parametrize("window", [1024, None], ids=["window", "full"])
+def test_flash_attention_window_and_grouped_heads_streamed(compile_for_chip,
+                                                           window):
+    """At 8192 positions of head size 128 the keys stream (two major blocks
+    of 4096) and backward is `_dq` and `_dkv` apart; the window and the
+    grouped heads change index maps and loop bounds, not the kernels'
+    number. K and V enter the kernels with their 4 heads: nothing repeats
+    them to 32 first."""
+    import re
+
+    text = compile_for_chip(
+        _with_grads(lambda q, k, v: flash_attention(
+            q, k, v, causal=True, window=window, interpret=False), 3),
+        MELLUM_Q, MELLUM_KV, MELLUM_KV)
+    calls = _custom_calls(text)
+    assert len(calls) == 3
+    for name in ("flash_attention_fwd)", "flash_attention_dq)",
+                 "flash_attention_dkv)"):
+        line, = [c for c in calls if name in c]
+        # results before the call, operands in its layout constraints
+        shapes = re.findall(r"(?:bf16|f32)\[([\d,]+)\]",
+                            line.split("metadata=")[0])
+        assert "4,8192,128" in shapes and "32,8192,128" in shapes, line
+
+
+def test_grouped_matmul_at_the_cells_sizes(compile_for_chip):
+    """The experts' three products forward and backward, 65536 rows held of
+    which the group sizes say how many are live, 8 experts of 2304 x 896:
+    six `gmm` and three `tgmm` kernels, no ragged-dot."""
+    from incubator_mxnet_tpu.ops.pallas import grouped_matmul
+
+    def experts(rows, gate, up, down, sizes):
+        inner = (jax.nn.silu(grouped_matmul(rows, gate, sizes,
+                                            interpret=False))
+                 * grouped_matmul(rows, up, sizes, interpret=False))
+        return grouped_matmul(inner, down, sizes, interpret=False)
+    text = compile_for_chip(
+        _with_grads(experts, 4), ((65536, 2304), BF16),
+        ((8, 2304, 896), BF16), ((8, 2304, 896), BF16),
+        ((8, 896, 2304), BF16), ((8,), jnp.int32))
+    calls = _custom_calls(text)
+    assert sum("tgmm" in c for c in calls) == 3
+    assert len(calls) == 9 and "ragged-dot" not in text
+
+
 LN = (((8192, 768), BF16), ((768,), BF16), ((768,), BF16))
 
 
@@ -309,3 +360,58 @@ def test_lm_train_step_kernels_are_named_and_owned(topo, no_compile_cache,
                 if " pad(" in line and "/attention" in line]
     assert all("[16,128,64]" in line for line in calls
                if "flash_attention" in line)
+
+
+def test_mellum2_cell_train_step(topo, no_compile_cache, monkeypatch):
+    """The FusedTrainStep program of the benchmark's Mellum2 cell at its
+    published widths and its 1 x 8192 tokens, one sliding and one full layer
+    of the period of four (half the cell's depth: the other two repeat the
+    sliding one): it compiles for the described v5e inside a chip's memory,
+    with the attention kernels, the grouped products and every new op scope
+    as the owners of their operations (docs/profiler.md)."""
+    import importlib.util
+    import json
+    import os
+    import re
+
+    import numpy as np
+
+    from incubator_mxnet_tpu import nd
+    from incubator_mxnet_tpu.parallel import FusedTrainStep, make_mesh
+
+    configs = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmark", "configs")
+    with open(os.path.join(configs, "mellum2_12b_a2.5b_ep8.json")) as f:
+        doc = json.load(f)
+    del doc["rehearse"]
+    doc.update(num_hidden_layers=2, layer_types=doc["layer_types"][2:4])
+    spec = importlib.util.spec_from_file_location(
+        "mellum2_config", os.path.join(configs, "mellum2_12b_a2.5b_ep8.py"))
+    model = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(model)
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    step = FusedTrainStep(model.net(doc, 1), model.loss(doc),
+                          model.optimizer(doc),
+                          mesh=make_mesh({"dp": 1}, topo.devices[:1]),
+                          sharding="dp")
+    tokens = nd.array(np.zeros((1, 8192), np.int32))
+    compiled = step.lower(tokens, tokens).compile()
+    held = compiled.memory_analysis()
+    assert (held.argument_size_in_bytes + held.temp_size_in_bytes
+            < 15 * 2 ** 30)
+    text = compiled.as_text()
+    kernels = {}
+    for line in _custom_calls(text):
+        name = re.match(r"\s*(?:ROOT )?%([\w\-]+?)(?:\.\d+)? = ",
+                        line).group(1)
+        kernels[name] = kernels.get(name, 0) + 1
+        op_name = re.search(r'op_name="([^"]*)"', line).group(1)
+        scope = "attention" if name.startswith("flash") else "moe/experts"
+        assert f"/{scope}/" in op_name, line[:160]
+    assert kernels == {"flash_attention_fwd": 2, "flash_attention_dq": 2,
+                       "flash_attention_dkv": 2, "gmm": 12, "tgmm": 6}
+    for scope in ("rms_norm", "rope", "moe/router", "moe/dispatch",
+                  "moe/experts", "moe/combine"):
+        assert f"/{scope}/" in text, scope
+    assert "ragged-dot" not in text

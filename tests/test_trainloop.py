@@ -300,6 +300,33 @@ class TestTrainLoop:
             "sgd", learning_rate=0.1), remat=True, remat_policy=policy)
         np.testing.assert_allclose(float(s2(x, y)), a, rtol=1e-6)
 
+    def test_states_show_the_master_weight_and_the_optimizers_own(self):
+        net = _net()
+        net.cast("bfloat16")
+        step = FusedTrainStep(net, L, mx.optimizer.create(
+            "adam", learning_rate=0.01, multi_precision=True))
+        assert step.states() == []
+        x, y = _data()
+        x = x.astype("bfloat16")
+        step.ensure_built(x, y)
+        before = [np.asarray(p.data().jax(), np.float32)
+                  for p in net.collect_params().values()]
+        step(x, y)
+        states = step.states()
+        assert len(states) == len(step.train_idx) == 4
+        for i, (master, mean, variance) in zip(step.train_idx, states):
+            assert master.dtype == mean.dtype == jnp.float32
+            assert master.shape == before[i].shape
+            # Adam's first update moves a weight by the rate against its
+            # gradient's sign, and keeps a tenth of the gradient as the mean
+            moved = np.asarray(master) - before[i]
+            sign = np.sign(np.asarray(mean))
+            np.testing.assert_allclose(moved[sign != 0],
+                                       -0.01 * sign[sign != 0], rtol=1e-3)
+            np.testing.assert_allclose(np.asarray(variance),
+                                       0.1 * np.asarray(mean) ** 2,
+                                       rtol=1e-4, atol=1e-30)
+
     def test_bad_remat_policy_raises(self):
         step = FusedTrainStep(_net(), L, "sgd", remat=True,
                               remat_policy="bogus")
@@ -438,7 +465,7 @@ class TestPallasSelection:
         assert log == [
             {"kernel": "layer_norm", "selected": True, "reason": "ok"},
             {"kernel": "flash_attention", "selected": False,
-             "reason": "explicit mask"}]
+             "reason": "explicit mask (causal and window stay in the kernel)"}]
         c = prof.counters()
         assert c["ops/pallas.selected.layer_norm"] >= 1
         assert c["ops/pallas.rejected.flash_attention"] >= 1

@@ -5,7 +5,12 @@ the chip tool; the benchmark's `correct` cannot see a wrong gradient that
 still descends).
 
     python3 tools/attention_parity.py [--shape B H L D] [--full]
+                                      [--kv-heads N] [--window W]
                                       [--kernel path/to/flash_attention.py]
+
+`--kv-heads` gives K and V fewer heads than Q (grouped heads), `--window`
+the keys a row sees up to its own; the Mellum2 cell's layers are `--shape 1
+32 8192 128 --kv-heads 4` with `--window 1024` and without.
 
 Prints one JSON line a kernel file (this tree's first): for the output and
 the three gradients the largest absolute error and that error over the
@@ -36,26 +41,35 @@ from incubator_mxnet_tpu.ops import _raw
 from lib import xplane
 
 
-def reference(q, k, v, w, causal):
+def reference(q, k, v, w, causal, window=None):
     """ops/_raw.py's XLA branch (an explicit all-ones mask keeps the call
-    off the kernel), float32 operands and float32 products."""
+    off the kernel), float32 operands and float32 products; one key/value
+    head with its query heads at a time, so that the (L, L) scores of a
+    long sequence fit."""
     b, h, lq, d = q.shape
+    group = h // k.shape[1]
 
     def merge(x):
         return x.astype(jnp.float32).transpose(0, 2, 1, 3).reshape(
-            b, x.shape[2], h * d)
+            b, x.shape[2], -1)
 
-    def loss(q, k, v):
+    def loss(q, k, v, w):
         out = _raw.multihead_attention(
-            merge(q), merge(k), merge(v), h, causal=causal,
+            merge(q), merge(k), merge(v), group, causal=causal,
+            num_kv_heads=1, window=window,
             mask=jnp.ones((lq, k.shape[2]), bool))
-        out = out.reshape(b, lq, h, d).transpose(0, 2, 1, 3)
+        out = out.reshape(b, lq, group, d).transpose(0, 2, 1, 3)
         return jnp.sum(out * w.astype(jnp.float32)), out
 
+    one = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2), has_aux=True))
+    parts = []
     with jax.default_matmul_precision("highest"):
-        (_, out), grads = jax.jit(jax.value_and_grad(
-            loss, argnums=(0, 1, 2), has_aux=True))(q, k, v)
-    return (out,) + grads
+        for j in range(k.shape[1]):
+            heads = slice(j * group, (j + 1) * group)
+            (_, out), grads = one(q[:, heads], k[:, j:j + 1], v[:, j:j + 1],
+                                  w[:, heads])
+            parts.append([np.asarray(x, np.float32) for x in (out,) + grads])
+    return [np.concatenate(xs, axis=1) for xs in zip(*parts)]
 
 
 def load(path):
@@ -95,6 +109,8 @@ def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--shape", type=int, nargs=4, default=[16, 12, 1024, 64])
     ap.add_argument("--full", action="store_true", help="not causal")
+    ap.add_argument("--kv-heads", type=int, help="key/value heads (grouped)")
+    ap.add_argument("--window", type=int, help="keys a row sees (causal)")
     ap.add_argument("--kernel", action="append", default=[])
     ap.add_argument("--reps", type=int, default=10)
     ap.add_argument("--seed", type=int, default=0)
@@ -104,16 +120,21 @@ def main():
     interpret = device.platform != "tpu"
 
     rng = np.random.RandomState(args.seed % 2**32)
-    q, k, v, w = (jnp.asarray(rng.randn(*args.shape), jnp.bfloat16)
-                  for _ in range(4))
-    ref = [np.asarray(x, np.float32) for x in reference(q, k, v, w, causal)]
+    b, h, length, d = args.shape
+    kv_shape = (b, args.kv_heads or h, length, d)
+    q, w = (jnp.asarray(rng.randn(*args.shape), jnp.bfloat16)
+            for _ in range(2))
+    k, v = (jnp.asarray(rng.randn(*kv_shape), jnp.bfloat16) for _ in range(2))
+    ref = reference(q, k, v, w, causal, args.window)
+    extra = {} if args.window is None else {"window": args.window}
 
     from incubator_mxnet_tpu.ops.pallas import flash_attention
     kernels = [("this tree", flash_attention)]
     kernels += [(path, load(path)) for path in args.kernel]
     for name, kernel in kernels:
         def fwd(q, k, v):
-            return kernel(q, k, v, causal=causal, interpret=interpret)
+            return kernel(q, k, v, causal=causal, interpret=interpret,
+                          **extra)
 
         def loss(q, k, v):
             out = fwd(q, k, v)
@@ -123,6 +144,7 @@ def main():
                                           has_aux=True))
         (_, out), grads = both(q, k, v)
         line = {"kernel": name, "shape": args.shape, "causal": causal,
+                "kv_heads": kv_shape[1], "window": args.window,
                 "device": device.device_kind, "platform": device.platform}
         for what, got, want in zip(("out", "dq", "dk", "dv"),
                                    (out,) + grads, ref):
