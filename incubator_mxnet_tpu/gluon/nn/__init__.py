@@ -383,7 +383,10 @@ class SparseExperts(HybridBlock):
     `load` (num_experts int32, `grad_req="null"`) holds the assignments each
     expert got in the last training step; it is updated as BatchNorm's
     running statistics are, keeps its type under `cast`, and `read_load()`
-    puts it on the profiler's counters."""
+    puts it on the profiler's counters (`moe.live_rows`,
+    `moe.load_max_over_mean`, and `moe.row_capacity`: the rows of the
+    buffers that step walked, a quarter over the even share while the
+    routing is near its balance, tokens x top_k when it is not)."""
 
     def __init__(self, units, hidden_size, num_experts, top_k, held=None,
                  norm_topk_prob=True, weight_initializer=None, prefix=None,
@@ -422,16 +425,24 @@ class SparseExperts(HybridBlock):
         return y
 
     def read_load(self):
-        """{live_rows, load_max_over_mean} of the last training step: the
-        assignments to the experts held here and the largest expert's load
-        over the mean, read from `load` (a device-to-host copy) and set as
-        the counters `moe.live_rows` and `moe.load_max_over_mean`."""
+        """{live_rows, load_max_over_mean, row_capacity} of the last training
+        step: the assignments to the experts held here, the largest expert's
+        load over the mean, and the rows of the buffers that step walked
+        (the rung of `ops._raw.row_capacities` that held the live rows; the
+        host applies the function the program traced). Read from `load` (a
+        device-to-host copy) and set as the counters `moe.live_rows`,
+        `moe.load_max_over_mean` and `moe.row_capacity`."""
         from ... import profiler as _prof
+        from ...ops import _raw
         load = np.asarray(self.load.data().asnumpy(), np.int64)
         count = self.gate.shape[0]
-        got = {"live_rows": int(load[self._first:self._first + count].sum()),
+        live = int(load[self._first:self._first + count].sum())
+        # every assignment is counted once: `load` sums to tokens x top_k
+        ladder = _raw.row_capacities(int(load.sum()), count, load.size)
+        got = {"live_rows": live,
                "load_max_over_mean": float(load.max() / max(load.mean(),
-                                                            1e-9))}
+                                                            1e-9)),
+               "row_capacity": ladder[_raw.row_capacity(live, ladder)]}
         for name, value in got.items():
             _prof.set_gauge("moe." + name, value)
         return got

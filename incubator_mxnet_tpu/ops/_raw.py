@@ -720,14 +720,14 @@ def rope(x, inv_freq, num_heads, factor=1.0):
 # sparse experts (dropless top-k routing over the experts held here)
 # ---------------------------------------------------------------------------
 
-def grouped_matmul(lhs, rhs, group_sizes):
+def grouped_matmul(lhs, rhs, group_sizes, kernel):
     """lhs[rows of group g] @ rhs[g] for consecutive row groups: lhs (M, K),
     rhs (G, K, N), group_sizes (G,) int32 -> (M, N). Rows beyond the groups
-    are UNSPECIFIED, in the result and in lhs's gradient (zeros from
-    `jax.lax.ragged_dot`, whatever the buffer held from the Mosaic grouped
-    matmul of ops/pallas/, which runs where ops/select.py qualifies it)."""
-    from . import select as _sel
-    if _sel.grouped_matmul(lhs, rhs):
+    are UNSPECIFIED, in the result and in lhs's gradient: zeros from
+    `jax.lax.ragged_dot`; whatever the buffer held from the Mosaic grouped
+    matmul of ops/pallas/, which runs where `kernel` says so (the caller
+    asks ops/select.py, under the scopes of whoever traces it)."""
+    if kernel:
         from . import pallas as _pallas
         return _pallas.grouped_matmul(lhs, rhs, group_sizes)
     return lax.ragged_dot(lhs, rhs, group_sizes)
@@ -774,7 +774,172 @@ _unsort_rows.defvjp(lambda y, order, slot: (y[slot], order),
                     lambda order, g: (g[order], None, None))
 
 
+def _gated(gate_rows, up_rows):
+    return (jax.nn.silu(gate_rows) * up_rows).astype(gate_rows.dtype)
+
+
 @jax.named_scope("moe")
+def _every_row(top_k, kernel, x2, prob, order, held, sizes, gate, up, down):
+    """Dispatch, experts and combine on buffers of tokens x top_k rows, for
+    a holder whose live rows may fill them (every expert held: PR 28's
+    program). Autodiff keeps what the backward needs, nothing is made
+    again; on the chip the rungs' backward, which keeps nothing, costs 29%
+    more at such a load (PERF.md, PR 29)."""
+    product = functools.partial(grouped_matmul, group_sizes=sizes,
+                                kernel=kernel)
+    with jax.named_scope("dispatch"):
+        slot = jnp.argsort(order)       # the inverse permutation
+        rows = _dispatch_rows(x2, order, slot, held, top_k)
+    with jax.named_scope("experts"):
+        out = product(_gated(product(rows, gate), product(rows, up)), down)
+    with jax.named_scope("combine"):
+        mine = _held_rows(_unsort_rows(out, order, slot), held, top_k)
+        y = jnp.sum(mine.astype(jnp.float32) * prob[..., None], axis=1)
+    return y.astype(x2.dtype)
+
+
+def row_capacities(rows, count, experts):
+    """The ladder of row-buffer capacities for `count` held of `experts`
+    over `rows` = tokens x top_k assignments, from the shapes alone: the
+    held experts' even share and a quarter more, up to a multiple of 128
+    (ops/select.py `grouped_matmul`), and `rows` itself on top, which holds
+    any routing. A holder of every expert has the one rung, `_every_row`.
+    (A rung is a compiled copy of the layer: 66 MB of program and over a
+    second of every start in the Mellum2 cell: PERF.md, PR 29.)"""
+    share = -(-rows * count // experts)
+    first = -(-5 * share // 512) * 128
+    return (first, rows) if first < rows else (rows,)
+
+
+def row_capacity(live, ladder):
+    """Index of the smallest rung of `ladder` that holds `live` rows; the
+    program picks it from a traced count, the host from a read one."""
+    return sum(live > rung for rung in ladder[:-1])
+
+
+def _rung_rows(top_k, capacity, x2, prob, order, sizes):
+    """(assignment, token, weight (capacity, 1), live (capacity, 1), rows)
+    of the first `capacity` assignments in expert order; `live` marks the
+    rows the groups cover."""
+    picked = order[:capacity]
+    live = jnp.arange(capacity) < jnp.sum(sizes)
+    with jax.named_scope("dispatch"):
+        rows = x2[picked // top_k]
+    return (picked, picked // top_k, prob.reshape(-1)[picked][:, None],
+            live[:, None], rows)
+
+
+def _sum_by_token(rows, picked, top_k, tokens):
+    """(tokens, D) float32: row t sums the `rows` of token t's assignments,
+    `picked`. A row an assignment is a gather token by token (on the chip
+    3.4 ms for the Mellum2 cell's 65536 rows, which cost 7.7 to add); fewer
+    are added where they belong (1.7 ms for 10240: PERF.md, PR 29)."""
+    if picked.shape[0] == tokens * top_k:
+        mine = rows[jnp.argsort(picked)].reshape(tokens, top_k, -1)
+        return jnp.sum(mine, axis=1, dtype=jnp.float32)
+    return jnp.zeros((tokens, rows.shape[1]), jnp.float32).at[
+        picked // top_k].add(rows.astype(jnp.float32))
+
+
+@jax.named_scope("moe")
+def _rung_fwd(top_k, capacity, kernel, x2, prob, order, sizes, gate, up,
+              down):
+    """The held experts' weighted sum a token, on buffers of `capacity`
+    rows. Rows beyond the groups hold whatever the kernels left: they are
+    masked with `where`, never multiplied away."""
+    product = functools.partial(grouped_matmul, group_sizes=sizes,
+                                kernel=kernel)
+    picked, _, weight, live, rows = _rung_rows(top_k, capacity, x2, prob,
+                                               order, sizes)
+    with jax.named_scope("experts"):
+        out = product(_gated(product(rows, gate), product(rows, up)), down)
+    with jax.named_scope("combine"):
+        mine = jnp.where(live, out.astype(jnp.float32) * weight, 0.0)
+        y = _sum_by_token(mine, picked, top_k, x2.shape[0])
+    return y.astype(x2.dtype)
+
+
+@jax.named_scope("moe")
+def _rung_bwd(top_k, capacity, kernel, x2, prob, order, sizes, gate, up,
+              down, g):
+    """Gradients of `_rung_fwd` for (x2, prob, gate, up, down): each gather
+    transposed as a sum by token and each sum as a gather. The rows are
+    gathered and the first two products made again: no residual has a
+    rung's shape. Written out, it runs a product less than `jax.vjp` of
+    `_rung_fwd` would (`through` serves the weight's gradient and
+    `inner`'s; 159.3 ms a step of the Mellum2 cell for 162.2: PERF.md,
+    PR 29) and sums the rows' gradients in float32."""
+    product = functools.partial(grouped_matmul, group_sizes=sizes,
+                                kernel=kernel)
+    picked, token, weight, live, rows = _rung_rows(top_k, capacity, x2,
+                                                   prob, order, sizes)
+    with jax.named_scope("combine"):
+        g_rows = g[token]
+        d_out = jnp.where(live, g_rows.astype(jnp.float32) * weight,
+                          0.0).astype(g.dtype)
+    with jax.named_scope("experts"):
+        hidden, pull_hidden = jax.vjp(
+            lambda r, a, b: (product(r, a), product(r, b)), rows, gate, up)
+        inner, pull_gated = jax.vjp(_gated, *hidden)
+        # taken for its pull alone: the product has no reader and is not run
+        _, pull_down = jax.vjp(product, inner, down)
+        through = pull_down(g_rows)[0].astype(jnp.float32)
+        d_down = pull_down(d_out)[1]
+        d_rows, d_gate, d_up = pull_hidden(pull_gated(
+            jnp.where(live, through * weight, 0.0).astype(g.dtype)))
+    with jax.named_scope("combine"):
+        d_weight = jnp.where(live[:, 0], jnp.sum(
+            through * inner.astype(jnp.float32), axis=-1), 0.0)
+        d_prob = jnp.zeros((prob.size,), prob.dtype).at[picked].set(
+            d_weight, unique_indices=True)
+    with jax.named_scope("dispatch"):
+        d_x2 = _sum_by_token(jnp.where(live, d_rows, jnp.zeros((), g.dtype)),
+                             picked, top_k, x2.shape[0])
+    return (d_x2.astype(x2.dtype), d_prob.reshape(prob.shape), d_gate, d_up,
+            d_down)
+
+
+# the inner `jit`: traced and lowered once for every layer of these shapes
+# and this choice of kernel, not once a layer (the Mellum2 cell has four)
+@functools.partial(jax.jit, static_argnums=(0, 1, 2, 3))
+def _on_rung(body, top_k, ladder, kernel, *operands):
+    """`body` at the smallest capacity of `ladder` that holds the live
+    rows, chosen on the device."""
+    # the barrier keeps XLA from cloning what reads the result into every
+    # branch: 159.2 ms a step of the Mellum2 cell for 161.0 without it
+    # (PERF.md, PR 29). It is also what the benchmark's memory check passes
+    # by (reserved HBM against the compiler's count of temporaries: 2.78%
+    # apart, 5.7% without it, limit 5: ROADMAP.md, Queue 3, item 3)
+    return lax.optimization_barrier(lax.switch(
+        row_capacity(jnp.sum(operands[3]), ladder),
+        [functools.partial(body, top_k, rung, kernel) for rung in ladder],
+        *operands))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2))
+def _routed(top_k, ladder, kernel, *operands):
+    """Dispatch, experts and combine of (x2, prob, order, sizes, gate, up,
+    down) as ONE differentiable unit: forward and backward each switch on
+    the same rung and run the same product (`kernel`: jax runs a backward
+    rule after the scopes that ops/select.py reads have closed), and what
+    passes between them has no rung's shape (autodiff of a `switch` would
+    make every branch return every branch's residuals, zero-filled)."""
+    # jax traces a backward rule under its operands' abstract mesh and a
+    # forward under none: under the same one, megablox's `jit`ted kernels
+    # are traced once for both (the backward makes products again)
+    with jax.sharding.use_abstract_mesh(jax.typeof(operands[0]).sharding.mesh):
+        return _on_rung(_rung_fwd, top_k, ladder, kernel, *operands)
+
+
+def _routed_bwd(top_k, ladder, kernel, operands, g):
+    d_x2, d_prob, *d_weights = _on_rung(_rung_bwd, top_k, ladder, kernel,
+                                        *operands, g)
+    return (d_x2, d_prob, None, None, *d_weights)
+
+
+_routed.defvjp(lambda *args: (_routed(*args), args[3:]), _routed_bwd)
+
+
 def sparse_experts(x, router, gate, up, down, top_k, first=0,
                    norm_topk_prob=True):
     """The part that the experts held here add to a sparse-expert layer.
@@ -784,41 +949,50 @@ def sparse_experts(x, router, gate, up, down, top_k, first=0,
     takes its `top_k` largest of softmax(x router^T) (float32), with weights
     normalised over the top_k when `norm_topk_prob`; expert e gives
     (silu(x gate_e) * (x up_e)) down_e. The result sums, for each token, the
-    weighted outputs of its experts that are held here; what the others
-    would add is left out. No assignment to a held expert is dropped,
-    whatever the routing: the row buffers hold tokens x top_k rows, the
-    assignments to held experts sorted by expert in front, and the grouped
-    products compute those rows and no other.
+    weighted outputs of its experts that are held here (in float32); what
+    the others would add is left out. No assignment to a held expert is
+    dropped, whatever the routing: the assignments to held experts are
+    sorted by expert, and the row buffers hold the first `capacity` of
+    them, the smallest rung of `row_capacities` that holds the live rows of
+    THIS call (chosen on the device from `load`: a quarter over the even
+    share while the routing is near its balance, tokens x top_k when it is
+    not). Everything that walks a row buffer walks `capacity` rows, and the
+    grouped products compute the live rows and no other. A holder of every
+    expert has the one capacity, tokens x top_k, and chooses nothing.
 
     Returns (y like x, load (E,) int32: assignments to each expert)."""
     lead, d = x.shape[:-1], x.shape[-1]
     x2 = x.reshape(-1, d)
     tokens, experts, count = x2.shape[0], router.shape[0], gate.shape[0]
-    with jax.named_scope("router"):
-        logits = jnp.dot(x2, router.T, preferred_element_type=jnp.float32)
-        prob, chosen = lax.top_k(jax.nn.softmax(logits, axis=-1), top_k)
-        if norm_topk_prob:
-            prob = prob / jnp.sum(prob, axis=-1, keepdims=True)
-        chosen = chosen.reshape(-1)
-        # counted by comparison: a scatter-add of every assignment is slow
-        load = jnp.sum(chosen[:, None] == jnp.arange(experts)[None, :],
-                       axis=0, dtype=jnp.int32)
-    with jax.named_scope("dispatch"):
-        local = chosen - first
-        held = jnp.logical_and(local >= 0, local < count)
-        # assignments to held experts first, by expert; the rest behind
-        order = jnp.argsort(jnp.where(held, local, count), stable=True)
-        slot = jnp.argsort(order)       # the inverse permutation
-        sizes = lax.dynamic_slice(load, (first,), (count,))
-        rows = _dispatch_rows(x2, order, slot, held, top_k)
-    with jax.named_scope("experts"):
-        inner = (jax.nn.silu(grouped_matmul(rows, gate, sizes))
-                 * grouped_matmul(rows, up, sizes))
-        out = grouped_matmul(inner.astype(x.dtype), down, sizes)
-    with jax.named_scope("combine"):
-        mine = _held_rows(_unsort_rows(out, order, slot), held, top_k)
-        y = jnp.sum(mine.astype(jnp.float32) * prob[..., None], axis=1)
-    return y.astype(x.dtype).reshape(*lead, d), load
+    with jax.named_scope("moe"):
+        with jax.named_scope("router"):
+            logits = jnp.dot(x2, router.T, preferred_element_type=jnp.float32)
+            prob, chosen = lax.top_k(jax.nn.softmax(logits, axis=-1), top_k)
+            if norm_topk_prob:
+                prob = prob / jnp.sum(prob, axis=-1, keepdims=True)
+            chosen = chosen.reshape(-1)
+            # counted by comparison: a scatter-add of every assignment is slow
+            load = jnp.sum(chosen[:, None] == jnp.arange(experts)[None, :],
+                           axis=0, dtype=jnp.int32)
+        with jax.named_scope("dispatch"):
+            local = chosen - first
+            held = jnp.logical_and(local >= 0, local < count)
+            # assignments to held experts first, by expert; the rest behind
+            order = jnp.argsort(jnp.where(held, local, count), stable=True)
+            sizes = lax.dynamic_slice(load, (first,), (count,))
+    # Pallas or XLA is chosen HERE, under the caller's scopes, for every rung
+    # and for the backward (the first rung is a multiple of 128 wherever the
+    # last one is); the rungs open `moe` again under their `cond`
+    ladder = row_capacities(tokens * top_k, count, experts)
+    from . import select as _sel
+    kernel = _sel.grouped_matmul(
+        jax.ShapeDtypeStruct((ladder[-1], d), x.dtype), gate)
+    operands = (x2, prob, order, sizes, gate, up, down)
+    if len(ladder) == 1:
+        y = _every_row(top_k, kernel, *operands[:3], held, *operands[3:])
+    else:
+        y = _routed(top_k, ladder, kernel, *operands)
+    return y.reshape(*lead, d), load
 
 
 # ---------------------------------------------------------------------------

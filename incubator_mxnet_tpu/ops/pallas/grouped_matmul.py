@@ -9,12 +9,14 @@ a VJP of its own (the weight gradient through `tgmm`).
 
 The kernels write the rows of the groups and NO other: the rest of the
 result, and of the gradient with respect to the rows, is whatever the
-buffer held. A dropless expert layer keeps far more rows than are live
-(tokens x top_k against about tokens), so zeroing them here would cost a
-pass over the whole buffer a product, more than the kernel itself takes (on
-the chip, 8192 live rows of 65536: 0.78 ms for the three products, 0.91 ms
-for one such pass; PERF.md, PR 28). The caller masks where it reads rows
-back (ops/_raw.py `sparse_experts`), fused into sums it makes anyway.
+buffer held. A dropless expert layer's buffer has a capacity, not a
+count: the smallest rung of ops/_raw.py `row_capacities` that holds the
+live rows (a quarter more than the even share when the routing is
+balanced, tokens x top_k when it is not), so zeroing the rest here would
+cost a pass over the buffer a product (on the chip, 8192 live rows of
+65536: 0.78 ms for the three products, 0.91 ms for one such pass; PERF.md,
+PR 28). The caller masks where it reads rows back (ops/_raw.py
+`_rung_fwd`, `_rung_bwd`, `_held_rows`), fused into sums it makes anyway.
 """
 from __future__ import annotations
 

@@ -3,6 +3,7 @@ with a window, dropless sparse experts that hold a share) against the plain
 reference of the benchmark's Mellum2 configuration, at toy sizes in float32
 on the CPU, where matrix products are true float32 and only the order of
 sums differs: 1e-4 (a bfloat16 pass is off by 1e-3 to 4e-2)."""
+import functools
 import importlib.util
 import json
 import math
@@ -295,8 +296,286 @@ def test_every_assignment_held_fills_every_row(toy):
                  .astype(np.float32))
     with autograd.record():
         layer(x)
+    # 40 assignments are fewer than the 128 rows a first rung has: one rung
     assert layer.read_load() == {"live_rows": 40,
-                                 "load_max_over_mean": pytest.approx(4.0)}
+                                 "load_max_over_mean": pytest.approx(4.0),
+                                 "row_capacity": 40}
+
+
+# -- (f) the ladder of row capacities ---------------------------------------
+# 512 tokens, top-2 of 16 experts, experts 4 and 5 held: 1024 assignments,
+# an even share of 128, rungs of 256 and 1024 rows
+
+LADDER = {"tokens": 512, "d": 64, "f": 32, "experts": 16, "top_k": 2,
+          "first": 4, "count": 2}
+
+
+def _forced_routing(live, seed=5):
+    """(x, router, gate, up, down) float32 whose top-2 routing sends exactly
+    `live` of the 1024 assignments to the held experts 4 and 5: x carries
+    its two experts in its first 16 entries, which the router reads."""
+    c = LADDER
+    rng = np.random.RandomState(seed)
+    both = max(live - c["tokens"], 0)        # tokens with both choices held
+    one = live - 2 * both                    # tokens with one
+    picks = np.empty((c["tokens"], 2), np.int64)
+    others = [e for e in range(c["experts"]) if e not in (4, 5)]
+    for t in range(c["tokens"]):
+        if t < both:
+            picks[t] = (4, 5)
+        elif t < both + one:
+            picks[t] = (4 + t % 2, others[t % len(others)])
+        else:
+            picks[t] = (others[t % len(others)], others[(t + 3) % len(others)])
+    picks = picks[rng.permutation(c["tokens"])]
+    x = rng.randn(c["tokens"], c["d"]).astype(np.float32) * 0.3
+    x[:, :c["experts"]] = 0.0
+    x[np.arange(c["tokens"]), picks[:, 0]] = 3.0
+    x[np.arange(c["tokens"]), picks[:, 1]] = 2.5
+    router = rng.randn(c["experts"], c["d"]).astype(np.float32) * 0.02
+    router[:, :c["experts"]] = np.eye(c["experts"], dtype=np.float32)
+    gate, up = (rng.randn(c["count"], c["d"], c["f"]).astype(np.float32) * 0.2
+                for _ in range(2))
+    down = rng.randn(c["count"], c["f"], c["d"]).astype(np.float32) * 0.2
+    return tuple(jnp.asarray(a) for a in (x, router, gate, up, down))
+
+
+def _against_the_dense_reference(toy, reference, operands, live):
+    """y, load and the five gradients of `_raw.sparse_experts` against the
+    dense float32 reference, under one cotangent."""
+    c = LADDER
+    doc = dict(toy, num_experts_per_tok=c["top_k"], norm_topk_prob=True,
+               num_experts_held={"first": c["first"], "count": c["count"]})
+    w = jnp.asarray(np.random.RandomState(9).randn(c["tokens"], c["d"]),
+                    jnp.float32)
+
+    def ours(*operands):
+        y, load = _raw.sparse_experts(*operands, c["top_k"], c["first"])
+        return jnp.sum(y * w), (y, load)
+
+    def dense(*operands):
+        y = reference._experts(doc, *operands, None)
+        return jnp.sum(y * w), y
+    every = tuple(range(5))
+    (_, (y, load)), grads = jax.jit(jax.value_and_grad(
+        ours, argnums=every, has_aux=True))(*operands)
+    (_, want), want_grads = jax.jit(jax.value_and_grad(
+        dense, argnums=every, has_aux=True))(*operands)
+    assert int(load.sum()) == c["tokens"] * c["top_k"]
+    assert int(load[4] + load[5]) == live
+    assert np.isfinite(np.asarray(y)).all()
+    np.testing.assert_allclose(y, want, rtol=1e-4, atol=1e-5)
+    for name, got, ref in zip(("x", "router", "gate", "up", "down"),
+                              grads, want_grads):
+        assert np.isfinite(np.asarray(got)).all(), name
+        np.testing.assert_allclose(got, ref, rtol=1e-4, atol=2e-5,
+                                   err_msg=name)
+    return load
+
+
+@pytest.fixture
+def fresh_traces():
+    """The rungs are traced once for every call of their shapes (an inner
+    `jit`): a test that swaps `_raw.grouped_matmul` under them starts and
+    ends with no trace kept."""
+    jax.clear_caches()
+    yield
+    jax.clear_caches()
+
+
+@pytest.mark.parametrize("live,capacity", [
+    (0, 256), (200, 256), (255, 256), (256, 256), (257, 1024), (512, 1024),
+    (1024, 1024)], ids=["none", "under-the-first", "one-under", "on-the-first",
+                        "one-over", "twice-the-first", "every-assignment"])
+def test_each_rung_of_the_ladder_is_the_dense_reference(
+        toy, reference, monkeypatch, fresh_traces, live, capacity):
+    """The routing forced to each side of the first rung, from no
+    assignment held to every one: the program runs on the smallest buffer
+    that holds the live rows (seen from inside the grouped product, which
+    only the branch taken calls), and on each the result, `load` and every
+    gradient are the dense reference's."""
+    c = LADDER
+    assert _raw.row_capacities(c["tokens"] * c["top_k"], c["count"],
+                               c["experts"]) == (256, 1024)
+    product, walked = _raw.grouped_matmul, []
+
+    def watched(lhs, rhs, group_sizes, kernel):
+        jax.debug.callback(lambda _: walked.append(lhs.shape[0]),
+                           group_sizes[0])
+        return product(lhs, rhs, group_sizes, kernel)
+    monkeypatch.setattr(_raw, "grouped_matmul", watched)
+    operands = _forced_routing(live)
+    load = _against_the_dense_reference(toy, reference, operands, live)
+    jax.effects_barrier()
+    # three products forward; in the backward the first two made again and
+    # the third, which its pull is taken from
+    assert walked == [capacity] * 6
+    # and the host reads the same rung from the same function
+    layer = nn.SparseExperts(c["d"], c["f"], c["experts"], c["top_k"],
+                             held=(c["first"], c["count"]))
+    layer.initialize()
+    layer.load.set_data(nd.array(np.asarray(load), dtype="int32"))
+    assert layer.read_load()["row_capacity"] == capacity
+    assert profiler.counters()["mxtpu/moe.row_capacity"] == capacity
+
+
+def test_rows_beyond_the_groups_may_hold_anything(toy, reference,
+                                                  monkeypatch, fresh_traces):
+    """A grouped product that leaves NaN beyond its groups, in the result
+    and in the rows' gradient (the Mosaic kernel leaves what the buffer
+    held): the layer masks those rows where it reads them, so the result
+    and every gradient stay finite and equal to the dense reference's."""
+    product = _raw.grouped_matmul
+
+    def beyond(sizes, rows):
+        return (jnp.arange(rows.shape[0]) >= jnp.sum(sizes))[:, None]
+
+    @functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+    def poisoned(lhs, rhs, group_sizes, kernel):
+        return forward(lhs, rhs, group_sizes, kernel)[0]
+
+    def forward(lhs, rhs, sizes, kernel):
+        out, pull = jax.vjp(lambda a, b: product(a, b, sizes, kernel),
+                            lhs, rhs)
+        return jnp.where(beyond(sizes, lhs), jnp.nan, out), (pull, sizes)
+
+    def backward(kernel, res, grad):
+        pull, sizes = res
+        d_lhs, d_rhs = pull(jnp.where(beyond(sizes, grad), 0.0, grad))
+        return jnp.where(beyond(sizes, d_lhs), jnp.nan, d_lhs), d_rhs, None
+    poisoned.defvjp(forward, backward)
+    monkeypatch.setattr(_raw, "grouped_matmul", poisoned)
+    _against_the_dense_reference(toy, reference, _forced_routing(200), 200)
+
+
+def _subjaxprs(jaxpr):
+    """Every jaxpr nested in `jaxpr`'s equations, itself first."""
+    yield jaxpr
+    for eqn in jaxpr.eqns:
+        for value in eqn.params.values():
+            for item in value if isinstance(value, (tuple, list)) else (value,):
+                inner = getattr(item, "jaxpr", item)
+                if hasattr(inner, "eqns"):
+                    yield from _subjaxprs(inner)
+
+
+def _conds(jaxpr):
+    return [eqn for sub in _subjaxprs(jaxpr) for eqn in sub.eqns
+            if eqn.primitive.name == "cond"]
+
+
+@pytest.mark.parametrize("held,rungs", [((4, 2), 2), ((0, 16), 1)],
+                         ids=["a-share", "every-expert"])
+def test_the_traced_program_walks_no_full_buffer_below_the_top_rung(held,
+                                                                    rungs):
+    """The forward and the VJP of `sparse_experts` each hold ONE `cond` with
+    a branch a rung, and no branch but the last has a value of tokens x
+    top_k rows as wide as the experts' inner size, let alone the hidden
+    one (what passes between the two has no rung's shape: the operands
+    themselves). A holder of every expert has no `cond` at all."""
+    c = LADDER
+    rows = c["tokens"] * c["top_k"]
+    x, router, gate, up, down = _forced_routing(200)
+    gate, up, down = (jnp.resize(a, (held[1],) + a.shape[1:])
+                      for a in (gate, up, down))
+
+    def layer(*operands):
+        return _raw.sparse_experts(*operands, c["top_k"], held[0])[0]
+    operands = (x, router, gate, up, down)
+    y, pull = jax.vjp(layer, *operands)
+    for program in (jax.make_jaxpr(layer)(*operands),
+                    jax.make_jaxpr(pull)(y)):
+        conds = _conds(program.jaxpr)
+        if rungs == 1:
+            assert not conds
+            continue
+        cond, = conds
+        assert len(cond.params["branches"]) == rungs
+        for branch in cond.params["branches"][:-1]:
+            checked = 0
+            for sub in _subjaxprs(branch.jaxpr):
+                for var in (*sub.invars, *sub.outvars,
+                            *(v for e in sub.eqns for v in e.outvars)):
+                    shape = getattr(var.aval, "shape", ())
+                    checked += len(shape) == 2
+                    assert not (len(shape) == 2 and shape[0] == rows
+                                and shape[1] >= c["f"]), (shape, sub)
+            assert checked > 20     # the rung's body was walked, not a stub
+
+
+def test_a_holder_of_every_expert_makes_no_product_again(monkeypatch,
+                                                         fresh_traces):
+    """With every expert held the row buffers may fill, so no capacity is
+    chosen: the layer is PR 28's program, whose backward finds the rows and
+    the experts' products where autodiff kept them. Three grouped products
+    run in a step (the rungs run six: they keep nothing and make two
+    again), each over all tokens x top_k rows."""
+    c = LADDER
+    x, router, gate, up, down = _forced_routing(200)
+    gate, up, down = (jnp.resize(a, (c["experts"],) + a.shape[1:])
+                      for a in (gate, up, down))
+    product, walked = _raw.grouped_matmul, []
+
+    def watched(lhs, rhs, group_sizes, kernel):
+        jax.debug.callback(lambda _: walked.append(lhs.shape[0]),
+                           group_sizes[0])
+        return product(lhs, rhs, group_sizes, kernel)
+    monkeypatch.setattr(_raw, "grouped_matmul", watched)
+
+    def scalar(*operands):
+        return jnp.sum(_raw.sparse_experts(*operands, c["top_k"])[0])
+    grads = jax.jit(jax.grad(scalar, argnums=(0, 1, 2, 3, 4)))(
+        x, router, gate, up, down)
+    jax.block_until_ready(grads)
+    jax.effects_barrier()
+    assert walked == [c["tokens"] * c["top_k"]] * 3
+
+
+def _primitives(jaxpr):
+    return {eqn.primitive.name for sub in _subjaxprs(jaxpr)
+            for eqn in sub.eqns}
+
+
+@pytest.mark.parametrize("devices,kernels", [(1, True), (2, False)],
+                         ids=["one-device", "mesh-of-two"])
+def test_the_backward_runs_the_product_its_forward_chose(
+        monkeypatch, fresh_traces, devices, kernels):
+    """The Mosaic kernel or `ragged_dot` is chosen ONCE, in the forward,
+    under the scopes of whoever traces it: jax runs the backward rule after
+    `select.partitioned(mesh)` has closed (parallel/trainer_step.py), and a
+    kernel chosen there would sit inside a program that GSPMD partitions,
+    which jax refuses to lower. With the kernels forced on, forward and VJP
+    traced under a mesh of two hold none, on either rung; on one device both
+    hold them. The rungs are traced once for their shapes AND that choice."""
+    from incubator_mxnet_tpu.ops import select
+    monkeypatch.setenv("MXTPU_PALLAS", "force")
+    c = LADDER
+    x, router, gate, up, down = _forced_routing(200)
+    # 128 wide: the kernel qualifies (rows, contraction and columns % 128)
+    x, router = (jnp.resize(a, (a.shape[0], 128)) for a in (x, router))
+    gate, up = (jnp.resize(a, (c["count"], 128, 128)) for a in (gate, up))
+    down = jnp.resize(down, (c["count"], 128, 128))
+
+    def layer(*operands):
+        return _raw.sparse_experts(*operands, c["top_k"], c["first"])[0]
+    operands = (x, router, gate, up, down)
+    mesh = jax.sharding.Mesh(np.array(jax.devices()[:devices]), ("dp",))
+    before = dict(profiler.counters())
+    with select.partitioned(mesh):
+        y, pull = jax.vjp(layer, *operands)
+        forward = jax.make_jaxpr(layer)(*operands)
+    # the scope has closed, as it has when jax transposes a step's loss
+    backward = jax.make_jaxpr(pull)(y)
+    for program in (forward, backward):
+        found = _primitives(program.jaxpr)
+        assert ("pallas_call" in found) == kernels, found
+        assert ("ragged_dot_general" in found) != kernels, found
+    moved = {k.split("/")[-1]: v - before.get(k, 0)
+             for k, v in profiler.counters().items()
+             if "grouped_matmul" in k and v != before.get(k, 0)}
+    # one decision a traced layer, counted where it was made
+    assert moved == {("pallas.selected.grouped_matmul" if kernels else
+                      "pallas.rejected.grouped_matmul"): 2}, moved
 
 
 def test_held_must_lie_inside_the_experts():

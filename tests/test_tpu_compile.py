@@ -224,6 +224,47 @@ def test_grouped_matmul_at_the_cells_sizes(compile_for_chip):
     assert len(calls) == 9 and "ragged-dot" not in text
 
 
+def test_sparse_experts_keeps_no_residual_a_rung(one_chip, no_compile_cache,
+                                                 monkeypatch,
+                                                 record_property):
+    """One expert layer of the Mellum2 cell, forward and backward (8192
+    tokens of 2304, top-8 of 64 experts, 8 held): a `conditional` each way
+    with a branch a rung, eleven grouped products a rung, and temporaries
+    that do not grow with the ladder. Autodiff of the `switch` would make
+    every branch return every branch's residuals, zero-filled (3,856,771,072
+    for this layer); what passes from the forward to the backward instead
+    is its operands. The top rung's own buffers set the size."""
+    from incubator_mxnet_tpu.ops import _raw
+
+    # the selection asks the platform, and the platform here is the CPU
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    jax.clear_caches()      # the rungs' traces of an earlier test
+
+    def layer(x, router, gate, up, down):
+        def scalar(*operands):
+            y, load = _raw.sparse_experts(*operands, 8, 8)
+            return jnp.sum(y.astype(jnp.float32)), load
+        return jax.value_and_grad(scalar, argnums=(0, 1, 2, 3, 4),
+                                  has_aux=True)(x, router, gate, up, down)
+    specs = [jax.ShapeDtypeStruct(shape, BF16, sharding=one_chip)
+             for shape in ((8192, 2304), (64, 2304), (8, 2304, 896),
+                           (8, 2304, 896), (8, 896, 2304))]
+    ladder = _raw.row_capacities(8192 * 8, 8, 64)
+    assert ladder == (10240, 65536)
+    compiled = jax.jit(layer).lower(*specs).compile()
+    text = compiled.as_text()
+    assert text.count(" conditional(") == 2
+    calls = _custom_calls(text)
+    assert sum("tgmm" in c for c in calls) == 3 * len(ladder)
+    assert len(calls) == 11 * len(ladder) and "ragged-dot" not in text
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    record_property("temp_size_in_bytes", temp)
+    assert temp < TEMP_OF_ONE_EXPERT_LAYER * 1.15, temp
+
+
+# as compiled when the ladder was written (AOT, PR 29)
+TEMP_OF_ONE_EXPERT_LAYER = 1_219_587_584
+
 LN = (((8192, 768), BF16), ((768,), BF16), ((768,), BF16))
 
 
@@ -367,8 +408,9 @@ def test_mellum2_cell_train_step(topo, no_compile_cache, monkeypatch):
     published widths and its 1 x 8192 tokens, one sliding and one full layer
     of the period of four (half the cell's depth: the other two repeat the
     sliding one): it compiles for the described v5e inside a chip's memory,
-    with the attention kernels, the grouped products and every new op scope
-    as the owners of their operations (docs/profiler.md)."""
+    with the attention kernels, the grouped products (a set for each
+    capacity the expert layer's row buffers may take) and every new op
+    scope as the owners of their operations (docs/profiler.md)."""
     import importlib.util
     import json
     import os
@@ -409,8 +451,14 @@ def test_mellum2_cell_train_step(topo, no_compile_cache, monkeypatch):
         op_name = re.search(r'op_name="([^"]*)"', line).group(1)
         scope = "attention" if name.startswith("flash") else "moe/experts"
         assert f"/{scope}/" in op_name, line[:160]
+    # a rung of a layer: three products forward; two made again, three for
+    # the rows' gradient and three `tgmm` for the weights' in the backward
+    from incubator_mxnet_tpu.ops import _raw
+    rungs = len(_raw.row_capacities(8192 * 8, 8, 64))
     assert kernels == {"flash_attention_fwd": 2, "flash_attention_dq": 2,
-                       "flash_attention_dkv": 2, "gmm": 12, "tgmm": 6}
+                       "flash_attention_dkv": 2, "gmm": 2 * 8 * rungs,
+                       "tgmm": 2 * 3 * rungs}
+    assert text.count(" conditional(") == 2 * 2
     for scope in ("rms_norm", "rope", "moe/router", "moe/dispatch",
                   "moe/experts", "moe/combine"):
         assert f"/{scope}/" in text, scope
