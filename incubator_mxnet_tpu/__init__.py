@@ -61,7 +61,6 @@ from . import memscope
 from . import servescope
 from . import serving
 from . import resilience
-from . import autotune
 from . import mxlint
 from . import embedding
 from . import trainloop

@@ -24,20 +24,20 @@ visible again:
   with the offending operand shapes, warned about, and counted in
   ``commscope.resharding_collectives``;
 * **step-budget integration** — perfscope's decomposition consumes
-  :func:`step_estimate` so sharded-mode BENCH json splits ``collective``
+  :func:`step_estimate` so sharded-mode artifact json splits ``collective``
   out of ``device_compute`` again, with the component's provenance
   pinned (``measured`` | ``estimated`` | ``unavailable``).
 
 Everything lands in the ``commscope.*`` counter family, flight-recorder
-compile spans, ``extra.commscope`` in BENCH json (``BENCH_MESH`` runs),
-and ``tools/mxdiag.py comms``.
+compile spans, ``bench_extra()``'s ``extra.commscope``, and
+``tools/mxdiag.py comms``.
 
 Cost model: with no mesh registered a capture records an empty
 inventory without compiling anything — zero cost on unsharded runs.
 Under a mesh, sites that only *lower* (FusedTrainStep, jit cache) pay
 one extra XLA compile per captured program signature, which is why
-commscope is **off by default**: ``enable()`` arms it (bench.py does,
-unless ``BENCH_COMMSCOPE=0``), ``MXTPU_COMMSCOPE=1`` arms it at import.
+commscope is **off by default**: ``enable()`` arms it,
+``MXTPU_COMMSCOPE=1`` arms it at import.
 Commscope rides perfscope's capture hooks, so enabling it arms
 perfscope too.
 """
@@ -107,7 +107,7 @@ def enable_from_env():
 
 
 def bench_extra() -> dict:
-    """The ``extra.commscope`` payload for BENCH json: every captured
+    """The ``extra.commscope`` payload for artifact json: every captured
     program's collective inventory, the ICI peak row the estimates were
     scored against, and the steady train program's per-step summary."""
     return {"programs": programs(), "peaks": ici_peaks(),

@@ -1,7 +1,7 @@
 """Collective inventory, link-time estimates, and the resharding detector.
 
 Per captured program this module turns :mod:`.hlo`'s raw collective
-records into the ``extra.commscope`` shape the bench embeds and
+records into the ``extra.commscope`` shape ``bench_extra()`` returns and
 ``tools/mxdiag.py comms`` renders:
 
 * **aggregation** — records grouped by (op kind, mesh axis): count,
@@ -14,7 +14,7 @@ records into the ``extra.commscope`` shape the bench embeds and
   peak-bandwidth tables (v5e/v4/v5p + a CPU fallback, same table-row
   matching as perfscope's FLOP peaks; ``MXTPU_PEAK_ICI_BW`` overrides).
   These are *analytic estimates from static shapes*, clearly marked so
-  downstream consumers (the step budget, BENCH json) never confuse them
+  downstream consumers (the step budget, artifact json) never confuse them
   with a measurement;
 * **resharding detection** — a collective is flagged as
   compiler-inserted resharding when (a) its kind is outside the mode's
@@ -27,8 +27,8 @@ records into the ``extra.commscope`` shape the bench embeds and
   (b): gathering parameters is that mode's contract.
 
 Everything lands in the ``commscope.*`` counter family, flight-recorder
-compile spans, and a process-wide program table mirrored into
-``extra.commscope`` by ``bench.py``.
+compile spans, and a process-wide program table (``bench_extra()``'s
+``extra.commscope``).
 """
 from __future__ import annotations
 
@@ -101,7 +101,7 @@ _RING_FACTOR = {
 
 def _env_float(name):
     # never-raise contract: a typo'd override keeps the table
-    from ..autotune.knobs import env_float
+    from ..settings import env_float
     return env_float(name, None, on_error="default")
 
 

@@ -145,8 +145,8 @@ def ctx_from_device(dev: jax.Device) -> Context:
 #
 # A TPU chip belongs to one process at a time: the first process that opens
 # the backend takes every local chip and keeps them until it exits. A
-# launcher whose CHILDREN need the device (autotune trials, spawned fleet
-# workers) therefore has to stay off jax until they are done — and can ask
+# launcher whose CHILDREN need the device (spawned fleet workers)
+# therefore has to stay off jax until they are done — and can ask
 # these two what is the case.
 
 def backend_opened() -> bool:
@@ -178,6 +178,14 @@ def devices_seen_by_a_child(timeout: float = 300.0):
             f"another, holding it?):\n{r.stderr[-1000:]}")
     platform, count, kind = r.stdout.strip().splitlines()[-1].split("|", 2)
     return platform, kind, int(count)
+
+
+def normalize_device_kind(kind) -> str:
+    """Canonical device-kind spelling: lowercased, stripped. jax reports
+    'TPU v4' raw while perfscope's peaks table records 'tpu v4' — compare
+    device kinds through this (mxlint's ``unnormalized-device-kind``),
+    or a raw == is a silent never-match."""
+    return str(kind or "unknown").strip().lower() or "unknown"
 
 
 def gpu_memory_info(device_id=0):

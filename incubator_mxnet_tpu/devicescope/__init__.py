@@ -9,8 +9,7 @@ actually did** and keeps those estimates honest:
 
 * **windowed capture** (:mod:`.window`) — ``devicescope.capture
   (steps=N)`` wraps a bounded N-step window of the steady train loop in
-  ``jax.profiler.trace``. Off by default; ``BENCH_DEVICESCOPE=1`` arms
-  one window per bench run; the artifact dir is rotated
+  ``jax.profiler.trace``. Off by default; the artifact dir is rotated
   (``MXTPU_DEVICESCOPE_KEEP``, default 3) so repeated runs don't grow
   it unboundedly.
 * **trace ingestion** (:mod:`.ingest`) — the emitted Chrome-trace
@@ -32,7 +31,7 @@ actually did** and keeps those estimates honest:
   signal that an estimate went stale.
 
 Everything lands in the ``devicescope.*`` counter family,
-``extra.devicescope`` in BENCH json, and ``tools/mxdiag.py device``.
+``extra.devicescope`` in artifact json, and ``tools/mxdiag.py device``.
 
 Fast-path contract: the single module global ``_DS`` (the perfscope /
 commscope / healthmon discipline) — every passive hook costs one
@@ -391,11 +390,11 @@ def _warn_drift(recon, drifted):
 
 
 # ---------------------------------------------------------------------------
-# bench payload
+# extra.devicescope payload
 # ---------------------------------------------------------------------------
 
 def bench_extra() -> dict:
-    """The ``extra.devicescope`` payload for BENCH json: the last
+    """The ``extra.devicescope`` payload for artifact json: the last
     window's measured summary (busy fraction, top-K ops joined to the
     roofline table, measured collectives, gap taxonomy, reconciliation),
     or the armed-but-no-window shape ``{"window": None}``."""

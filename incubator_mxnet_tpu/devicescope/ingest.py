@@ -36,7 +36,7 @@ reconciled against:
 
 Every entry point is never-raise by contract: a malformed artifact (the
 profiler was killed mid-write, an XLA upgrade renamed a lane) degrades
-to an empty summary, not a crashed bench run. tests/test_devicescope.py
+to an empty summary, not a crashed run. tests/test_devicescope.py
 pins the edge cases (empty trace, single event, overlapping lanes,
 missing metadata) against a checked-in real XLA:CPU artifact.
 """

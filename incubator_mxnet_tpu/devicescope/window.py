@@ -6,7 +6,7 @@ the artifact into the measured summary :mod:`.ingest` derives. The
 lifecycle is built for a hot loop that must not care about profiling:
 
 * ``start()`` — rotate old artifact dirs (keep the newest
-  ``MXTPU_DEVICESCOPE_KEEP``, default 3, so repeated bench runs never
+  ``MXTPU_DEVICESCOPE_KEEP``, default 3, so repeated runs never
   grow the dir unboundedly), snapshot the gap-taxonomy counters
   (``io.wait_ms`` + ``trainloop.dispatch_ms``), start the device trace.
   A profiler that is already tracing (``profile_xla``, a concurrent
@@ -53,12 +53,12 @@ _TRACKED = {"io_wait_ms": "io/io.wait_ms",
 
 
 def base_dir() -> str:
-    from ..autotune.knobs import env_str
+    from ..settings import env_str
     return env_str("MXTPU_DEVICESCOPE_DIR", "/tmp/mxtpu_devicescope")
 
 
 def _env_keep() -> int:
-    from ..autotune.knobs import env_int
+    from ..settings import env_int
     return max(1, env_int("MXTPU_DEVICESCOPE_KEEP", DEFAULT_KEEP,
                           on_error="default"))
 
@@ -148,8 +148,8 @@ class CaptureWindow:
         dispatch path the host mark runs ahead of the device (dispatch
         returns at enqueue), so without a barrier the window could
         close with its own steps still in flight and under-count busy
-        time. Pass a host value fetch of the step's result (bench
-        fetches the latest loss — steps chain through params, so that
+        time. Pass a host value fetch of the step's result (the
+        latest loss — steps chain through params, so that
         one fetch completes them all). Never raises.
 
         ``workload``: identity stamp ("train"/"serving") so consumers

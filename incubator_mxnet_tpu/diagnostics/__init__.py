@@ -69,7 +69,7 @@ def enable(memory: bool = True, flight: bool = True,
     flight recorder (with crash dumps), and — when
     ``sampler_interval_ms > 0`` — the metrics sampler writing
     ``metrics.jsonl`` / ``metrics.prom`` under ``diag_dir``."""
-    from ..autotune.knobs import env_str
+    from ..settings import env_str
     diag_dir = diag_dir or env_str("MXTPU_DIAG_DIR", "/tmp")
     if memory:
         enable_memory()
@@ -100,7 +100,7 @@ def enabled() -> bool:
 def enable_from_env():
     """Honor MXTPU_DIAG=1 (called from package import)."""
     if os.environ.get("MXTPU_DIAG", "0") in ("1", "true", "on"):
-        from ..autotune.knobs import env_int
+        from ..settings import env_int
         enable(
             flight_capacity=env_int("MXTPU_FLIGHT_CAPACITY", 4096),
             sampler_interval_ms=env_int("MXTPU_DIAG_SAMPLE_MS", 0))

@@ -52,7 +52,7 @@ def init(coordinator_address=None, num_processes=None, process_id=None,
         # env applies only as a COMPLETE set — a partial/leaked variable
         # (e.g. a stray MXTPU_NUM_PROCESSES) must not reroute a plain
         # single-host init() into a hard-crashing explicit rendezvous
-        from .autotune.knobs import env_str
+        from .settings import env_str
         env_vals = [env_str("MXTPU_COORDINATOR", ""),
                     env_str("MXTPU_NUM_PROCESSES", ""),
                     env_str("MXTPU_PROCESS_ID", "")]
@@ -61,7 +61,7 @@ def init(coordinator_address=None, num_processes=None, process_id=None,
             num_processes = int(env_vals[1])
             process_id = int(env_vals[2])
     if initialization_timeout is None:
-        from .autotune.knobs import env_int
+        from .settings import env_int
         initialization_timeout = env_int("MXTPU_INIT_TIMEOUT", None)
     timeout_kw = ({} if initialization_timeout is None
                   else {"initialization_timeout": int(initialization_timeout)})

@@ -17,12 +17,11 @@ for GSPMD (docs/embedding.md):
   scatter-update only touched rows and their per-row state, verified
   equivalent to the dense reference rule on overlapping ids
   (tests/test_embedding.py).
-* :mod:`.stats` — the table census behind ``extra.embedding`` in BENCH
-  json (per-device vs replicated table bytes, dedup rate, rows
+* :mod:`.stats` — the table census behind ``extra.embedding``
+  (per-device vs replicated table bytes, dedup rate, rows
   touched/step), schema-gated by tools/trace_check.py.
 
-``BENCH_MODEL=recsys`` (bench.py + models/dlrm.py) is the workload that
-exercises all of it end to end.
+``models/dlrm.py`` is the model that exercises all of it end to end.
 """
 from .lookup import (OOR_POLICIES, normalize_ids, dedup_lookup,
                      dedup_capacity, segment_rowgrads, embed)

@@ -14,7 +14,7 @@ the one collective XLA emits for the sharded gather moves
 matrix pools (sum/mean) into one (batch, dim) vector per sample —
 DLRM's per-feature multi-hot aggregation.
 
-Knob defaults (all through autotune/knobs.py, mxlint-governed):
+Defaults (all through settings.py, mxlint-governed):
 ``MXTPU_EMBEDDING_DEDUP`` (default on) and
 ``MXTPU_EMBEDDING_OOR_POLICY`` (default ``clip``) set the
 construction-time defaults; explicit constructor args win.
@@ -30,12 +30,12 @@ __all__ = ["ShardedEmbedding", "EmbeddingBag"]
 
 
 def _default_dedup() -> bool:
-    from ..autotune.knobs import env_flag
+    from ..settings import env_flag
     return env_flag("MXTPU_EMBEDDING_DEDUP", True)
 
 
 def _default_policy() -> str:
-    from ..autotune.knobs import env_str
+    from ..settings import env_str
     return env_str("MXTPU_EMBEDDING_OOR_POLICY", "clip")
 
 

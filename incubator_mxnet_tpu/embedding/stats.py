@@ -2,19 +2,18 @@
 
 Every :class:`~.blocks.ShardedEmbedding` registers itself here at
 construction; :func:`bench_extra` walks the live tables and reports the
-numbers the BENCH json schema (tools/trace_check.py
+numbers the artifact json schema (tools/trace_check.py
 ``check_embedding_extra``) gates:
 
 * ``table_bytes_logical`` — what a replicated copy of every table costs
   per device (the number memscope would show with no sharding);
 * ``table_bytes_per_device`` — what device 0 actually holds, read off
   the jax arrays' addressable shards (ground truth, not an estimate).
-  Sharded correctly, this is strictly below logical — the acceptance
-  criterion the embedding smoke asserts;
+  Sharded correctly, this is strictly below logical;
 * ``dedup_rate`` / ``rows_touched_per_step`` / ``ids_per_step`` — from
-  :func:`observe_batch`, which the bench's eager loop feeds with the
+  :func:`observe_batch`, which a driver's eager loop feeds with the
   raw id stream (host-side numpy: the jit'd program cannot count for
-  us, and the bench already owns the concrete batch).
+  us, and the driver already owns the concrete batch).
 
 dedup_rate = 1 - unique/total: 0.0 means dedup buys nothing, 0.75 means
 the gather moves a quarter of the naive traffic. perf_regress.py gates
@@ -55,7 +54,7 @@ def _live_blocks():
 def observe_batch(ids, input_dim: int) -> dict:
     """Account one concrete id batch (any shape, any integer/float
     carrier): total ids, unique rows touched, dedup rate. Called from
-    the bench's eager loop; cheap host-side numpy."""
+    a driver's eager loop; cheap host-side numpy."""
     ids = np.asarray(ids)
     total = int(ids.size)
     uniq = int(np.unique(np.rint(ids.reshape(-1)).astype(np.int64)).size)
@@ -87,7 +86,7 @@ def _param_device_bytes(p) -> "tuple[int, int]":
         if shards:
             dev_bytes = int(sum(int(np.prod(s.data.shape)) *
                                 s.data.dtype.itemsize for s in shards))
-    except Exception:  # noqa: BLE001 — census never breaks a bench
+    except Exception:  # noqa: BLE001 — census never breaks a run
         pass
     return logical, dev_bytes
 
@@ -112,7 +111,7 @@ def table_stats() -> "list[dict]":
 
 
 def bench_extra() -> dict:
-    """The ``extra.embedding`` block for BENCH json."""
+    """The ``extra.embedding`` block for artifact json."""
     from ..profiler.counters import counters as _counters
     from ..profiler.counters import set_gauge as _set_gauge
     tables = table_stats()

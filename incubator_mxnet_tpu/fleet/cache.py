@@ -153,7 +153,7 @@ def shared_cache():
     cache) unless `set_shared_cache` installed one explicitly."""
     with _shared_lock:
         if not _shared["resolved"]:
-            from ..autotune.knobs import env_str
+            from ..settings import env_str
             path = env_str("MXTPU_FLEET_CACHE", "")
             _shared["cache"] = CompileCache(path) if path else None
             _shared["resolved"] = True

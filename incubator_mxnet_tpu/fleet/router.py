@@ -293,7 +293,7 @@ class Router:
         self.replicas = list(replicas)
         self.host = host
         self.port = int(port)
-        from ..autotune.knobs import env_float
+        from ..settings import env_float
         self.poll_interval_s = float(
             env_float("MXTPU_FLEET_POLL_S", 0.25,
                       call_site=poll_interval_s))

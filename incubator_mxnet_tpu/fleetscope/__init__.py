@@ -26,7 +26,7 @@ joins them, in three parts (docs/fleetscope.md):
   per-replica aggregate with skew and straggler flags (report-only
   context for the router's least-loaded score), and
   ``tools/serve_load.py`` writes ``extra.fleetscope`` (trace-join
-  rate, per-replica spread, wire-gap percentiles) into BENCH json,
+  rate, per-replica spread, wire-gap percentiles) into artifact json,
   validated by ``tools/trace_check.py``.
 
 Cost model (the house off-path discipline): off = ONE predicate —

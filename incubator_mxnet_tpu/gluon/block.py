@@ -458,7 +458,7 @@ class HybridBlock(Block):
         # The kernel-selection layer (ops/select) logs which pallas
         # kernels this signature's trace picked; the decisions go to the
         # flight recorder so "which kernels did my model get" is
-        # answerable from a crash dump or a bench artifact.
+        # answerable from a crash dump.
         p_raws = [p.data()._data for p in params]
         dummy_key = jax.random.PRNGKey(0)
         with _sel.capture() as kernel_log:

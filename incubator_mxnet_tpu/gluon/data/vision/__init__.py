@@ -2,8 +2,8 @@
 
 Zero-egress note: datasets read standard local files (idx/npz/binary); when
 files are absent, MNIST/FashionMNIST/CIFAR fall back to deterministic
-synthetic data with the real shapes/classes so examples, tests, and benches
-run anywhere."""
+synthetic data with the real shapes/classes so examples and tests run
+anywhere."""
 from __future__ import annotations
 
 import gzip

@@ -3,7 +3,7 @@
 step() = allreduce_grads() (kvstore) + update() (optimizer), as in the
 reference. Each parameter's update is one jitted XLA kernel; the fully-fused
 single-computation train step (forward+backward+psum+update in one jit) lives
-in parallel/ and is what bench/dryrun use.
+in parallel/ and is what the benchmark times.
 """
 from __future__ import annotations
 
@@ -238,21 +238,20 @@ class Trainer:
         # each group's updates as ONE jitted call (vs one call per param).
         # Default on; env MXTPU_FUSED_UPDATE=0 disables globally.
         if fused_update is None:
-            from ..autotune.knobs import env_flag
+            from ..settings import env_flag
             fused_update = env_flag("MXTPU_FUSED_UPDATE", True)
         self._fused_update = bool(fused_update)
         # loop_chunk=N marks this trainer for WHOLE-LOOP execution: the
         # trainloop executor (mxtpu.trainloop.TrainLoop) compiles N
         # micro-steps (fwd+bwd+collective+update+lr schedule) into one
         # donated XLA program and reads this chunk size when constructed
-        # from the Trainer. The env layers resolve through the ONE knob
-        # table (autotune.knobs: BENCH_LOOP_CHUNK > MXTPU_LOOP_CHUNK >
-        # cached tuning winner); an explicit loop_chunk= argument wins.
+        # from the Trainer. Env default: MXTPU_LOOP_CHUNK (settings);
+        # an explicit loop_chunk= argument wins.
         # The eager step()/update() path ignores it (per-step by
         # construction).
         if loop_chunk is None:
-            from ..autotune import knobs as _knobs
-            loop_chunk = _knobs.resolve("loop_chunk")[0]
+            from .. import settings as _settings
+            loop_chunk = _settings.resolve("loop_chunk")[0]
         self.loop_chunk = int(loop_chunk) if loop_chunk else None
         # sharding='dp'|'fsdp'|'auto' marks this trainer for MESH-NATIVE
         # execution (mxtpu.sharding, docs/sharding.md): TrainLoop /
@@ -264,7 +263,7 @@ class Trainer:
         # stays). Env default: MXTPU_SHARDING. Needs a process-global
         # mesh (sharding.set_mesh) or an explicit mesh= at the executor.
         if sharding is None:
-            from ..autotune.knobs import env_str
+            from ..settings import env_str
             sharding = env_str("MXTPU_SHARDING", None)
         from ..parallel import sharding as _sharding_mod
         if sharding is not None and sharding not in _sharding_mod.MODES:
@@ -278,7 +277,7 @@ class Trainer:
         # back on NaN instead of dying. Env default: MXTPU_RESILIENCE_DIR.
         # The eager step()/update() path ignores it.
         if resilience is None:
-            from ..autotune.knobs import env_str
+            from ..settings import env_str
             resilience = env_str("MXTPU_RESILIENCE_DIR", None)
         self.resilience = resilience
         self._kv_params_init = False

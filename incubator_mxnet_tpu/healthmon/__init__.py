@@ -88,7 +88,7 @@ def _default_rank() -> int:
     """This process's rank without touching the backend: the launcher's
     MXTPU_PROCESS_ID wins (valid even before distributed.init), then a
     formed cluster's process_index, else 0."""
-    from ..autotune.knobs import env_str
+    from ..settings import env_str
     env = env_str("MXTPU_PROCESS_ID")
     if env:
         try:
@@ -106,7 +106,7 @@ def _resolve_run_id(rank: int) -> str:
     wins; on a formed cluster rank 0 publishes one through the
     coordination KV (one-time traffic — the sustained-RPC segfault the
     async PS wire avoids does not apply); fallback is process-local."""
-    from ..autotune.knobs import env_str
+    from ..settings import env_str
     rid = env_str("MXTPU_RUN_ID")
     if rid:
         return rid
@@ -127,7 +127,7 @@ def _resolve_run_id(rank: int) -> str:
 
 def _env_float(name, default):
     # watchdog cadence knobs degrade on a typo, never crash enable()
-    from ..autotune.knobs import env_float
+    from ..settings import env_float
     return float(env_float(name, default, on_error="default"))
 
 
@@ -153,7 +153,7 @@ class HealthMonitor:
                  straggler_factor=2.0, stall_check_interval_s=None):
         self.rank = int(rank if rank is not None else _default_rank())
         self.run_id = run_id or _resolve_run_id(self.rank)
-        from ..autotune.knobs import env_str
+        from ..settings import env_str
         self.hm_dir = hm_dir or env_str(
             "MXTPU_HM_DIR", env_str("MXTPU_DIAG_DIR", "/tmp"))
         self.exchange_every = int(

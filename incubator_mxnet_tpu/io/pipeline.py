@@ -177,10 +177,9 @@ def _stack_dev(arrs):
 
 
 def _resolve_workers(workers):
-    """Decode-pool width through the ONE knob table (call-site >
-    BENCH_IO_WORKERS > MXTPU_IO_WORKERS > cached winner > 2)."""
-    from ..autotune import knobs as _knobs
-    v = int(_knobs.resolve("io_workers", workers)[0])
+    """Decode-pool width (call-site > MXTPU_IO_WORKERS > 2)."""
+    from .. import settings as _settings
+    v = int(_settings.resolve("io_workers", workers)[0])
     if v < 1:
         raise ValueError(f"io workers must be >= 1, got {v}")
     return v
@@ -193,12 +192,11 @@ class Pipeline:
     face and documents the source/depth/chunk/sharding/cycle/skip
     contract (unchanged from PR 6).
 
-    workers   : decode-pool width (the ``io_workers`` knob; None
-                resolves through autotune.knobs).
+    workers   : decode-pool width (the ``io_workers`` setting; None
+                resolves through settings.py).
     transform : optional host-side hook ``(x, y) -> (x, y)`` applied to
                 each batch INSIDE the decode pool — the place for
-                per-batch decode/augment work (and for the smoke's
-                injected decode latency), because the pool parallelizes
+                per-batch decode/augment work, because the pool parallelizes
                 it while order stays pinned by the ring.
     """
 
@@ -240,8 +238,8 @@ class Pipeline:
         # per chunk popped from the ring). Without it the decode pool
         # churns arbitrarily far ahead of a slow consumer on a cycling
         # source — unbounded ring memory AND host CPU stolen from
-        # compute (the io_smoke caught the pipelined run running SLOWER
-        # than serial through exactly this)
+        # compute (a pipelined run once ran SLOWER than serial through
+        # exactly this)
         self._window = threading.Semaphore(
             self._workers + self._depth + 2)
         self._ring = {}          # seq -> ("ok", payload) | ("err", exc)

@@ -14,8 +14,8 @@ docs/io.md for the stage model). Batches are converted +
 ``depth`` deep, in a bounded device-resident buffer. The consumer's
 ``next()`` is then a queue pop of arrays already on the chip.
 
-Telemetry (shared counters registry — visible in /metrics, flight dumps,
-and BENCH_*.json like every other family):
+Telemetry (shared counters registry — visible in /metrics and flight dumps
+like every other family):
 
 * ``io/io.batches_prefetched``  counter — batches landed on device;
 * ``io/io.wait_ms``             counter — cumulative ms the CONSUMER
@@ -57,11 +57,8 @@ class DevicePrefetcher(Pipeline):
     source    : DataIter / iterable / iterator yielding DataBatch or
                 (x, y) pairs (NDArray or numpy).
     depth     : device-side buffer depth (2 = classic double buffering).
-                A tunable knob: TrainLoop resolves it through the
-                autotune knob table (BENCH_PREFETCH_DEPTH >
-                MXTPU_PREFETCH_DEPTH > cached tuning winner > 2;
-                docs/autotune.md), and the tuner explores it when the
-                measured gap taxonomy says the chip is input-starved.
+                TrainLoop resolves it through settings.py
+                (argument > MXTPU_PREFETCH_DEPTH > 2).
     chunk     : group k consecutive batches and stack them on a new
                 leading axis — the shape the whole-loop executor's
                 run_k/run_chunk consumes. None = per-batch.
@@ -79,9 +76,8 @@ class DevicePrefetcher(Pipeline):
                 ``io.batches_skipped``. The cursor is applied by the
                 single reader stage BEFORE the decode pool, so resume
                 order is identical at any worker count.
-    workers   : decode-pool width (the ``io_workers`` knob; None
-                resolves through the autotune table —
-                BENCH_IO_WORKERS > MXTPU_IO_WORKERS > cached winner > 2).
+    workers   : decode-pool width (the ``io_workers`` setting; None
+                resolves to MXTPU_IO_WORKERS, else 2).
     transform : optional host hook ``(x, y) -> (x, y)`` run inside the
                 decode pool (per-batch decode/augment work).
     """

@@ -12,8 +12,8 @@ IS an XLA collective over the device mesh. Two surfaces:
   sharded over a Mesh, and summed with replicated output sharding — XLA
   lowers that to an all-reduce that rides ICI on real hardware;
 * the fused path (parallel/trainer_step) inlines a `psum` over the 'dp' mesh
-  axis inside the compiled train step — the highest-performance route that
-  bench/dryrun use.
+  axis inside the compiled train step — the highest-performance route,
+  the one the benchmark times.
 
 `dist_async` semantics (parity: `src/kvstore/kvstore_dist_server.h`): each
 worker's push applies as its OWN optimizer update in arrival order — no

@@ -100,7 +100,7 @@ class AsyncPSTransport:
         self._seq = 0                 # my push sequence (per-worker FIFO)
         self._pushed = 0
         self._poll_s = poll_ms / 1e3
-        from ..autotune.knobs import env_float
+        from ..settings import env_float
         self.flush_timeout = float(env_float(
             "MXTPU_APS_FLUSH_TIMEOUT", 120.0, call_site=flush_timeout))
         self._stop = threading.Event()
@@ -117,7 +117,7 @@ class AsyncPSTransport:
                                            socket.SOCK_STREAM)
             self._listener.setsockopt(socket.SOL_SOCKET,
                                       socket.SO_REUSEADDR, 1)
-            from ..autotune.knobs import env_str
+            from ..settings import env_str
             host = env_str("MXTPU_APS_HOST", "127.0.0.1")
             self._listener.bind((host, 0))
             self._listener.listen(64)

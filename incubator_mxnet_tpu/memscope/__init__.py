@@ -4,8 +4,8 @@ timelines, and OOM forensics.
 The eighth observability layer (docs/observability.md). The earlier
 layers explain *time* — perfscope's rooflines, devicescope's measured
 timelines, commscope's collectives, servescope's request tails — but
-*memory*, the resource that bounds every knob the autotuner searches
-(batch × remat × mesh) and the classic way a TPU run dies
+*memory*, the resource that bounds batch × remat × mesh and the
+classic way a TPU run dies
 (``RESOURCE_EXHAUSTED`` with no attribution), had no layer. Memscope is
 that layer:
 
@@ -26,18 +26,14 @@ that layer:
   allocator-failure hook on the dispatch sites that assembles a
   post-mortem (the offending program's static footprint, the watermark
   tail, top-K live buffers from the diagnostics ledger, the resolved
-  knob config) and lands it on the healthmon alert surfaces, so an OOM
+  settings) and lands it on the healthmon alert surfaces, so an OOM
   names its program instead of dying mute.
-* **feasibility** (:mod:`.feasibility`) — the memory-feasibility math
-  the autotuner's pre-trial pruner spends: a batch/remat candidate
-  whose predicted peak exceeds device capacity ×
-  ``MXTPU_MEMSCOPE_HEADROOM`` is a counted reject (``reason=memory``)
-  before a subprocess trial is ever paid for; fleet/serving admission
-  embeds the live headroom in deep ``/healthz`` so the router can
-  weigh it.
+* **headroom** — live bytes against device capacity ×
+  ``MXTPU_MEMSCOPE_HEADROOM``; fleet/serving admission embeds it in
+  deep ``/healthz`` so the router can weigh it.
 
 Everything lands in the ``memscope.*`` counter family,
-``extra.memscope`` in BENCH json, and ``tools/mxdiag.py mem``.
+``extra.memscope`` in artifact json, and ``tools/mxdiag.py mem``.
 
 Fast-path contract: the single module global ``_MS`` (the perfscope /
 commscope / devicescope discipline) — every passive hook costs one
@@ -50,11 +46,9 @@ import warnings
 
 from ..diagnostics import flight as _flight
 from ..profiler.counters import counter as _counter
-from . import feasibility as _feasibility
 from . import footprint as _footprint
 from . import forensics as _forensics
 from . import watermark as _watermark
-from .feasibility import predict_candidate_peak, feasibility_check
 from .footprint import capture, footprints, footprint_of
 from .forensics import is_oom_error, post_mortem, record_oom, \
     last_post_mortem
@@ -65,8 +59,7 @@ __all__ = ["enable", "disable", "enabled", "enable_from_env", "reset",
            "watermark_summary", "device_capacity", "headroom_target",
            "headroom_state", "register_analytic", "reconciliation",
            "bench_extra", "is_oom_error", "post_mortem", "record_oom",
-           "last_post_mortem", "predict_candidate_peak",
-           "feasibility_check", "WatermarkRing", "host_rss_bytes",
+           "last_post_mortem", "WatermarkRing", "host_rss_bytes",
            "DRIFT_THRESHOLD", "DEFAULT_HEADROOM", "DEFAULT_RING"]
 
 # analytic-vs-measured relative disagreement that fires the loud drift
@@ -85,8 +78,7 @@ DEFAULT_RING = 256
 _MS = None
 
 # analytic per-device expectation registered by an FSDP-aware call site
-# (bench.py hands fsdp.memory_report here) — the reconciliation's
-# analytic side
+# (fsdp.memory_report) — the reconciliation's analytic side
 _ANALYTIC = None
 
 
@@ -96,7 +88,7 @@ class _MemScope:
 
     def __init__(self, ring_limit=None):
         if ring_limit is None:
-            from ..autotune.knobs import env_int
+            from ..settings import env_int
             ring_limit = env_int("MXTPU_MEMSCOPE_RING", DEFAULT_RING,
                                  on_error="default")
         self.ring = WatermarkRing(ring_limit)
@@ -178,7 +170,7 @@ def watermark_summary():
 def headroom_target() -> float:
     """Usable fraction of capacity (MXTPU_MEMSCOPE_HEADROOM, default
     0.9): predicted peaks above capacity * target are infeasible."""
-    from ..autotune.knobs import env_float
+    from ..settings import env_float
     v = env_float("MXTPU_MEMSCOPE_HEADROOM", DEFAULT_HEADROOM,
                   on_error="default")
     try:
@@ -196,7 +188,7 @@ def device_capacity() -> dict:
     ``memory_stats()["bytes_limit"]`` (the tightest device bounds) >
     host RAM (the honest bound on XLA:CPU, where device stats are
     absent) > unknown. Never raises."""
-    from ..autotune.knobs import env_int
+    from ..settings import env_int
     override = env_int("MXTPU_MEMSCOPE_CAPACITY", None,
                        on_error="default")
     if override:
@@ -276,8 +268,8 @@ def headroom_state() -> dict:
 # ---------------------------------------------------------------------------
 
 def register_analytic(report, source="fsdp.memory_report"):
-    """Hand memscope an analytic per-device expectation (bench.py calls
-    this with ``parallel/fsdp.memory_report`` under fsdp meshes) — the
+    """Hand memscope an analytic per-device expectation
+    (``parallel/fsdp.memory_report`` under fsdp meshes) — the
     reconciliation's analytic side. Never raises; a malformed report is
     dropped."""
     global _ANALYTIC
@@ -372,7 +364,7 @@ def _warn_drift(analytic, measured, drift):
 
 
 # ---------------------------------------------------------------------------
-# bench payload
+# extra.memscope payload
 # ---------------------------------------------------------------------------
 
 def _programs_joined() -> list:
@@ -392,7 +384,7 @@ def _programs_joined() -> list:
 
 
 def bench_extra() -> dict:
-    """The ``extra.memscope`` payload for BENCH json: the footprint
+    """The ``extra.memscope`` payload for artifact json: the footprint
     table joined to the roofline verdicts, the watermark summary, the
     capacity/headroom verdict, the analytic-vs-measured
     reconciliation, and the last OOM post-mortem (usually None)."""

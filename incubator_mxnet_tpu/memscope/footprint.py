@@ -12,7 +12,7 @@ uses, so ``extra.memscope.programs`` joins the two for free.
 Acquisition follows commscope's discipline: a site that already holds
 the compiled executable (serving buckets) passes it and the analysis is
 free; a site that only lowered pays one extra host-side XLA compile —
-which is why memscope is off by default and armed per bench run.
+which is why memscope is off by default and armed per run.
 
 Peak provenance is a CLOSED taxonomy (trace_check pins it):
 

@@ -17,7 +17,7 @@ post-mortem is assembled from evidence memscope already holds —
 
 — counted, breadcrumbed, and emitted on the healthmon alert surface,
 then the exception re-raises unchanged. The last post-mortem rides
-``extra.memscope.oom`` in BENCH json and renders via
+``extra.memscope.oom`` in artifact json and renders via
 ``tools/mxdiag.py mem``. Assembly never raises: forensics on a dying
 process must not replace the real error with its own.
 """
@@ -115,8 +115,9 @@ def post_mortem(error=None, program=None, step=None) -> dict:
     except Exception:  # noqa: BLE001
         pass
     try:
-        from ..autotune.knobs import KnobConfig
-        pm["knobs"] = KnobConfig.from_env().to_dict()
+        from .. import settings as _settings
+        pm["knobs"] = {f: _settings.resolve(f)[0]
+                       for f in _settings.FIELDS}
     except Exception:  # noqa: BLE001
         pass
     try:

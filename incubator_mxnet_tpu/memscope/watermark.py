@@ -1,7 +1,7 @@
 """Runtime watermark timeline: a bounded ring of allocator samples.
 
 Each sample is taken at an existing step mark (TrainLoop.run_chunk,
-bench.py's steady loops, the serving batcher) and records what the XLA
+the serving batcher) and records what the XLA
 allocator says each device holds RIGHT NOW — ``bytes_in_use`` and
 ``peak_bytes_in_use`` from ``device.memory_stats()`` via the
 normalized :func:`profiler.device_memory_stats` helper — plus the host

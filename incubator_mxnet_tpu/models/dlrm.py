@@ -6,7 +6,7 @@ the (vocab, dim) tables dominate bytes (not FLOPs), so this is the
 model family that makes the sharding/comms/memscope layers load-bearing
 (docs/embedding.md).
 
-Input convention (matches the `BENCH_MODEL=recsys` record stream): one
+Input convention (one record of a recsys record stream): one
 float32 matrix ``(batch, dense_dim + num_tables * bag_size)`` — dense
 features first, then the categorical ids FLOAT-ENCODED (a record
 stream's natural carrier; exact for any vocab < 2^24). The id policy
@@ -117,7 +117,7 @@ def dlrm_bytes_per_sample(net: DLRM, dedup_rate: float = 0.0) -> float:
 
 
 def dlrm_small(**kwargs) -> DLRM:
-    """The bench/default config: 8 tables x 512 rows x 32 dims, 4-hot
+    """The default config: 8 tables x 512 rows x 32 dims, 4-hot
     bags, 13 dense features (a scaled-down Criteo shape)."""
     cfg = dict(num_tables=8, vocab_size=512, embed_dim=32, dense_dim=13,
                bag_size=4, bottom_units=(64,), top_units=(128, 64))

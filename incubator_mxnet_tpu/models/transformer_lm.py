@@ -249,7 +249,7 @@ class TransformerLM(HybridBlock):
 
 
 def transformer_lm_small(vocab_size=10000, **kwargs):
-    """4-layer, 256-unit causal LM (toy/bench scale)."""
+    """4-layer, 256-unit causal LM (toy scale)."""
     kwargs.setdefault("num_layers", 4)
     kwargs.setdefault("units", 256)
     kwargs.setdefault("hidden_size", 1024)

@@ -80,7 +80,7 @@ class Monitor:
         """End-of-batch: collect stats from every installed executor. Each
         scalar stat is also published as a `monitor/<tag>` gauge in the
         profiler counters registry — the single stats path shared with
-        bench/profiler consumers."""
+        the profiler's consumers."""
         if not self.activated:
             return []
         res = []

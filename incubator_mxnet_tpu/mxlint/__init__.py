@@ -6,7 +6,7 @@ Two halves, one contract (docs/mxlint.md):
 * **static** (:mod:`.engine` + :mod:`.rules`, driven by
   ``tools/mxlint.py``) — an stdlib-``ast`` lint suite whose rules encode
   the invariants PR 6–13's review-hardening passes kept re-finding by
-  hand: knob reads that bypass ``autotune/knobs.py``'s documented
+  hand: env reads that bypass ``settings.py``'s documented
   resolution order, counter names drifting from the family tables,
   raises inside never-raise parsers, raw device-kind comparisons,
   unlocked writes to thread-shared module state, and duplicated default
@@ -17,7 +17,7 @@ Two halves, one contract (docs/mxlint.md):
   transfer-guard-based host-sync detection, a recompile-storm detector
   over perfscope's compile captures, and a donated-buffer-read check,
   all reporting through the ``mxlint.*`` counter family plus flight /
-  ``mxtpu.events/1``, and landing in BENCH json as ``extra.mxlint``.
+  ``mxtpu.events/1``, and ``bench_extra()``'s ``extra.mxlint`` shape.
 
 :mod:`.families` is the ONE home of the counter-family tables —
 ``tools/trace_check.py`` derives its ``*_FAMILIES`` globals from it, and

@@ -169,19 +169,6 @@ FAMILY_TABLES = {
         "resilience/resilience.copy_ms": "histogram",
         "resilience/resilience.save_ms": "histogram",
     },
-    # docs/autotune.md — measurement-driven knob tuner (PR 13)
-    "autotune": {
-        "autotune/autotune.searches": "counter",
-        "autotune/autotune.trials": "counter",
-        "autotune/autotune.trials_pruned": "counter",
-        "autotune/autotune.trials_failed": "counter",
-        "autotune/autotune.cache_hits": "counter",
-        "autotune/autotune.cache_misses": "counter",
-        "autotune/autotune.cache_rejects": "counter",
-        "autotune/autotune.env_conflicts": "counter",
-        "autotune/autotune.best_busy_fraction": "gauge",
-        "autotune/autotune.trials_last_search": "gauge",
-    },
     # docs/memscope.md — memory footprints, watermarks, OOM forensics
     "memscope": {
         "memscope/memscope.programs_captured": "counter",
@@ -192,7 +179,6 @@ FAMILY_TABLES = {
         "memscope/memscope.stats_unavailable": "counter",
         "memscope/memscope.oom_events": "counter",
         "memscope/memscope.drift_warnings": "counter",
-        "memscope/memscope.infeasible_candidates": "counter",
         "memscope/memscope.bytes_in_use": "gauge",
         "memscope/memscope.peak_bytes_in_use": "gauge",
         "memscope/memscope.host_rss_bytes": "gauge",

@@ -6,9 +6,8 @@ re-learn by hand (docs/mxlint.md cites the motivating PR per rule):
 =============================  =========================================
 rule id                        invariant
 =============================  =========================================
-``raw-env-read``               every MXTPU_*/BENCH_* knob read inside
-                               the package routes through
-                               ``autotune/knobs.py`` resolution (or the
+``raw-env-read``               every MXTPU_* read inside the package
+                               routes through ``settings.py`` (or the
                                documented allowlist below)
 ``unregistered-counter``       a metric in a governed family
                                (``mxlint/families.py``) must be
@@ -96,12 +95,13 @@ RAW_ENV_ALLOWLIST = {
         "files": ("diagnostics/flight.py",)},
 }
 
-_ENV_PREFIXES = ("MXTPU_", "BENCH_")
+_ENV_PREFIXES = ("MXTPU_",)
 
 # the resolution home itself, plus this package (the rule engine and
 # allowlist tables spell knob names as data)
-_ENV_EXEMPT_SUFFIXES = ("autotune/knobs.py", "mxlint/rules.py",
-                        "mxlint/engine.py", "mxlint/families.py")
+_ENV_EXEMPT_SUFFIXES = ("incubator_mxnet_tpu/settings.py",
+                        "mxlint/rules.py", "mxlint/engine.py",
+                        "mxlint/families.py")
 
 
 def _path_matches(relpath: str, suffixes) -> bool:
@@ -129,14 +129,14 @@ def _is_getenv(func) -> bool:
 
 class RawEnvReadRule(Rule):
     id = "raw-env-read"
-    hint = ("resolve through autotune/knobs.py (KnobConfig/resolve for "
-            "search-space knobs; knobs.env_str/env_int/env_float/"
-            "env_flag for everything else), or add the knob to "
-            "mxlint.rules.RAW_ENV_ALLOWLIST with a reason")
+    hint = ("resolve through incubator_mxnet_tpu/settings.py (resolve() "
+            "for loop_chunk/prefetch_depth/io_workers/pallas; "
+            "env_str/env_int/env_float/env_flag for everything else), "
+            "or add the name to mxlint.rules.RAW_ENV_ALLOWLIST with a "
+            "reason")
 
     def applies(self, relpath: str) -> bool:
-        # the package only: bench.py and tools/ are the BENCH_* driver
-        # layer — their own spelling by the documented precedence
+        # the package only: tools/ parse their own command lines
         if "/incubator_mxnet_tpu/" not in f"/{relpath}":
             return False
         return not _path_matches(relpath, _ENV_EXEMPT_SUFFIXES)
@@ -155,18 +155,18 @@ class RawEnvReadRule(Rule):
             return [self.finding(
                 ctx, node,
                 f"raw environment read of knob {name!r} bypasses the "
-                f"documented resolution order (call-site > BENCH_* > "
-                f"MXTPU_* > cached winner > default)")]
+                f"documented resolution order (call-site > MXTPU_* > "
+                f"default)")]
         # dynamic name: local env helpers are exactly how the knob
-        # spellings historically drifted — they must live in knobs.py
+        # spellings historically drifted — they must live in settings.py
         return [self.finding(
             ctx, node,
             f"environment read with a dynamic name "
             f"({ctx.segment(name_node) or '<expr>'!s}) — local env "
             f"helpers are how knob spellings drift",
-            hint="call the knobs.env_* accessors instead of wrapping "
+            hint="call the settings.env_* accessors instead of wrapping "
                  "os.environ locally (allowlist the file if it truly "
-                 "cannot import the knob home)")]
+                 "cannot import settings.py)")]
 
     def check(self, ctx):
         out = []
@@ -334,7 +334,7 @@ class RaiseInNeverRaiseRule(Rule):
 
 # where the canonical spelling lives — comparisons inside it are the
 # definition, not a violation
-_DEVICE_KIND_HOME = ("autotune/cache.py",)
+_DEVICE_KIND_HOME = ("incubator_mxnet_tpu/context.py",)
 
 
 def _is_device_kind_ref(node) -> bool:
@@ -363,9 +363,9 @@ def _is_stringy(node) -> bool:
 
 class UnnormalizedDeviceKindRule(Rule):
     id = "unnormalized-device-kind"
-    hint = ("compare through autotune.cache.normalize_device_kind(...) "
-            "— jax reports 'TPU v4' raw while perfscope/the tuning "
-            "cache store lowercase, so a raw == is a silent never-match")
+    hint = ("compare through context.normalize_device_kind(...) — jax "
+            "reports 'TPU v4' raw while perfscope's peaks table stores "
+            "lowercase, so a raw == is a silent never-match")
 
     def applies(self, relpath: str) -> bool:
         return not _path_matches(relpath, _DEVICE_KIND_HOME)
@@ -465,10 +465,9 @@ class ThreadSharedMutationRule(Rule):
 
 class DuplicatedDefaultTableRule(Rule):
     id = "duplicated-default-table"
-    hint = ("keep ONE home for the table and import it (PR 13's "
-            "DEFAULT_BATCH, copied into a tool, drifted from bench.py's); "
-            "if the copies are genuinely independent, suppress "
-            "with a reason")
+    hint = ("keep ONE home for the table and import it (a copy in a "
+            "second module WILL drift); if the copies are genuinely "
+            "independent, suppress with a reason")
 
     MIN_ENTRIES = 4
 

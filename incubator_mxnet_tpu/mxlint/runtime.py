@@ -2,7 +2,7 @@
 
 The static half of mxlint proves properties of the SOURCE; this module
 audits what the process actually DOES. Armed (``MXTPU_STRICT=1``, or
-``enable()`` — bench.py and the smokes arm it), three detectors watch
+``enable()``), three detectors watch
 the steady loop:
 
 * **host-sync detection** — :meth:`StrictAuditor.guarded` wraps each
@@ -315,7 +315,8 @@ def mark_warmup_done():
 
 
 def settle():
-    """Publish end-of-run gauges (bench calls this before emitting)."""
+    """Publish end-of-run gauges (a driver calls this before it reads
+    ``bench_extra()``)."""
     if _AUD is not None:
         _gauge("mxlint.findings", _AUD.findings(), "mxlint")
 

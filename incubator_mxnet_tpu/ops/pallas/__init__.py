@@ -29,16 +29,12 @@ def enabled() -> bool:
     use). MXTPU_NO_PALLAS=1 / MXTPU_FORCE_PALLAS=1 are the legacy
     spellings and keep working.
 
-    The three spellings resolve through the ONE knob home
-    (``autotune.knobs.resolve("pallas")``, same off > force > on > auto
-    order this function always had) — which also gives this switch the
-    cached-tuning-winner layer: before, a ``pallas`` winner installed by
-    ``MXTPU_AUTOTUNE=1`` configured every knob EXCEPT this one, because
-    this function read the raw env below the cache. Per-call-site
-    qualification (shape/dtype/layout) lives in ops/select.py on top of
-    this switch."""
-    from ...autotune import knobs as _knobs
-    mode = _knobs.resolve("pallas")[0]
+    The three spellings resolve in ``settings.resolve("pallas")``
+    (off > force > on > auto). Per-call-site qualification
+    (shape/dtype/layout) lives in ops/select.py on top of this
+    switch."""
+    from ... import settings as _settings
+    mode = _settings.resolve("pallas")[0]
     if mode == "off":
         return False
     if mode in ("force", "on"):

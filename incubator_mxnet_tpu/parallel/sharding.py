@@ -27,7 +27,7 @@ is the annotation/resolution layer that makes that work through Gluon:
 * **telemetry** — the `sharding.*` counter family (enforced by
   tools/trace_check.py) publishes mesh shape, per-param spec counts and
   per-device parameter/optimizer-state bytes through the shared
-  registry, so every exporter (Prometheus, flight, BENCH json) sees the
+  registry, so every exporter (Prometheus, flight, artifact json) sees the
   layout actually compiled.
 
 The execution side lives in parallel/trainer_step.py (the one-jit
@@ -94,7 +94,7 @@ class _RulesState(threading.local):
 
 
 _rules_state = _RulesState()
-# last published layout stats — bench.py's extra.sharding reads this
+# last published layout stats (summary() reads this)
 _LAST: dict = {}
 
 
@@ -391,7 +391,7 @@ def publish_param_stats(params, states=None, mesh: Mesh | None = None,
 
     Called by FusedTrainStep after its first dispatch (params are live,
     concrete jax.Arrays then). Returns — and caches for `summary()` —
-    the dict bench.py embeds as `extra.sharding.params`."""
+    the dict of `extra.sharding.params` (tools/trace_check.py)."""
     mesh = mesh if mesh is not None else _MESH
     d_ax, m_ax = data_axis(mesh), model_axis(mesh)
     n_model = n_data = n_repl = 0
@@ -442,5 +442,5 @@ def publish_param_stats(params, states=None, mesh: Mesh | None = None,
 
 def summary() -> dict:
     """The last published layout (mesh shape, mode, spec counts,
-    per-device bytes) — what bench.py records as `extra.sharding`."""
+    per-device bytes), in the `extra.sharding` shape."""
     return dict(_LAST)

@@ -149,7 +149,7 @@ class FusedTrainStep:
         else:
             self.optimizer = optimizer
         if sharding is None:
-            from ..autotune.knobs import env_str
+            from ..settings import env_str
             sharding = env_str("MXTPU_SHARDING", None)
         if sharding is not None and sharding not in _sharding.MODES:
             raise ValueError(f"unknown sharding mode {sharding!r}; "
@@ -566,8 +566,8 @@ class FusedTrainStep:
             with _prof.Scope("mxtpu.step.rebind", "trainer", sync=False):
                 self._rebind(new_train, new_aux, new_states)
         # fully-fused path: forward+backward+collective+update is ONE call
-        # of the jitted step per step (bench.py surfaces this in
-        # BENCH_*.json); what the device sees is programs_per_step.train
+        # of the jitted step per step; what the device sees is the
+        # benchmark's programs_per_step.train
         _prof.set_gauge("trainer.dispatches_per_step", 1)
         return NDArray(loss)
 

@@ -17,17 +17,16 @@ slow and what fixing it would buy**:
   ``step_ms = device_compute + collective + input_wait + host_gap +
   other``, assembled from signals the earlier layers already export
   (``io.wait_ms``, ``kvstore.collective_ms``, dispatch wall) plus a
-  fetch-barrier device-time probe. ``bench.py`` embeds it as
-  ``extra.perfscope`` in every training BENCH json;
-  ``tools/mxdiag.py perf`` renders the MFU-decomposition report.
-* **regression gate** — ``tools/perf_regress.py`` compares BENCH
+  fetch-barrier device-time probe. ``bench_extra()`` returns it as
+  ``extra.perfscope``; ``tools/mxdiag.py perf`` renders the
+  MFU-decomposition report.
+* **regression gate** — ``tools/perf_regress.py`` compares run
   artifacts with noise-aware thresholds and skips ``env_failure``
   artifacts, so every future perf PR gets a machine verdict instead of
   an anecdote.
 
 Cost capture costs one extra host-side trace per compiled signature, so
-it is **off by default** outside bench runs: ``enable()`` arms it
-(bench.py does, unless ``BENCH_PERFSCOPE=0``), ``MXTPU_PERFSCOPE=1``
+it is **off by default**: ``enable()`` arms it, ``MXTPU_PERFSCOPE=1``
 arms it at import. The fast-path contract matches healthmon: every hook
 site checks the single module global ``_PS`` and pays one predicate when
 perfscope is off.
@@ -93,8 +92,8 @@ def enable_from_env():
 
 
 def bench_extra(decomposition=None) -> dict:
-    """The ``extra.perfscope`` payload for BENCH json: the step budget
-    (when the bench ran one), every analyzed program's roofline record,
+    """The ``extra.perfscope`` payload for artifact json: the step budget
+    (when the caller ran one), every analyzed program's roofline record,
     and the peak table the verdicts were scored against."""
     out = {"programs": programs(), "peaks": device_peaks()}
     if decomposition is not None:

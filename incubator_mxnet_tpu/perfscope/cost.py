@@ -17,10 +17,10 @@ which:
   (XLA:CPU reports ``{}`` for data-movement-only programs) or the
   device has no row in the peak table;
 * records the verdict as a flight-recorder compile span (so crash dumps
-  and bench artifacts say not just *that* a program compiled but *what
+  and run artifacts say not just *that* a program compiled but *what
   it is bound by*), bumps the ``perfscope.*`` counters, and files the
-  program in a process-wide table that ``bench.py`` embeds under
-  ``extra.perfscope.programs`` and ``tools/mxdiag.py perf`` renders.
+  program in a process-wide table (``extra.perfscope.programs``, which
+  ``tools/mxdiag.py perf`` renders).
 
 The peak table is keyed by ``device_kind`` and holds published per-chip
 numbers only. A device that is not in it — the CPU of a test run
@@ -71,7 +71,7 @@ _KIND_PATTERNS = (
 def _env_float(name):
     # malformed override: keep the default (the analysis path promises it
     # never raises)
-    from ..autotune.knobs import env_float
+    from ..settings import env_float
     return env_float(name, None, on_error="default")
 
 
@@ -218,7 +218,7 @@ def record_program(name: str, flops, bytes_accessed, dtype="float32",
     _counter(f"perfscope.{rec['verdict']}", "perfscope").increment()
     if _flight._REC is not None:
         # the compile-span record gains the cost fields — a crash dump or
-        # bench artifact now says what each program is bound by
+        # run artifact now says what each program is bound by
         _flight.record("compile", f"perfscope.cost:{name}", {
             "flops": rec["flops"], "bytes_accessed": rec["bytes_accessed"],
             "roofline": rec["verdict"], "ai": rec["ai"],

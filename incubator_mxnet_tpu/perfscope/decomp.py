@@ -32,9 +32,8 @@ observability layers already export into one per-step budget::
   ``other`` is itself a finding.
 
 Everything lands in ``perfscope.*`` gauges through the shared registry
-(so /metrics, flight dumps and BENCH json carry it with zero wiring) and
-in the dict :meth:`finish` returns, which bench.py embeds as
-``extra.perfscope.decomposition``.
+(so /metrics, flight dumps and artifact json carry it with zero wiring) and
+in the dict :meth:`finish` returns (``extra.perfscope.decomposition``).
 """
 from __future__ import annotations
 
@@ -78,7 +77,7 @@ def probe_device_time(sync_step_fn, iters: int = 5) -> dict:
 class StepBudget:
     """Accumulate the steady-phase signals and settle the budget.
 
-    Usage (bench.py's shape)::
+    Usage::
 
         budget = StepBudget()
         budget.begin()                      # snapshot counters

@@ -5,8 +5,7 @@ Parity surface: python/mxnet/profiler.py (`set_config` / `set_state` /
 Chrome-trace-event JSON loadable in chrome://tracing / Perfetto, plus an
 aggregate-stats backend (per-op count/total/min/max — the reference
 `profiler.dumps()` table) and a counters/gauges registry (see
-``profiler.counters``) that bench.py uses for per-phase step-time
-breakdowns.
+``profiler.counters``) that every telemetry layer publishes into.
 
 Three event sources feed one recorder:
 

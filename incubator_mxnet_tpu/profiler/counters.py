@@ -3,8 +3,8 @@
 A :class:`Counter` is a named monotonically-adjustable value grouped under
 a domain. The registry is always live (reads/writes are independent of
 whether tracing is running) so subsystems can share one stats path —
-`Monitor` publishes per-tensor stats here, `bench.py` publishes per-phase
-step-time breakdowns, the jit cache publishes hit/miss counts. `dump()`
+`Monitor` publishes per-tensor stats here, the fused step publishes its
+dispatch gauges, the jit cache publishes hit/miss counts. `dump()`
 folds the registry into the Chrome trace as counter ('C') events so
 values show up in chrome://tracing.
 

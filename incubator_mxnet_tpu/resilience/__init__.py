@@ -26,8 +26,8 @@ MegaScale-style (recovery as an ops-cost multiplier):
   survivors re-form at the smaller world size and roll back to
   last-good instead of dying; re-join is admitted at the next
   checkpoint boundary;
-* **a chaos harness that proves it** (tools/chaos_cluster.py,
-  tools/resilience_smoke.sh) — NaN injection, mid-step rank kill,
+* **a chaos harness that proves it** (tools/chaos_cluster.py) —
+  NaN injection, mid-step rank kill,
   torn checkpoint, frozen rank: training must converge THROUGH each
   fault with the recovery visible on all three surfaces (counters,
   flight breadcrumbs, ``mxtpu.events/1`` records — rendered by
@@ -128,7 +128,7 @@ def status():
 
 
 def bench_extra(manager=None):
-    """The ``extra.resilience`` block for training BENCH json
+    """The ``extra.resilience`` block for training artifact json
     (validated by tools/trace_check.py check_resilience_extra):
     checkpoint cadence + save cost percentiles + recovery accounting."""
     c = _snap()

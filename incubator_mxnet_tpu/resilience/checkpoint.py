@@ -13,7 +13,7 @@ time — the save-is-async contract tests/test_resilience.py pins.
 Telemetry (domain ``resilience``): ``checkpoints_saved`` /
 ``checkpoints_pruned`` / ``saves_skipped`` / ``save_errors`` counters,
 ``last_checkpoint_step`` gauge, ``copy_ms`` / ``save_ms`` histograms
-(boundary copy vs worker serialization — the BENCH ``extra.resilience``
+(boundary copy vs worker serialization — the ``extra.resilience``
 save p50/p95 read the latter), plus a ``resilience.checkpoint_saved``
 event per completed save.
 """
@@ -73,7 +73,7 @@ class CheckpointManager:
         step = getattr(step, "step", step)   # accept a TrainLoop
         self._step = step
         self.directory = os.path.abspath(directory)
-        from ..autotune.knobs import env_float
+        from ..settings import env_float
         self.every = int(env_float("MXTPU_RESILIENCE_EVERY", 50.0,
                                    call_site=every))
         self.keep = int(env_float("MXTPU_RESILIENCE_KEEP", 3.0,

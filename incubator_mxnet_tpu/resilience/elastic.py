@@ -96,7 +96,7 @@ class ElasticGroup:
     def __init__(self, rank, addr=None, port=0, sync_timeout_s=None,
                  host="127.0.0.1", startup_grace_s=None):
         self.rank = int(rank)
-        from ..autotune.knobs import env_float
+        from ..settings import env_float
         self.sync_timeout_s = float(env_float(
             "MXTPU_ELASTIC_SYNC_TIMEOUT", 10.0,
             call_site=sync_timeout_s))
@@ -149,7 +149,7 @@ class ElasticGroup:
         if addr is not None:
             return tuple(addr) if not isinstance(addr, str) else \
                 (addr.rsplit(":", 1)[0], int(addr.rsplit(":", 1)[1]))
-        from ..autotune.knobs import env_str
+        from ..settings import env_str
         env = env_str("MXTPU_ELASTIC_ADDR")
         if env:
             host, port = env.rsplit(":", 1)

@@ -88,7 +88,7 @@ class Supervisor:
         self.max_retries = int(max_retries)
         self.backoff_s = float(backoff_s)
         self.skip_poison = bool(skip_poison)
-        from ..autotune.knobs import env_str
+        from ..settings import env_str
         self.on_stall = (on_stall or env_str(
             "MXTPU_RESILIENCE_ON_STALL", "none")).lower()
         if self.on_stall not in ("none", "exit"):

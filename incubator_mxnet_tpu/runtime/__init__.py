@@ -355,7 +355,7 @@ _global_lock = threading.Lock()
 def engine_type() -> str:
     """'native' (C++ threaded engine) unless MXTPU_ENGINE=python or the
     toolchain is unavailable."""
-    from ..autotune.knobs import env_str
+    from ..settings import env_str
     if env_str(_ENGINE_ENV, "native") == "python" or \
             not native_available():
         return "python"

@@ -22,12 +22,12 @@ and the guard disables the persistent cache for the process (with a
 warning and a `compile_cache.guard_tripped` counter) instead of letting
 training proceed on a broken executable.
 
-`FusedTrainStep` runs the check before its first build; bench.py and
-chip_smoke.py arm it right after backend init. MXTPU_CACHE_GUARD=0 skips
+`FusedTrainStep` runs the check before its first build; chip_smoke.py
+arms it right after backend init. MXTPU_CACHE_GUARD=0 skips
 the check (trust the cache).
 
-:func:`use_compile_cache` is the one place an entry point (bench.py,
-chip_smoke.py) chooses where the cache lives.
+:func:`use_compile_cache` is the one place an entry point
+(benchmark/run.py, chip_smoke.py) chooses where the cache lives.
 """
 from __future__ import annotations
 
@@ -128,7 +128,7 @@ def check(force=False) -> bool:
 
 
 def _disabled_by_env():
-    from ..autotune.knobs import env_flag
+    from ..settings import env_flag
     return not env_flag("MXTPU_CACHE_GUARD", True)
 
 

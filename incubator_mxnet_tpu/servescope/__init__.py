@@ -30,7 +30,7 @@ attributes the tail:
   concurrent closed-loop clients through :class:`ModelServer` over a
   ramped concurrency sweep, finds the saturation knee where p99
   inflects, and writes the full attribution into trace_check-valid
-  BENCH json gated by ``tools/perf_regress.py``.
+  artifact json gated by ``tools/perf_regress.py``.
 
 Cost model: off = one predicate per batcher hook (the
 perfscope/commscope/devicescope module-global discipline). Armed, the
@@ -39,9 +39,8 @@ per-request cost is bounded by ``MXTPU_SERVESCOPE_SAMPLE``: a value in
 the stride directly; unsampled requests pay one counter increment and a
 modulo, keeping steady-state overhead inside healthmon's <5% budget.
 
-``enable()`` arms it (bench.py's serving path and tools/serve_load.py
-do, unless ``BENCH_SERVESCOPE=0``); ``MXTPU_SERVESCOPE=1`` arms at
-import.
+``enable()`` arms it (tools/serve_load.py does); ``MXTPU_SERVESCOPE=1``
+arms at import.
 """
 from __future__ import annotations
 
@@ -85,7 +84,7 @@ def _resolve_sample(sample) -> int:
     itself; malformed values fall back to 1 (trace everything) — the
     hot path never raises over an env typo."""
     if sample is None:
-        from ..autotune.knobs import env_str
+        from ..settings import env_str
         sample = env_str("MXTPU_SERVESCOPE_SAMPLE", "1")
     try:
         v = float(sample)
@@ -151,7 +150,7 @@ def attribution_brief() -> dict | None:
 
 
 def bench_extra() -> dict | None:
-    """The ``extra.servescope`` payload for BENCH json: the full
+    """The ``extra.servescope`` payload for artifact json: the full
     attribution plus the sampling header. None when servescope is off
     (the section is simply absent, like an unarmed commscope)."""
     ss = _SS

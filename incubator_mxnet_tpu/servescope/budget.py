@@ -58,7 +58,7 @@ _QUANTILES = (0.50, 0.95, 0.99)
 
 
 def _env_window() -> int:
-    from ..autotune.knobs import env_int
+    from ..settings import env_int
     return max(64, env_int("MXTPU_SERVESCOPE_WINDOW", DEFAULT_WINDOW,
                            on_error="default"))
 

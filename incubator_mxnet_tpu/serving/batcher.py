@@ -385,7 +385,7 @@ class DynamicBatcher:
                 late.append(req)
             else:
                 responded.append((i, req))
-        # telemetry BEFORE fulfil: a /stats (or bench snapshot) taken the
+        # telemetry BEFORE fulfil: a /stats (or load-harness snapshot) taken the
         # instant a client's predict() returns must already contain that
         # request — observing after _fulfil let percentiles/responses mix
         # epochs mid-read (the waiting client races the counter updates)
@@ -416,7 +416,7 @@ class DynamicBatcher:
     @staticmethod
     def stats() -> dict:
         """Serving-domain counters + derived headline numbers (shared by
-        /stats and the bench)."""
+        /stats and tools/serve_load.py)."""
         snap = {k.split("/", 1)[1]: v
                 for k, v in _prof.counters().items()
                 if k.startswith("serving/")}

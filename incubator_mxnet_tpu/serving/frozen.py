@@ -49,7 +49,7 @@ __all__ = ["FrozenModel", "default_buckets"]
 def default_buckets(max_batch: int | None = None):
     """Power-of-two bucket ladder, overridable via MXTPU_SERVING_BUCKETS
     (comma-separated batch sizes)."""
-    from ..autotune.knobs import env_str
+    from ..settings import env_str
     env = env_str("MXTPU_SERVING_BUCKETS")
     if env:
         sizes = sorted({int(s) for s in env.split(",") if s.strip()})

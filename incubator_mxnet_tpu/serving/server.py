@@ -53,7 +53,7 @@ __all__ = ["ModelServer"]
 
 
 def _env_float(name, default):
-    from ..autotune.knobs import env_float
+    from ..settings import env_float
     return float(env_float(name, default))
 
 
@@ -73,7 +73,7 @@ class ModelServer:
                                  "unfrozen block")
             model = FrozenModel(model, input_shape, **freeze_kwargs)
         self.model = model
-        from ..autotune.knobs import env_int, env_str
+        from ..settings import env_int, env_str
         self.host = host or env_str("MXTPU_SERVING_HOST", "127.0.0.1")
         self.port = env_int("MXTPU_SERVING_PORT", 0, call_site=port)
         # scheduler selection: "dynamic" (coalesce-then-dispatch, the

@@ -10,7 +10,7 @@ accumulate on device, and a double-buffered prefetcher
 the current chunk runs. The host's only per-chunk work is a queue pop
 and one dispatch; between chunk boundaries it never touches the device.
 
-What this fixes over the bench-only ``FusedTrainStep.run_k`` knob:
+What this adds over ``FusedTrainStep.run_k``:
 
 * **scheduler granularity** — lr is per MICRO-STEP, not per chunk:
   closed-form schedulers (optimizer/lr_scheduler.as_jax) compute lr
@@ -22,7 +22,7 @@ What this fixes over the bench-only ``FusedTrainStep.run_k`` knob:
 * **input starvation is visible** — the prefetcher exports ``io.*``
   counters (batches_prefetched / wait_ms / put_ms / depth / buffer_fill)
   through the shared registry, so "TPU starved by input" shows up in
-  /metrics, flight dumps and BENCH json next to step times.
+  /metrics and flight dumps next to step times.
 * **first-class selection** — ``Trainer(..., loop_chunk=N)`` or
   ``MXTPU_LOOP_CHUNK=N`` marks a training setup for whole-loop
   execution; ``TrainLoop(net, loss, trainer)`` picks the chunk size up.
@@ -40,7 +40,7 @@ Telemetry (domain ``trainloop``): ``trainloop.chunks`` /
 ``trainloop.steps`` counters, ``trainloop.k`` / ``trainloop.chunk_ms`` /
 ``trainloop.in_program_lr`` gauges — plus the existing
 ``trainer.dispatches_per_step`` gauge, which reads 1/k under the
-executor (the smoke test asserts < 1). The chunk program's compile
+executor. The chunk program's compile
 capture (perfscope roofline + commscope collective inventory) rides
 FusedTrainStep's ``fused_step_k<k>`` hook — a scan-body inventory is
 static, i.e. PER MICRO-STEP, which is exactly the granularity the step
@@ -58,7 +58,7 @@ import numpy as np
 from . import devicescope as _devicescope
 from . import memscope as _memscope
 from . import profiler as _prof
-from .autotune import knobs as _knobs
+from . import settings as _settings
 from .io.prefetch import DevicePrefetcher
 from .parallel.trainer_step import FusedTrainStep
 
@@ -67,11 +67,7 @@ __all__ = ["TrainLoop", "resolve_chunk"]
 
 def resolve_chunk(explicit=None, optimizer=None, default=4):
     """Chunk-size resolution: explicit argument > Trainer.loop_chunk >
-    env/cached-winner layers > default. The env layers
-    (BENCH_LOOP_CHUNK > MXTPU_LOOP_CHUNK > autotune cached winner) are
-    the ONE knob table's (autotune.knobs) — every consumer resolves the
-    same spellings in the same order, so bench.py and a hand-built
-    TrainLoop can never disagree on what the env means. The default
+    MXTPU_LOOP_CHUNK (settings.resolve) > default. The default
     stays 4 here: constructing a TrainLoop IS choosing whole-loop
     execution, so an unconfigured chunk of 0 would be self-
     contradictory."""
@@ -80,10 +76,7 @@ def resolve_chunk(explicit=None, optimizer=None, default=4):
     lc = getattr(optimizer, "loop_chunk", None)
     if lc:
         return int(lc)
-    v, src = _knobs.resolve("loop_chunk")
-    if v and src != "default":
-        return int(v)
-    return int(default)
+    return int(_settings.resolve("loop_chunk")[0] or default)
 
 
 class TrainLoop:
@@ -116,24 +109,22 @@ class TrainLoop:
         self.chunk = resolve_chunk(explicit=chunk, optimizer=optimizer)
         if self.chunk < 1:
             raise ValueError(f"loop chunk must be >= 1, got {self.chunk}")
-        # buffer depth through the one knob table: explicit arg >
-        # BENCH_PREFETCH_DEPTH > MXTPU_PREFETCH_DEPTH > cached winner >
-        # 2 (classic double buffering). An explicit 0 is rejected HERE
+        # buffer depth: explicit arg > MXTPU_PREFETCH_DEPTH > 2
+        # (classic double buffering). An explicit 0 is rejected HERE
         # (not deferred to the first _prefetcher build) so the error
         # names the constructor argument, same verdict as the env parse
         self.prefetch_depth = int(
             prefetch_depth if prefetch_depth is not None
-            else _knobs.resolve("prefetch_depth")[0])
+            else _settings.resolve("prefetch_depth")[0])
         if self.prefetch_depth < 1:
             raise ValueError(f"prefetch_depth must be >= 1, "
                              f"got {self.prefetch_depth}")
-        # decode-pool width through the same table: explicit arg >
-        # BENCH_IO_WORKERS > MXTPU_IO_WORKERS > cached winner > 2;
+        # decode-pool width: explicit arg > MXTPU_IO_WORKERS > 2;
         # io_transform is a per-item decode hook (docs/io.md) run on
         # the pool threads, off the training thread's critical path
         self.io_workers = int(
             io_workers if io_workers is not None
-            else _knobs.resolve("io_workers")[0])
+            else _settings.resolve("io_workers")[0])
         if self.io_workers < 1:
             raise ValueError(f"io_workers must be >= 1, "
                              f"got {self.io_workers}")

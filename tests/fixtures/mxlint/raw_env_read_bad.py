@@ -1,10 +1,10 @@
-"""BAD fixture: every raw-read spelling of a MXTPU_*/BENCH_* knob the
+"""BAD fixture: every raw-read spelling of a MXTPU_* setting the
 rule must catch (linted as if at incubator_mxnet_tpu/somemod.py)."""
 import os
 from os import getenv
 
 a = os.environ.get("MXTPU_SOME_KNOB", "1")          # .get
-b = os.getenv("BENCH_SOME_KNOB")                    # os.getenv
+b = os.getenv("MXTPU_GETENV_KNOB")                  # os.getenv
 c = getenv("MXTPU_OTHER_KNOB")                      # bare getenv
 d = os.environ["MXTPU_SUBSCRIPT_KNOB"]              # subscript read
 e = "MXTPU_MEMBERSHIP_KNOB" in os.environ           # membership read

@@ -1,15 +1,15 @@
-"""GOOD fixture: knob reads routed through the knobs home, allowlisted
+"""GOOD fixture: reads routed through settings.py, allowlisted
 arming knobs, non-knob env reads, and env WRITES (config, not reads)."""
 import os
 
 
 def resolved():
-    from incubator_mxnet_tpu.autotune.knobs import env_int, env_str
-    return env_int("MXTPU_SOME_KNOB", 1), env_str("BENCH_SOME_KNOB")
+    from incubator_mxnet_tpu.settings import env_int, env_str
+    return env_int("MXTPU_SOME_KNOB", 1), env_str("MXTPU_OTHER_KNOB")
 
 
 def non_knob():
-    # not a MXTPU_*/BENCH_* name: out of the rule's jurisdiction
+    # not a MXTPU_* name: out of the rule's jurisdiction
     return os.environ.get("JAX_PLATFORMS", "")
 
 

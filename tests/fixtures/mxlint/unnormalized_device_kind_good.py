@@ -1,6 +1,6 @@
 """GOOD fixture: device-kind comparisons through the canonical
 normalizer (or an explicit lowering pipeline)."""
-from incubator_mxnet_tpu.autotune.cache import normalize_device_kind
+from incubator_mxnet_tpu.context import normalize_device_kind
 
 
 def lookup(entry, device):

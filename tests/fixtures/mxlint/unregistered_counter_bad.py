@@ -4,6 +4,6 @@ from incubator_mxnet_tpu.profiler.counters import (counter, histogram,
                                                    observe, set_gauge)
 
 counter("healthmon.not_a_real_metric", "healthmon").increment()
-histogram("autotune.invented_histogram", "autotune")
+histogram("memscope.invented_histogram", "memscope")
 observe("perfscope.mfu", 0.5, "perfscope")       # mfu is a gauge
 set_gauge("resilience.rollbacks", 1, "resilience")   # a counter

@@ -997,7 +997,7 @@ class TestMxdiag:
         doc = self._bench_doc()
         del doc["extra"]["devicescope"]
         assert md.print_device(doc) == 1
-        assert "BENCH_DEVICESCOPE=1" in capsys.readouterr().out
+        assert "devicescope was off" in capsys.readouterr().out
 
     def test_device_armed_no_window(self, capsys):
         md = _load_tool("mxdiag")

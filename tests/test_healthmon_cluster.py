@@ -5,8 +5,7 @@ and asserts the cross-rank contract — skew metric with slowest-rank
 attribution on every rank, NaN watchdog alert within one step, and a
 validated `mxdiag merge` timeline spanning both ranks.
 
-The driver is shared with tools/health_smoke.sh so CI and the tier-1
-suite exercise the identical harness; this test only asserts the
+The driver is tools/health_cluster.py; this test only asserts the
 driver's verdict (and keeps its artifacts out of /tmp's shared path).
 """
 import json

@@ -4,8 +4,7 @@ backends degrade to the honest all-None shape), the bounded watermark
 ring, capacity/headroom math with the like-with-like pairing,
 analytic-vs-measured reconciliation incl. the drift warning, OOM
 forensics assembled from a synthesized RESOURCE_EXHAUSTED, the off
-path's one-predicate contract, the deep-/healthz headroom embed, the
-autotuner's memory-feasibility pruner (counter == payload), and the
+path's one-predicate contract, the deep-/healthz headroom embed, and the
 tooling satellites (trace_check check_memscope_extra both ways,
 perf_regress peak-memory gate incl. both-sides and same-instrument
 skips, mxdiag mem rendering, profiler.device_memory_stats
@@ -25,10 +24,6 @@ from incubator_mxnet_tpu import gluon, nd
 from incubator_mxnet_tpu import memscope as ms
 from incubator_mxnet_tpu import perfscope as ps
 from incubator_mxnet_tpu import profiler as prof
-from incubator_mxnet_tpu.autotune.knobs import KnobConfig
-from incubator_mxnet_tpu.autotune.trial import TrialResult
-from incubator_mxnet_tpu.autotune.tuner import search
-from incubator_mxnet_tpu.memscope import feasibility as feas
 from incubator_mxnet_tpu.memscope import footprint as fp
 from incubator_mxnet_tpu.memscope import forensics as forens
 from incubator_mxnet_tpu.memscope.watermark import (WatermarkRing,
@@ -50,7 +45,7 @@ def _load_tool(name):
 @pytest.fixture(autouse=True)
 def _memscope_teardown(monkeypatch):
     # the capacity/headroom knobs must come from THIS test, never from
-    # the invoking shell (the smoke exports MXTPU_MEMSCOPE_CAPACITY)
+    # the invoking shell
     for var in ("MXTPU_MEMSCOPE", "MXTPU_MEMSCOPE_RING",
                 "MXTPU_MEMSCOPE_HEADROOM", "MXTPU_MEMSCOPE_CAPACITY"):
         monkeypatch.delenv(var, raising=False)
@@ -413,8 +408,9 @@ class TestForensics:
         # the watermark tail: what memory did in the steps before death
         assert 0 < len(pm["watermark_tail"]) <= 8
         assert pm["watermark_tail"][-1]["step"] == 11
-        # the resolved knob config that produced the shape
-        assert isinstance(pm["knobs"], dict) and "batch" in pm["knobs"]
+        # the resolved settings that produced the shape
+        assert pm["knobs"] == {"loop_chunk": 0, "prefetch_depth": 2,
+                               "io_workers": 2, "pallas": "auto"}
         assert pm["capacity"]["source"] == "host_ram"
         assert _counters()["memscope/memscope.oom_events"] == before + 1
         # the last post-mortem is what extra.memscope.oom publishes
@@ -601,138 +597,6 @@ class TestMxdiagMem:
         md = _load_tool("mxdiag")
         md.print_mem({"extra": {}})
         assert "memscope" in capsys.readouterr().out
-
-
-# ---------------------------------------------------------------------------
-# feasibility math + the tuner's pre-trial pruner
-# ---------------------------------------------------------------------------
-
-class TestFeasibility:
-    def test_linear_batch_prediction(self):
-        p, basis = feas.predict_candidate_peak(
-            "batch", 128, {"peak_bytes": 1000, "batch": 64})
-        assert (p, basis) == (2000.0, "linear_batch")
-
-    def test_missing_baseline_disables(self):
-        assert feas.predict_candidate_peak(
-            "batch", 128, {"batch": 64}) == (None, "no_baseline_peak")
-        assert feas.predict_candidate_peak(
-            "batch", 128, {"peak_bytes": 1000}) \
-            == (None, "no_baseline_batch")
-        assert feas.predict_candidate_peak(
-            "batch", 128, None) == (None, "no_baseline_peak")
-
-    def test_remat_floor(self):
-        base = {"peak_bytes": 1000, "batch": 64, "remat": True}
-        p, basis = feas.predict_candidate_peak("remat_policy", None, base)
-        assert (p, basis) == (1000.0, "remat_floor")
-        # a non-rematerializing baseline predicts nothing
-        p, basis = feas.predict_candidate_peak(
-            "remat_policy", None, {"peak_bytes": 1000, "batch": 64})
-        assert p is None
-
-    def test_non_memory_knob_runs_normally(self):
-        p, basis = feas.predict_candidate_peak(
-            "loop_chunk", 8, {"peak_bytes": 1000, "batch": 64})
-        assert (p, basis) == (None, "not_memory_knob")
-
-    def test_check_feasible_and_infeasible(self):
-        base = {"peak_bytes": GiB, "batch": 64}
-        ok = feas.feasibility_check("batch", 128, base,
-                                    capacity_bytes=8 * GiB, target=0.9)
-        assert ok["feasible"] is True and ok["reason"] is None
-        before = _counters().get(
-            "memscope/memscope.infeasible_candidates", 0)
-        bad = feas.feasibility_check("batch", 1024, base,
-                                     capacity_bytes=8 * GiB, target=0.5)
-        assert bad["feasible"] is False
-        assert bad["reason"].startswith("memory:")
-        assert bad["predicted_peak_bytes"] == 16 * GiB
-        assert bad["limit_bytes"] == 4 * GiB
-        assert _counters()["memscope/memscope.infeasible_candidates"] \
-            == before + 1
-
-    def test_fails_open(self):
-        v = feas.feasibility_check("batch", 128, "garbage")
-        assert v["feasible"] is True
-
-
-GAPS_DISPATCH = {"input_starved_ms": 0.2, "dispatch_serialized_ms": 3.0,
-                 "host_gap_ms": 2.0}
-
-
-def _mem_runner(calls=None):
-    """A deterministic fake trial whose baseline measurement carries
-    the measured memscope peak the pruner scales over: 2 GiB RSS at
-    batch 64."""
-    def run(cfg, knob=None, value=None):
-        if calls is not None:
-            calls.append((knob, value, cfg))
-        m = {"busy_fraction": 0.5, "step_ms": 10.0, "mfu": 0.1,
-             "value": 100.0, "gaps": dict(GAPS_DISPATCH),
-             "mfu_if_removed": None, "provenance": "measured(profile)",
-             "memscope": {"peak_bytes": 2 * GiB,
-                          "peak_source": "watermark_host_rss",
-                          "batch": 64, "capacity": None}}
-        return TrialResult(cfg, "ok", measurement=m, knob=knob,
-                           value=value)
-    return run
-
-
-class TestTunerMemoryPruner:
-    def test_infeasible_batch_rejected_pre_trial(self, tmp_path,
-                                                 monkeypatch):
-        monkeypatch.setenv("MXTPU_MEMSCOPE_CAPACITY", str(8 * GiB))
-        calls = []
-        before = prof.counters().get(
-            "autotune/autotune.trials_pruned", 0)
-        r = search(model="lenet", batch=64, runner=_mem_runner(calls),
-                   cache_dir=str(tmp_path), use_cache=False, budget=12,
-                   batch_candidates=(65536,))
-        # the verdict: filed beside the knob-family prunes
-        reason = r.pruned.get("batch=65536")
-        assert isinstance(reason, str) and reason.startswith("memory:"),\
-            r.pruned
-        assert "linear_batch" in reason
-        # zero subprocess spent: the runner never saw the candidate
-        assert all(v != 65536 for _k, v, _c in calls)
-        # counter == payload contract across BOTH prune kinds
-        extra = r.to_extra()
-        assert extra["pruned"]["batch=65536"] == reason
-        delta = prof.counters()["autotune/autotune.trials_pruned"] \
-            - before
-        assert delta == extra["trials_pruned"] >= 1
-
-    def test_feasible_batch_candidate_is_tried(self, tmp_path,
-                                               monkeypatch):
-        monkeypatch.setenv("MXTPU_MEMSCOPE_CAPACITY", str(8 * GiB))
-        calls = []
-        r = search(model="lenet", batch=64, runner=_mem_runner(calls),
-                   cache_dir=str(tmp_path), use_cache=False, budget=20,
-                   batch_candidates=(128,))
-        # 2 GiB x 2 = 4 GiB < 8 GiB x 0.9: feasible, so it runs
-        assert "batch=128" not in r.pruned
-        assert any(v == 128 for _k, v, _c in calls)
-
-    def test_no_memscope_baseline_disables_gate(self, tmp_path,
-                                                monkeypatch):
-        monkeypatch.setenv("MXTPU_MEMSCOPE_CAPACITY", str(8 * GiB))
-
-        def run(cfg, knob=None, value=None):
-            m = {"busy_fraction": 0.5, "step_ms": 10.0, "mfu": 0.1,
-                 "value": 100.0, "gaps": dict(GAPS_DISPATCH),
-                 "mfu_if_removed": None,
-                 "provenance": "measured(profile)",
-                 "memscope": {"peak_bytes": None, "peak_source": None,
-                              "batch": None, "capacity": None}}
-            return TrialResult(cfg, "ok", measurement=m, knob=knob,
-                               value=value)
-        r = search(model="lenet", batch=64, runner=run,
-                   cache_dir=str(tmp_path), use_cache=False, budget=20,
-                   batch_candidates=(65536,))
-        # the pruner only rejects what it can defend: no baseline peak,
-        # no verdict — the candidate runs like any other
-        assert "batch=65536" not in r.pruned
 
 
 # ---------------------------------------------------------------------------

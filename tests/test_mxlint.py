@@ -86,16 +86,16 @@ class TestRuleMatrix:
         assert len(found) == 6
 
     def test_raw_env_read_skips_driver_layer(self):
-        # bench.py / tools are the BENCH_* driver spelling — out of scope
+        # tools/ parse their own command lines — out of scope
         rule = rules.rule_by_id("raw-env-read")
         assert engine.lint_sources(
-            [("bench.py", _fixture("raw_env_read_bad.py"))],
+            [("tools/serve_load.py", _fixture("raw_env_read_bad.py"))],
             [rule]) == []
 
-    def test_raw_env_read_exempts_knob_home(self):
+    def test_raw_env_read_exempts_settings_home(self):
         rule = rules.rule_by_id("raw-env-read")
         assert engine.lint_sources(
-            [("incubator_mxnet_tpu/autotune/knobs.py",
+            [("incubator_mxnet_tpu/settings.py",
               _fixture("raw_env_read_bad.py"))], [rule]) == []
 
     def test_raw_env_read_allowlist_is_file_scoped(self):
@@ -119,7 +119,7 @@ class TestRuleMatrix:
                               rules.rule_by_id("unregistered-counter"))
         msgs = " ".join(f.message for f in found)
         assert "healthmon/healthmon.not_a_real_metric" in msgs
-        assert "autotune/autotune.invented_histogram" in msgs
+        assert "memscope/memscope.invented_histogram" in msgs
         # kind mismatches: a gauge observed as histogram, a counter
         # written as gauge
         assert "perfscope/perfscope.mfu" in msgs
@@ -226,7 +226,7 @@ class TestFamiliesSingleHome:
             "servescope")
         assert tc.RESILIENCE_FAMILIES == families.family_table(
             "resilience")
-        assert tc.AUTOTUNE_FAMILIES == families.family_table("autotune")
+        assert tc.MEMSCOPE_FAMILIES == families.family_table("memscope")
         assert tc.MXLINT_FAMILIES == families.family_table("mxlint")
 
     def test_table_shape(self):
@@ -259,66 +259,64 @@ class TestEnvAccessors:
     teardown_method = setup_method
 
     def test_precedence_call_site_beats_env(self):
-        from incubator_mxnet_tpu.autotune import knobs
+        from incubator_mxnet_tpu import settings
         os.environ["MXTPU_T_INT"] = "5"
-        assert knobs.env_int("MXTPU_T_INT", 1) == 5
-        assert knobs.env_int("MXTPU_T_INT", 1, call_site=9) == 9
-        assert knobs.env_int("MXTPU_T_INT_UNSET", 7) == 7
+        assert settings.env_int("MXTPU_T_INT", 1) == 5
+        assert settings.env_int("MXTPU_T_INT", 1, call_site=9) == 9
+        assert settings.env_int("MXTPU_T_INT_UNSET", 7) == 7
 
     def test_empty_env_is_unset(self):
-        from incubator_mxnet_tpu.autotune import knobs
+        from incubator_mxnet_tpu import settings
         os.environ["MXTPU_T_STR"] = "   "
-        assert knobs.env_str("MXTPU_T_STR", "d") == "d"
-        assert knobs.env_raw("MXTPU_T_STR") is None
+        assert settings.env_str("MXTPU_T_STR", "d") == "d"
+        assert settings.env_raw("MXTPU_T_STR") is None
 
     def test_int_garbage_raises_naming_the_knob(self):
-        from incubator_mxnet_tpu.autotune import knobs
+        from incubator_mxnet_tpu import settings
         os.environ["MXTPU_T_INT"] = "banana"
         with pytest.raises(ValueError, match="MXTPU_T_INT"):
-            knobs.env_int("MXTPU_T_INT", 1)
+            settings.env_int("MXTPU_T_INT", 1)
 
     def test_int_garbage_degrades_for_never_raise_consumers(self):
-        from incubator_mxnet_tpu.autotune import knobs
-        knobs.reset_warned()
+        from incubator_mxnet_tpu import settings
+        settings.reset_warned()
         os.environ["MXTPU_T_INT"] = "banana"
         with pytest.warns(UserWarning, match="MXTPU_T_INT"):
-            assert knobs.env_int("MXTPU_T_INT", 3,
+            assert settings.env_int("MXTPU_T_INT", 3,
                                  on_error="default") == 3
 
     def test_flag_spelling_table(self):
-        from incubator_mxnet_tpu.autotune import knobs
+        from incubator_mxnet_tpu import settings
         for raw, want in (("1", True), ("true", True), ("on", True),
                           ("yes", True), ("0", False), ("false", False),
                           ("off", False), ("no", False)):
             os.environ["MXTPU_T_FLAG"] = raw
-            assert knobs.env_flag("MXTPU_T_FLAG", not want) is want, raw
+            assert settings.env_flag("MXTPU_T_FLAG", not want) is want, raw
 
     def test_flag_garbage_warns_and_defaults(self):
-        from incubator_mxnet_tpu.autotune import knobs
-        knobs.reset_warned()
+        from incubator_mxnet_tpu import settings
+        settings.reset_warned()
         os.environ["MXTPU_T_FLAG"] = "maybe"
         with pytest.warns(UserWarning, match="MXTPU_T_FLAG"):
-            assert knobs.env_flag("MXTPU_T_FLAG", True) is True
+            assert settings.env_flag("MXTPU_T_FLAG", True) is True
 
-    def test_pallas_switch_rides_the_knob_home(self):
-        """The PR 14 bugfix: a cached tuning winner's pallas knob now
-        reaches ops/pallas.enabled() (it used to read raw env BELOW the
-        cache layer and silently ignore the winner)."""
-        from incubator_mxnet_tpu.autotune import knobs
+    def test_pallas_switch_rides_the_settings_home(self):
+        """ops/pallas.enabled() decides from settings.resolve("pallas"),
+        not from a raw env read of its own."""
+        from incubator_mxnet_tpu import settings
         from incubator_mxnet_tpu.ops import pallas
         for k in ("MXTPU_PALLAS", "MXTPU_NO_PALLAS",
                   "MXTPU_FORCE_PALLAS"):
             os.environ.pop(k, None)
-        knobs.clear_cached_defaults()
         try:
             assert pallas.enabled() is False       # cpu default: auto
-            knobs.set_cached_defaults({"pallas": "force"})
-            assert pallas.enabled() is True        # winner applies
-            os.environ["MXTPU_PALLAS"] = "0"       # env still beats it
+            os.environ["MXTPU_PALLAS"] = "force"
+            assert settings.resolve("pallas")[0] == "force"
+            assert pallas.enabled() is True
+            os.environ["MXTPU_PALLAS"] = "0"
             assert pallas.enabled() is False
         finally:
             os.environ.pop("MXTPU_PALLAS", None)
-            knobs.clear_cached_defaults()
 
 
 class TestTreeClean:

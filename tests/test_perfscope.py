@@ -634,7 +634,7 @@ class TestPerfRegress:
         pr = _load_tool("perf_regress")
         a = self._write(tmp_path, "BENCH_a.json", _bench_doc())
         b = self._write(tmp_path, "BENCH_b.json", {
-            "n": 2, "cmd": "python bench.py", "rc": 3,
+            "n": 2, "cmd": "python tools/serve_load.py", "rc": 3,
             "parsed": {"metric": "m_img_s", "value": 0.0,
                        "unit": "images/sec", "vs_baseline": 0.0,
                        "error": "hard watchdog: backend init exceeded"}})

@@ -296,7 +296,7 @@ class TestSupervisor:
         got = np.concatenate([first, resumed])
         # the continued trajectory matches the uninterrupted one. NOT
         # assert_array_equal: gold and the resumed run execute
-        # separately-compiled XLA:CPU programs, and the autotuner's
+        # separately-compiled XLA:CPU programs, and the compiler's
         # per-compile choices (measured: a ~2^-8 dot-precision variant
         # under load) are not bit-stable across compiles — compiler
         # variance, not resume state drift (pinned bit-exactly above).

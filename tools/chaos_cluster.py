@@ -1,7 +1,7 @@
 #!/usr/bin/env python
 """Chaos harness for mxtpu.resilience: inject real faults, assert real
-recovery (tools/resilience_smoke.sh runs it; the tier-1 test
-tests/test_resilience.py::test_chaos_* asserts on its output). The
+recovery (the tier-1 test tests/test_resilience.py::test_chaos_*
+asserts on its output). The
 health_cluster.py pattern, escalated from detection to self-healing:
 healthmon's harness proves the verdicts fire; THIS one proves training
 outlives them.

@@ -1,7 +1,7 @@
 #!/usr/bin/env python
 """Healthmon 2-process cluster exercise: the acceptance harness for
-cross-rank training health (tools/health_smoke.sh runs it; the tier-1
-test tests/test_healthmon_cluster.py asserts on its output).
+cross-rank training health (the tier-1 test
+tests/test_healthmon_cluster.py asserts on its output).
 
 Parent mode (default): spawns a REAL 2-process jax cluster over loopback
 gloo (the same bootstrap tests/test_multihost_real.py exercises), with

@@ -75,12 +75,6 @@ per-replica trace table with straggler flags (report-only context for
 the router's least-loaded score), and the collector's per-process
 clock-offset estimates ± rtt/2.
 
-`tune`: the autotune report from a BENCH json (`extra.autotune`) —
-cache hit/miss verdict, the trial table with measured busy fraction /
-step wall / MFU / score provenance per config, the pruning reasons
-(which knob families the measured gap taxonomy cut), and the
-winner-vs-default delta.
-
 Usage:
     python tools/mxdiag.py DUMP.json [--events N]
     python tools/mxdiag.py metrics.jsonl
@@ -91,7 +85,6 @@ Usage:
     python tools/mxdiag.py io BENCH.json
     python tools/mxdiag.py serve BENCH.json
     python tools/mxdiag.py fleet BENCH.json [--events EVENTS.jsonl]
-    python tools/mxdiag.py tune BENCH.json
     python tools/mxdiag.py trace TRACE_ID events.jsonl \\
         events_replica_*.jsonl
     python tools/mxdiag.py pod BENCH.json
@@ -305,8 +298,7 @@ def print_perf(doc: dict) -> int:
         return 1
     ps = extra.get("perfscope")
     if not isinstance(ps, dict):
-        print("  no extra.perfscope section (perfscope was off — "
-              "rerun without BENCH_PERFSCOPE=0)")
+        print("  no extra.perfscope section (perfscope was off)")
         return 1
     peaks = ps.get("peaks") or {}
     print(f"  peaks: {peaks.get('device_kind')} "
@@ -377,7 +369,7 @@ def _perf_main(argv) -> int:
         prog="mxdiag.py perf",
         description="MFU-decomposition report from a BENCH json "
                     "(extra.perfscope)")
-    ap.add_argument("path", help="BENCH json (bench.py output or the "
+    ap.add_argument("path", help="artifact json (serve_load.py output or the "
                                  "driver wrapper)")
     args = ap.parse_args(argv)
     try:
@@ -386,123 +378,6 @@ def _perf_main(argv) -> int:
         print(f"perf: {e}", file=sys.stderr)
         return 1
     return print_perf(doc)
-
-
-# ---------------------------------------------------------------------------
-# tune: the autotune report from a BENCH json (extra.autotune)
-# ---------------------------------------------------------------------------
-
-def _fmt_busy(bf) -> str:
-    return f"{bf:.1%}" if isinstance(bf, (int, float)) else "-"
-
-
-def _fmt_ms(v) -> str:
-    return f"{v:.2f}" if isinstance(v, (int, float)) else "-"
-
-
-def print_tune(doc: dict) -> int:
-    """The "what did the tuner decide and why" report: cache verdict,
-    the trial table (config, measured busy, step wall, MFU, score
-    provenance), the pruning reasons (which knob families the measured
-    gap taxonomy cut, and why), and the winner-vs-default delta."""
-    extra = doc.get("extra") or {}
-    print(f"bench: {doc.get('metric')} = {doc.get('value')} "
-          f"{doc.get('unit')}  (model {extra.get('model')}, batch "
-          f"{extra.get('batch')}, {extra.get('dtype')})")
-    at = extra.get("autotune")
-    if not isinstance(at, dict):
-        print("  no extra.autotune section (pre-autotune artifact)")
-        return 1
-    if not at.get("enabled"):
-        print("  autotune DISABLED for this run (MXTPU_AUTOTUNE unset)")
-        resolved = at.get("resolved")
-        if isinstance(resolved, dict):
-            print(f"  resolved knobs: "
-                  + " ".join(f"{k}={v}" for k, v in resolved.items()))
-        return 0
-    if at.get("error"):
-        print(f"  autotune ERRORED: {at['error']} (run was untuned)")
-        return 1
-    cache = at.get("cache") or {}
-    verdict = "HIT (0 trials — started tuned)" if at.get("cache_hit") \
-        else (f"MISS -> searched {at.get('trials')} trial(s)"
-              + (", budget exhausted -> best-so-far"
-                 if at.get("budget_exhausted") else ""))
-    print(f"\n  tuning cache: {verdict}")
-    print(f"    key: fingerprint={cache.get('fingerprint')}  "
-          f"mesh={cache.get('mesh')}  device={cache.get('device_kind')}")
-    if cache.get("rejects"):
-        print(f"    {cache['rejects']} stale/corrupt cache entry(ies) "
-              f"rejected (counted; re-searched)")
-    if at.get("diagnosis"):
-        print(f"  baseline diagnosis: {at['diagnosis']}")
-    table = at.get("trial_table") or []
-    if table:
-        print(f"\n  trials ({len(table)}):")
-        print(f"    {'move':<24} {'status':<7} {'busy':>7} "
-              f"{'step_ms':>9} {'mfu':>8} {'provenance':<18}")
-        win = at.get("winner")
-        for row in table:
-            cfg = row.get("config") or {}
-            move = (f"{row['knob']}={row.get('value')}"
-                    if row.get("knob") else "baseline (default)")
-            mfu = row.get("mfu")
-            tag = "  << WINNER" if win and cfg == win else ""
-            err = f"  ({str(row.get('error'))[:40]})" \
-                if row.get("status") == "failed" else ""
-            print(f"    {move:<24} {row.get('status', '?'):<7} "
-                  f"{_fmt_busy(row.get('busy_fraction')):>7} "
-                  f"{_fmt_ms(row.get('step_ms')):>9} "
-                  f"{mfu if isinstance(mfu, (int, float)) else '-':>8} "
-                  f"{row.get('provenance') or '-':<18}{tag}{err}")
-    pruned = at.get("pruned") or {}
-    if pruned:
-        print(f"\n  pruned knob families ({len(pruned)}):")
-        for k in sorted(pruned):
-            print(f"    {k:<15} {pruned[k]}")
-    win, sc, df = at.get("winner"), at.get("score"), at.get("default")
-    if win:
-        print(f"\n  winner: "
-              + (" ".join(f"{k}={v}" for k, v in win.items()
-                          if v not in (None, False)) or "default"))
-    if isinstance(sc, dict):
-        line = (f"    score: busy {_fmt_busy(sc.get('busy_fraction'))}  "
-                f"step {_fmt_ms(sc.get('step_ms'))} ms  "
-                f"mfu {sc.get('mfu')}  [{sc.get('provenance')}]")
-        if isinstance(df, dict):
-            line += (f"\n    vs default: busy "
-                     f"{_fmt_busy(df.get('busy_fraction'))}  "
-                     f"step {_fmt_ms(df.get('step_ms'))} ms  "
-                     f"mfu {df.get('mfu')}")
-            b0, b1 = df.get("busy_fraction"), sc.get("busy_fraction")
-            if isinstance(b0, (int, float)) and isinstance(b1,
-                                                           (int, float)) \
-                    and b0 > 0:
-                line += f"  (busy delta {(b1 - b0) / b0:+.1%})"
-        print(line)
-    resolved = at.get("resolved")
-    if isinstance(resolved, dict) and win and resolved != win:
-        diff = {k for k in resolved
-                if win.get(k) != resolved.get(k)}
-        if diff:
-            print(f"\n  NOTE: the run OVERRODE the winner on "
-                  f"{sorted(diff)} (env beats the tuner by precedence)")
-    return 0
-
-
-def _tune_main(argv) -> int:
-    ap = argparse.ArgumentParser(
-        prog="mxdiag.py tune",
-        description="Autotune report from a BENCH json (extra.autotune)")
-    ap.add_argument("path", help="BENCH json (bench.py output or the "
-                                 "driver wrapper)")
-    args = ap.parse_args(argv)
-    try:
-        doc = _load_bench(args.path)
-    except (OSError, ValueError) as e:
-        print(f"tune: {e}", file=sys.stderr)
-        return 1
-    return print_tune(doc)
 
 
 # ---------------------------------------------------------------------------
@@ -524,8 +399,8 @@ def print_comms(doc: dict) -> int:
         return 1
     cs = extra.get("commscope")
     if not isinstance(cs, dict):
-        print("  no extra.commscope section (commscope was off — rerun "
-              "without BENCH_COMMSCOPE=0, with a BENCH_MESH layout)")
+        print("  no extra.commscope section (commscope was off, or the "
+              "run had no mesh)")
         return 1
     peaks = cs.get("peaks") or {}
     print(f"  ICI peaks: {peaks.get('device_kind')} "
@@ -581,7 +456,7 @@ def _comms_main(argv) -> int:
         prog="mxdiag.py comms",
         description="per-program collective tables from a BENCH json "
                     "(extra.commscope)")
-    ap.add_argument("path", help="BENCH json (bench.py output or the "
+    ap.add_argument("path", help="artifact json (serve_load.py output or the "
                                  "driver wrapper)")
     args = ap.parse_args(argv)
     try:
@@ -613,8 +488,7 @@ def print_device(doc: dict) -> int:
         return 1
     ds = extra.get("devicescope")
     if not isinstance(ds, dict):
-        print("  no extra.devicescope section (devicescope was off — "
-              "rerun with BENCH_DEVICESCOPE=1)")
+        print("  no extra.devicescope section (devicescope was off)")
         return 1
     win = ds.get("window")
     if not isinstance(win, dict):
@@ -695,7 +569,7 @@ def _device_main(argv) -> int:
         prog="mxdiag.py device",
         description="measured device-timeline report from a BENCH json "
                     "(extra.devicescope)")
-    ap.add_argument("path", help="BENCH json (bench.py output or the "
+    ap.add_argument("path", help="artifact json (serve_load.py output or the "
                                  "driver wrapper)")
     args = ap.parse_args(argv)
     try:
@@ -745,8 +619,7 @@ def print_mem(doc: dict) -> int:
         return 1
     ms = extra.get("memscope")
     if not isinstance(ms, dict):
-        print("  no extra.memscope section (memscope was off — rerun "
-              "with BENCH_MEMSCOPE=1)")
+        print("  no extra.memscope section (memscope was off)")
         return 1
     progs = [p for p in (ms.get("programs") or []) if isinstance(p, dict)]
     if progs:
@@ -812,10 +685,6 @@ def print_mem(doc: dict) -> int:
                      f"[{hr.get('capacity_source')}], target "
                      f"{hr.get('target')})")
         print(line)
-        if verdict == "tight":
-            print("    predicted peaks above capacity x target are "
-                  "infeasible — the autotuner prunes such candidates "
-                  "pre-trial (reason=memory)")
     recon = ms.get("reconciliation")
     if isinstance(recon, dict) and recon.get("analytic"):
         a, m = recon["analytic"], recon.get("measured") or {}
@@ -869,7 +738,7 @@ def _mem_main(argv) -> int:
     ap = argparse.ArgumentParser(
         prog="mxdiag.py mem",
         description="memory report from a BENCH json (extra.memscope)")
-    ap.add_argument("path", help="BENCH json (bench.py output or the "
+    ap.add_argument("path", help="artifact json (serve_load.py output or the "
                                  "driver wrapper)")
     args = ap.parse_args(argv)
     try:
@@ -968,7 +837,7 @@ def _io_main(argv) -> int:
         prog="mxdiag.py io",
         description="ingest-pipeline report from a BENCH json "
                     "(extra.io + devicescope starvation split)")
-    ap.add_argument("path", help="BENCH json (bench.py output or the "
+    ap.add_argument("path", help="artifact json (serve_load.py output or the "
                                  "driver wrapper)")
     args = ap.parse_args(argv)
     try:
@@ -1042,8 +911,7 @@ def print_serve(doc: dict) -> int:
               f"invalid {sv.get('rejected_invalid', 0)}")
     ss = extra.get("servescope")
     if not isinstance(ss, dict):
-        print("\n  no extra.servescope section (servescope was off — "
-              "rerun without BENCH_SERVESCOPE=0)")
+        print("\n  no extra.servescope section (servescope was off)")
         return 1
     src = ss.get("device_exec_source")
     tag = ""
@@ -1088,7 +956,7 @@ def _serve_main(argv) -> int:
         prog="mxdiag.py serve",
         description="tail-latency attribution report from a BENCH json "
                     "(extra.servescope / extra.serve_load)")
-    ap.add_argument("path", help="BENCH json (bench.py / serve_load.py "
+    ap.add_argument("path", help="artifact json (serve_load.py "
                                  "output or the driver wrapper)")
     args = ap.parse_args(argv)
     try:
@@ -1449,8 +1317,8 @@ def _lint_main(argv) -> int:
         description="render the mxlint findings report (rule ids + "
                     "fix-it hints) for the repo or specific paths")
     ap.add_argument("paths", nargs="*",
-                    help="files/dirs to lint (default: the package, "
-                         "tools/ and bench.py)")
+                    help="files/dirs to lint (default: the package "
+                         "and tools/)")
     ap.add_argument("--rule", action="append", default=None,
                     help="run only these rule ids (repeatable)")
     args = ap.parse_args(argv)
@@ -1734,8 +1602,6 @@ def main(argv=None) -> int:
         return _trace_main(argv[1:])
     if argv and argv[0] == "pod":
         return _pod_main(argv[1:])
-    if argv and argv[0] == "tune":
-        return _tune_main(argv[1:])
     if argv and argv[0] == "recover":
         return _recover_main(argv[1:])
     if argv and argv[0] == "lint":

@@ -8,11 +8,10 @@ Runs the mxtpu.mxlint rule suite (stdlib ast, no deps) over the repo:
     python tools/mxlint.py --list-rules       # rule table with hints
     python tools/mxlint.py --check --json     # machine-readable findings
 
-Default lint set: the ``incubator_mxnet_tpu/`` package, ``tools/`` and
-``bench.py`` (tests/, examples/ and docs/ are excluded — fixtures carry
-deliberate violations). Per-rule path scopes live on the rules
-themselves (e.g. ``raw-env-read`` judges only the package: BENCH_* is
-the driver layer's own documented spelling).
+Default lint set: the ``incubator_mxnet_tpu/`` package and ``tools/``
+(tests/, examples/ and docs/ are excluded — fixtures carry deliberate
+violations). Per-rule path scopes live on the rules themselves (e.g.
+``raw-env-read`` judges only the package).
 
 Suppression: ``# mxlint: disable=<rule> -- <reason>`` (the reason is
 required; a reasonless directive suppresses nothing and is itself a
@@ -71,8 +70,7 @@ def _load_mxlint():
 
 def default_paths() -> list:
     return [os.path.join(_REPO, "incubator_mxnet_tpu"),
-            os.path.join(_REPO, "tools"),
-            os.path.join(_REPO, "bench.py")]
+            os.path.join(_REPO, "tools")]
 
 
 def run_lint(paths=None, rules=None, root=None):
@@ -101,8 +99,8 @@ def main(argv=None) -> int:
         description=__doc__.splitlines()[0],
         formatter_class=argparse.RawDescriptionHelpFormatter)
     ap.add_argument("paths", nargs="*",
-                    help="files/dirs to lint (default: the package, "
-                         "tools/ and bench.py)")
+                    help="files/dirs to lint (default: the package "
+                         "and tools/)")
     ap.add_argument("--check", action="store_true",
                     help="gate mode: print findings, exit 1 if any")
     ap.add_argument("--json", action="store_true",

@@ -38,7 +38,7 @@ Usage:
     python tools/perf_regress.py BASELINE.json CANDIDATE.json
     python tools/perf_regress.py --dir REPO_DIR [--candidate FILE]
 
-Accepted artifact shapes: direct bench.py output
+Accepted artifact shapes: direct tools/serve_load.py output
 (``{"metric", "value", ...}``) and the driver wrapper
 (``{"n", "cmd", "rc", "parsed": {...}}``).
 
@@ -67,7 +67,7 @@ DEFAULT_COLL_THRESHOLD = 0.10  # 10% relative increase on bytes/step
 DEFAULT_BUSY_THRESHOLD = 0.10
 # measured peak memory (memscope watermark ring, static footprint
 # fallback): >10% growth is a memory regression — the number that eats
-# the autotuner's batch headroom and ends runs in RESOURCE_EXHAUSTED
+# the batch headroom and ends runs in RESOURCE_EXHAUSTED
 DEFAULT_PEAK_THRESHOLD = 0.10
 # dedup rate (extra.embedding.dedup_rate, recsys artifacts): for a
 # fixed record stream the id distribution is deterministic, so like the
@@ -178,21 +178,6 @@ def load_artifact(path):
     dr = emb.get("dedup_rate") if isinstance(emb, dict) else None
     rec["dedup_rate"] = (float(dr) if isinstance(dr, (int, float))
                          and not isinstance(dr, bool) else None)
-    # the knob config the run ACTUALLY resolved to (extra.autotune.
-    # resolved — present on every post-autotune training artifact,
-    # tuned or not; `winner` is the fallback for tuned artifacts that
-    # predate the resolved field). A tuner-chosen config change must
-    # never be silently read as a code regression OR silently mask one,
-    # so compare() attaches the knob diff as a context note — the same
-    # both-sides contract as the commscope gates
-    at = extra.get("autotune") or {}
-    knobs = at.get("resolved") if isinstance(at.get("resolved"), dict) \
-        else (at.get("winner") if isinstance(at.get("winner"), dict)
-              else None)
-    rec["knobs"] = knobs
-    rec["autotune_cache_hit"] = (at.get("cache_hit")
-                                 if isinstance(at.get("cache_hit"), bool)
-                                 else None)
     # resilience accounting (extra.resilience): a RECOVERED run's BENCH
     # is USABLE — the measured throughput is real — but the recovery
     # cost (steps lost to rollbacks) must be reported, never hidden;
@@ -247,32 +232,6 @@ def compare(baseline, candidate, threshold=DEFAULT_THRESHOLD,
         notes.append(f"metric mismatch ({baseline['metric']!r} vs "
                      f"{candidate['metric']!r}) — nothing comparable")
         return regressions, notes
-    # knob-config context FIRST, so every verdict below is read with it:
-    # two artifacts measured under different tuner-resolved knob configs
-    # are comparing configs as much as code — the diff is attached as a
-    # note (never a verdict by itself), and its absence on either side
-    # is noted too (both-sides contract, like the commscope gates)
-    bk, ck = baseline.get("knobs"), candidate.get("knobs")
-    if bk is not None and ck is not None:
-        diff = sorted(k for k in set(bk) | set(ck)
-                      if bk.get(k) != ck.get(k))
-        if diff:
-            detail = ", ".join(f"{k}: {bk.get(k)!r} -> {ck.get(k)!r}"
-                               for k in diff)
-            notes.append(
-                f"CONTEXT: knob config differs baseline -> candidate "
-                f"({detail}) — the verdicts below compare DIFFERENT "
-                f"configs: a tuned-config change is not a code "
-                f"regression, and can mask one (re-run both sides with "
-                f"MXTPU_AUTOTUNE=0 and matching BENCH_* knobs to "
-                f"isolate the code)")
-        else:
-            notes.append("ok knob config identical on both sides")
-    elif (bk is None) != (ck is None):
-        side = "candidate" if bk is None else "baseline"
-        notes.append(f"note: only the {side} carries a resolved knob "
-                     f"config — knob context skipped (needs "
-                     f"extra.autotune on both sides)")
     eff = max(threshold, noise_mult * noise)
     if noise:
         notes.append(f"noise band {noise:.1%} -> effective threshold "

@@ -1,8 +1,7 @@
 #!/usr/bin/env python
 """Closed-loop serving load harness: ramp concurrency, find the knee.
 
-The serving-path counterpart of ``bench.py``'s training sweep (ROADMAP
-item 1's closing gate): freeze a model, start :class:`ModelServer`, and
+Freeze a model, start :class:`ModelServer`, and
 drive K concurrent **closed-loop** clients (each fires its next request
 the moment the previous response lands — the load model under which
 "QPS at a p99 target" is well-defined) through a ramped concurrency
@@ -38,7 +37,7 @@ trace_check-valid BENCH json:
 
 A server that dies mid-sweep (every request of a level failing, or a
 dead /healthz) produces a self-describing ``{"status": "env_failure"}``
-artifact — the bench.py convention perf_regress skips — instead of a
+artifact — the convention perf_regress skips — instead of a
 zero that would poison the BENCH trajectory.
 
 With ``--fleet N`` the harness drives a whole replica fleet instead of
@@ -457,8 +456,8 @@ def build_fleetscope_extra(client_minted: int, router_records,
 
 
 def write_env_failure(path: str, metric: str, error: str) -> dict:
-    """The self-describing environment-failure artifact (bench.py's
-    preflight convention): perf_regress skips it, the trajectory stays
+    """The self-describing environment-failure artifact:
+    perf_regress skips it, the trajectory stays
     unpoisoned, and the error travels with the file."""
     doc = {"status": "env_failure", "metric": metric, "value": 0.0,
            "unit": "requests/sec", "error": str(error)[:500],

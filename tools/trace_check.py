@@ -14,8 +14,8 @@
   across samples (a decrease means a broken registry or a torn read).
   Histogram-kind metrics are validated structurally (cumulative buckets,
   `+Inf` == count) and their observation count must be monotonic;
-* **bench result JSON** (`BENCH_*.json`) — when the result carries an
-  `extra.serving` section (the serving benchmark), its latency
+* **run artifact JSON** (what `tools/serve_load.py` writes) — when the
+  result carries an `extra.serving` section, its latency
   histograms, percentiles, and fill-ratio/error accounting are
   structurally validated;
 * **structured event logs** (`healthmon.events` / ``mxtpu.events/1``
@@ -23,8 +23,7 @@
   run_id/rank/step correlation ids, non-decreasing timestamps;
 * **counter families** — any `healthmon/*`, `io/*`, `trainloop/*`,
   `perfscope/*`, `commscope/*`, `devicescope/*`, `servescope/*`,
-  `autotune/*`, `mxlint/*` or
-  `sharding/*` metric appearing in a flight dump or metrics series must
+  `mxlint/*` or `sharding/*` metric appearing in a flight dump or metrics series must
   belong to the known family table with the declared kind (an unknown
   or re-kinded metric means a producer drifted from the documented
   schema). The tables have ONE home —
@@ -35,9 +34,10 @@ Usage:
     python tools/trace_check.py FILE [more files ...]
 
 File kind is auto-detected (extension, then content). Exit status 0 iff
-every file validates; errors are printed one per line. bench.py imports
-:func:`check_trace` / :func:`check_file` and fails the run on malformed
-output, so a broken exporter can't silently ship garbage telemetry.
+every file validates; errors are printed one per line.
+`tools/serve_load.py` imports :func:`check_file` and fails the run
+on malformed output, so a broken exporter can't silently ship garbage
+telemetry.
 """
 from __future__ import annotations
 
@@ -53,7 +53,7 @@ __all__ = ["check_trace", "check_events", "check_flight", "check_prom",
            "check_commscope_extra", "check_devicescope_extra",
            "check_servescope_extra", "check_serve_load_extra",
            "check_sharding_extra", "check_resilience_extra",
-           "check_autotune_extra", "check_mxlint_extra", "check_io_extra",
+           "check_mxlint_extra", "check_io_extra",
            "check_embedding_extra", "check_fleetscope_extra",
            "check_file"]
 
@@ -96,7 +96,6 @@ SERVESCOPE_FAMILIES = _families.family_table("servescope")
 # (docs/memscope.md)
 MEMSCOPE_FAMILIES = _families.family_table("memscope")
 RESILIENCE_FAMILIES = _families.family_table("resilience")
-AUTOTUNE_FAMILIES = _families.family_table("autotune")
 # mxlint.* — the strict-mode jit-program auditor (docs/mxlint.md)
 MXLINT_FAMILIES = _families.family_table("mxlint")
 # fleet.* — continuous batching + replica fleet (docs/serving.md)
@@ -152,23 +151,6 @@ MEMSCOPE_OOM_SCHEMA = "mxtpu.memscope.oom/1"
 # (devicescope/ingest.py _starved_split), plus its dominant-stage tags
 DEVICESCOPE_STARVED_SPLIT = ("read_ms", "decode_ms", "transfer_ms")
 DEVICESCOPE_STARVED_DOMINANTS = ("read", "decode", "transfer")
-
-# score provenance an `extra.autotune` record may declare: the trial's
-# busy fraction came from a measured devicescope window, or degraded to
-# host-side wall/throughput scoring (autotune/trial.py SCORE_SOURCES)
-AUTOTUNE_SCORE_SOURCES = ("measured(profile)", "host_wall")
-
-# the knob fields a winner/resolved config may carry
-# (autotune/knobs.py KNOB_FIELDS)
-AUTOTUNE_KNOB_FIELDS = ("loop_chunk", "remat", "remat_policy",
-                        "prefetch_depth", "io_workers", "pallas", "mesh",
-                        "batch")
-
-AUTOTUNE_PALLAS_MODES = ("auto", "on", "force", "off")
-AUTOTUNE_REMAT_POLICIES = (None, "dots", "nothing", "everything")
-AUTOTUNE_TRIAL_STATUSES = ("ok", "failed")
-AUTOTUNE_DIAGNOSES = ("input_starved", "dispatch_bound", "device_bound",
-                      "unknown", None)
 
 # the closed request-latency component taxonomy an `extra.servescope`
 # attribution decomposes into (servescope/spans.py COMPONENTS)
@@ -346,7 +328,6 @@ def check_healthmon_kinds(kinds: dict) -> list:
               ("memscope/", MEMSCOPE_FAMILIES, "MEMSCOPE_FAMILIES"),
               ("resilience/", RESILIENCE_FAMILIES,
                "RESILIENCE_FAMILIES"),
-              ("autotune/", AUTOTUNE_FAMILIES, "AUTOTUNE_FAMILIES"),
               ("mxlint/", MXLINT_FAMILIES, "MXLINT_FAMILIES"),
               ("fleet/", FLEET_FAMILIES, "FLEET_FAMILIES"),
               ("fleetscope/", FLEETSCOPE_FAMILIES,
@@ -1101,154 +1082,6 @@ def check_memscope_extra(ms) -> list:
 
 
 # ---------------------------------------------------------------------------
-# autotune bench section (extra.autotune)
-# ---------------------------------------------------------------------------
-
-def _check_knob_dict(d, where: str) -> list:
-    """One knob config object (winner / resolved / a trial row's
-    config): known fields only, each well-typed."""
-    errors = []
-    if not isinstance(d, dict):
-        return [f"{where}: must be an object, got {type(d).__name__}"]
-    unknown = sorted(set(d) - set(AUTOTUNE_KNOB_FIELDS))
-    if unknown:
-        errors.append(f"{where}: unknown knob field(s) {unknown} "
-                      f"(update AUTOTUNE_KNOB_FIELDS if intentional)")
-    for key in ("loop_chunk", "prefetch_depth"):
-        v = d.get(key)
-        if key in d and (not isinstance(v, int) or isinstance(v, bool)
-                         or v < 0):
-            errors.append(f"{where}[{key!r}] must be an int >= 0, "
-                          f"got {v!r}")
-    w = d.get("io_workers")
-    if "io_workers" in d and (not isinstance(w, int)
-                              or isinstance(w, bool) or w < 1):
-        errors.append(f"{where}['io_workers'] must be an int >= 1, "
-                      f"got {w!r}")
-    if "remat" in d and not isinstance(d["remat"], bool):
-        errors.append(f"{where}['remat'] must be a bool, "
-                      f"got {d['remat']!r}")
-    if d.get("remat_policy") not in AUTOTUNE_REMAT_POLICIES:
-        errors.append(f"{where}['remat_policy'] {d.get('remat_policy')!r} "
-                      f"not in {AUTOTUNE_REMAT_POLICIES}")
-    if "pallas" in d and d["pallas"] not in AUTOTUNE_PALLAS_MODES:
-        errors.append(f"{where}['pallas'] {d.get('pallas')!r} not in "
-                      f"{AUTOTUNE_PALLAS_MODES}")
-    b = d.get("batch")
-    if b is not None and (not isinstance(b, int) or isinstance(b, bool)
-                          or b < 1):
-        errors.append(f"{where}['batch'] must be an int >= 1 or null, "
-                      f"got {b!r}")
-    m = d.get("mesh")
-    if m is not None and (not isinstance(m, str) or not m):
-        errors.append(f"{where}['mesh'] must be a non-empty string or "
-                      f"null, got {m!r}")
-    return errors
-
-
-def _check_autotune_score(sc, where: str) -> list:
-    """One measurement summary (score / default): busy fraction in
-    [0, 1] or null, non-negative step wall, provenance from the closed
-    taxonomy."""
-    errors = []
-    if not isinstance(sc, dict):
-        return [f"{where}: must be an object, got {type(sc).__name__}"]
-    bf = sc.get("busy_fraction")
-    if bf is not None and (not _is_num(bf) or not 0.0 <= bf <= 1.0):
-        errors.append(f"{where}.busy_fraction={bf!r} outside [0, 1]")
-    for key in ("step_ms", "mfu", "value"):
-        v = sc.get(key)
-        if v is not None and (not _is_num(v) or v < 0):
-            errors.append(f"{where}.{key} must be numeric >= 0 or "
-                          f"null, got {v!r}")
-    prov = sc.get("provenance")
-    if prov is not None and prov not in AUTOTUNE_SCORE_SOURCES:
-        errors.append(f"{where}.provenance={prov!r} not in "
-                      f"{AUTOTUNE_SCORE_SOURCES}")
-    return errors
-
-
-def check_autotune_extra(at) -> list:
-    """Validate an `extra.autotune` BENCH section: the disabled shape
-    (`enabled: false`, optionally the resolved knob config), or the
-    full tuning record — cache hit/miss with the hit-means-zero-trials
-    invariant, trial accounting, a well-typed winner/resolved config,
-    score + default measurements with closed provenance, pruning
-    reasons, and a trial table whose rows carry valid statuses."""
-    if at is None:
-        return []
-    if not isinstance(at, dict):
-        return [f"must be an object, got {type(at).__name__}"]
-    errors = []
-    enabled = at.get("enabled")
-    if not isinstance(enabled, bool):
-        errors.append(f"needs a boolean 'enabled', got {enabled!r}")
-        return errors
-    if isinstance(at.get("resolved"), dict) or at.get("resolved") is None:
-        if at.get("resolved") is not None:
-            errors += _check_knob_dict(at["resolved"], "resolved")
-    else:
-        errors.append("'resolved' must be a knob object or null")
-    if not enabled:
-        return errors
-    hit = at.get("cache_hit")
-    if not isinstance(hit, bool):
-        errors.append(f"enabled record needs boolean 'cache_hit', "
-                      f"got {hit!r}")
-    for key in ("trials", "trials_pruned", "trials_failed"):
-        v = at.get(key)
-        if not isinstance(v, int) or isinstance(v, bool) or v < 0:
-            errors.append(f"'{key}' must be an int >= 0, got {v!r}")
-    if hit is True and at.get("trials") != 0:
-        errors.append(f"cache_hit=true must report trials=0 (the "
-                      f"hit-skips-search contract), got "
-                      f"{at.get('trials')!r}")
-    if at.get("error") is None:
-        if at.get("winner") is None:
-            errors.append("an enabled, error-free record needs a "
-                          "'winner' config")
-        else:
-            errors += _check_knob_dict(at["winner"], "winner")
-        if at.get("score") is not None:
-            errors += _check_autotune_score(at["score"], "score")
-    if at.get("default") is not None:
-        errors += _check_autotune_score(at["default"], "default")
-    diag = at.get("diagnosis")
-    if diag not in AUTOTUNE_DIAGNOSES:
-        errors.append(f"diagnosis={diag!r} not in {AUTOTUNE_DIAGNOSES}")
-    pruned = at.get("pruned")
-    if pruned is not None:
-        if not isinstance(pruned, dict):
-            errors.append("'pruned' must be an object of knob -> reason")
-        else:
-            for k, v in pruned.items():
-                if not isinstance(v, str) or not v:
-                    errors.append(f"pruned[{k!r}] needs a non-empty "
-                                  f"reason string, got {v!r}")
-    table = at.get("trial_table")
-    if table is not None:
-        if not isinstance(table, list):
-            errors.append("'trial_table' must be a list")
-        else:
-            for i, row in enumerate(table):
-                if not isinstance(row, dict):
-                    errors.append(f"trial_table[{i}]: not an object")
-                    continue
-                if row.get("status") not in AUTOTUNE_TRIAL_STATUSES:
-                    errors.append(
-                        f"trial_table[{i}]: status "
-                        f"{row.get('status')!r} not in "
-                        f"{AUTOTUNE_TRIAL_STATUSES}")
-                if row.get("status") == "failed" and not row.get("error"):
-                    errors.append(f"trial_table[{i}]: failed trial "
-                                  f"needs an 'error' reason")
-                if isinstance(row.get("config"), dict):
-                    errors += _check_knob_dict(row["config"],
-                                               f"trial_table[{i}].config")
-    return errors
-
-
-# ---------------------------------------------------------------------------
 # mxlint bench section (extra.mxlint)
 # ---------------------------------------------------------------------------
 
@@ -1683,8 +1516,7 @@ def check_fleetscope_extra(fs) -> list:
 
 
 def check_sharding_extra(sh) -> list:
-    """Validate an `extra.sharding` BENCH section (bench.py BENCH_MESH
-    runs): a positive mesh shape, a mode from the closed taxonomy, and
+    """Validate an `extra.sharding` section (mesh runs): a positive mesh shape, a mode from the closed taxonomy, and
     spec counts that add up to the param total."""
     if sh is None:
         return []
@@ -1728,8 +1560,8 @@ def check_sharding_extra(sh) -> list:
 
 
 def check_embedding_extra(em) -> list:
-    """Validate an `extra.embedding` BENCH section (BENCH_MODEL=recsys
-    runs; emitted by mxtpu.embedding.bench_extra): the table census
+    """Validate an `extra.embedding` section (emitted by
+    mxtpu.embedding.bench_extra): the table census
     (logical vs per-device bytes — sharded means per-device <=
     logical), the dedup accounting (rate in [0, 1], rows touched never
     above ids seen), and the closed out-of-range-id policy."""
@@ -1764,7 +1596,7 @@ def check_embedding_extra(em) -> list:
 
 
 # ---------------------------------------------------------------------------
-# bench result JSON (BENCH_*.json with serving stats)
+# run artifact JSON (with serving stats)
 # ---------------------------------------------------------------------------
 
 def check_resilience_extra(rx) -> list:
@@ -1823,7 +1655,8 @@ def check_resilience_extra(rx) -> list:
 
 
 def check_bench_json(path: str) -> list:
-    """Validate a bench.py result line/file. Core keys always; when the
+    """Validate a run artifact (tools/serve_load.py's result file).
+    Core keys always; when the
     run was the serving benchmark, its `extra.serving` section must carry
     well-formed latency histograms and request accounting."""
     try:
@@ -1878,9 +1711,6 @@ def check_bench_json(path: str) -> list:
     errors += [f"extra.resilience: {e}"
                for e in check_resilience_extra(
                    (doc.get("extra") or {}).get("resilience"))]
-    errors += [f"extra.autotune: {e}"
-               for e in check_autotune_extra(
-                   (doc.get("extra") or {}).get("autotune"))]
     errors += [f"extra.mxlint: {e}"
                for e in check_mxlint_extra(
                    (doc.get("extra") or {}).get("mxlint"))]
