@@ -4,9 +4,10 @@ Replaces the reference's interleaved_matmul_selfatt_* / cuDNN attention
 (src/operator/contrib/transformer.cc) with a FlashAttention-2 style tiled
 kernel: online softmax over K/V blocks, O(L) memory, scores never hit HBM.
 Forward saves the per-row logsumexp; backward recomputes scores blockwise,
-in one kernel while a head's queries fit VMEM (`flash_attention_bwd`) and in
-two beyond (dq; dk/dv). Operands keep their dtype (bf16 on the chip);
-scores, softmax statistics and accumulators are float32.
+in one kernel while a head's queries fit VMEM (`flash_attention_bwd`: up to
+32768 positions in bf16) and in two beyond (dq; dk/dv). Operands keep their
+dtype (bf16 on the chip); scores, softmax statistics and accumulators are
+float32.
 
 Tiling. A grid step costs ~0.4 us whatever it does, so a step does a
 step's worth of work: one OUTER block of one sequence against an INNER
@@ -26,11 +27,26 @@ in VMEM.
   held in VMEM across the key blocks: backward recomputes the scores and
   their exp once, and the dq kernel does not run.
 - `k_major` (`q_major`) is the whole padded sequence while two operands of
-  that length, double buffered, fit a third of `_VMEM_BUDGET`: the last
-  grid axis then has ONE step and K and V (Q and dO) are fetched once a
-  head. Longer sequences stream major blocks of at least 512 along that
+  that length, double buffered, fit a third of `_VMEM_BUDGET` (96 of the
+  128 MiB a v5e core has): the last grid axis then has ONE step, K and V
+  (Q and dO) are fetched once a head (once a GROUP's key/value head: the
+  block index does not change between its query heads), no carry is
+  parked and backward is one kernel. That holds up to 32768 positions in
+  bf16 and 16384 in float32, at d = 128 and at d = 64 alike (a row of 64
+  takes the 128 lanes all the same; d = 256 halves both). From 32769
+  (16385) on a sequence streams major blocks of at least 512 along that
   axis, with index maps clamped to the diagonal so that a skipped step
-  re-uses the block it holds. Which regime runs depends on the shape alone.
+  re-uses the block it holds, and backward is `_dq` and `_dkv` apart.
+  Which regime runs depends on the shape alone.
+- what is asked of Mosaic: it grants a kernel 16 MiB of VMEM unless the
+  call says otherwise, which at d = 128 in bf16 held 4096 resident
+  positions. `_plan` reckons what each kernel holds (operands x pipeline
+  buffers, the float32 dq and its output block, score tiles, accumulators,
+  lse / delta rows at their sublane padding) and `_call` asks for that and
+  a quarter (`vmem_limit_bytes`) where it passes the default; where it does
+  not (1024 x 64: 10 MiB) nothing is asked and the kernels are built as
+  before. tests/test_tpu_compile.py compiles each kernel inside the bare
+  reckoning at 8192, 16384 (float32) and 32768.
 - only the sub-blocks the diagonal (or the padding of the keys) crosses
   build a mask; the ones below it run a loop body without one.
 - `window=w` (with `causal`): row r sees the w keys up to its own. The
@@ -38,13 +54,16 @@ in VMEM.
   the first sub-block the window reaches, the query loop of dk/dv ends at
   the last one, and the streamed index maps are clamped on both sides, so
   a window layer costs window / length of a full one. Only the sub-blocks
-  either edge crosses build a mask. `window=None` builds today's kernels.
+  either edge crosses build a mask; where the blocks line up with the
+  window (`_paired`) the forward folds the two masked tiles of a row block
+  into one softmax pass. `window=None` builds today's kernels.
 - grouped heads: K and V may hold fewer heads than Q (`group` query heads
   to one). Forward and dq read them through the index map (head b // group);
   dk/dv are written per QUERY head and summed over the group outside.
 
-`_plan` derives every block from (lq, lk, d, dtype) under the budget;
-`block_q=` / `block_k=` override it for the tests.
+`_plan` derives every block and every grant from (lq, lk, d, dtype) under
+the budget; `block_q=` / `block_k=` / `vmem_budget=` override it for the
+tests.
 
 Layout (what Mosaic accepted, tests/test_tpu_compile.py):
 - q/k/v/o are (batch*heads, seq, head_dim). A block's last dimension is the
@@ -76,16 +95,30 @@ __all__ = ["flash_attention"]
 _NEG = -1e30
 _LANES = 128
 
-# What one kernel may hold in VMEM: 12 of the 16 MiB Mosaic grants a kernel
-# on a v5e by default (the core has 128 MiB). Split in three:
+# What one kernel may hold in VMEM. A v5e core has 128 MiB of it. Mosaic
+# grants a kernel 16 MiB unless the call asks for more
+# (`CompilerParams.vmem_limit_bytes`), and XLA keeps the rest, 112 MiB, as
+# the pool its own fusions prefetch into (the 117,440,512 bytes of `color 1`
+# in every program's buffer assignment). What a kernel is granted comes out
+# of that pool for the length of the call only. So the plan may fill three
+# quarters of the core, 96 MiB: the other quarter is XLA's own scoped 16 MiB
+# for the fusions on either side of the call, and the margin `_call` adds
+# to the reckoned need for what Mosaic lays out itself. Split in three:
 # - the operands of the inner loop (K and V, or Q and dO), 2 arrays x 2
-#   pipeline buffers x length x 128 lanes x itemsize: 4 MiB holds 4096
+#   pipeline buffers x length x 128 lanes x itemsize: 32 MiB holds 32768
 #   positions in bf16 at any head size up to 128;
-# - the score tiles, ~4 live float32 (outer x inner) arrays (s, p, dp, ds):
-#   4 MiB holds 512 x 512;
-# - the outer block's operands and outputs (double buffered), the float32
-#   accumulators, the lse / delta rows.
-_VMEM_BUDGET = 12 * 1024 * 1024
+# - what the merged backward keeps of the whole query sequence beside them:
+#   the float32 dq it accumulates and dq's double-buffered output block,
+#   (4 + 2 x itemsize) x length x 128 lanes, never more than the operands;
+# - everything that does not grow with the length: the score tiles (~6 live
+#   (outer x inner) arrays of 32 bits: s, p, dp, ds, the mask's iota, the
+#   bf16 casts; 6 MiB at 512 x 512, the fastest of 13 tilings on the chip),
+#   the outer block's operands and outputs (double buffered), the float32
+#   accumulators, and the lse / delta rows at 8 sublanes a row.
+_VMEM = 128 * 1024 * 1024
+_VMEM_BUDGET = _VMEM * 3 // 4
+_MOSAIC_DEFAULT = 16 * 1024 * 1024    # a kernel's grant when it asks nothing
+_LIVE_TILES = 6
 _OUTER, _INNER = 512, 512     # largest outer block and inner sub-block
 _MIN_MAJOR = 512              # least a streamed major block may hold
 
@@ -116,6 +149,9 @@ class _Plan(NamedTuple):
     dkv_bk: int     # dk/dv: outer key block, inner query sub-block, major
     dkv_bq: int     # query block
     q_major: int
+    fwd_vmem: int   # bytes each kernel holds in VMEM (`_call` asks Mosaic for
+    dq_vmem: int    # them where they pass its default grant)
+    dkv_vmem: int
 
 
 def _plan(lq, lk, d, itemsize, interpret, block_q=None, block_k=None,
@@ -147,14 +183,43 @@ def _plan(lq, lk, d, itemsize, interpret, block_q=None, block_k=None,
     lqp, bq_outer, bq_inner = blocks(lq, block_q)
     lkp, bk_outer, bk_inner = blocks(lk, block_k)
     # the score tile of a step: ~4 live float32 arrays in a third of the
-    # budget; shrink the inner sub-block until it fits
+    # budget; shrink the inner sub-block until it fits (the tests' tight
+    # budgets only: the chip's holds any tile up to `_OUTER` x `_INNER`)
     most = max(vmem_budget // 3 // 16, align * align)
     while bq_outer * bk_inner > most and bk_inner > align:
         bk_inner = _divisor(bk_outer, align, bk_inner - align)
     while bk_outer * bq_inner > most and bq_inner > align:
         bq_inner = _divisor(bq_outer, align, bq_inner - align)
-    return _Plan(lqp, lkp, dp, bq_outer, bk_inner, major(lkp, bk_inner),
-                 bk_outer, bq_inner, major(lqp, bq_inner))
+    k_major, q_major = major(lkp, bk_inner), major(lqp, bq_inner)
+
+    def held(rows, cols, size=4, buffers=1):
+        # a (rows, cols) array in VMEM: 128 lanes a row, 32-bit sublane
+        # tiles (8 rows of float32, 16 of bf16); 2 buffers where the
+        # pipeline fetches the next block beside the one in use
+        return buffers * _ru(rows, 32 // size) * _ru(cols, _LANES) * size
+
+    def carry(steps, nbytes):
+        # a loop carry, and the scratch it is parked in between the steps
+        # of a streamed axis
+        return nbytes * (1 if steps == 1 else 2)
+
+    kv = 2 * held(k_major, dp, itemsize, 2)
+    q_block = held(bq_outer, dp, itemsize, 2)
+    tiles = _LIVE_TILES * held(bq_outer, bk_inner)
+    k_steps, q_steps = lkp // k_major, lqp // q_major
+    fwd = (2 * q_block + kv + held(1, bq_outer, buffers=2) + tiles
+           + carry(k_steps, held(bq_outer, dp) + 2 * held(bq_outer, 1)))
+    dq = (3 * q_block + kv + 2 * held(1, bq_outer, buffers=2) + tiles
+          + carry(k_steps, held(bq_outer, dp)))
+    # dk and dv leave in float32 when a group's are summed outside
+    dkv = (2 * held(q_major, dp, itemsize, 2) + 2 * held(1, q_major, buffers=2)
+           + 2 * held(bk_outer, dp, itemsize, 2) + 2 * held(bk_outer, dp, 4, 2)
+           + _LIVE_TILES * held(bk_outer, bq_inner)
+           + carry(q_steps, 2 * held(bk_outer, dp)))
+    if q_steps == 1:        # `_merged`: dq in float32, and its output block
+        dkv += held(lqp, dp) + held(lqp, dp, itemsize, 2)
+    return _Plan(lqp, lkp, dp, bq_outer, bk_inner, k_major,
+                 bk_outer, bq_inner, q_major, fwd, dq, dkv)
 
 
 class _Cfg(NamedTuple):
@@ -172,12 +237,23 @@ def _vspec(shape, index_map):
     return pl.BlockSpec(shape, index_map, memory_space=pltpu.VMEM)
 
 
-def _call(kernel, cfg, name, carried=(2,), **kw):
+def _grant(vmem):
+    """What a kernel reckoned to hold `vmem` bytes asks of Mosaic: that and
+    a quarter more, for what Mosaic lays out itself (relayouts, spilled
+    registers); nothing where that is inside the default grant, so that a
+    short sequence's kernels are built as if no one had asked."""
+    limit = vmem + vmem // 4
+    return {"vmem_limit_bytes": limit} if limit > _MOSAIC_DEFAULT else {}
+
+
+def _call(kernel, cfg, name, vmem, carried=(2,), **kw):
     """pallas_call of a three-axis grid; `carried` are the axes along
-    which a step hands something to the next."""
+    which a step hands something to the next, `vmem` what the plan reckons
+    the kernel holds."""
     params = {} if cfg.interpret else {"compiler_params": pltpu.CompilerParams(
         dimension_semantics=tuple("arbitrary" if axis in carried else
-                                  "parallel" for axis in range(3)))}
+                                  "parallel" for axis in range(3)),
+        **_grant(vmem))}
     return pl.pallas_call(kernel, interpret=cfg.interpret, name=name,
                           **params, **kw)
 
@@ -306,6 +382,21 @@ def _kv_map(cfg, num):
 # forward
 # ---------------------------------------------------------------------------
 
+def _paired(cfg):
+    """Whether a row block's window-edge tile and its diagonal tile are
+    exact complements: with square tiles and window and offset whole
+    numbers of them, entry (r, c) counts in the first iff c > r and in the
+    second iff c <= r. The forward then selects the two score tiles into
+    one and makes ONE max / exp / sum pass for both (two of the three
+    tiles of a row block at window = 2 x block; 0.47 ms of 2.39 a layer at
+    8192 x 128, window 1024, on the chip). K and V resident and unpadded
+    only: both tiles are then at hand."""
+    p_ = cfg.plan
+    return (cfg.window is not None and p_.bq == p_.bk
+            and cfg.window % p_.bk == 0 and cfg.offset % p_.bk == 0
+            and cfg.kv_len == p_.lkp == p_.k_major)
+
+
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch, cfg):
     p_ = cfg.plan
     bq, bk = p_.bq, p_.bk
@@ -314,30 +405,67 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch, cfg):
     edges = _key_range(cfg, qi, kj, bq, bk, p_.k_major)
     mask = _masker(cfg, (bq, bk), 0)
 
+    def tile(kb):
+        """Scores and values of key sub-block kb, and its first key."""
+        start = pl.multiple_of(kb * bk, bk)
+        k = k_ref[0, pl.ds(start, bk), :]
+        v = v_ref[0, pl.ds(start, bk), :]
+        s = jax.lax.dot_general(
+            q, k, _NT, preferred_element_type=jnp.float32) * cfg.scale
+        return s, v, start
+
+    def online(carry, s, parts):
+        """One step of the online softmax over the score tile s;
+        parts(p) gives the (probabilities, values) pairs whose products
+        the step adds."""
+        m, l, acc = carry
+        m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
+        alpha = jnp.exp(m - m_new)
+        p = jnp.exp(s - m_new)
+        l = alpha * l + jnp.sum(p, axis=1, keepdims=True)
+        acc = acc * alpha
+        for part, v in parts(p):
+            acc = acc + jax.lax.dot(part.astype(v.dtype), v,
+                                    preferred_element_type=jnp.float32)
+        return m_new, l, acc
+
     def body(masked):
         def step(kb, carry):
-            m, l, acc = carry
-            start = pl.multiple_of(kb * bk, bk)
-            k = k_ref[0, pl.ds(start, bk), :]
-            v = v_ref[0, pl.ds(start, bk), :]
-            s = jax.lax.dot_general(
-                q, k, _NT, preferred_element_type=jnp.float32) * cfg.scale
+            s, v, start = tile(kb)
             if masked:
                 s = _where(mask(qi * bq, kj * p_.k_major + start), s, _NEG)
-            m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
-            alpha = jnp.exp(m - m_new)
-            p = jnp.exp(s - m_new)
-            l = alpha * l + jnp.sum(p, axis=1, keepdims=True)
-            acc = acc * alpha + jax.lax.dot(
-                p.astype(v.dtype), v, preferred_element_type=jnp.float32)
-            return m_new, l, acc
+            return online(carry, s, lambda p: [(p, v)])
         return step
+
+    def paired(carry):
+        """A row block whose two masked tiles are one: the sub-blocks
+        between them first, then the window-edge tile and the diagonal
+        tile through ONE max / exp / sum pass."""
+        last = qi + cfg.offset // bk            # the diagonal's sub-block
+        edge = last - cfg.window // bk          # the window edge's
+        carry = jax.lax.fori_loop(jnp.maximum(edge + 1, 0), last,
+                                  body(False), carry)
+
+        def both(carry):
+            lower = (jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
+                     <= jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0))
+            (s_d, v_d, _), (s_e, v_e, _) = tile(last), tile(edge)
+            return online(carry, jnp.where(lower, s_d, s_e), lambda p: [
+                (jnp.where(lower, p, 0.0), v_d),
+                (jnp.where(lower, 0.0, p), v_e)])
+
+        # the first row blocks have no edge yet: the diagonal's tile alone
+        return jax.lax.cond(edge >= 0, both,
+                            lambda carry: body(True)(last, carry), carry)
 
     init = (jnp.full((bq, 1), _NEG, jnp.float32),
             jnp.zeros((bq, 1), jnp.float32),
             jnp.zeros((bq, q.shape[1]), jnp.float32))
-    m, l, acc = _carry(scratch, init, kj, lambda carry: _loops(
-        edges, body, carry, cfg.window is not None))
+    if _paired(cfg):
+        m, l, acc = paired(init)
+    else:
+        m, l, acc = _carry(scratch, init, kj, lambda carry: _loops(
+            edges, body, carry, cfg.window is not None))
 
     def store():
         l1 = jnp.where(l == 0.0, 1.0, l)
@@ -358,7 +486,7 @@ def _fwd(q, k, v, cfg):
     kv_map = _kv_map(cfg, num_k)
     return _call(
         functools.partial(_fwd_kernel, cfg=cfg), cfg, "flash_attention_fwd",
-        grid=(bh, num_q, num_k),
+        p_.fwd_vmem, grid=(bh, num_q, num_k),
         in_specs=[_vspec((1, bq, d), lambda b, i, j: (b, i, 0)),
                   _vspec((1, km, d), kv_map),
                   _vspec((1, km, d), kv_map)],
@@ -518,7 +646,7 @@ def _bwd(cfg, res, dout):
     merged = _merged(p_)
     dq = None if merged else _call(
         functools.partial(_dq_kernel, cfg=cfg), cfg, "flash_attention_dq",
-        grid=(bh, num_q, num_k),
+        p_.dq_vmem, grid=(bh, num_q, num_k),
         in_specs=[_vspec((1, bq, d), lambda b, i, j: (b, i, 0)),
                   _vspec((1, km, d), kv_map),
                   _vspec((1, km, d), kv_map),
@@ -557,7 +685,7 @@ def _bwd(cfg, res, dout):
     dk, dv, *dq_merged = _call(
         functools.partial(_dkv_kernel, cfg=cfg), cfg,
         "flash_attention_bwd" if merged else "flash_attention_dkv",
-        carried=(1, 2) if merged else (2,),
+        p_.dkv_vmem, carried=(1, 2) if merged else (2,),
         grid=(bh, lk // bk, num_qm),
         in_specs=[_vspec((1, qm, d), q_map),
                   _vspec((1, bk, d), kv_head),

@@ -42,7 +42,31 @@ CASES = {
     "grouped-window": (128, 128, 16, 8, 2, 40, 32, 32, None),
     "grouped-window-streamed": (1024, 1024, 16, 4, 1, 200, 128, 128, _TIGHT),
     "grouped-ragged-derived": (300, 300, 32, 4, 2, 64, None, None, None),
+    # the Mellum2 cell's regime since PR 31: a window AND grouped heads
+    # through the ONE backward kernel (queries resident), over many blocks
+    "grouped-window-merged-8-key-blocks": (512, 512, 16, 8, 2, 100, 64, 32,
+                                           None),
+    "grouped-window-of-two-blocks-merged": (384, 384, 16, 4, 1, 128, 64, 64,
+                                            None),
+    "grouped-window-offset-merged": (192, 320, 16, 4, 2, 70, 32, 32, None),
+    # blocks that line up with the window (`_paired`): the forward folds a
+    # row block's two masked tiles into one softmax pass; with an offset of
+    # whole blocks, over grouped heads, a window of one and of four blocks
+    "paired-offset-of-two-blocks": (64, 128, 16, 2, 2, 32, 16, 16, None),
+    "paired-grouped-window-of-four-blocks": (256, 256, 16, 4, 1, 128, 32, 32,
+                                             None),
+    "paired-window-of-one-block-offset": (96, 128, 16, 2, 1, 32, 32, 32,
+                                          None),
+    # ... and what stays apart: a window that is no whole number of blocks,
+    # keys that are padded, keys that stream
+    "unpaired-padded-keys": (120, 120, 16, 2, 2, 64, 32, 32, None),
+    "unpaired-streamed": (1024, 1024, 16, 1, 1, 256, 128, 128, _TIGHT),
 }
+
+PAIRED = {"window-is-block", "grouped-window-of-two-blocks-merged",
+          "paired-offset-of-two-blocks",
+          "paired-grouped-window-of-four-blocks",
+          "paired-window-of-one-block-offset"}
 
 
 def _xla(q, k, v, heads, kv_heads, causal, window):
@@ -67,6 +91,13 @@ def test_window_and_grouped_heads_against_the_band_mask(case):
     k, v = (jnp.asarray(rng.randn(2, kv_heads, lk, d).astype(np.float32))
             for _ in range(2))
     kw = {} if budget is None else {"vmem_budget": budget}
+    # backward is one kernel exactly where nothing is streamed
+    plan = fa._plan(lq, lk, d, 4, True, bq, bk, **kw)
+    assert fa._merged(plan) == (budget is None)
+    cfg = fa._Cfg(1.0, causal, lk, lk - lq, True, plan,
+                  window if window is not None and window < lk else None,
+                  heads // kv_heads)
+    assert fa._paired(cfg) == (case in PAIRED)
 
     def f_flash(q, k, v):
         out = fa._attention(q, k, v, causal, None, bq, bk, True,

@@ -210,16 +210,47 @@ PLANS = {
     # up to a power of two
     "odd-multiple": ((1152, 1152, 64, 2, False, None, None, None),
                      dict(lqp=1152, lkp=1152, bq=384, bk=384)),
-    # 47 x 128 tokens: padded to 12 x 512 (2% more), not cut into 128s
+    # 47 x 128 tokens: padded to 12 x 512 (2% more), not cut into 128s;
+    # resident under the chip's budget (two blocks of 3072 under PR 26's)
     "awkward-long": ((6000, 6000, 64, 2, False, None, None, None),
-                     dict(lqp=6144, lkp=6144, bq=512, bk=512, k_major=3072,
-                          dkv_bk=512, q_major=3072)),
+                     dict(lqp=6144, lkp=6144, bq=512, bk=512, k_major=6144,
+                          dkv_bk=512, q_major=6144)),
+    "awkward-long-default-grant": (
+        (6000, 6000, 64, 2, False, None, None, 12 * 2 ** 20),
+        dict(lqp=6144, lkp=6144, bq=512, bk=512, k_major=3072,
+             dkv_bk=512, q_major=3072)),
     # just over one block: nothing divides it but 128
     "just-over-a-block": ((520, 520, 64, 2, False, None, None, None),
                           dict(lqp=640, bq=128, k_major=640)),
+    # the Mellum2 cell: K and V (Q and dO) of a head resident, the last
+    # grid axis one step, backward one kernel
+    "mellum2-cell": ((8192, 8192, 128, 2, False, None, None, None),
+                     dict(lqp=8192, lkp=8192, dp=128, bq=512, bk=512,
+                          k_major=8192, dkv_bk=512, dkv_bq=512,
+                          q_major=8192)),
+    # what Mosaic's default grant held of it (the parent's plan)
+    "mellum2-cell-default-grant": (
+        (8192, 8192, 128, 2, False, None, None, 12 * 2 ** 20),
+        dict(k_major=4096, q_major=4096, bq=512, bk=512)),
+    # the longest sequence that is resident in bf16, at d = 128 and d = 64
+    # (a head of 64 takes the 128 lanes of a row all the same) ...
+    "longest-resident": ((32768, 32768, 128, 2, False, None, None, None),
+                         dict(lqp=32768, k_major=32768, q_major=32768)),
+    "longest-resident-d64": ((32768, 32768, 64, 2, False, None, None, None),
+                             dict(lqp=32768, k_major=32768, q_major=32768)),
+    # ... and the first that streams: 65 blocks of 512, in five major
+    # blocks of 13; in float32 half the length
+    "first-streamed": ((32769, 32769, 128, 2, False, None, None, None),
+                       dict(lqp=33280, k_major=6656, q_major=6656)),
+    "longest-resident-float32": (
+        (16384, 16384, 128, 4, False, None, None, None),
+        dict(k_major=16384, q_major=16384)),
+    "first-streamed-float32": (
+        (16385, 16385, 128, 4, False, None, None, None),
+        dict(lqp=16896, k_major=5632, q_major=5632)),
     # beyond the budget the keys stream, in major blocks of >= 512
-    "long": ((16384, 16384, 128, 2, False, None, None, None),
-             dict(lqp=16384, k_major=4096, q_major=4096)),
+    "long": ((65536, 65536, 128, 2, False, None, None, None),
+             dict(lqp=65536, k_major=32768, q_major=32768)),
     "overrides": ((100, 100, 16, 4, True, 16, 16, None),
                   dict(lqp=112, lkp=112, bq=16, bk=16, dkv_bk=16,
                        dkv_bq=16, k_major=112)),
@@ -242,6 +273,77 @@ def test_flash_plan_follows_the_shape(case):
     assert plan.k_major % plan.bk == 0 and plan.lkp % plan.dkv_bk == 0
     assert plan.lqp % plan.q_major == 0 and plan.q_major % plan.dkv_bq == 0
     assert plan.lqp >= lq and plan.lkp >= lk
+    # what the kernels are reckoned to hold, with `_call`'s margin, is
+    # inside the core's VMEM whatever the plan
+    for need in (plan.fwd_vmem, plan.dq_vmem, plan.dkv_vmem):
+        assert 0 < need + need // 4 < fa._VMEM
+
+
+def _grants(shape, kv_shape=None, **kw):
+    """{kernel name: the `vmem_limit_bytes` its pallas_call carries} of a
+    bf16 causal attention's value and gradients as the chip would run them
+    (traced, not lowered: the CPU has no Mosaic)."""
+    import re
+    q = jax.ShapeDtypeStruct(shape, jnp.bfloat16)
+    k = jax.ShapeDtypeStruct(kv_shape or shape, jnp.bfloat16)
+
+    def f(q, k, v):
+        return jnp.sum(flash_attention(q, k, v, causal=True, interpret=False,
+                                       **kw).astype(jnp.float32))
+    text = str(jax.make_jaxpr(jax.value_and_grad(f, (0, 1, 2)))(q, k, k))
+    grants = re.findall(r"vmem_limit_bytes=(\w+)", text)
+    names = re.findall(r"name=(flash_attention_\w+)", text)
+    assert len(grants) == len(names)
+    return {n: None if g == "None" else int(g)
+            for n, g in zip(names, grants)}
+
+
+# PR 30's plan at the GPT-2 cell's shape, field for field: the resident
+# regime it already ran must not move
+GPT2_PLAN = (1024, 1024, 64, 512, 512, 1024, 512, 512, 1024)
+
+
+def test_flash_plan_and_grant_at_gpt2s_shape_are_the_parents():
+    plan = fa._plan(1024, 1024, 64, 2, False)
+    assert tuple(plan)[:9] == GPT2_PLAN
+    assert plan._fields[:9] == ("lqp", "lkp", "dp", "bq", "bk", "k_major",
+                                "dkv_bk", "dkv_bq", "q_major")
+    # under Mosaic's default grant nothing is asked: the kernels' compiler
+    # parameters are the parent's
+    assert _grants((16, 12, 1024, 64)) == {"flash_attention_fwd": None,
+                                           "flash_attention_bwd": None}
+    assert _grants((32, 12, 128, 64)) == {"flash_attention_fwd": None,
+                                          "flash_attention_bwd": None}
+
+
+@pytest.mark.parametrize("window", [None, 1024], ids=["full", "window"])
+def test_flash_grant_at_the_mellum2_cells_shape(window):
+    """8192 x 128 over grouped heads: two kernels, each asking Mosaic for
+    what the plan reckons and a quarter, more than the default and far less
+    than the core has."""
+    plan = fa._plan(8192, 8192, 128, 2, False)
+    assert fa._merged(plan)
+    # a window of two blocks of 512: the forward pairs its masked tiles
+    cfg = fa._Cfg(1.0, True, 8192, 0, False, plan, window, 8)
+    assert fa._paired(cfg) == (window is not None)
+    grants = _grants((1, 32, 8192, 128), (1, 4, 8192, 128), window=window)
+    assert grants == {
+        "flash_attention_fwd": plan.fwd_vmem + plan.fwd_vmem // 4,
+        "flash_attention_bwd": plan.dkv_vmem + plan.dkv_vmem // 4}
+    assert fa._MOSAIC_DEFAULT < grants["flash_attention_fwd"] < 2 ** 25
+    assert fa._MOSAIC_DEFAULT < grants["flash_attention_bwd"] < 2 ** 26
+
+
+def test_flash_streamed_kernels_ask_for_their_major_blocks():
+    """Past the budget three kernels, each granted its own need."""
+    plan = fa._plan(33280, 33280, 128, 2, False)
+    assert not fa._merged(plan)
+    grants = _grants((1, 1, 33280, 128))
+    assert set(grants) == {"flash_attention_fwd", "flash_attention_dq",
+                           "flash_attention_dkv"}
+    for name, need in (("fwd", plan.fwd_vmem), ("dq", plan.dq_vmem),
+                       ("dkv", plan.dkv_vmem)):
+        assert grants["flash_attention_" + name] == need + need // 4
 
 
 def test_flash_causal_needs_keys_for_every_query():

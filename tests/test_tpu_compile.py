@@ -158,10 +158,11 @@ def test_flash_attention_decode_step(compile_for_chip):
 
 
 def test_flash_attention_streams_a_long_sequence(compile_for_chip):
-    """16384 keys at d = 128 are beyond the VMEM budget: the key axis
-    stays a grid axis, with scratch between its steps, and backward is
-    two kernels (dq; dk and dv), each streaming the other sequence."""
-    shape = ((1, 4, 16384, 128), BF16)
+    """65536 keys at d = 128 are beyond the VMEM budget (32768 resident
+    positions in bf16): the key axis stays a grid axis, with scratch
+    between its steps, and backward is two kernels (dq; dk and dv), each
+    streaming the other sequence in major blocks of 32768."""
+    shape = ((1, 2, 65536, 128), BF16)
     text = compile_for_chip(
         _with_grads(lambda q, k, v: flash_attention(
             q, k, v, causal=True, interpret=False), 3),
@@ -180,13 +181,14 @@ MELLUM_KV = ((1, 4, 8192, 128), BF16)
 
 
 @pytest.mark.parametrize("window", [1024, None], ids=["window", "full"])
-def test_flash_attention_window_and_grouped_heads_streamed(compile_for_chip,
+def test_flash_attention_window_and_grouped_heads_resident(compile_for_chip,
                                                            window):
-    """At 8192 positions of head size 128 the keys stream (two major blocks
-    of 4096) and backward is `_dq` and `_dkv` apart; the window and the
-    grouped heads change index maps and loop bounds, not the kernels'
-    number. K and V enter the kernels with their 4 heads: nothing repeats
-    them to 32 first."""
+    """At 8192 positions of head size 128 a head's K and V (Q and dO) are
+    resident under the grant the plan asks Mosaic for, so backward is the
+    ONE kernel `_bwd` and `_dq` does not run; the window and the grouped
+    heads change index maps and loop bounds, not the kernels' number. K and
+    V enter the kernels with their 4 heads: nothing repeats them to 32
+    first."""
     import re
 
     text = compile_for_chip(
@@ -194,14 +196,57 @@ def test_flash_attention_window_and_grouped_heads_streamed(compile_for_chip,
             q, k, v, causal=True, window=window, interpret=False), 3),
         MELLUM_Q, MELLUM_KV, MELLUM_KV)
     calls = _custom_calls(text)
-    assert len(calls) == 3
-    for name in ("flash_attention_fwd)", "flash_attention_dq)",
-                 "flash_attention_dkv)"):
+    assert len(calls) == 2
+    for name in ("flash_attention_fwd)", "flash_attention_bwd)"):
         line, = [c for c in calls if name in c]
         # results before the call, operands in its layout constraints
         shapes = re.findall(r"(?:bf16|f32)\[([\d,]+)\]",
                             line.split("metadata=")[0])
         assert "4,8192,128" in shapes and "32,8192,128" in shapes, line
+
+
+# (query shape, key/value shape, dtype, window, kernels): the cell's two
+# layer kinds, the longest resident sequences and the first streamed one
+NEEDS = {
+    "mellum2-window": ((1, 32, 8192, 128), (1, 4, 8192, 128), BF16, 1024, 2),
+    "mellum2-full": ((1, 32, 8192, 128), (1, 4, 8192, 128), BF16, None, 2),
+    "longest-resident": ((1, 8, 32768, 128), (1, 2, 32768, 128), BF16, None,
+                         2),
+    "longest-resident-window": ((1, 2, 32768, 128), (1, 2, 32768, 128), BF16,
+                                1024, 2),
+    "longest-resident-float32": ((1, 2, 16384, 128), (1, 2, 16384, 128),
+                                 jnp.float32, None, 2),
+    "first-streamed": ((1, 2, 33280, 128), (1, 2, 33280, 128), BF16, None,
+                       3),
+}
+
+
+@pytest.mark.parametrize("case", NEEDS)
+def test_flash_attention_compiles_inside_its_reckoned_need(
+        compile_for_chip, monkeypatch, case):
+    """Each kernel compiles with `vmem_limit_bytes` set to what `_plan`
+    reckons it holds, WITHOUT the quarter `_grant` adds: the reckoning is
+    an upper bound of what Mosaic lays out, so the grant is one too."""
+    import importlib
+    fa = importlib.import_module(
+        "incubator_mxnet_tpu.ops.pallas.flash_attention")
+    q_shape, kv_shape, dtype, window, kernels = NEEDS[case]
+    asked = []
+
+    def bare(vmem):
+        asked.append(vmem)
+        return {"vmem_limit_bytes": vmem}
+    monkeypatch.setattr(fa, "_grant", bare)
+    text = compile_for_chip(
+        _with_grads(lambda q, k, v: flash_attention(
+            q, k, v, causal=True, window=window, interpret=False), 3),
+        (q_shape, dtype), (kv_shape, dtype), (kv_shape, dtype))
+    assert len(_custom_calls(text)) == kernels
+    plan = fa._plan(q_shape[2], kv_shape[2], q_shape[3],
+                    jnp.dtype(dtype).itemsize, False)
+    needs = [plan.fwd_vmem, plan.dkv_vmem] + [plan.dq_vmem] * (kernels == 3)
+    assert sorted(set(asked)) == sorted(set(needs))
+    assert fa._merged(plan) == (kernels == 2)
 
 
 def test_grouped_matmul_at_the_cells_sizes(compile_for_chip):
@@ -455,8 +500,8 @@ def test_mellum2_cell_train_step(topo, no_compile_cache, monkeypatch):
     # the rows' gradient and three `tgmm` for the weights' in the backward
     from incubator_mxnet_tpu.ops import _raw
     rungs = len(_raw.row_capacities(8192 * 8, 8, 64))
-    assert kernels == {"flash_attention_fwd": 2, "flash_attention_dq": 2,
-                       "flash_attention_dkv": 2, "gmm": 2 * 8 * rungs,
+    assert kernels == {"flash_attention_fwd": 2, "flash_attention_bwd": 2,
+                       "gmm": 2 * 8 * rungs,
                        "tgmm": 2 * 3 * rungs}
     assert text.count(" conditional(") == 2 * 2
     for scope in ("rms_norm", "rope", "moe/router", "moe/dispatch",

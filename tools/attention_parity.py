@@ -5,12 +5,14 @@ the chip tool; the benchmark's `correct` cannot see a wrong gradient that
 still descends).
 
     python3 tools/attention_parity.py [--shape B H L D] [--full]
-                                      [--kv-heads N] [--window W]
+                                      [--kv-heads N] [--window W] [--block N]
                                       [--kernel path/to/flash_attention.py]
 
 `--kv-heads` gives K and V fewer heads than Q (grouped heads), `--window`
 the keys a row sees up to its own; the Mellum2 cell's layers are `--shape 1
-32 8192 128 --kv-heads 4` with `--window 1024` and without.
+32 8192 128 --kv-heads 4` with `--window 1024` and without. `--block`
+overrides the blocks the kernels derive from the shape (`block_q` =
+`block_k` = N), to time a tiling the plan does not choose.
 
 Prints one JSON line a kernel file (this tree's first): for the output and
 the three gradients the largest absolute error and that error over the
@@ -111,6 +113,7 @@ def main():
     ap.add_argument("--full", action="store_true", help="not causal")
     ap.add_argument("--kv-heads", type=int, help="key/value heads (grouped)")
     ap.add_argument("--window", type=int, help="keys a row sees (causal)")
+    ap.add_argument("--block", type=int, help="block_q = block_k override")
     ap.add_argument("--kernel", action="append", default=[])
     ap.add_argument("--reps", type=int, default=10)
     ap.add_argument("--seed", type=int, default=0)
@@ -127,6 +130,8 @@ def main():
     k, v = (jnp.asarray(rng.randn(*kv_shape), jnp.bfloat16) for _ in range(2))
     ref = reference(q, k, v, w, causal, args.window)
     extra = {} if args.window is None else {"window": args.window}
+    if args.block:
+        extra.update(block_q=args.block, block_k=args.block)
 
     from incubator_mxnet_tpu.ops.pallas import flash_attention
     kernels = [("this tree", flash_attention)]
@@ -145,6 +150,7 @@ def main():
         (_, out), grads = both(q, k, v)
         line = {"kernel": name, "shape": args.shape, "causal": causal,
                 "kv_heads": kv_shape[1], "window": args.window,
+                "block": args.block,
                 "device": device.device_kind, "platform": device.platform}
         for what, got, want in zip(("out", "dq", "dk", "dv"),
                                    (out,) + grads, ref):
