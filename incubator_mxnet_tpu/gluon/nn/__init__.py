@@ -7,6 +7,7 @@ TPU-preferred channels-last path (model zoo does this on TPU).
 """
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -20,7 +21,7 @@ from ..block import Block, HybridBlock
 __all__ = ["Sequential", "HybridSequential", "Dense", "Dropout", "Flatten",
            "Activation", "LeakyReLU", "PReLU", "ELU", "SELU", "GELU", "Swish",
            "SiLU", "Embedding", "BatchNorm", "BatchNormReLU", "LayerNorm", "InstanceNorm",
-           "GroupNorm", "RMSNorm", "SparseExperts", "Conv1D", "Conv2D", "Conv3D", "Conv1DTranspose",
+           "GroupNorm", "RMSNorm", "GatedFFN", "SparseExperts", "Conv1D", "Conv2D", "Conv3D", "Conv1DTranspose",
            "Conv2DTranspose", "Conv3DTranspose", "MaxPool1D", "MaxPool2D",
            "MaxPool3D", "AvgPool1D", "AvgPool2D", "AvgPool3D",
            "GlobalMaxPool1D", "GlobalMaxPool2D", "GlobalMaxPool3D",
@@ -371,6 +372,27 @@ class RMSNorm(HybridBlock):
         return ops.RMSNorm(x, self.gamma.data(), eps=self._eps)
 
 
+class GatedFFN(HybridBlock):
+    """(silu(x gate) * (x up)) down, `units` -> `hidden_size` -> `units`, no
+    bias (ops/_raw.py `gated_ffn`): a decoder's dense feed-forward, or the
+    shared expert of a sparse layer."""
+
+    def __init__(self, units, hidden_size, weight_initializer=None,
+                 prefix=None, params=None):
+        super().__init__(prefix, params)
+        get = self.params.get
+        self.gate = get("gate", shape=(units, hidden_size),
+                        init=weight_initializer)
+        self.up = get("up", shape=(units, hidden_size),
+                      init=weight_initializer)
+        self.down = get("down", shape=(hidden_size, units),
+                        init=weight_initializer)
+
+    def forward(self, x):
+        return ops.gated_ffn(x, self.gate.data(), self.up.data(),
+                             self.down.data())
+
+
 class SparseExperts(HybridBlock):
     """Sparse-expert feed-forward layer, as ONE holder of an expert-parallel
     deployment runs it: the router scores all `num_experts`, every token
@@ -379,6 +401,13 @@ class SparseExperts(HybridBlock):
     Dropless: no assignment to a held expert is lost, whatever the routing
     (ops/_raw.py `sparse_experts`). Expert e is (silu(x gate_e) * (x up_e))
     down_e, `units` -> `hidden_size` -> `units`, no bias.
+
+    `scoring="sigmoid"`, `selection_bias=True` (a parameter `bias`, one
+    float32 an expert, zeros, `grad_req="null"`: it moves the choice and
+    not the weights, and whoever balances the load writes it) and
+    `scale` are the router of the families that score experts one by one;
+    `shared_hidden_size` adds a `GatedFFN` that every token passes, whole on
+    every holder, under the op scope `moe/shared`.
 
     `load` (num_experts int32, `grad_req="null"`) holds the assignments each
     expert got in the last training step; it is updated as BatchNorm's
@@ -389,15 +418,20 @@ class SparseExperts(HybridBlock):
     routing is near its balance, tokens x top_k when it is not)."""
 
     def __init__(self, units, hidden_size, num_experts, top_k, held=None,
-                 norm_topk_prob=True, weight_initializer=None, prefix=None,
-                 params=None):
+                 norm_topk_prob=True, weight_initializer=None,
+                 scoring="softmax", selection_bias=False, scale=1.0,
+                 shared_hidden_size=None, prefix=None, params=None):
         super().__init__(prefix, params)
         first, count = held if held is not None else (0, num_experts)
         if not 0 <= first <= first + count <= num_experts or count < 1:
             raise ValueError(f"held={held!r} of {num_experts} experts")
+        if scoring not in ("softmax", "sigmoid"):
+            raise ValueError(f"scoring={scoring!r}")
         self._top_k = top_k
         self._first = first
         self._norm = norm_topk_prob
+        self._scoring = scoring
+        self._scale = scale
         get = self.params.get
         self.router = get("router", shape=(num_experts, units),
                           init=weight_initializer)
@@ -409,19 +443,31 @@ class SparseExperts(HybridBlock):
                         init=weight_initializer)
         self.load = get("load", shape=(num_experts,), dtype="int32",
                         init="zeros", grad_req="null")
+        self.bias = (get("bias", shape=(num_experts,), init="zeros",
+                         grad_req="null") if selection_bias else None)
+        self.shared = (GatedFFN(units, shared_hidden_size, weight_initializer)
+                       if shared_hidden_size else None)
 
     def cast(self, dtype):
-        # `load` counts: it stays int32 (bfloat16 cannot count past 256)
+        # `load` counts: it stays int32 (bfloat16 cannot count past 256);
+        # `bias` is added to float32 scores and stays float32
         for p in (self.router, self.gate, self.up, self.down):
             p.cast(dtype)
+        if self.shared is not None:
+            self.shared.cast(dtype)
         self._dtype = dtype
 
     def forward(self, x):
         y, load = ops.sparse_experts(
             x, self.router.data(), self.gate.data(), self.up.data(),
-            self.down.data(), self._top_k, self._first, self._norm)
+            self.down.data(), self._top_k, self._first, self._norm,
+            self._scoring, None if self.bias is None else self.bias.data(),
+            self._scale)
         if autograd.is_training():
             self.load.update_aux(load._data)
+        if self.shared is not None:
+            with jax.named_scope("moe"), jax.named_scope("shared"):
+                y = y + self.shared(x)
         return y
 
     def read_load(self):

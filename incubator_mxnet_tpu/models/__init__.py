@@ -22,7 +22,8 @@ from .ssd import (SSD, SSDLoss, ssd_512_resnet18_v1, ssd_512_resnet50_v1,
 from .transformer_lm import (TransformerLM, lm_loss, transformer_lm_small,
                              transformer_lm_base)
 from .dlrm import DLRM, dlrm_loss, dlrm_small
-from .moe_lm import MoeLM, MoeLMCell, GroupedQueryAttentionCell
+from .moe_lm import (MoeLM, MoeLMCell, GroupedQueryAttentionCell,
+                     LinearAttentionCell, LatentAttentionCell)
 
 _MODELS = {}
 for _name in ["resnet18_v1", "resnet34_v1", "resnet50_v1", "resnet101_v1",

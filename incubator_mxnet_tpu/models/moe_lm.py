@@ -1,8 +1,11 @@
 """Decoder-only language model of the current sparse-expert families: RMS
-norm, rotary positions, grouped key/value heads, layers that mix window and
-full attention, a sparse-expert feed-forward in every layer and an untied
-head — built from a published `config.json`'s own keys (`layer_types`,
-`rope_parameters`, `sliding_window` and the widths).
+norm, an untied head, and layer by layer one of four sequence mixers (window
+or full attention over grouped key/value heads with rotary positions; gated
+delta-rule linear attention; latent attention without positions) and one of
+two feed-forwards (sparse experts, with the routers of both families and a
+shared expert; a dense gated one) — built from a published `config.json`'s
+own keys (`layer_types`, `mlp_layer_types`, `rope_parameters`,
+`sliding_window` and the widths).
 
 A holder of an expert-parallel deployment builds the model with its share:
 `held=(first, count)` of every layer's experts (gluon.nn.SparseExperts
@@ -14,12 +17,19 @@ forward(tokens): (B, L) int ids -> (B, L, vocab_size) logits.
 """
 from __future__ import annotations
 
-from .. import ops
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from .. import autograd, ops
+from .. import ndarray as nd
 from ..gluon import nn
 from ..gluon.block import HybridBlock
+from ..initializer import Initializer
 from ..ops._raw import rope_frequencies
 
-__all__ = ["MoeLM", "MoeLMCell", "GroupedQueryAttentionCell"]
+__all__ = ["MoeLM", "MoeLMCell", "GroupedQueryAttentionCell",
+           "LinearAttentionCell", "LatentAttentionCell"]
 
 
 def _dense(out_units, in_units, weight_initializer):
@@ -65,9 +75,152 @@ class GroupedQueryAttentionCell(HybridBlock):
         return self.proj(out)
 
 
+class _DecayRate(Initializer):
+    """The state-space families' start for a decay: `A_log` = log U(1, 16);
+    `dt_bias` = softplus^-1 of exp U(log 0.001, log 0.1)."""
+
+    def __init__(self, low, high, log_uniform=False):
+        self.low, self.high, self.log_uniform = low, high, log_uniform
+
+    def _init(self, key, shape, dtype):
+        if not self.log_uniform:
+            return jnp.log(jax.random.uniform(
+                key, shape, jnp.float32, self.low, self.high)).astype(dtype)
+        step = jnp.exp(jax.random.uniform(
+            key, shape, jnp.float32, np.log(self.low), np.log(self.high)))
+        return (step + jnp.log(-jnp.expm1(-step))).astype(dtype)
+
+
+class LinearAttentionCell(HybridBlock):
+    """Kimi Delta Attention (Kimi Linear, arXiv:2510.26692): q, k and v
+    through a causal depthwise convolution of `conv_size` taps and silu, q
+    and k of unit length by head, a log-decay a CHANNEL through a rank of
+    `head_dim` (-exp(A_log) softplus(. + dt_bias)), a writing strength a
+    head (sigmoid), the gated delta rule in chunks (ops/_raw.py
+    `gated_delta_rule`), and an RMS norm by head times a sigmoid gate, also
+    through a rank of `head_dim`, before the output projection. No bias, no
+    positions. `A_log` starts as log U(1, 16) and `dt_bias` so that
+    softplus gives exp U(log 0.001, log 0.1): the state-space families'
+    convention, a memory of 1 to 1000 tokens.
+
+    `log_decay_min` (float32, `grad_req="null"`) holds the most negative
+    log-decay cumulated over any chunk of the last training step;
+    `read_decay()` puts it on the profiler's counters."""
+
+    def __init__(self, units, num_heads, head_dim, conv_size=4,
+                 epsilon=1e-5, weight_initializer=None, prefix=None,
+                 params=None):
+        super().__init__(prefix, params)
+        self._num_heads = num_heads
+        self._eps = epsilon
+        init, wide = weight_initializer, num_heads * head_dim
+        self.q = _dense(wide, units, init)
+        self.k = _dense(wide, units, init)
+        self.v = _dense(wide, units, init)
+        self.decay_down = _dense(head_dim, units, init)
+        self.decay_up = _dense(wide, head_dim, init)
+        self.beta = _dense(num_heads, units, init)
+        self.gate_down = _dense(head_dim, units, init)
+        self.gate_up = _dense(wide, head_dim, init)
+        self.proj = _dense(units, wide, init)
+        get = self.params.get
+        self.conv_q = get("conv_q", shape=(conv_size, wide), init=init)
+        self.conv_k = get("conv_k", shape=(conv_size, wide), init=init)
+        self.conv_v = get("conv_v", shape=(conv_size, wide), init=init)
+        self.a_log = get("a_log", shape=(num_heads,),
+                         init=_DecayRate(1.0, 16.0))
+        self.dt_bias = get("dt_bias", shape=(wide,),
+                           init=_DecayRate(1e-3, 1e-1, log_uniform=True))
+        self.gamma = get("gamma", shape=(head_dim,), init="ones")
+        self.log_decay_min = get("log_decay_min", shape=(1,), init="zeros",
+                                 grad_req="null")
+        self._chunks = 0
+
+    def cast(self, dtype):
+        # the counter stays float32: bfloat16 holds a cumulated log-decay
+        # of -300 to 2 digits
+        for p in self._reg_params.values():
+            if p is not self.log_decay_min:
+                p.cast(dtype)
+        for child in self._children.values():
+            child.cast(dtype)
+        self._dtype = dtype
+
+    def forward(self, x):
+        # the projections' weights go to the op, which makes their products
+        # again in its backward (ops/_raw.py `linear_attention`)
+        out, lowest = ops.linear_attention(
+            x, [block.weight.data() for block in (
+                self.q, self.k, self.v, self.decay_down, self.decay_up,
+                self.beta, self.gate_down, self.gate_up, self.proj)]
+            + [p.data() for p in (self.conv_q, self.conv_k, self.conv_v,
+                                  self.a_log, self.dt_bias, self.gamma)],
+            self._num_heads, self._eps)
+        self._chunks = x.shape[0] * -(-x.shape[1] // ops._raw._DELTA_CHUNK)
+        if autograd.is_training():
+            self.log_decay_min.update_aux(lowest._data.reshape(1))
+        return out
+
+    def read_decay(self):
+        """{log_decay_min, chunks} of the last training step, set as the
+        counters `linear_attention.log_decay_min` and
+        `linear_attention.chunks` (chunks of the scan a sequence mixer ran:
+        sequences x ceil(length / 64))."""
+        from .. import profiler as _prof
+        got = {"log_decay_min": float(self.log_decay_min.data().asnumpy()[0]),
+               "chunks": self._chunks}
+        for name, value in got.items():
+            _prof.set_gauge("linear_attention." + name, value)
+        return got
+
+
+class LatentAttentionCell(HybridBlock):
+    """Multi-head latent attention WITHOUT positions (`mla_use_nope`): q = h
+    Wq in heads of `nope_dim + rope_dim`; [c ; k_r] = h Wkv_a, a latent of
+    `kv_rank` and one part of `rope_dim` that every head's key shares;
+    [k_n ; v] = rms_norm(c) Wkv_b by head; head h's key is [k_n,h ; k_r],
+    nothing rotated; causal softmax(q k^T / sqrt(nope_dim + rope_dim)) v
+    over values of `v_dim`, then Wo. No bias. The down and up projections
+    run under the op scopes `latent_attention/kv_down` and `/kv_up`."""
+
+    def __init__(self, units, num_heads, kv_rank, nope_dim, rope_dim, v_dim,
+                 epsilon=1e-5, weight_initializer=None, prefix=None,
+                 params=None):
+        super().__init__(prefix, params)
+        self._num_heads = num_heads
+        self._dims = (kv_rank, nope_dim, rope_dim, v_dim)
+        init = weight_initializer
+        self.q = _dense(num_heads * (nope_dim + rope_dim), units, init)
+        self.kv_down = _dense(kv_rank + rope_dim, units, init)
+        self.kv_norm = nn.RMSNorm(epsilon, in_channels=kv_rank)
+        self.kv_up = _dense(num_heads * (nope_dim + v_dim), kv_rank, init)
+        self.proj = _dense(units, num_heads * v_dim, init)
+
+    def forward(self, x):
+        kv_rank, nope_dim, rope_dim, v_dim = self._dims
+        heads = self._num_heads
+        b, length = x.shape[:2]
+        q = self.q(x)
+        with jax.named_scope("latent_attention"):
+            with jax.named_scope("kv_down"):
+                down = self.kv_down(x)
+                latent = self.kv_norm(down[:, :, :kv_rank])
+                shared = down[:, :, kv_rank:].reshape(b, length, 1, rope_dim)
+            with jax.named_scope("kv_up"):
+                up = self.kv_up(latent).reshape(b, length, heads,
+                                                nope_dim + v_dim)
+                k = nd.concat(
+                    up[:, :, :, :nope_dim],
+                    shared.broadcast_to((b, length, heads, rope_dim)),
+                    dim=3).reshape(b, length, heads * (nope_dim + rope_dim))
+                v = up[:, :, :, nope_dim:].reshape(b, length, heads * v_dim)
+            out = ops.multihead_attention(q, k, v, heads, causal=True)
+        return self.proj(out)
+
+
 class MoeLMCell(HybridBlock):
     """Pre-norm block: x += attention(norm(x)); x += ffn(norm(x)), where the
-    feed-forward is a `nn.SparseExperts`."""
+    feed-forward is a `nn.SparseExperts` or a dense `nn.GatedFFN`."""
 
     def __init__(self, attention, ffn, units, epsilon=1e-6, prefix=None,
                  params=None):
@@ -84,28 +237,66 @@ class MoeLMCell(HybridBlock):
 
 class MoeLM(HybridBlock):
     """Token embedding (no scale, no position table), one `MoeLMCell` for
-    each entry of `layer_types` ("sliding_attention" or "full_attention";
-    `rope_parameters` has a section for each kind in use), a final RMS norm
-    and the untied vocabulary head."""
+    each entry of `layer_types`, a final RMS norm and the untied vocabulary
+    head.
+
+    `layer_types[i]` names layer i's sequence mixer: "sliding_attention" or
+    "full_attention" (`GroupedQueryAttentionCell`; `rope_parameters` has a
+    section for each kind in use), "linear_attention" (`LinearAttentionCell`,
+    built from `linear_attention={num_heads, head_dim,
+    short_conv_kernel_size}`, a published `linear_attn_config`) or
+    "latent_attention" (`LatentAttentionCell`, from `latent_attention=
+    {kv_lora_rank, qk_nope_head_dim, qk_rope_head_dim, v_head_dim}`).
+    `mlp_layer_types[i]` names its feed-forward: "sparse" (the default for
+    every layer: `nn.SparseExperts`, with `router={scoring, selection_bias,
+    scale, shared_hidden_size}` where the family scores by sigmoid and adds
+    a shared expert) or "dense" (`nn.GatedFFN` of `hidden_size`)."""
 
     def __init__(self, vocab_size, layer_types, units, num_heads,
                  num_kv_heads, head_dim, moe_hidden_size, num_experts, top_k,
                  held=None, rope_parameters=None, sliding_window=None,
-                 rms_norm_eps=1e-6, norm_topk_prob=True, prefix=None,
+                 rms_norm_eps=1e-6, norm_topk_prob=True, mlp_layer_types=None,
+                 hidden_size=None, linear_attention=None,
+                 latent_attention=None, router=None, prefix=None,
                  params=None):
         super().__init__(prefix, params)
         rope_parameters = rope_parameters or {}
+        mlp_layer_types = mlp_layer_types or ["sparse"] * len(layer_types)
+        sized = {"linear_attention": linear_attention,
+                 "latent_attention": latent_attention}
         self.embedding = nn.Embedding(vocab_size, units)
         self.layers = []
-        for i, kind in enumerate(layer_types):
-            if kind not in ("sliding_attention", "full_attention"):
+        for i, (kind, mlp) in enumerate(zip(layer_types, mlp_layer_types)):
+            if kind in ("sliding_attention", "full_attention"):
+                attention = GroupedQueryAttentionCell(
+                    units, num_heads, num_kv_heads, head_dim,
+                    rope=rope_parameters.get(kind),
+                    window=(sliding_window if kind == "sliding_attention"
+                            else None))
+            elif kind in sized and sized[kind] is None:
+                raise ValueError(f"layer_types[{i}] = {kind!r} needs its "
+                                 f"sizes: {kind}={{...}}")
+            elif kind == "linear_attention":
+                attention = LinearAttentionCell(
+                    units, linear_attention["num_heads"],
+                    linear_attention["head_dim"],
+                    linear_attention["short_conv_kernel_size"], rms_norm_eps)
+            elif kind == "latent_attention":
+                attention = LatentAttentionCell(
+                    units, num_heads, latent_attention["kv_lora_rank"],
+                    latent_attention["qk_nope_head_dim"],
+                    latent_attention["qk_rope_head_dim"],
+                    latent_attention["v_head_dim"], rms_norm_eps)
+            else:
                 raise ValueError(f"layer_types[{i}] = {kind!r}")
-            attention = GroupedQueryAttentionCell(
-                units, num_heads, num_kv_heads, head_dim,
-                rope=rope_parameters.get(kind),
-                window=sliding_window if kind == "sliding_attention" else None)
-            ffn = nn.SparseExperts(units, moe_hidden_size, num_experts, top_k,
-                                   held, norm_topk_prob)
+            if mlp == "sparse":
+                ffn = nn.SparseExperts(units, moe_hidden_size, num_experts,
+                                       top_k, held, norm_topk_prob,
+                                       **(router or {}))
+            elif mlp == "dense":
+                ffn = nn.GatedFFN(units, hidden_size)
+            else:
+                raise ValueError(f"mlp_layer_types[{i}] = {mlp!r}")
             cell = MoeLMCell(attention, ffn, units, rms_norm_eps)
             self.register_child(cell, f"layer{i}")
             self.layers.append(cell)
@@ -119,6 +310,13 @@ class MoeLM(HybridBlock):
         return self.head(self.norm(h))
 
     def read_load(self):
-        """`nn.SparseExperts.read_load()` of every layer, in order of depth;
-        the counters keep the last layer's."""
-        return [layer.ffn.read_load() for layer in self.layers]
+        """`nn.SparseExperts.read_load()` of every expert layer, in order of
+        depth; the counters keep the last layer's."""
+        return [layer.ffn.read_load() for layer in self.layers
+                if isinstance(layer.ffn, nn.SparseExperts)]
+
+    def read_decay(self):
+        """`LinearAttentionCell.read_decay()` of every linear-attention
+        layer, in order of depth; the counters keep the last layer's."""
+        return [layer.attention.read_decay() for layer in self.layers
+                if isinstance(layer.attention, LinearAttentionCell)]

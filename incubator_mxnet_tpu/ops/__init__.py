@@ -24,7 +24,8 @@ __all__ = ["FullyConnected", "Convolution", "Deconvolution", "Pooling",
            "BatchNorm", "LayerNorm", "InstanceNorm", "GroupNorm", "Activation",
            "Dropout", "L2Normalization", "softmax_cross_entropy", "smooth_l1",
            "UpSampling", "multihead_attention", "RMSNorm", "rope",
-           "sparse_experts", "box_iou", "box_nms",
+           "sparse_experts", "gated_ffn", "linear_attention", "box_iou",
+           "box_nms",
            "MultiBoxPrior", "MultiBoxTarget", "MultiBoxDetection",
            "ROIPooling", "ROIAlign", "BilinearResize2D",
            "AdaptiveAvgPooling2D", "im2col", "SliceChannel",
@@ -343,17 +344,35 @@ def rope(data, inv_freq, num_heads, factor=1.0):
 
 
 def sparse_experts(data, router, gate, up, down, top_k, first=0,
-                   norm_topk_prob=True):
+                   norm_topk_prob=True, scoring="softmax", bias=None,
+                   scale=1.0):
     """(y, load) of `ops._raw.sparse_experts`: the held experts' part of a
     dropless top-k expert layer, and the assignments each expert got."""
-    def f(x, r, g, u, d):
+    def f(x, r, g, u, d, *b):
         y, load = _raw.sparse_experts(x, r, g, u, d, top_k, first,
-                                      norm_topk_prob)
+                                      norm_topk_prob, scoring,
+                                      b[0] if b else None, scale)
         # the tape wants a cotangent of every output's own dtype
         return y, load.astype(jnp.float32)
-    y, load = _apply(f, [data, router, gate, up, down], n_out=2,
+    y, load = _apply(f, [data, router, gate, up, down]
+                     + ([] if bias is None else [bias]), n_out=2,
                      name="sparse_experts")
     return y, NDArray(load._data.astype(jnp.int32))
+
+
+def gated_ffn(data, gate, up, down):
+    """(silu(x gate) * (x up)) down (ops/_raw.py `gated_ffn`)."""
+    return _apply(_raw.gated_ffn, [data, gate, up, down], name="gated_ffn")
+
+
+def linear_attention(data, weights, num_heads, eps=1e-5):
+    """(out, lowest cumulated log-decay) of `ops._raw.linear_attention`:
+    Kimi Delta Attention (projections, short convolutions, the chunked
+    gated delta rule, the gated output norm) on a block's input (B, L, D);
+    `weights` in that function's order, Wq to gamma."""
+    def f(*arrays):
+        return _raw.linear_attention(*arrays, num_heads, eps)
+    return _apply(f, [data, *weights], n_out=2, name="linear_attention")
 
 
 def SequenceMask(data, sequence_length=None, use_sequence_length=False,

@@ -594,10 +594,12 @@ def multihead_attention(q, k, v, num_heads, mask=None, dropout_rate=0.0,
     scaled-dot-product, merges heads. Reference: src/operator/contrib/
     transformer.cc (interleaved_matmul_*).
 
-    `num_kv_heads` (grouped heads): k and v hold that many heads of q's
+    `num_kv_heads` (grouped heads): k and v hold that many heads, k of q's
     head size, and query head h reads key/value head h // (num_heads /
-    num_kv_heads). `window` (with `causal`): a row sees the `window` keys
-    up to its own.
+    num_kv_heads). v's heads may have a size of their own (latent attention:
+    keys of 192 beside values of 128); the result is (B, L, num_heads x
+    that). `window` (with `causal`): a row sees the `window` keys up to its
+    own.
 
     Fast path: the pallas flash-attention kernel (ops/pallas/) — O(L)
     memory, scores stay in VMEM; causal, window and grouped heads all stay
@@ -610,16 +612,18 @@ def multihead_attention(q, k, v, num_heads, mask=None, dropout_rate=0.0,
     lk = k.shape[1]
     hd = d // num_heads
     kv_heads = num_heads if num_kv_heads is None else num_kv_heads
-    if num_heads % kv_heads or k.shape[2] != kv_heads * hd:
+    if (num_heads % kv_heads or k.shape[2] != kv_heads * hd
+            or v.shape[2] % kv_heads):
         raise ValueError(f"multihead_attention: {num_heads} heads of {hd} over "
                          f"{kv_heads} key/value heads need k of width "
-                         f"{kv_heads * hd}, got {k.shape[2]}")
+                         f"{kv_heads * hd}, got {k.shape[2]} (and v of "
+                         f"{v.shape[2]})")
     if window is not None and not causal:
         raise ValueError("multihead_attention: window= needs causal=True")
     scale = scale if scale is not None else 1.0 / (hd ** 0.5)
 
     def split(x, l, heads):
-        return x.reshape(b, l, heads, hd).transpose(0, 2, 1, 3)
+        return x.reshape(b, l, heads, -1).transpose(0, 2, 1, 3)
 
     qh = split(q, lq, num_heads)
     kh, vh = split(k, lk, kv_heads), split(v, lk, kv_heads)
@@ -627,7 +631,7 @@ def multihead_attention(q, k, v, num_heads, mask=None, dropout_rate=0.0,
         from . import pallas as _pallas
         out = _pallas.flash_attention(qh, kh, vh, causal=causal,
                                       window=window, scale=scale)
-        return out.transpose(0, 2, 1, 3).reshape(b, lq, d)
+        return out.transpose(0, 2, 1, 3).reshape(b, lq, -1)
 
     if kv_heads != num_heads:
         kh, vh = (jnp.repeat(x, num_heads // kv_heads, axis=1)
@@ -648,7 +652,7 @@ def multihead_attention(q, k, v, num_heads, mask=None, dropout_rate=0.0,
     if dropout_rate > 0.0 and training and key is not None:
         w = dropout(w, key, dropout_rate, training)
     out = jnp.einsum("bhqk,bhkd->bhqd", w, vh)
-    return out.transpose(0, 2, 1, 3).reshape(b, lq, d)
+    return out.transpose(0, 2, 1, 3).reshape(b, lq, -1)
 
 
 # ---------------------------------------------------------------------------
@@ -714,6 +718,423 @@ def rope(x, inv_freq, num_heads, factor=1.0):
     x1, x2 = xf[..., :hd // 2], xf[..., hd // 2:]
     out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
     return out.reshape(b, l, d).astype(x.dtype)
+
+
+# ---------------------------------------------------------------------------
+# linear attention: the gated delta rule, chunked (Kimi Delta Attention)
+# ---------------------------------------------------------------------------
+
+_DELTA_CHUNK = 64   # tokens whose products inside are matrix products
+_DELTA_SUB = 16     # tokens of a chunk's diagonal blocks, decay by decay
+_DELTA_GROUP = 8    # chunks a step of the outer scan; a state kept a step
+
+_exact = functools.partial(jnp.matmul, precision=lax.Precision.HIGHEST)
+
+
+def short_conv(x, weight):
+    """Causal depthwise convolution along the sequence: x (B, L, C), weight
+    (K, C); y_t = sum_j weight[j] x_(t - K + 1 + j), zeros before the
+    sequence. Summed in float32, returned in x's dtype."""
+    taps, length = weight.shape[0], x.shape[1]
+    padded = jnp.pad(x, ((0, 0), (taps - 1, 0), (0, 0)))
+    y = sum(weight[j].astype(jnp.float32) * padded[:, j:j + length]
+            for j in range(taps))
+    return y.astype(x.dtype)
+
+
+def _diagonal_blocks(a, size):
+    """(..., n, n) -> (..., n / size, size, size): the blocks on the
+    diagonal."""
+    return jnp.stack([a[..., i:i + size, i:i + size]
+                      for i in range(0, a.shape[-1], size)], -3)
+
+
+def _unit_lower_inverse(a):
+    """(I + a)^-1 of strictly lower triangular a (..., n, n), n a power of
+    two times `_DELTA_SUB`: the diagonal blocks of that size, all at once,
+    by the finite series (I - a)(I + a^2)(I + a^4)(I + a^8) (a^16 = 0;
+    squared three times, its terms stay within 16-choose-8 of the
+    result's), then neighbours joined level by level, [[P, 0], [-R C P, R]]
+    for [[P^-1, 0], [C, R^-1]]. float32 at `highest`: what follows a chunk
+    multiplies with it."""
+    n, size = a.shape[-1], min(a.shape[-1], _DELTA_SUB)
+    x = -_diagonal_blocks(a, size)
+    inverse = jnp.eye(size, dtype=a.dtype) + x
+    for _ in range((size - 1).bit_length() - 1):
+        x = _exact(x, x)
+        inverse = inverse + _exact(inverse, x)
+    while size < n:
+        top, bottom = inverse[..., 0::2, :, :], inverse[..., 1::2, :, :]
+        below = _diagonal_blocks(a, 2 * size)[..., size:, :size]
+        corner = -_exact(_exact(bottom, below), top)
+        inverse = jnp.concatenate([
+            jnp.concatenate([top, jnp.zeros_like(top)], -1),
+            jnp.concatenate([corner, bottom], -1)], -2)
+        size *= 2
+    return inverse[..., 0, :, :]
+
+
+def _delta_group(state, q, k, v, g, beta):
+    """`_DELTA_GROUP` chunks of the gated delta rule from `state` (B, H, dk,
+    dv) float32: q, k, g (B, H, T, dk), v (B, H, T, dv), beta (B, H, T) ->
+    (the state after them, o (B, H, T, dv)).
+
+    With G the log-decays cumulated over a chunk, the chunk's T rows obey
+    (I + A) U = beta (V - (K e^G) S), A_ti = beta_t sum_c k_tc k_ic
+    e^(G_tc - G_ic) for i < t; then O = (Q e^G) S + P U with P_ti the same
+    sum over q_t for i <= t, and S' = e^(G_last) S + (K e^(G_last - G))^T U.
+    No decay is divided by: between sub-chunks of `_DELTA_SUB` rows, row t
+    carries e^(G_t - G_before), G_before at its sub-chunk's start, and
+    column i e^(G_before - G_i), both exponents <= 0; inside a sub-chunk
+    the (rows, rows, dk) differences are exponentiated themselves. What is
+    parallel over the chunks runs for all of them at once; the inner scan
+    over them holds three products a chunk. Products take operands in q's
+    dtype and sum in float32; the inverse and what it multiplies are
+    float32 at `highest`."""
+    b, h, t, dk = k.shape
+    c, s = _DELTA_CHUNK, _DELTA_SUB
+    n, m = t // c, c // s
+    dtype, f32 = q.dtype, jnp.float32
+
+    def chunked(x):
+        return x.reshape(b, h, n, c, *x.shape[3:])
+
+    q, k, v, g, beta = (chunked(x).astype(f32) for x in (q, k, v, g, beta))
+    total = jnp.cumsum(g, axis=3)                       # (b, h, n, c, dk)
+    sub = total.reshape(b, h, n, m, s, dk)
+    before = jnp.concatenate([jnp.zeros_like(sub[:, :, :, :1, 0]),
+                              sub[:, :, :, :-1, -1]], 3)   # (b, h, n, m, dk)
+    inside = sub - before[..., None, :]                 # <= 0
+    by_sub = (b, h, n, m, s, dk)
+    k_sub, q_sub = k.reshape(by_sub), q.reshape(by_sub)
+    # between sub-chunks: rows of sub-chunk a against the columns before it
+    reach = before[:, :, :, :, None] - total[:, :, :, None]  # (.., m, c, dk)
+    earlier = jnp.arange(c)[None, :] < s * jnp.arange(m)[:, None]
+    k_col = (k[:, :, :, None] * jnp.exp(jnp.where(
+        earlier[:, :, None], reach, -jnp.inf))).astype(dtype)
+    rows = jnp.exp(inside)
+    pairs = "bhnmsd,bhnmcd->bhnmsc"
+    kk = jnp.einsum(pairs, (k_sub * rows).astype(dtype), k_col,
+                    preferred_element_type=f32)
+    qk = jnp.einsum(pairs, (q_sub * rows).astype(dtype), k_col,
+                    preferred_element_type=f32)
+    # inside a sub-chunk: e^(G_t - G_i) entry by entry, i <= t
+    lower = jnp.arange(s)[:, None] >= jnp.arange(s)[None, :]
+    decay = jnp.exp(jnp.where(
+        lower[:, :, None],
+        inside[:, :, :, :, :, None] - inside[:, :, :, :, None], -jnp.inf))
+    k_cols = k_sub[:, :, :, :, None] * decay             # (.., m, s, s, dk)
+    on_diagonal = "bhnmstd,bhnmsd->bhnmst"
+    place = jnp.eye(m, dtype=f32)
+
+    def square(between, within):
+        whole = between + jnp.einsum("bhnmst,mr->bhnmsrt", within,
+                                     place).reshape(between.shape)
+        return whole.reshape(b, h, n, c, c)
+
+    kk = square(kk, jnp.einsum(on_diagonal, k_cols, k_sub))
+    qk = square(qk, jnp.einsum(on_diagonal, k_cols, q_sub))
+    strictly = jnp.tril(jnp.ones((c, c), f32), -1)
+    inverse = _unit_lower_inverse(beta[..., None] * kk * strictly)
+    carried = jnp.exp(total)
+    w = _exact(inverse, beta[..., None] * k * carried)
+    u_alone = _exact(inverse, beta[..., None] * v)
+    last = total[:, :, :, -1:]
+    per_chunk = (w.astype(dtype), u_alone, (q * carried).astype(dtype),
+                 qk.astype(dtype), (k * jnp.exp(last - total)).astype(dtype),
+                 jnp.exp(last[:, :, :, 0]))
+
+    def chunk(state, xs):
+        w, u_alone, q_in, qk, k_out, kept = xs
+        held = state.astype(dtype)
+        u = u_alone - jnp.einsum("bhck,bhkv->bhcv", w, held,
+                                 preferred_element_type=f32)
+        u_low = u.astype(dtype)
+        o = (jnp.einsum("bhck,bhkv->bhcv", q_in, held,
+                        preferred_element_type=f32)
+             + jnp.einsum("bhct,bhtv->bhcv", qk, u_low,
+                          preferred_element_type=f32))
+        state = state * kept[..., None] + jnp.einsum(
+            "bhck,bhcv->bhkv", k_out, u_low, preferred_element_type=f32)
+        return state, o
+
+    state, o = lax.scan(chunk, state,
+                        tuple(jnp.moveaxis(x, 2, 0) for x in per_chunk))
+    return state, jnp.moveaxis(o, 0, 2).reshape(b, h, t, -1).astype(dtype)
+
+
+def _by_group(x, t):
+    """(B, H, L, ...) -> (L / t, B, H, t, ...): the outer scan's steps."""
+    b, h, length = x.shape[:3]
+    return jnp.moveaxis(x.reshape(b, h, length // t, t, *x.shape[3:]), 2, 0)
+
+
+def _joined(x):
+    """`_by_group`'s inverse."""
+    x = jnp.moveaxis(x, 0, 2)
+    return x.reshape(*x.shape[:2], -1, *x.shape[4:])
+
+
+def _delta_steps(q, k, v, g, beta):
+    t = min(_DELTA_GROUP * _DELTA_CHUNK, q.shape[2])
+    return tuple(_by_group(x, t) for x in (q, k, v, g, beta))
+
+
+def _delta_fwd(q, k, v, g, beta):
+    def step(state, xs):
+        after, o = _delta_group(state, *xs)
+        return after, (o, state)
+    zero = jnp.zeros((*q.shape[:2], q.shape[3], v.shape[3]), jnp.float32)
+    _, (o, starts) = lax.scan(step, zero, _delta_steps(q, k, v, g, beta))
+    return _joined(o), (q, k, v, g, beta, starts)
+
+
+def _delta_bwd(res, d_o):
+    """The inputs and the state each step of the outer scan started from
+    are all that was kept: a step's chunks are made again, and taken back,
+    one step at a time from the last."""
+    *inputs, starts = res
+
+    def step(d_state, xs):
+        start, d_o, *given = xs
+        _, pull = jax.vjp(_delta_group, start, *given)
+        d_start, *d_given = pull((d_state, d_o))
+        return d_start, d_given
+    steps = _delta_steps(*inputs)
+    _, grads = lax.scan(step, jnp.zeros_like(starts[0]),
+                        (starts, _by_group(d_o, steps[0].shape[3]), *steps),
+                        reverse=True)
+    return tuple(_joined(x) for x in grads)
+
+
+@jax.custom_vjp
+def _delta_rule(q, k, v, g, beta):
+    return _delta_fwd(q, k, v, g, beta)[0]
+
+
+_delta_rule.defvjp(_delta_fwd, _delta_bwd)
+
+
+def _delta_inputs(*arrays):
+    """((B, L, H, ...) arrays as the scan takes them: heads first, L padded
+    to whole steps with tokens that change nothing (beta = 0, g = 0); the
+    way back for a result)."""
+    length = arrays[0].shape[1]
+    chunks = -(-length // _DELTA_CHUNK)
+    pad = -length % (min(_DELTA_GROUP, chunks) * _DELTA_CHUNK)
+
+    def heads_first(x):
+        x = jnp.moveaxis(x, 2, 1)
+        return jnp.pad(x, ((0, 0), (0, 0), (0, pad)) + ((0, 0),) * (x.ndim - 3))
+
+    def back(x):
+        return jnp.moveaxis(x[:, :, :length], 1, 2)
+    return tuple(heads_first(x) for x in arrays), back
+
+
+def _lowest_decay(g):
+    """The most negative log-decay cumulated over a chunk of g (B, H, L,
+    dk): a chunk's own sum, since no entry is positive."""
+    by_chunk = g.reshape(*g.shape[:2], -1, _DELTA_CHUNK, g.shape[-1])
+    return lax.stop_gradient(jnp.min(jnp.sum(by_chunk, axis=3)))
+
+
+@jax.named_scope("scan")
+def gated_delta_rule(q, k, v, g, beta):
+    """The gated delta rule with a decay a channel, from a zero state:
+
+        S_t = (I - beta_t k_t k_t^T) diag(exp(g_t)) S_(t-1) + beta_t k_t v_t^T
+        o_t = S_t^T q_t
+
+    q, k, g (B, L, H, dk), v (B, L, H, dv), beta (B, L, H); g <= 0 is the
+    LOG of the decay. Returns (o (B, L, H, dv) in q's dtype, the most
+    negative log-decay cumulated over any chunk: float32's exp underflows
+    below -87, and a chunk that reaches it has forgotten its state anyway).
+
+    Chunked (`_delta_group`): chunks of 64 tokens, matrix products inside,
+    a `lax.scan` that carries S (float32) across them, 8 chunks a step.
+    The backward is the op's own (`_delta_bwd`): it keeps the inputs and a
+    state every 512 tokens and makes the rest again. L is padded with
+    tokens that change nothing (beta = 0, g = 0)."""
+    inputs, unpad = _delta_inputs(q, k, v, g.astype(jnp.float32),
+                                     beta.astype(jnp.float32))
+    return unpad(_delta_rule(*inputs)), _lowest_decay(inputs[3])
+
+
+def _scoped(scope, fn):
+    """fn, traced under the op scope `scope` (or none)."""
+    if scope is None:
+        return fn
+
+    def run(*args):
+        with jax.named_scope(scope):
+            return fn(*args)
+    return run
+
+
+def _taken_back(scope, fn, *args):
+    """(fn(*args), its pullback), both traced under the op scope `scope`. A
+    name pushed INSIDE a function that `jax.vjp` transforms comes out
+    wrapped whole, `transpose(jvp(a/b))`, which a reader of owners cannot
+    split at the `/` (docs/profiler.md): so the stages of the mixer push
+    their own last name only, and `linear_attention` is opened out here."""
+    out, pull = _scoped(scope, functools.partial(jax.vjp, fn))(*args)
+    return out, _scoped(scope, pull)
+
+
+def _project(x, *weights):
+    """x through (out, in) matrices one after the other, no bias."""
+    for w in weights:
+        x = dense(x, w, None, flatten=False)
+    return x
+
+
+@jax.named_scope("projections")
+def _kda_projected(h, wq, wk, wv, wfa, wfb, wb):
+    """h Wq, h Wk, h Wv, the log-decay's rank (h Wfa) Wfb, and h Wb."""
+    return (_project(h, wq), _project(h, wk), _project(h, wv),
+            _project(h, wfa, wfb), _project(h, wb))
+
+
+@jax.named_scope("conv")
+def _kda_mixed(num_heads, q, k, v, conv_q, conv_k, conv_v):
+    """silu(conv(.)) in heads; q and k of unit length, q times dk^-1/2."""
+    b, length = q.shape[:2]
+
+    def mixed(x, taps, unit=None):
+        x = jax.nn.silu(short_conv(x, taps)).reshape(b, length, num_heads, -1)
+        if unit is None:
+            return x
+        xf = x.astype(jnp.float32)
+        norm = lax.rsqrt(jnp.sum(xf * xf, -1, keepdims=True) + 1e-6)
+        return (xf * (norm * unit)).astype(x.dtype)
+
+    return (mixed(q, conv_q, (q.shape[2] // num_heads) ** -0.5),
+            mixed(k, conv_k, 1.0), mixed(v, conv_v))
+
+
+@jax.named_scope("gate")
+def _kda_gates(num_heads, decay, beta, a_log, dt_bias):
+    """(the log-decay a channel, beta), float32."""
+    f32 = jnp.float32
+    g = -jnp.exp(a_log.astype(f32))[:, None] * jax.nn.softplus(
+        (decay.astype(f32) + dt_bias.astype(f32)).reshape(
+            *decay.shape[:2], num_heads, -1))
+    return g, jax.nn.sigmoid(beta.astype(f32))
+
+
+@jax.named_scope("out_norm")
+def _kda_normed(eps, o, gate, gamma):
+    """rms_norm(o; gamma) by head times sigmoid(gate), heads merged."""
+    of = o.astype(jnp.float32)
+    of = of * lax.rsqrt(jnp.mean(of * of, -1, keepdims=True) + eps)
+    of = of * gamma.astype(jnp.float32) * jax.nn.sigmoid(
+        gate.astype(jnp.float32).reshape(of.shape))
+    return of.astype(o.dtype).reshape(*o.shape[:2], -1)
+
+
+_kda_rank = jax.named_scope("projections")(_project)   # the gate's, and Wo
+
+
+def _kda_stages(num_heads, eps, h, back, front):
+    """The mixer's stages around the scan, each with its pullback: (what the
+    scan reads, then a function of the scan's o that gives (out, pull))."""
+    wga, wgb, gamma, wo = back
+    wq, wk, wv, wfa, wfb, wb, conv_q, conv_k, conv_v, a_log, dt_bias = front
+    la = "linear_attention"
+    projected, pull_projected = _taken_back(None, _kda_projected, h, wq, wk,
+                                            wv, wfa, wfb, wb)
+    qkv, pull_mixed = _taken_back(
+        la, functools.partial(_kda_mixed, num_heads), *projected[:3], conv_q,
+        conv_k, conv_v)
+    gates, pull_gates = _taken_back(
+        la, functools.partial(_kda_gates, num_heads), *projected[3:], a_log,
+        dt_bias)
+
+    def pull_front(d_qkv, d_gates):
+        *d_pre, d_cq, d_ck, d_cv = pull_mixed(tuple(d_qkv))
+        d_decay, d_beta, d_a_log, d_dt_bias = pull_gates(tuple(d_gates))
+        d_h, *d_weights = pull_projected((*d_pre, d_decay, d_beta))
+        return d_h, (*d_weights, d_cq, d_ck, d_cv, d_a_log, d_dt_bias)
+
+    def after(o):
+        gate, pull_gate = _taken_back(None, _kda_rank, h, wga, wgb)
+        merged, pull_normed = _taken_back(
+            la, functools.partial(_kda_normed, eps), o, gate, gamma)
+        out, pull_out = _taken_back(None, _kda_rank, merged, wo)
+
+        def pull_back(d_out):
+            d_merged, d_wo = pull_out(d_out)
+            d_o, d_gate, d_gamma = pull_normed(d_merged)
+            d_h, d_wga, d_wgb = pull_gate(d_gate)
+            return d_o, d_h, (d_wga, d_wgb, d_gamma, d_wo)
+        return out, pull_back
+
+    return (*qkv, *gates), pull_front, after
+
+
+def _kda_fwd(num_heads, eps, h, back, front):
+    scanned, _, after = _kda_stages(num_heads, eps, h, back, front)
+    inputs, unpad = _delta_inputs(*scanned)
+    with jax.named_scope("linear_attention/scan"):
+        o, (*_, starts) = _delta_fwd(*inputs)
+        lowest = _lowest_decay(inputs[3])
+    o = unpad(o)
+    return (after(o)[0], lowest), (h, back, front, starts, o)
+
+
+def _kda_bwd(num_heads, eps, res, cotangents):
+    """The block's input, its weights, the scan's result and a state every
+    512 tokens are all the rule keeps (`_delta_bwd`): projections,
+    convolutions, norms and gates are written as made again, then taken back
+    with the scan. XLA decides what that costs: where memory allows it
+    merges what is made again with the forward's own and keeps it (the
+    Kimi-Linear cell: 7.35 GB of temporaries; behind an
+    `optimization_barrier`, which forbids the merge, 6.43 GB, four times the
+    program, and the benchmark's memory check at 8.5%: PERF.md, PR 32)."""
+    h, back, front, starts, o = res
+    scanned, pull_front, after = _kda_stages(num_heads, eps, h, back, front)
+    inputs, unpad = _delta_inputs(*scanned)
+    d_o, d_h_back, d_back = after(o)[1](cotangents[0])
+    with jax.named_scope("linear_attention/scan"):
+        d_inputs = _delta_bwd((*inputs, starts), _delta_inputs(d_o)[0][0])
+    d_scanned = [unpad(d).astype(x.dtype) for d, x in zip(d_inputs, scanned)]
+    d_h, d_front = pull_front(d_scanned[:3], d_scanned[3:])
+    return d_h + d_h_back, d_back, d_front
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
+def _kda(num_heads, eps, h, back, front):
+    return _kda_fwd(num_heads, eps, h, back, front)[0]
+
+
+_kda.defvjp(_kda_fwd, _kda_bwd)
+
+
+def linear_attention(h, wq, wk, wv, wfa, wfb, wb, wga, wgb, wo, conv_q,
+                     conv_k, conv_v, a_log, dt_bias, gamma, num_heads,
+                     eps=1e-5):
+    """Kimi Delta Attention, one sequence mixer, on the block's input h (B,
+    L, D) with its weights ((out, in) each, no bias): Wq, Wk, Wv to H heads
+    of dk (dv); Wfa then Wfb the log-decay's rank; Wb the writing strength
+    a head; Wga then Wgb the output gate's rank; Wo back to D; conv_* (K,
+    channels) the depthwise taps; a_log (H,), dt_bias (H dk), gamma (dv,).
+
+        q, k, v = silu(conv(h W.)); by head q^ = q / |q| dk^-1/2, k^ = k / |k|
+        g = -exp(a_log) softplus((h Wfa) Wfb + dt_bias);  beta = sigmoid(h Wb)
+        o = gated_delta_rule(q^, k^, v, g, beta)
+        out = (rms_norm(o; gamma, by head) sigmoid((h Wga) Wgb)) Wo
+
+    under the op scopes `linear_attention/conv` (with silu and the unit
+    norm), `/gate`, `/scan` and `/out_norm`, the matrix products around
+    them under `projections`. ONE differentiable unit (`_kda_bwd`): its
+    backward keeps h, the weights, o and the scan's state every 512 tokens
+    (0.14 GB a layer at 8192 x 2304 where the projections' results would be
+    0.6), and makes the rest again. Returns (out (B, L, D),
+    `gated_delta_rule`'s most negative cumulated log-decay)."""
+    return _kda(num_heads, eps, h, (wga, wgb, gamma, wo),
+                (wq, wk, wv, wfa, wfb, wb, conv_q, conv_k, conv_v, a_log,
+                 dt_bias))
 
 
 # ---------------------------------------------------------------------------
@@ -940,15 +1361,25 @@ def _routed_bwd(top_k, ladder, kernel, operands, g):
 _routed.defvjp(lambda *args: (_routed(*args), args[3:]), _routed_bwd)
 
 
+def gated_ffn(x, gate, up, down):
+    """(silu(x gate) * (x up)) down: x (..., D), gate and up (D, F), down
+    (F, D); the gated feed-forward of a dense layer or a shared expert."""
+    return jnp.dot(_gated(jnp.dot(x, gate), jnp.dot(x, up)), down)
+
+
 def sparse_experts(x, router, gate, up, down, top_k, first=0,
-                   norm_topk_prob=True):
+                   norm_topk_prob=True, scoring="softmax", bias=None,
+                   scale=1.0):
     """The part that the experts held here add to a sparse-expert layer.
 
     x (..., D); router (E, D) over ALL E experts; gate and up (C, D, F),
     down (C, F, D): the C experts [first, first + C) held here. Every token
     takes its `top_k` largest of softmax(x router^T) (float32), with weights
     normalised over the top_k when `norm_topk_prob`; expert e gives
-    (silu(x gate_e) * (x up_e)) down_e. The result sums, for each token, the
+    (silu(x gate_e) * (x up_e)) down_e. `scoring="sigmoid"` scores each
+    expert by itself; `bias` (E,) is added to the scores for the CHOICE
+    alone (the weights are the scores', and no gradient reaches it: a
+    balancing bias that is no weight); `scale` multiplies the weights. The result sums, for each token, the
     weighted outputs of its experts that are held here (in float32); what
     the others would add is left out. No assignment to a held expert is
     dropped, whatever the routing: the assignments to held experts are
@@ -967,9 +1398,19 @@ def sparse_experts(x, router, gate, up, down, top_k, first=0,
     with jax.named_scope("moe"):
         with jax.named_scope("router"):
             logits = jnp.dot(x2, router.T, preferred_element_type=jnp.float32)
-            prob, chosen = lax.top_k(jax.nn.softmax(logits, axis=-1), top_k)
+            if scoring == "softmax" and bias is None:
+                prob, chosen = lax.top_k(jax.nn.softmax(logits, axis=-1), top_k)
+            else:
+                score = (jax.nn.softmax(logits, axis=-1)
+                         if scoring == "softmax" else jax.nn.sigmoid(logits))
+                _, chosen = lax.top_k(
+                    score if bias is None else lax.stop_gradient(
+                        score + bias.astype(jnp.float32)), top_k)
+                prob = jnp.take_along_axis(score, chosen, axis=-1)
             if norm_topk_prob:
                 prob = prob / jnp.sum(prob, axis=-1, keepdims=True)
+            if scale != 1.0:
+                prob = prob * scale
             chosen = chosen.reshape(-1)
             # counted by comparison: a scatter-add of every assignment is slow
             load = jnp.sum(chosen[:, None] == jnp.arange(experts)[None, :],

@@ -138,6 +138,13 @@ def _divisor(length, align, most):
                        if n % c == 0 and (c * align <= most or c == 1))
 
 
+def _head_lanes(d):
+    """The head size the kernels see for a head of d: d itself when it is a
+    multiple of 128 or an even split of the 128 lanes, else padded (80 ->
+    128, 192 -> 256)."""
+    return d if d % _LANES == 0 or d in (8, 16, 32, 64) else _ru(d, _LANES)
+
+
 class _Plan(NamedTuple):
     """Block sizes of the three kernels; static, part of the jit key."""
     lqp: int        # padded lengths
@@ -157,7 +164,7 @@ class _Plan(NamedTuple):
 def _plan(lq, lk, d, itemsize, interpret, block_q=None, block_k=None,
           vmem_budget=_VMEM_BUDGET):
     align = 16 if interpret else _LANES
-    dp = d if d % _LANES == 0 or d in (8, 16, 32, 64) else _ru(d, _LANES)
+    dp = _head_lanes(d)
     lanes = _ru(dp, _LANES)
 
     def blocks(length, override):
@@ -460,7 +467,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch, cfg):
 
     init = (jnp.full((bq, 1), _NEG, jnp.float32),
             jnp.zeros((bq, 1), jnp.float32),
-            jnp.zeros((bq, q.shape[1]), jnp.float32))
+            jnp.zeros((bq, v_ref.shape[2]), jnp.float32))
     if _paired(cfg):
         m, l, acc = paired(init)
     else:
@@ -481,6 +488,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch, cfg):
 def _fwd(q, k, v, cfg):
     p_ = cfg.plan
     bh, lq, d = q.shape
+    dv = v.shape[2]         # the values' head size, and the output's
     bq, km = p_.bq, p_.k_major
     num_q, num_k = lq // bq, k.shape[1] // km
     kv_map = _kv_map(cfg, num_k)
@@ -489,12 +497,12 @@ def _fwd(q, k, v, cfg):
         p_.fwd_vmem, grid=(bh, num_q, num_k),
         in_specs=[_vspec((1, bq, d), lambda b, i, j: (b, i, 0)),
                   _vspec((1, km, d), kv_map),
-                  _vspec((1, km, d), kv_map)],
-        out_specs=[_vspec((1, bq, d), lambda b, i, j: (b, i, 0)),
+                  _vspec((1, km, dv), kv_map)],
+        out_specs=[_vspec((1, bq, dv), lambda b, i, j: (b, i, 0)),
                    _vspec((1, 1, bq), lambda b, i, j: (b, 0, i))],
-        out_shape=[jax.ShapeDtypeStruct((bh, lq, d), q.dtype),
+        out_shape=[jax.ShapeDtypeStruct((bh, lq, dv), q.dtype),
                    jax.ShapeDtypeStruct((bh, 1, lq), jnp.float32)],
-        scratch_shapes=_scratch(num_k, (bq, 1), (bq, 1), (bq, d)),
+        scratch_shapes=_scratch(num_k, (bq, 1), (bq, 1), (bq, dv)),
     )(q, k, v)
 
 
@@ -617,8 +625,10 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref, dk_ref, dv_ref,
         return step
 
     zeros = jnp.zeros(k.shape, jnp.float32)
-    dk, dv = _carry(scratch, (zeros, zeros), qm, lambda carry: _loops(
-        edges, body, carry, True))
+    dk, dv = _carry(
+        scratch, (zeros, zeros if v.shape == k.shape
+                  else jnp.zeros(v.shape, jnp.float32)), qm,
+        lambda carry: _loops(edges, body, carry, True))
 
     def store():
         dk_ref[0] = (dk * cfg.scale).astype(dk_ref.dtype)
@@ -636,7 +646,7 @@ def _bwd(cfg, res, dout):
     q, k, v, out, lse = res
     do, _ = dout
     bh, lq, d = q.shape
-    lk = k.shape[1]
+    lk, dv_ = k.shape[1], v.shape[2]
     delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32),
                     axis=-1).reshape(bh, 1, lq)
 
@@ -649,8 +659,8 @@ def _bwd(cfg, res, dout):
         p_.dq_vmem, grid=(bh, num_q, num_k),
         in_specs=[_vspec((1, bq, d), lambda b, i, j: (b, i, 0)),
                   _vspec((1, km, d), kv_map),
-                  _vspec((1, km, d), kv_map),
-                  _vspec((1, bq, d), lambda b, i, j: (b, i, 0)),
+                  _vspec((1, km, dv_), kv_map),
+                  _vspec((1, bq, dv_), lambda b, i, j: (b, i, 0)),
                   _vspec((1, 1, bq), lambda b, i, j: (b, 0, i)),
                   _vspec((1, 1, bq), lambda b, i, j: (b, 0, i))],
         out_specs=_vspec((1, bq, d), lambda b, i, j: (b, i, 0)),
@@ -689,24 +699,24 @@ def _bwd(cfg, res, dout):
         grid=(bh, lk // bk, num_qm),
         in_specs=[_vspec((1, qm, d), q_map),
                   _vspec((1, bk, d), kv_head),
-                  _vspec((1, bk, d), kv_head),
-                  _vspec((1, qm, d), q_map),
+                  _vspec((1, bk, dv_), kv_head),
+                  _vspec((1, qm, dv_), q_map),
                   _vspec((1, 1, qm), row_map),
                   _vspec((1, 1, qm), row_map)],
         out_specs=[_vspec((1, bk, d), lambda b, j, i: (b, j, 0)),
-                   _vspec((1, bk, d), lambda b, j, i: (b, j, 0))]
+                   _vspec((1, bk, dv_), lambda b, j, i: (b, j, 0))]
         + whole * merged,
         # a group's dk and dv are summed from float32, rounded once
         out_shape=[jax.ShapeDtypeStruct(
-            (bh, lk, d), x.dtype if cfg.group == 1 else jnp.float32)
+            (bh, lk, x.shape[2]), x.dtype if cfg.group == 1 else jnp.float32)
             for x in (k, v)]
         + [jax.ShapeDtypeStruct(q.shape, q.dtype)] * merged,
         scratch_shapes=([pltpu.VMEM((lq, d), jnp.float32)] if merged else
-                        _scratch(num_qm, (bk, d), (bk, d))),
+                        _scratch(num_qm, (bk, d), (bk, dv_))),
     )(q, k, v, do, lse, delta)
     if cfg.group > 1:       # one dk, dv a query head: sum each group's
-        dk, dv = (x.reshape(-1, cfg.group, lk, d).sum(1).astype(k.dtype)
-                  for x in (dk, dv))
+        dk, dv = (x.reshape(-1, cfg.group, lk, x.shape[2]).sum(1).astype(
+            k.dtype) for x in (dk, dv))
     return (dq_merged[0] if merged else dq), dk, dv
 
 
@@ -738,17 +748,22 @@ def _attention(q, k, v, causal, scale, block_q, block_k, interpret,
     if window is not None and not (causal and window > 0):
         raise ValueError("flash_attention: window= counts the keys up to a "
                          "row's own, so it needs causal=True and window > 0")
-    if h % k.shape[1] or k.shape != v.shape:
-        raise ValueError(f"flash_attention: {h} query heads over key/value "
-                         f"shapes {k.shape} / {v.shape}")
-    plan = _plan(lq, lk, d, q.dtype.itemsize, interpret, block_q, block_k,
-                 vmem_budget)
+    if h % k.shape[1] or k.shape[:3] != v.shape[:3] or k.shape[3] != d:
+        raise ValueError(f"flash_attention: {h} query heads of {d} over "
+                         f"key/value shapes {k.shape} / {v.shape}")
+    # the plan reckons every operand at the query/key head size, the larger
+    # of the two wherever they differ (192 beside values of 128)
+    plan = _plan(lq, lk, max(d, v.shape[3]), q.dtype.itemsize, interpret,
+                 block_q, block_k, vmem_budget)
+    dv = v.shape[3]
 
     def prep(x, lp):
-        x = x.reshape(-1, x.shape[2], d)
-        if lp == x.shape[1] and plan.dp == d:
+        width = x.shape[3]
+        lanes = plan.dp if width == d else _head_lanes(width)
+        x = x.reshape(-1, x.shape[2], width)
+        if lp == x.shape[1] and lanes == width:
             return x
-        return jnp.pad(x, ((0, 0), (0, lp - x.shape[1]), (0, plan.dp - d)))
+        return jnp.pad(x, ((0, 0), (0, lp - x.shape[1]), (0, lanes - width)))
 
     if window is not None and window >= lk:
         window = None           # every key up to a row's own: plain causal
@@ -757,9 +772,9 @@ def _attention(q, k, v, causal, scale, block_q, block_k, interpret,
                window, h // k.shape[1])
     out, _ = _flash(prep(q, plan.lqp), prep(k, plan.lkp), prep(v, plan.lkp),
                     cfg)
-    if (plan.lqp, plan.dp) != (lq, d):
-        out = out[:, :lq, :d]
-    return out.reshape(b, h, lq, d)
+    if out.shape[1:] != (lq, dv):
+        out = out[:, :lq, :dv]
+    return out.reshape(b, h, lq, dv)
 
 
 def flash_attention(q, k, v, *, causal=False, window=None, scale=None,
@@ -768,7 +783,8 @@ def flash_attention(q, k, v, *, causal=False, window=None, scale=None,
 
     `window=w` (causal only): a row sees the w keys up to its own. K and V
     may hold fewer heads than Q, (B, H / group, Lk, D): query head h reads
-    key/value head h // group.
+    key/value head h // group. V's head size may differ from Q's and K's
+    (latent attention: 192 beside 128); the result has V's.
 
     Differentiable (custom VJP with blockwise recompute). The block sizes
     come from the shape (`_plan`); `block_q` / `block_k` override them for

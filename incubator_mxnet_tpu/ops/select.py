@@ -33,8 +33,9 @@ kernel           qualifies when
 ===============  =========================================================
 flash_attention  pallas enabled; no explicit mask; no attention-weight
                  dropout in training mode (the kernel keeps scores in
-                 VMEM and applies no dropout). Causal, a window and
-                 grouped key/value heads stay in the kernel, and shape
+                 VMEM and applies no dropout). Causal, a window,
+                 grouped key/value heads and values whose heads have a
+                 size of their own stay in the kernel, and shape
                  never disqualifies: the kernel derives its blocks from
                  (lq, lk, d), keeps K/V resident while a head fits VMEM
                  and streams them beyond, and leaves a head size of 64

@@ -508,3 +508,85 @@ def test_mellum2_cell_train_step(topo, no_compile_cache, monkeypatch):
                   "moe/experts", "moe/combine"):
         assert f"/{scope}/" in text, scope
     assert "ragged-dot" not in text
+
+
+def test_kimi_linear_cell_train_step(topo, no_compile_cache, monkeypatch):
+    """The FusedTrainStep program of the benchmark's Kimi-Linear cell at its
+    published widths and its 1 x 8192 tokens, the leading KDA + dense layer
+    and the MLA + experts layer (two of the cell's five: the other three
+    repeat the KDA mixer and the expert layer): it compiles for the
+    described v5e inside a chip's memory, with the flash kernels on keys of
+    192 (padded to 256 lanes) beside values of 128, the chunked delta rule
+    as two scans (forward, and its own backward), the grouped products and
+    every new op scope as the owners of their operations
+    (docs/profiler.md)."""
+    import importlib.util
+    import json
+    import os
+    import re
+
+    import numpy as np
+
+    from incubator_mxnet_tpu import nd
+    from incubator_mxnet_tpu.parallel import FusedTrainStep, make_mesh
+
+    configs = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmark", "configs")
+    name = "kimi_linear_48b_a3b_ep32"
+    with open(os.path.join(configs, name + ".json")) as f:
+        doc = json.load(f)
+    del doc["rehearse"]
+    doc.update(num_hidden_layers=2,
+               layer_types=["linear_attention", "latent_attention"],
+               mlp_layer_types=["dense", "sparse"])
+    spec = importlib.util.spec_from_file_location(
+        "kimi_config", os.path.join(configs, name + ".py"))
+    model = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(model)
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    step = FusedTrainStep(model.net(doc, 1), model.loss(doc),
+                          model.optimizer(doc),
+                          mesh=make_mesh({"dp": 1}, topo.devices[:1]),
+                          sharding="dp")
+    tokens = nd.array(np.zeros((1, 8192), np.int32))
+    compiled = step.lower(tokens, tokens).compile()
+    held = compiled.memory_analysis()
+    assert (held.argument_size_in_bytes + held.temp_size_in_bytes
+            < 15 * 2 ** 30)
+    text = compiled.as_text()
+    kernels = {}
+    for line in _custom_calls(text):
+        kernel = re.match(r"\s*(?:ROOT )?%([\w\-]+?)(?:\.\d+)? = ",
+                          line).group(1)
+        kernels[kernel] = kernels.get(kernel, 0) + 1
+        op_name = re.search(r'op_name="([^"]*)"', line).group(1)
+        scope = ("latent_attention/attention" if kernel.startswith("flash")
+                 else "moe/experts")
+        assert f"/{scope}/" in op_name, line[:160]
+        if kernel.startswith("flash"):
+            # 32 heads of 8192: queries and keys of 256 lanes, values of 128
+            assert "[32,8192,256]" in line and "[32,8192,128]" in line
+    from incubator_mxnet_tpu.ops import _raw
+    rungs = len(_raw.row_capacities(8192 * 8, 8, 256))
+    assert rungs == 2 and _raw.row_capacities(8192 * 8, 8, 256)[0] == 2560
+    assert kernels == {"flash_attention_fwd": 1, "flash_attention_bwd": 1,
+                       "gmm": 8 * rungs, "tgmm": 3 * rungs}
+    import sys
+    sys.path.insert(0, os.path.dirname(configs))
+    from lib import scopes              # the benchmark's reader of owners
+    owners = {scopes.owner(name)
+              for name in re.findall(r'op_name="([^"]*)"', text)}
+    for scope in ("linear_attention/conv", "linear_attention/gate",
+                  "linear_attention/scan", "linear_attention/out_norm",
+                  "linear_attention_cell_0/projections",
+                  "latent_attention/kv_down", "latent_attention/kv_up",
+                  "latent_attention/attention", "moe/router", "moe/shared",
+                  "moe/experts"):
+        assert any(f"/{scope}" in owner + "/" for owner in owners), scope
+    assert not any("(" in owner or ")" in owner for owner in owners)
+    # the mixer's backward takes its stages back one by one, so a wrapped
+    # name never holds a `/` (lib/scopes.py splits owners there)
+    assert "/linear_attention/transpose(jvp(conv))/" in text
+    # the scan's two loops over 16 steps of 8 chunks, forward and backward
+    assert len(re.findall(r"/linear_attention/scan/while", text)) >= 2
