@@ -65,6 +65,7 @@ FULL = {
     "attn": {"b": 16, "h": 12, "l": 512, "d": 64},
     "ssa": [(128, 56, 56, 256), (128, 7, 7, 2048)],
     "cbr": {"x": (8, 56, 56, 256), "cout": 128},
+    "delta": {"b": 1, "l": 1024, "h": 4, "d": 128},
     "serve": {"buckets": (1, 2, 4, 8), "waves": (1, 3, 8)},
 }
 TINY = {
@@ -74,9 +75,13 @@ TINY = {
     "attn": {"b": 2, "h": 2, "l": 128, "d": 64},
     "ssa": [(2, 8, 8, 128)],
     "cbr": {"x": (2, 8, 8, 128), "cout": 128},
+    "delta": {"b": 1, "l": 100, "h": 1, "d": 128},
     "serve": {"buckets": (1, 2, 4), "waves": (1, 3)},
 }
 STEPS = 5
+# the rows of ops/select.py's table that `_kernel_cases` goes through
+SELECTED_BY_THE_CASES = ("flash_attention", "layer_norm", "scale_shift_act",
+                         "conv_bn_relu", "gated_delta_rule")
 
 
 # ---------------------------------------------------------------------------
@@ -316,6 +321,22 @@ def _kernel_cases(cfg):
                 x, w, g, b, m, v, pad=(pad, pad), layout="NHWC",
                 training=False),
             [rand(xs), w] + stats, (0, 1)))
+    # the gated delta rule's scan (heads of 128, two grid steps of 8 chunks)
+    # against `_delta_group`'s XLA form: unit keys, decays of the Kimi
+    # mixer's size, a writing strength in (0, 1)
+    s = cfg["delta"]
+    by_head = (s["b"], s["l"], s["h"], s["d"])
+
+    def unit(x):
+        x = x.astype(jnp.float32)
+        return x * jax.lax.rsqrt(jnp.sum(x * x, -1, keepdims=True))
+    cases.append((
+        "gated_delta_rule",
+        lambda q, k, v, g, beta: _raw.gated_delta_rule(q, k, v, g, beta)[0],
+        [(unit(rand(by_head)) * s["d"] ** -0.5).astype(jnp.bfloat16),
+         unit(rand(by_head)).astype(jnp.bfloat16), rand(by_head),
+         -jnp.exp(rand(by_head, jnp.float32) - 3.0),
+         jax.nn.sigmoid(rand(by_head[:3], jnp.float32))], (0, 1, 2, 3, 4)))
     return cases
 
 
@@ -325,6 +346,7 @@ def phase_kernels(cfg, on_tpu):
     with phase("kernels") as rec:
         rec["tolerance"] = TOL_BF16
         rec["kernels"] = {}
+        before = _pallas_counters()
         for name, fn, args, wrt in _kernel_cases(cfg):
             def build(args, fn=fn, wrt=wrt):
                 # a fresh function each time: jax keys its trace cache on
@@ -367,6 +389,10 @@ def phase_kernels(cfg, on_tpu):
                     if k.startswith("pallas.rejected.")}
         if rejected:
             raise AssertionError(f"selection rejected a kernel: {rejected}")
+        moved = rec["pallas"] = _pallas_counters(before)
+        for kernel in SELECTED_BY_THE_CASES:
+            if on_tpu and not moved.get(f"pallas.selected.{kernel}"):
+                raise AssertionError(f"{kernel} was not selected: {moved}")
 
 
 def _train(rec, step, x, y):

@@ -932,11 +932,53 @@ def _delta_inputs(*arrays):
     return tuple(heads_first(x) for x in arrays), back
 
 
-def _lowest_decay(g):
-    """The most negative log-decay cumulated over a chunk of g (B, H, L,
-    dk): a chunk's own sum, since no entry is positive."""
-    by_chunk = g.reshape(*g.shape[:2], -1, _DELTA_CHUNK, g.shape[-1])
-    return lax.stop_gradient(jnp.min(jnp.sum(by_chunk, axis=3)))
+def _delta_padded(*arrays):
+    """((B, L, H, ...) arrays as the Pallas kernels read them: as they are,
+    L padded to whole grid steps with tokens that change nothing; the way
+    back for a result)."""
+    from .pallas import gated_delta_rule as _kernels
+    length = arrays[0].shape[1]
+    pad = _kernels.padded_length(length) - length
+
+    def padded(x):
+        return x if not pad else jnp.pad(
+            x, ((0, 0), (0, pad)) + ((0, 0),) * (x.ndim - 2))
+
+    def back(x):
+        return x[:, :length]
+    return tuple(padded(x) for x in arrays), back
+
+
+def _delta_kernel_fwd(q, k, v, g, beta):
+    from .pallas import gated_delta_rule as _kernels
+    inputs, back = _delta_padded(q, k, v, g, beta)
+    o, starts = _kernels.delta_rule_fwd(*inputs)
+    return back(o), (q, k, v, g, beta, starts)
+
+
+def _delta_kernel_bwd(res, d_o):
+    from .pallas import gated_delta_rule as _kernels
+    *given, starts = res
+    inputs, back = _delta_padded(*given)
+    grads = _kernels.delta_rule_bwd(*inputs, starts, _delta_padded(d_o)[0][0])
+    return tuple(back(d) for d in grads)
+
+
+@jax.custom_vjp
+def _delta_rule_kernel(q, k, v, g, beta):
+    return _delta_kernel_fwd(q, k, v, g, beta)[0]
+
+
+_delta_rule_kernel.defvjp(_delta_kernel_fwd, _delta_kernel_bwd)
+
+
+def _lowest_decay(g, axis=2):
+    """The most negative log-decay cumulated over a chunk of g, whose
+    tokens lie along `axis` (whole chunks of them): a chunk's own sum,
+    since no entry is positive."""
+    by_chunk = g.reshape(*g.shape[:axis], -1, _DELTA_CHUNK,
+                         *g.shape[axis + 1:])
+    return lax.stop_gradient(jnp.min(jnp.sum(by_chunk, axis=axis + 1)))
 
 
 @jax.named_scope("scan")
@@ -951,13 +993,23 @@ def gated_delta_rule(q, k, v, g, beta):
     negative log-decay cumulated over any chunk: float32's exp underflows
     below -87, and a chunk that reaches it has forgotten its state anyway).
 
-    Chunked (`_delta_group`): chunks of 64 tokens, matrix products inside,
-    a `lax.scan` that carries S (float32) across them, 8 chunks a step.
-    The backward is the op's own (`_delta_bwd`): it keeps the inputs and a
-    state every 512 tokens and makes the rest again. L is padded with
-    tokens that change nothing (beta = 0, g = 0)."""
-    inputs, unpad = _delta_inputs(q, k, v, g.astype(jnp.float32),
-                                     beta.astype(jnp.float32))
+    Chunked: chunks of 64 tokens, matrix products inside, the state S
+    (float32) carried across them, 8 chunks a step. Where ops/select.py
+    says so (`gated_delta_rule`: the chip, one device, heads of a multiple
+    of 128) the steps are the Pallas kernels of ops/pallas/
+    gated_delta_rule.py, which read q, k, v, g as they are, with no
+    heads-first copy, and keep a chunk's tiles and the state in VMEM;
+    elsewhere `_delta_group` under a `lax.scan`, heads first. Either way
+    the backward is the op's own: it keeps the inputs and a state every 512
+    tokens and makes the rest again (`_delta_bwd`: `jax.vjp` of a step; the
+    kernels: a rule written by hand). L is padded with tokens that change
+    nothing (beta = 0, g = 0)."""
+    from . import select
+    g, beta = g.astype(jnp.float32), beta.astype(jnp.float32)
+    if select.gated_delta_rule(q.shape[3], v.shape[3], q.dtype):
+        return (_delta_rule_kernel(q, k, v, g, beta),
+                _lowest_decay(_delta_padded(g)[0][0], 1))
+    inputs, unpad = _delta_inputs(q, k, v, g, beta)
     return unpad(_delta_rule(*inputs)), _lowest_decay(inputs[3])
 
 
@@ -1073,39 +1125,47 @@ def _kda_stages(num_heads, eps, h, back, front):
     return (*qkv, *gates), pull_front, after
 
 
-def _kda_fwd(num_heads, eps, h, back, front):
+def _kda_fwd(num_heads, eps, kernel, h, back, front):
     scanned, _, after = _kda_stages(num_heads, eps, h, back, front)
-    inputs, unpad = _delta_inputs(*scanned)
     with jax.named_scope("linear_attention/scan"):
-        o, (*_, starts) = _delta_fwd(*inputs)
-        lowest = _lowest_decay(inputs[3])
-    o = unpad(o)
+        if kernel:
+            o, (*_, starts) = _delta_kernel_fwd(*scanned)
+            lowest = _lowest_decay(_delta_padded(scanned[3])[0][0], 1)
+        else:
+            inputs, unpad = _delta_inputs(*scanned)
+            o, (*_, starts) = _delta_fwd(*inputs)
+            o, lowest = unpad(o), _lowest_decay(inputs[3])
     return (after(o)[0], lowest), (h, back, front, starts, o)
 
 
-def _kda_bwd(num_heads, eps, res, cotangents):
+def _kda_bwd(num_heads, eps, kernel, res, cotangents):
     """The block's input, its weights, the scan's result and a state every
-    512 tokens are all the rule keeps (`_delta_bwd`): projections,
-    convolutions, norms and gates are written as made again, then taken back
-    with the scan. XLA decides what that costs: where memory allows it
-    merges what is made again with the forward's own and keeps it (the
-    Kimi-Linear cell: 7.35 GB of temporaries; behind an
-    `optimization_barrier`, which forbids the merge, 6.43 GB, four times the
-    program, and the benchmark's memory check at 8.5%: PERF.md, PR 32)."""
+    512 tokens are all the rule keeps (`_delta_bwd`, or the kernels'
+    `delta_rule_bwd`): projections, convolutions, norms and gates are
+    written as made again, then taken back with the scan. XLA decides what
+    that costs: where memory allows it merges what is made again with the
+    forward's own and keeps it (the Kimi-Linear cell: 7.35 GB of
+    temporaries; behind an `optimization_barrier`, which forbids the merge,
+    6.43 GB, four times the program, and the benchmark's memory check at
+    8.5%: PERF.md, PR 32)."""
     h, back, front, starts, o = res
     scanned, pull_front, after = _kda_stages(num_heads, eps, h, back, front)
-    inputs, unpad = _delta_inputs(*scanned)
     d_o, d_h_back, d_back = after(o)[1](cotangents[0])
     with jax.named_scope("linear_attention/scan"):
-        d_inputs = _delta_bwd((*inputs, starts), _delta_inputs(d_o)[0][0])
-    d_scanned = [unpad(d).astype(x.dtype) for d, x in zip(d_inputs, scanned)]
+        if kernel:
+            d_scanned = _delta_kernel_bwd((*scanned, starts), d_o)
+        else:
+            inputs, unpad = _delta_inputs(*scanned)
+            d_scanned = [unpad(d) for d in _delta_bwd(
+                (*inputs, starts), _delta_inputs(d_o)[0][0])]
+    d_scanned = [d.astype(x.dtype) for d, x in zip(d_scanned, scanned)]
     d_h, d_front = pull_front(d_scanned[:3], d_scanned[3:])
     return d_h + d_h_back, d_back, d_front
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
-def _kda(num_heads, eps, h, back, front):
-    return _kda_fwd(num_heads, eps, h, back, front)[0]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2))
+def _kda(num_heads, eps, kernel, h, back, front):
+    return _kda_fwd(num_heads, eps, kernel, h, back, front)[0]
 
 
 _kda.defvjp(_kda_fwd, _kda_bwd)
@@ -1130,9 +1190,15 @@ def linear_attention(h, wq, wk, wv, wfa, wfb, wb, wga, wgb, wo, conv_q,
     them under `projections`. ONE differentiable unit (`_kda_bwd`): its
     backward keeps h, the weights, o and the scan's state every 512 tokens
     (0.14 GB a layer at 8192 x 2304 where the projections' results would be
-    0.6), and makes the rest again. Returns (out (B, L, D),
-    `gated_delta_rule`'s most negative cumulated log-decay)."""
-    return _kda(num_heads, eps, h, (wga, wgb, gamma, wo),
+    0.6), and makes the rest again. The scan is the Pallas kernels
+    `gated_delta_rule_fwd` / `_bwd` where ops/select.py's row
+    `gated_delta_rule` qualifies (decided here, once a layer, and handed
+    to the backward), `_delta_group`'s XLA form elsewhere. Returns (out (B,
+    L, D), `gated_delta_rule`'s most negative cumulated log-decay)."""
+    from . import select
+    kernel = select.gated_delta_rule(wq.shape[0] // num_heads,
+                                     wv.shape[0] // num_heads, h.dtype)
+    return _kda(num_heads, eps, kernel, h, (wga, wgb, gamma, wo),
                 (wq, wk, wv, wfa, wfb, wb, conv_q, conv_k, conv_v, a_log,
                  dt_bias))
 

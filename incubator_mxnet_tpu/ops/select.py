@@ -28,9 +28,9 @@ Escape hatches (checked by ``pallas.enabled()``):
 
 Selection table (docs/trainloop.md renders this):
 
-===============  =========================================================
+================ =========================================================
 kernel           qualifies when
-===============  =========================================================
+================ =========================================================
 flash_attention  pallas enabled; no explicit mask; no attention-weight
                  dropout in training mode (the kernel keeps scores in
                  VMEM and applies no dropout). Causal, a window,
@@ -40,6 +40,10 @@ flash_attention  pallas enabled; no explicit mask; no attention-weight
                  (lq, lk, d), keeps K/V resident while a head fits VMEM
                  and streams them beyond, and leaves a head size of 64
                  unpadded
+gated_delta_rule pallas enabled; heads of dk and dv both multiples of
+                 128 (a head is a row of whole 128-lane tiles of the (B,
+                 L, H, d) arrays, read with no copy); q's dtype bfloat16
+                 or float32; else `_delta_group` under `lax.scan`
 grouped_matmul   pallas enabled; rows, contraction and columns all
                  multiples of 128 (the Mosaic grouped matmul's tiles);
                  else `jax.lax.ragged_dot`
@@ -52,7 +56,7 @@ conv_bn_relu     pallas enabled; inference-style BN (moving stats);
                  NHWC; 1x1/stride-1/no-pad conv runs as one fused
                  matmul+epilogue kernel, any other geometry keeps the
                  XLA conv and fuses only the epilogue
-===============  =========================================================
+================ =========================================================
 
 Every row also needs a program on ONE device: inside a program that
 GSPMD partitions over a mesh (:class:`partitioned`) no kernel qualifies.
@@ -61,11 +65,13 @@ from __future__ import annotations
 
 import threading
 
+import numpy as np
+
 from .. import profiler as _prof
 
-__all__ = ["flash_attention", "grouped_matmul", "layer_norm",
-           "scale_shift_act", "conv_bn_relu", "capture", "quiet",
-           "partitioned", "selection_table"]
+__all__ = ["flash_attention", "gated_delta_rule", "grouped_matmul",
+           "layer_norm", "scale_shift_act", "conv_bn_relu", "capture",
+           "quiet", "partitioned", "selection_table"]
 
 _tls = threading.local()
 
@@ -182,6 +188,22 @@ def grouped_matmul(lhs, rhs) -> bool:
     return _decide("grouped_matmul", True, "ok")
 
 
+def gated_delta_rule(dk, dv, dtype) -> bool:
+    """Qualify the Pallas kernels of the gated delta rule's chunked scan
+    (ops/pallas/gated_delta_rule.py: a chunk's tiles and the state stay in
+    VMEM, forward and backward) for heads of dk (keys) and dv (values) in
+    `dtype`: a head is a row of whole 128-lane tiles of the (B, L, H, d)
+    arrays."""
+    if not _open("gated_delta_rule"):
+        return False
+    if dk % 128 or dv % 128:
+        return _decide("gated_delta_rule", False,
+                       f"heads of ({dk}, {dv}) not multiples of 128")
+    if np.dtype(dtype).name not in ("bfloat16", "float32"):
+        return _decide("gated_delta_rule", False, f"dtype {dtype}")
+    return _decide("gated_delta_rule", True, "ok")
+
+
 def layer_norm(x, gamma, axis) -> bool:
     """Qualify the fused pallas layernorm (one HBM pass, f32 stats)."""
     if not _open("layer_norm"):
@@ -248,6 +270,8 @@ def selection_table():
         "flash_attention": ("no explicit mask, no attention-weight "
                             "dropout; causal, window and grouped heads "
                             "stay"),
+        "gated_delta_rule": ("heads of dk and dv % 128 == 0; bfloat16 or "
+                             "float32"),
         "grouped_matmul": "rows, contraction and columns % 128 == 0",
         "layer_norm": "last-axis, 1-D gamma; TPU: width % 128 == 0",
         "scale_shift_act": "channels-last; TPU: channels % 128 == 0",

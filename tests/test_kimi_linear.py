@@ -81,17 +81,30 @@ def _delta_inputs(length, seed=0, heads=3, dk=8, dv=12, batch=2):
     return [jnp.asarray(a, jnp.float32) for a in (q, k, v, g, beta)]
 
 
+def _moved(before, kernel="gated_delta_rule"):
+    """The selection counters of `kernel` that moved since `before`."""
+    return {k.split("/")[-1]: v - before.get(k, 0)
+            for k, v in profiler.counters().items()
+            if k.endswith("." + kernel) and v != before.get(k, 0)}
+
+
+@pytest.mark.parametrize("pallas", ["force", "0"], ids=["kernels", "xla"])
 @pytest.mark.parametrize("length", [150, 64, 700],
                          ids=["ragged", "one-chunk", "two-steps"])
-def test_the_chunked_delta_rule_is_the_recurrence(reference, length):
+def test_the_chunked_delta_rule_is_the_recurrence(reference, monkeypatch,
+                                                  length, pallas):
     """Outputs and all five gradients of `gated_delta_rule` against the
     reference's token-by-token scan, at a length that is no multiple of
     the chunk (150 = 2 x 64 + 22), at one chunk, and over two steps of the
     outer scan (700 > 512), with decays that underflow a cumulated
-    product: 1e-4 of the largest entry."""
-    inputs = _delta_inputs(length)
+    product: 1e-4 of the largest entry. Through the XLA form, and through
+    the Pallas kernels (interpreted; heads of 128, which they take)."""
+    monkeypatch.setenv("MXTPU_PALLAS", pallas)
+    kernels = pallas == "force"
+    heads, dk, dv = (2, 128, 128) if kernels else (3, 8, 12)
+    inputs = _delta_inputs(length, heads=heads, dk=dk, dv=dv)
     cotangent = jnp.asarray(np.random.RandomState(1).randn(
-        2, length, 3, 12), jnp.float32)
+        2, length, heads, dv), jnp.float32)
 
     def plain(*a):
         return jax.vmap(lambda *s: reference._recurrence(*s, None, None))(*a)
@@ -99,11 +112,14 @@ def test_the_chunked_delta_rule_is_the_recurrence(reference, length):
     def chunked(*a):
         return _raw.gated_delta_rule(*a)[0]
 
+    before = dict(profiler.counters())
     got, lowest = _raw.gated_delta_rule(*inputs)
+    assert _moved(before) == ({"pallas.selected.gated_delta_rule": 1}
+                              if kernels else {})
     want = plain(*inputs)
     assert float(lowest) < -200        # exp underflows float32 below -104
     by_chunk = np.asarray(inputs[3])[:, :length // 64 * 64].reshape(
-        2, -1, 64, 3, 8).sum(2)
+        2, -1, 64, heads, dk).sum(2)
     assert float(lowest) <= by_chunk.min() + 1e-3
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=0,
                                atol=1e-4 * float(jnp.max(jnp.abs(want))))
@@ -119,10 +135,21 @@ def test_the_chunked_delta_rule_is_the_recurrence(reference, length):
             atol=1e-4 * float(jnp.max(jnp.abs(theirs))))
 
 
-def test_the_backward_keeps_a_state_a_step_and_no_chunk(reference):
+@pytest.mark.parametrize("pallas", ["force", "0"], ids=["kernels", "xla"])
+def test_the_backward_keeps_a_state_a_step_and_no_chunk(reference, pallas):
     """The op's own rule: what the forward hands the backward is the five
-    inputs and one (dk, dv) state a head for each step of the outer scan
-    (8 chunks), nothing of a chunk's shape."""
+    inputs and one (dk, dv) state a head for each step of 8 chunks
+    (transposed and by head where the kernels wrote it), nothing of a
+    chunk's shape."""
+    from incubator_mxnet_tpu.ops.pallas import gated_delta_rule as kernels
+    if pallas == "force":
+        inputs = _delta_inputs(1100, heads=2, dk=128, dv=256)
+        assert kernels.padded_length(1100) == 1536
+        _, residuals = jax.eval_shape(_raw._delta_kernel_fwd, *inputs)
+        assert [r.shape for r in residuals] == [
+            (2, 1100, 2, 128), (2, 1100, 2, 128), (2, 1100, 2, 256),
+            (2, 1100, 2, 128), (2, 1100, 2), (2, 2, 3, 256, 128)]
+        return
     inputs = _delta_inputs(1100, heads=2)       # 18 chunks: 3 steps of 8
     _, residuals = jax.eval_shape(_raw._delta_fwd, *(
         jnp.moveaxis(jnp.pad(x, ((0, 0), (0, 1536 - 1100))
@@ -131,6 +158,75 @@ def test_the_backward_keeps_a_state_a_step_and_no_chunk(reference):
     assert [r.shape for r in residuals] == [
         (2, 2, 1536, 8), (2, 2, 1536, 8), (2, 2, 1536, 12), (2, 2, 1536, 8),
         (2, 2, 1536), (3, 2, 2, 8, 12)]
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_the_backward_written_by_hand_is_the_vjp_of_a_step(dtype):
+    """The rule the backward kernel runs (`head_backward`: one head, a step
+    of 8 chunks, as plain `jax.numpy`) against `jax.vjp(_delta_group)`
+    from a state that is not zero: the gradients of q, k, v, g and beta and
+    the carried dS, in float32 to 1e-5 of each one's largest entry; in
+    bfloat16 (operands rounded where `_delta_group` rounds them, its
+    transposes rounding cotangents besides) to a hundredth."""
+    from incubator_mxnet_tpu.ops.pallas import gated_delta_rule as kernels
+    rng = np.random.RandomState(5)
+    tokens, dk, dv = 512, 128, 128
+    q, k, v, g, beta = (x[0, :, 0] for x in _delta_inputs(
+        tokens, seed=5, heads=1, dk=dk, dv=dv, batch=1))
+    g = g * 0.2                      # down to -100 a chunk: float32 holds
+    q, k, v = (x.astype(dtype) for x in (q, k, v))
+    beta = beta[:, None]
+    state, d_state = (jnp.asarray(rng.randn(dk, dv) * scale, jnp.float32)
+                      for scale in (0.5, 1.0))
+    d_o = jnp.asarray(rng.randn(tokens, dv), dtype)
+
+    def group(state, q, k, v, g, beta):
+        return _raw._delta_group(*(x[None, None] for x in (
+            state, q, k, v, g, beta[:, 0])))
+    (after, o), pull = jax.vjp(group, state, q, k, v, g, beta)
+    want = pull((d_state[None, None], d_o[None, None]))
+    dtype = jnp.dtype(dtype)
+    got_after, got_o = kernels.head_forward(dtype, state.T, q, k, v, g, beta)
+    got = kernels.head_backward(dtype, state.T, d_state.T, q, k, v, g, beta,
+                                d_o)
+    got = [got_after.T, got_o, got[0].T, *got[1:5], got[5].T]
+    want = [after[0, 0], o[0, 0], *want]
+    for name, mine, theirs in zip(
+            "after o dS dq dk dv dg dbeta".split(), got, want):
+        theirs = np.asarray(theirs, np.float32).reshape(mine.shape)
+        np.testing.assert_allclose(
+            np.asarray(mine, np.float32), theirs, rtol=0, err_msg=name,
+            atol=(1e-5 if dtype == "float32" else 1e-2) * np.abs(theirs).max())
+
+
+@pytest.mark.parametrize("why", ["mesh", "dk64", "float16"])
+def test_a_rejected_selection_runs_the_xla_form(monkeypatch, why):
+    """Under a mesh program, with heads that are no multiple of 128 lanes,
+    or in a dtype the kernels do not take, `gated_delta_rule` and the
+    mixer trace the scans of `_delta_group` and no kernel, and the
+    rejection is counted."""
+    from incubator_mxnet_tpu.ops import select
+    monkeypatch.setenv("MXTPU_PALLAS", "force")
+    dk = 64 if why == "dk64" else 128
+    inputs = _delta_inputs(100, heads=2, dk=dk, dv=128, batch=1)
+    if why == "float16":
+        inputs[:3] = [x.astype(jnp.float16) for x in inputs[:3]]
+    mesh = jax.sharding.Mesh(np.array(jax.devices()[:2 if why == "mesh"
+                                                     else 1]), ("dp",))
+    before = dict(profiler.counters())
+    with select.partitioned(mesh):
+        program = jax.make_jaxpr(
+            lambda *a: jax.vjp(_raw.gated_delta_rule, *a)[1](
+                (jnp.ones((1, 100, 2, 128), a[0].dtype), jnp.float32(0))))(
+                    *inputs)
+    assert _moved(before) == {"pallas.rejected.gated_delta_rule": 1}
+    text = str(program)
+    assert "pallas_call" not in text and text.count("scan[") >= 2
+    with select.partitioned(None):
+        program = jax.make_jaxpr(_raw.gated_delta_rule)(*(
+            x.astype(jnp.float32) if why == "float16" else x
+            for x in inputs))
+    assert ("pallas_call" in str(program)) == (why != "dk64")
 
 
 def test_short_conv_is_causal_and_depthwise():

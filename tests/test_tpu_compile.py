@@ -249,6 +249,54 @@ def test_flash_attention_compiles_inside_its_reckoned_need(
     assert fa._merged(plan) == (kernels == 2)
 
 
+# the Kimi-Linear cell's mixer: 1 x 8192 tokens, 32 heads of 128
+KDA = ((1, 8192, 32, 128), BF16)
+KDA_BETA = ((1, 8192, 32), jnp.float32)
+
+
+@pytest.mark.parametrize("direction", ["fwd", "bwd"])
+def test_gated_delta_rule_at_the_cells_sizes(compile_for_chip, monkeypatch,
+                                             direction):
+    """The scan's two kernels at the Kimi-Linear cell's sizes, forward alone
+    and with all five gradients, through `ops/_raw.py`'s rule: they read
+    the (1, 8192, 32, 128) arrays as the mixer's stages hand them over (no
+    heads-first copy, no transpose of an operand in the program), and each
+    compiles with `vmem_limit_bytes` set to what `_plan` reckons it holds,
+    WITHOUT the quarter `_grant` adds."""
+    import importlib
+    import re
+    from incubator_mxnet_tpu.ops import _raw
+    gdr = importlib.import_module(
+        "incubator_mxnet_tpu.ops.pallas.gated_delta_rule")
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    asked = []
+
+    def bare(vmem):
+        asked.append(vmem)
+        return {"vmem_limit_bytes": vmem}
+    monkeypatch.setattr(gdr, "_grant", bare)
+    jax.clear_caches()      # the kernels' jits key on shapes, not on `_grant`
+    fn = _raw._delta_rule_kernel
+    if direction == "bwd":
+        fn = _with_grads(fn, 5)
+    text = compile_for_chip(fn, KDA, KDA, KDA, (KDA[0], jnp.float32),
+                            KDA_BETA)
+    jax.clear_caches()
+    names = [re.match(r"\s*(?:ROOT )?%([\w\-]+?)(?:\.\d+)? = ", line).group(1)
+             for line in _custom_calls(text)]
+    plan = gdr._plan(8192, 32, 128, 128, 2, False)
+    if direction == "fwd":
+        assert names == ["gated_delta_rule_fwd"] and asked == [plan.fwd_vmem]
+    else:
+        assert sorted(names) == ["gated_delta_rule_bwd",
+                                 "gated_delta_rule_fwd"]
+        assert sorted(asked) == sorted([plan.fwd_vmem, plan.bwd_vmem])
+    assert (plan.block, plan.heads) == (512, 8)
+    for line in _custom_calls(text):
+        assert "[1,8192,32,128]" in line and "[1,32,8192,128]" not in line
+    assert " transpose(" not in text.replace("transpose(jvp", "")
+
+
 def test_grouped_matmul_at_the_cells_sizes(compile_for_chip):
     """The experts' three products forward and backward, 65536 rows held of
     which the group sizes say how many are live, 8 experts of 2304 x 896:
@@ -510,16 +558,17 @@ def test_mellum2_cell_train_step(topo, no_compile_cache, monkeypatch):
     assert "ragged-dot" not in text
 
 
-def test_kimi_linear_cell_train_step(topo, no_compile_cache, monkeypatch):
+def test_kimi_linear_cell_train_step(topo, no_compile_cache, monkeypatch,
+                                     record_property):
     """The FusedTrainStep program of the benchmark's Kimi-Linear cell at its
     published widths and its 1 x 8192 tokens, the leading KDA + dense layer
     and the MLA + experts layer (two of the cell's five: the other three
     repeat the KDA mixer and the expert layer): it compiles for the
     described v5e inside a chip's memory, with the flash kernels on keys of
     192 (padded to 256 lanes) beside values of 128, the chunked delta rule
-    as two scans (forward, and its own backward), the grouped products and
-    every new op scope as the owners of their operations
-    (docs/profiler.md)."""
+    as two Pallas kernels (forward, and its own backward) and no `while`,
+    the grouped products and every new op scope as the owners of their
+    operations (docs/profiler.md)."""
     import importlib.util
     import json
     import os
@@ -552,6 +601,7 @@ def test_kimi_linear_cell_train_step(topo, no_compile_cache, monkeypatch):
     tokens = nd.array(np.zeros((1, 8192), np.int32))
     compiled = step.lower(tokens, tokens).compile()
     held = compiled.memory_analysis()
+    record_property("temp_size_in_bytes", held.temp_size_in_bytes)
     assert (held.argument_size_in_bytes + held.temp_size_in_bytes
             < 15 * 2 ** 30)
     text = compiled.as_text()
@@ -562,8 +612,12 @@ def test_kimi_linear_cell_train_step(topo, no_compile_cache, monkeypatch):
         kernels[kernel] = kernels.get(kernel, 0) + 1
         op_name = re.search(r'op_name="([^"]*)"', line).group(1)
         scope = ("latent_attention/attention" if kernel.startswith("flash")
+                 else "linear_attention/scan" if kernel.startswith("gated")
                  else "moe/experts")
         assert f"/{scope}/" in op_name, line[:160]
+        if kernel.startswith("gated"):
+            assert ("transpose(" in op_name) == kernel.endswith("_bwd")
+            assert op_name.endswith(f"/{kernel}/pallas_call")
         if kernel.startswith("flash"):
             # 32 heads of 8192: queries and keys of 256 lanes, values of 128
             assert "[32,8192,256]" in line and "[32,8192,128]" in line
@@ -571,6 +625,7 @@ def test_kimi_linear_cell_train_step(topo, no_compile_cache, monkeypatch):
     rungs = len(_raw.row_capacities(8192 * 8, 8, 256))
     assert rungs == 2 and _raw.row_capacities(8192 * 8, 8, 256)[0] == 2560
     assert kernels == {"flash_attention_fwd": 1, "flash_attention_bwd": 1,
+                       "gated_delta_rule_fwd": 1, "gated_delta_rule_bwd": 1,
                        "gmm": 8 * rungs, "tgmm": 3 * rungs}
     import sys
     sys.path.insert(0, os.path.dirname(configs))
@@ -588,5 +643,5 @@ def test_kimi_linear_cell_train_step(topo, no_compile_cache, monkeypatch):
     # the mixer's backward takes its stages back one by one, so a wrapped
     # name never holds a `/` (lib/scopes.py splits owners there)
     assert "/linear_attention/transpose(jvp(conv))/" in text
-    # the scan's two loops over 16 steps of 8 chunks, forward and backward
-    assert len(re.findall(r"/linear_attention/scan/while", text)) >= 2
+    # the scan is its two kernels: no loop over steps of 8 chunks is left
+    assert "/linear_attention/scan/while" not in text

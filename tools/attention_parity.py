@@ -81,9 +81,10 @@ def load(path):
     return module.flash_attention
 
 
-def device_ms(fn, args, reps):
+def device_ms(fn, args, reps, kernels=r"flash_attention_\w+?"):
     """{operation: ms a call} on the device, from a trace of `reps` calls;
-    custom-calls by their kernel's name, the rest under "xla"."""
+    custom-calls by their kernel's name (a pattern of names, `kernels`),
+    the rest under "xla"."""
     jax.block_until_ready(fn(*args))
     trace_dir = tempfile.mkdtemp()
     try:
@@ -99,7 +100,7 @@ def device_ms(fn, args, reps):
         if not plane.startswith(xplane.DEVICE_PLANE):
             continue
         for name, start, end in lines.get(xplane.OPS_LINE, []):
-            kernel = re.match(r"%(?:\w*?jvp_)?(flash_attention_\w+?)_*[.\d]* =",
+            kernel = re.match(r"%(?:\w*?jvp_)?(" + kernels + r")_*[.\d]* =",
                               name) if "custom-call" in name else None
             key = kernel.group(1) if kernel else "xla"
             table[key] = table.get(key, 0.0) + (end - start) / reps / 1e6
