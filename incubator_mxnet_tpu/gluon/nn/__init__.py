@@ -409,6 +409,17 @@ class SparseExperts(HybridBlock):
     `shared_hidden_size` adds a `GatedFFN` that every token passes, whole on
     every holder, under the op scope `moe/shared`.
 
+    `router_hidden_size` makes the router an MLP of that width in place of
+    the one matrix `router` (ops/_raw.py `router_mlp`): `router_down`, then
+    `router_w1`, `router_w2` and `router_w3` with GELU between. Such a
+    router has a state, its down projection, that travels from layer to
+    layer: forward(x, state) takes the layer before's (None for a model's
+    first) and returns (y, its own). `previous` says that a layer with
+    such a router comes before this one (a model works it out, it is not a
+    choice): the layer then owns `router_gamma` (zeros) and adds gamma x
+    the state it is given to its own. With `norm_topk_prob=False` and one expert a token the weight is
+    the chosen expert's probability.
+
     `load` (num_experts int32, `grad_req="null"`) holds the assignments each
     expert got in the last training step; it is updated as BatchNorm's
     running statistics are, keeps its type under `cast`, and `read_load()`
@@ -420,7 +431,8 @@ class SparseExperts(HybridBlock):
     def __init__(self, units, hidden_size, num_experts, top_k, held=None,
                  norm_topk_prob=True, weight_initializer=None,
                  scoring="softmax", selection_bias=False, scale=1.0,
-                 shared_hidden_size=None, prefix=None, params=None):
+                 shared_hidden_size=None, router_hidden_size=None,
+                 previous=False, prefix=None, params=None):
         super().__init__(prefix, params)
         first, count = held if held is not None else (0, num_experts)
         if not 0 <= first <= first + count <= num_experts or count < 1:
@@ -433,8 +445,22 @@ class SparseExperts(HybridBlock):
         self._scoring = scoring
         self._scale = scale
         get = self.params.get
-        self.router = get("router", shape=(num_experts, units),
-                          init=weight_initializer)
+        self.router_gamma = None
+        if router_hidden_size is None:
+            self.router = get("router", shape=(num_experts, units),
+                              init=weight_initializer)
+            self._router = [self.router]
+        else:
+            wide = router_hidden_size
+            self._router = [
+                get("router_" + name, shape=shape, init=weight_initializer)
+                for name, shape in (("down", (wide, units)),
+                                    ("w1", (wide, wide)), ("w2", (wide, wide)),
+                                    ("w3", (num_experts, wide)))]
+            if previous:
+                self.router_gamma = get("router_gamma", shape=(wide,),
+                                        init="zeros")
+        self._mlp = router_hidden_size is not None
         self.gate = get("gate", shape=(count, units, hidden_size),
                         init=weight_initializer)
         self.up = get("up", shape=(count, units, hidden_size),
@@ -451,24 +477,35 @@ class SparseExperts(HybridBlock):
     def cast(self, dtype):
         # `load` counts: it stays int32 (bfloat16 cannot count past 256);
         # `bias` is added to float32 scores and stays float32
-        for p in (self.router, self.gate, self.up, self.down):
-            p.cast(dtype)
+        for p in (*self._router, self.router_gamma, self.gate, self.up,
+                  self.down):
+            if p is not None:
+                p.cast(dtype)
         if self.shared is not None:
             self.shared.cast(dtype)
         self._dtype = dtype
 
-    def forward(self, x):
+    def forward(self, x, state=None):
+        router = logits = None
+        if self._mlp:
+            down, *mlp = (p.data() for p in self._router)
+            average = self.router_gamma is not None and state is not None
+            logits, state = ops.router_mlp(
+                x, state if average else None, down,
+                self.router_gamma.data() if average else None, *mlp)
+        else:
+            router = self.router.data()
         y, load = ops.sparse_experts(
-            x, self.router.data(), self.gate.data(), self.up.data(),
+            x, router, self.gate.data(), self.up.data(),
             self.down.data(), self._top_k, self._first, self._norm,
             self._scoring, None if self.bias is None else self.bias.data(),
-            self._scale)
+            self._scale, logits)
         if autograd.is_training():
             self.load.update_aux(load._data)
         if self.shared is not None:
             with jax.named_scope("moe"), jax.named_scope("shared"):
                 y = y + self.shared(x)
-        return y
+        return (y, state) if self._mlp else y
 
     def read_load(self):
         """{live_rows, load_max_over_mean, row_capacity} of the last training

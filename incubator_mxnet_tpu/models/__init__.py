@@ -23,7 +23,8 @@ from .transformer_lm import (TransformerLM, lm_loss, transformer_lm_small,
                              transformer_lm_base)
 from .dlrm import DLRM, dlrm_loss, dlrm_small
 from .moe_lm import (MoeLM, MoeLMCell, GroupedQueryAttentionCell,
-                     LinearAttentionCell, LatentAttentionCell)
+                     LinearAttentionCell, LatentAttentionCell,
+                     CompressedAttentionCell)
 
 _MODELS = {}
 for _name in ["resnet18_v1", "resnet34_v1", "resnet50_v1", "resnet101_v1",

@@ -1,11 +1,12 @@
 """Decoder-only language model of the current sparse-expert families: RMS
-norm, an untied head, and layer by layer one of four sequence mixers (window
-or full attention over grouped key/value heads with rotary positions; gated
-delta-rule linear attention; latent attention without positions) and one of
-two feed-forwards (sparse experts, with the routers of both families and a
-shared expert; a dense gated one) — built from a published `config.json`'s
-own keys (`layer_types`, `mlp_layer_types`, `rope_parameters`,
-`sliding_window` and the widths).
+norm, a head of its own or the embedding table's, and layer by layer one of
+five sequence mixers (window or full attention over grouped key/value heads
+with rotary positions; gated delta-rule linear attention; latent attention
+without positions; attention inside a compressed latent with convolutions)
+and one of two feed-forwards (sparse experts, with the routers of the three
+families and a shared expert; a dense gated one) — built from a published
+`config.json`'s own keys (`layer_types`, `mlp_layer_types`,
+`rope_parameters`, `sliding_window`, `tie_word_embeddings` and the widths).
 
 A holder of an expert-parallel deployment builds the model with its share:
 `held=(first, count)` of every layer's experts (gluon.nn.SparseExperts
@@ -29,7 +30,8 @@ from ..initializer import Initializer
 from ..ops._raw import rope_frequencies
 
 __all__ = ["MoeLM", "MoeLMCell", "GroupedQueryAttentionCell",
-           "LinearAttentionCell", "LatentAttentionCell"]
+           "LinearAttentionCell", "LatentAttentionCell",
+           "CompressedAttentionCell"]
 
 
 def _dense(out_units, in_units, weight_initializer):
@@ -218,9 +220,54 @@ class LatentAttentionCell(HybridBlock):
         return self.proj(out)
 
 
+class CompressedAttentionCell(HybridBlock):
+    """Compressed convolutional attention (CCA, arXiv:2510.04476): q, k and
+    v are projected DOWN to `num_heads`, `num_kv_heads` and `num_kv_heads`
+    heads of `head_dim`, everything between them and the output projection
+    happens at that width (ops/_raw.py `compressed_attention`: the value
+    shift, a depthwise and a head-mixing causal convolution of `conv_sizes`
+    taps on [q ; k], the q-k mean, unit norms with a temperature a
+    key/value head, rotary positions on the first `rotary_dim` channels of
+    a head, causal attention), and `proj` maps the heads back to `units`.
+    No bias. `rope` is a section of a published `rope_parameters`; its
+    `partial_rotary_factor` gives `rotary_dim`."""
+
+    def __init__(self, units, num_heads, num_kv_heads, head_dim, rope,
+                 conv_sizes=(2, 2), weight_initializer=None, prefix=None,
+                 params=None):
+        super().__init__(prefix, params)
+        self._heads = (num_heads, num_kv_heads)
+        rope = dict(rope)
+        self._rotary_dim = int(head_dim * rope.pop("partial_rotary_factor",
+                                                   1.0))
+        self._rope = rope_frequencies(self._rotary_dim, **rope)
+        init, every = weight_initializer, num_heads + num_kv_heads
+        self.q = _dense(num_heads * head_dim, units, init)
+        self.k = _dense(num_kv_heads * head_dim, units, init)
+        self.v = _dense(num_kv_heads * head_dim, units, init)
+        self.proj = _dense(units, num_heads * head_dim, init)
+        get = self.params.get
+        self.conv0 = get("conv0", shape=(conv_sizes[0], every * head_dim),
+                         init=init)
+        self.conv1 = get("conv1", shape=(conv_sizes[1], every, head_dim,
+                                         head_dim), init=init)
+        self.temp = get("temp", shape=(num_kv_heads,), init="ones")
+
+    def forward(self, x):
+        inv_freq, factor = self._rope
+        out = ops.compressed_attention(
+            self.q(x), self.k(x), self.v(x), self.conv0.data(),
+            self.conv1.data(), self.temp.data(), inv_freq, *self._heads,
+            self._rotary_dim, factor)
+        return self.proj(out)
+
+
 class MoeLMCell(HybridBlock):
     """Pre-norm block: x += attention(norm(x)); x += ffn(norm(x)), where the
-    feed-forward is a `nn.SparseExperts` or a dense `nn.GatedFFN`."""
+    feed-forward is a `nn.SparseExperts` or a dense `nn.GatedFFN`. Where
+    the experts' router carries a state from layer to layer
+    (`SparseExperts(router_hidden_size=)`), forward(x, state) takes the
+    layer before's and returns (x, this layer's)."""
 
     def __init__(self, attention, ffn, units, epsilon=1e-6, prefix=None,
                  params=None):
@@ -230,40 +277,54 @@ class MoeLMCell(HybridBlock):
         self.norm2 = nn.RMSNorm(epsilon, in_channels=units)
         self.ffn = ffn
 
-    def forward(self, x):
+    def forward(self, x, state=None):
         x = x + self.attention(self.norm1(x))
-        return x + self.ffn(self.norm2(x))
+        h = self.norm2(x)
+        carried = state is not None and isinstance(self.ffn, nn.SparseExperts)
+        y = self.ffn(h, state) if carried else self.ffn(h)
+        if isinstance(y, tuple):
+            return x + y[0], y[1]
+        return x + y
 
 
 class MoeLM(HybridBlock):
     """Token embedding (no scale, no position table), one `MoeLMCell` for
-    each entry of `layer_types`, a final RMS norm and the untied vocabulary
-    head.
+    each entry of `layer_types`, a final RMS norm and the vocabulary head:
+    a `Dense` of its own, or with `tie_word_embeddings` the embedding table
+    transposed (its gradient then sums both uses).
 
     `layer_types[i]` names layer i's sequence mixer: "sliding_attention" or
     "full_attention" (`GroupedQueryAttentionCell`; `rope_parameters` has a
     section for each kind in use), "linear_attention" (`LinearAttentionCell`,
     built from `linear_attention={num_heads, head_dim,
-    short_conv_kernel_size}`, a published `linear_attn_config`) or
+    short_conv_kernel_size}`, a published `linear_attn_config`),
     "latent_attention" (`LatentAttentionCell`, from `latent_attention=
-    {kv_lora_rank, qk_nope_head_dim, qk_rope_head_dim, v_head_dim}`).
+    {kv_lora_rank, qk_nope_head_dim, qk_rope_head_dim, v_head_dim}`) or
+    "hybrid" (`CompressedAttentionCell`, from `compressed_attention=
+    {cca_time0, cca_time1}` and `rope_parameters["hybrid"]`, whose
+    `partial_rotary_factor` says how much of a head is rotated).
     `mlp_layer_types[i]` names its feed-forward: "sparse" (the default for
     every layer: `nn.SparseExperts`, with `router={scoring, selection_bias,
     scale, shared_hidden_size}` where the family scores by sigmoid and adds
-    a shared expert) or "dense" (`nn.GatedFFN` of `hidden_size`)."""
+    a shared expert, or `router={router_hidden_size, selection_bias}` where
+    the router is an MLP whose state every layer hands to the next) or
+    "dense" (`nn.GatedFFN` of `hidden_size`)."""
 
     def __init__(self, vocab_size, layer_types, units, num_heads,
                  num_kv_heads, head_dim, moe_hidden_size, num_experts, top_k,
                  held=None, rope_parameters=None, sliding_window=None,
                  rms_norm_eps=1e-6, norm_topk_prob=True, mlp_layer_types=None,
                  hidden_size=None, linear_attention=None,
-                 latent_attention=None, router=None, prefix=None,
+                 latent_attention=None, compressed_attention=None,
+                 router=None, tie_word_embeddings=False, prefix=None,
                  params=None):
         super().__init__(prefix, params)
         rope_parameters = rope_parameters or {}
         mlp_layer_types = mlp_layer_types or ["sparse"] * len(layer_types)
+        router = dict(router or {})
         sized = {"linear_attention": linear_attention,
-                 "latent_attention": latent_attention}
+                 "latent_attention": latent_attention,
+                 "hybrid": compressed_attention}
         self.embedding = nn.Embedding(vocab_size, units)
         self.layers = []
         for i, (kind, mlp) in enumerate(zip(layer_types, mlp_layer_types)):
@@ -274,8 +335,9 @@ class MoeLM(HybridBlock):
                     window=(sliding_window if kind == "sliding_attention"
                             else None))
             elif kind in sized and sized[kind] is None:
+                name = ("compressed_attention" if kind == "hybrid" else kind)
                 raise ValueError(f"layer_types[{i}] = {kind!r} needs its "
-                                 f"sizes: {kind}={{...}}")
+                                 f"sizes: {name}={{...}}")
             elif kind == "linear_attention":
                 attention = LinearAttentionCell(
                     units, linear_attention["num_heads"],
@@ -287,12 +349,25 @@ class MoeLM(HybridBlock):
                     latent_attention["qk_nope_head_dim"],
                     latent_attention["qk_rope_head_dim"],
                     latent_attention["v_head_dim"], rms_norm_eps)
+            elif kind == "hybrid":
+                attention = CompressedAttentionCell(
+                    units, num_heads, num_kv_heads, head_dim,
+                    rope_parameters[kind],
+                    (compressed_attention["cca_time0"],
+                     compressed_attention["cca_time1"]))
             else:
-                raise ValueError(f"layer_types[{i}] = {kind!r}")
+                raise ValueError(
+                    f"layer_types[{i}] = {kind!r}; MoeLM builds "
+                    f"'sliding_attention', 'full_attention', "
+                    f"'linear_attention', 'latent_attention' and 'hybrid'")
             if mlp == "sparse":
+                if "router_hidden_size" in router:
+                    # the first expert layer is handed no state to average
+                    router["previous"] = any(
+                        isinstance(cell.ffn, nn.SparseExperts)
+                        for cell in self.layers)
                 ffn = nn.SparseExperts(units, moe_hidden_size, num_experts,
-                                       top_k, held, norm_topk_prob,
-                                       **(router or {}))
+                                       top_k, held, norm_topk_prob, **router)
             elif mlp == "dense":
                 ffn = nn.GatedFFN(units, hidden_size)
             else:
@@ -301,13 +376,20 @@ class MoeLM(HybridBlock):
             self.register_child(cell, f"layer{i}")
             self.layers.append(cell)
         self.norm = nn.RMSNorm(rms_norm_eps, in_channels=units)
-        self.head = _dense(vocab_size, units, None)
+        self.head = (None if tie_word_embeddings
+                     else _dense(vocab_size, units, None))
 
     def forward(self, tokens):
         h = self.embedding(tokens)
+        state = None        # of a router that carries one
         for layer in self.layers:
-            h = layer(h)
-        return self.head(self.norm(h))
+            h = layer(h) if state is None else layer(h, state)
+            if isinstance(h, tuple):
+                h, state = h
+        h = self.norm(h)
+        if self.head is None:
+            return nd.dot(h, self.embedding.weight.data(), transpose_b=True)
+        return self.head(h)
 
     def read_load(self):
         """`nn.SparseExperts.read_load()` of every expert layer, in order of

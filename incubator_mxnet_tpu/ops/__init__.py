@@ -24,7 +24,8 @@ __all__ = ["FullyConnected", "Convolution", "Deconvolution", "Pooling",
            "BatchNorm", "LayerNorm", "InstanceNorm", "GroupNorm", "Activation",
            "Dropout", "L2Normalization", "softmax_cross_entropy", "smooth_l1",
            "UpSampling", "multihead_attention", "RMSNorm", "rope",
-           "sparse_experts", "gated_ffn", "linear_attention", "box_iou",
+           "sparse_experts", "router_mlp", "compressed_attention",
+           "gated_ffn", "linear_attention", "box_iou",
            "box_nms",
            "MultiBoxPrior", "MultiBoxTarget", "MultiBoxDetection",
            "ROIPooling", "ROIAlign", "BilinearResize2D",
@@ -336,26 +337,60 @@ def RMSNorm(data, gamma, eps=1e-6):
                   name="RMSNorm")
 
 
-def rope(data, inv_freq, num_heads, factor=1.0):
+def rope(data, inv_freq, num_heads, factor=1.0, rotary_dim=None):
     """Rotary positions on (B, L, heads * head_dim); `inv_freq` and `factor`
-    as `ops._raw.rope_frequencies` gives them."""
-    return _apply(lambda x: _raw.rope(x, inv_freq, num_heads, factor),
+    as `ops._raw.rope_frequencies` gives them; `rotary_dim`: the leading
+    channels of every head that are rotated (default: all of them)."""
+    return _apply(lambda x: _raw.rope(x, inv_freq, num_heads, factor,
+                                      rotary_dim),
                   [data], name="rope")
+
+
+def compressed_attention(q, k, v, conv0, conv1, temp, inv_freq, num_heads,
+                         num_kv_heads, rotary_dim=None, factor=1.0):
+    """`ops._raw.compressed_attention`: between a CCA mixer's down
+    projections and its output projection, the value shift, the two causal
+    convolutions, the q-k mean, the unit norms, rotary positions on part
+    of a head and causal attention over grouped heads."""
+    def f(*arrays):
+        return _raw.compressed_attention(*arrays, inv_freq, num_heads,
+                                         num_kv_heads, rotary_dim, factor)
+    return _apply(f, [q, k, v, conv0, conv1, temp],
+                  name="compressed_attention")
+
+
+def router_mlp(data, previous, down, gamma, w1, w2, w3):
+    """(logits, state) of `ops._raw.router_mlp`: a router that is a down
+    projection, an average with the `previous` layer's state (None, with
+    `gamma` None, for a model's first layer) and three products with GELU
+    between; `sparse_experts(logits=)` routes by the logits."""
+    if previous is None:
+        return _apply(lambda x, d, *w: _raw.router_mlp(x, None, d, None, *w),
+                      [data, down, w1, w2, w3], n_out=2, name="router_mlp")
+    return _apply(_raw.router_mlp, [data, previous, down, gamma, w1, w2, w3],
+                  n_out=2, name="router_mlp")
 
 
 def sparse_experts(data, router, gate, up, down, top_k, first=0,
                    norm_topk_prob=True, scoring="softmax", bias=None,
-                   scale=1.0):
+                   scale=1.0, logits=None):
     """(y, load) of `ops._raw.sparse_experts`: the held experts' part of a
-    dropless top-k expert layer, and the assignments each expert got."""
-    def f(x, r, g, u, d, *b):
-        y, load = _raw.sparse_experts(x, r, g, u, d, top_k, first,
-                                      norm_topk_prob, scoring,
-                                      b[0] if b else None, scale)
+    dropless top-k expert layer, and the assignments each expert got. The
+    router is one matrix `router`, or None beside the `logits` of one
+    computed outside (`router_mlp`)."""
+    given = {"x": data, "router": router, "gate": gate, "up": up,
+             "down": down, "bias": bias, "logits": logits}
+    names = [name for name, array in given.items() if array is not None]
+
+    def f(*arrays):
+        a = dict(zip(names, arrays))
+        y, load = _raw.sparse_experts(
+            a["x"], a.get("router"), a["gate"], a["up"], a["down"], top_k,
+            first, norm_topk_prob, scoring, a.get("bias"), scale,
+            a.get("logits"))
         # the tape wants a cotangent of every output's own dtype
         return y, load.astype(jnp.float32)
-    y, load = _apply(f, [data, router, gate, up, down]
-                     + ([] if bias is None else [bias]), n_out=2,
+    y, load = _apply(f, [given[name] for name in names], n_out=2,
                      name="sparse_experts")
     return y, NDArray(load._data.astype(jnp.int32))
 

@@ -703,17 +703,30 @@ def rope_frequencies(head_dim, rope_type="default", rope_theta=10000.0,
 
 
 @jax.named_scope("rope")
-def rope(x, inv_freq, num_heads, factor=1.0):
+def rope(x, inv_freq, num_heads, factor=1.0, rotary_dim=None):
     """Rotary positions on (B, L, H * head_dim), HF's rotate-half form on
     each head: x cos + rotate_half(x) sin, with cos and sin of
     position * inv_freq repeated over both halves and times `factor`.
-    Angles and the rotation in float32, the result in x's dtype."""
+    Angles and the rotation in float32, the result in x's dtype.
+
+    `rotary_dim` (a published `partial_rotary_factor` x head_dim): only the
+    first `rotary_dim` channels of every head are rotated, by `inv_freq`'s
+    rotary_dim / 2 frequencies; the others pass as they are, bit for bit."""
     b, l, d = x.shape
     hd = d // num_heads
     angle = (jnp.arange(l, dtype=jnp.float32)[:, None]
              * jnp.asarray(inv_freq, jnp.float32)[None, :])
     cos = (jnp.cos(angle) * factor)[None, :, None, :]
     sin = (jnp.sin(angle) * factor)[None, :, None, :]
+    if rotary_dim is not None and rotary_dim != hd:
+        heads = x.reshape(b, l, num_heads, hd)
+        xf = heads[..., :rotary_dim].astype(jnp.float32)
+        x1, x2 = xf[..., :rotary_dim // 2], xf[..., rotary_dim // 2:]
+        out = jnp.concatenate(
+            [(x1 * cos - x2 * sin).astype(x.dtype),
+             (x2 * cos + x1 * sin).astype(x.dtype),
+             heads[..., rotary_dim:]], -1)
+        return out.reshape(b, l, d)
     xf = x.astype(jnp.float32).reshape(b, l, num_heads, hd)
     x1, x2 = xf[..., :hd // 2], xf[..., hd // 2:]
     out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
@@ -1204,6 +1217,88 @@ def linear_attention(h, wq, wk, wv, wfa, wfb, wb, wga, wgb, wo, conv_q,
 
 
 # ---------------------------------------------------------------------------
+# compressed convolutional attention (CCA, arXiv:2510.04476)
+# ---------------------------------------------------------------------------
+
+def head_conv(x, weight):
+    """Causal convolution along the sequence that MIXES the channels of a
+    head: x (B, L, H * d), weight (K, H, d, d); head h of y_t is sum_j
+    x_(t - K + 1 + j)[h] weight[j, h], zeros before the sequence. ONE
+    product a head, over the K taps' channels side by side."""
+    taps, heads, hd = weight.shape[:3]
+    b, length = x.shape[:2]
+    padded = jnp.pad(x, ((0, 0), (taps - 1, 0), (0, 0))).reshape(
+        b, length + taps - 1, heads, hd)
+    before = jnp.concatenate([padded[:, j:j + length] for j in range(taps)],
+                             -1)
+    y = jnp.einsum("blhc,hcd->blhd", before,
+                   weight.transpose(1, 0, 2, 3).reshape(heads, taps * hd, hd))
+    return y.reshape(b, length, heads * hd)
+
+
+def _unit_heads(x, num_heads, gain):
+    """sqrt(d) gain x / sqrt(sum x^2 + 1e-6) by head of d channels, in
+    float32; `gain` (num_heads,) or None."""
+    b, length, d = x.shape
+    hd = d // num_heads
+    xf = x.astype(jnp.float32).reshape(b, length, num_heads, hd)
+    y = xf * (lax.rsqrt(jnp.sum(xf * xf, -1, keepdims=True) + 1e-6)
+              * hd ** 0.5)
+    if gain is not None:
+        y = y * gain.astype(jnp.float32)[:, None]
+    return y.reshape(b, length, d).astype(x.dtype)
+
+
+@jax.named_scope("compressed_attention")
+def compressed_attention(q, k, v, conv0, conv1, temp, inv_freq, num_heads,
+                         num_kv_heads, rotary_dim=None, factor=1.0):
+    """Attention inside a compressed latent, between a mixer's down
+    projections and its output projection: q (B, L, H d), k and v (B, L,
+    G d) as projected from the block's input, H query heads over G
+    key/value heads of d.
+
+    `shift`: the upper half of v's channels comes from the token BEFORE
+    (zeros at the first): v = [x W_V1 ; shift(x) W_V2] with the shift made
+    behind the product, which it commutes with. `conv`: [q ; k] through a
+    causal depthwise convolution (`conv0` (K0, (H + G) d)) and then one that
+    mixes the d channels of each of the H + G heads (`conv1` (K1, H + G, d,
+    d)). `mean`: the convolved q gains (q + k) / 2 of the projections, k of
+    its group broadcast over the group's query heads; the convolved k gains
+    (the group's mean q + k) / 2. `norm`: q and k to length sqrt(d) by head,
+    k times its head's `temp` (G,). `rope` on the first `rotary_dim`
+    channels of every head (`rope`), then causal attention with query head
+    h reading key/value head h // (H / G) (`multihead_attention`, so the
+    flash kernels where ops/select.py says so). Returns (B, L, H d)."""
+    b, length, wide = q.shape
+    hd = wide // num_heads
+    group = num_heads // num_kv_heads
+    with jax.named_scope("shift"):
+        half = v.shape[-1] // 2
+        v = jnp.concatenate(
+            [v[..., :half],
+             jnp.pad(v[:, :-1, half:], ((0, 0), (1, 0), (0, 0)))], -1)
+    with jax.named_scope("conv"):
+        mixed = head_conv(short_conv(jnp.concatenate([q, k], -1), conv0),
+                          conv1)
+        q_c, k_c = mixed[..., :wide], mixed[..., wide:]
+    with jax.named_scope("mean"):
+        qg = q.astype(jnp.float32).reshape(b, length, num_kv_heads, group, hd)
+        kg = k.astype(jnp.float32).reshape(b, length, num_kv_heads, 1, hd)
+        q = (q_c.astype(jnp.float32)
+             + ((qg + kg) * 0.5).reshape(b, length, wide)).astype(q.dtype)
+        k = (k_c.astype(jnp.float32)
+             + ((jnp.mean(qg, 3, keepdims=True) + kg) * 0.5).reshape(
+                 b, length, -1)).astype(k.dtype)
+    with jax.named_scope("norm"):
+        q = _unit_heads(q, num_heads, None)
+        k = _unit_heads(k, num_kv_heads, temp)
+    q = rope(q, inv_freq, num_heads, factor, rotary_dim)
+    k = rope(k, inv_freq, num_kv_heads, factor, rotary_dim)
+    return multihead_attention(q, k, v, num_heads, causal=True,
+                               num_kv_heads=num_kv_heads)
+
+
+# ---------------------------------------------------------------------------
 # sparse experts (dropless top-k routing over the experts held here)
 # ---------------------------------------------------------------------------
 
@@ -1433,21 +1528,48 @@ def gated_ffn(x, gate, up, down):
     return jnp.dot(_gated(jnp.dot(x, gate), jnp.dot(x, up)), down)
 
 
+def router_mlp(x, previous, down, gamma, w1, w2, w3):
+    """A router that is more than one product (ZAYA1, arXiv:2511.17127), on
+    x (..., D): r = x down^T (R wide); with the `previous` layer's state,
+    r += gamma * previous (`gamma` (R,): an average over depth, learned);
+    logits = w3 gelu(w2 gelu(w1 r)) over the E experts in float32 (w1, w2
+    (R, R), w3 (E, R); erf GELU, no bias). Returns (logits (..., E), the
+    state r (..., R) that the next layer's router takes). Under the op
+    scopes `moe/router/down`, `/average`, `/mlp`; `sparse_experts(logits=)`
+    routes by them."""
+    with jax.named_scope("moe"), jax.named_scope("router"):
+        with jax.named_scope("down"):
+            state = jnp.dot(x, down.T)
+        if previous is not None:
+            with jax.named_scope("average"):
+                state = state + gamma * previous
+        with jax.named_scope("mlp"):
+            hidden = jax.nn.gelu(jnp.dot(state, w1.T), approximate=False)
+            hidden = jax.nn.gelu(jnp.dot(hidden, w2.T), approximate=False)
+            logits = jnp.dot(hidden, w3.T,
+                             preferred_element_type=jnp.float32)
+    return logits, state
+
+
 def sparse_experts(x, router, gate, up, down, top_k, first=0,
                    norm_topk_prob=True, scoring="softmax", bias=None,
-                   scale=1.0):
+                   scale=1.0, logits=None):
     """The part that the experts held here add to a sparse-expert layer.
 
-    x (..., D); router (E, D) over ALL E experts; gate and up (C, D, F),
-    down (C, F, D): the C experts [first, first + C) held here. Every token
-    takes its `top_k` largest of softmax(x router^T) (float32), with weights
-    normalised over the top_k when `norm_topk_prob`; expert e gives
-    (silu(x gate_e) * (x up_e)) down_e. `scoring="sigmoid"` scores each
-    expert by itself; `bias` (E,) is added to the scores for the CHOICE
-    alone (the weights are the scores', and no gradient reaches it: a
-    balancing bias that is no weight); `scale` multiplies the weights. The result sums, for each token, the
-    weighted outputs of its experts that are held here (in float32); what
-    the others would add is left out. No assignment to a held expert is
+    x (..., D); router (E, D) over ALL E experts, or None with `logits`
+    (..., E), float32, of a router computed outside this op's one product
+    (`router_mlp`); gate and up (C, D, F), down (C, F, D): the C experts
+    [first, first + C) held here. Every token takes its `top_k` largest of
+    softmax(x router^T) (float32), with weights normalised over the top_k
+    when `norm_topk_prob` (with ONE expert a token that makes every weight
+    1 and leaves the router no gradient: such a family's weight is the
+    chosen expert's own probability); expert e gives (silu(x gate_e) * (x
+    up_e)) down_e. `scoring="sigmoid"` scores each expert by itself; `bias`
+    (E,) is added to the scores for the CHOICE alone (the weights are the
+    scores', and no gradient reaches it: a balancing bias that is no
+    weight); `scale` multiplies the weights. The result sums, for each
+    token, the weighted outputs of its experts that are held here (in
+    float32); what the others would add is left out. No assignment to a held expert is
     dropped, whatever the routing: the assignments to held experts are
     sorted by expert, and the row buffers hold the first `capacity` of
     them, the smallest rung of `row_capacities` that holds the live rows of
@@ -1460,10 +1582,15 @@ def sparse_experts(x, router, gate, up, down, top_k, first=0,
     Returns (y like x, load (E,) int32: assignments to each expert)."""
     lead, d = x.shape[:-1], x.shape[-1]
     x2 = x.reshape(-1, d)
-    tokens, experts, count = x2.shape[0], router.shape[0], gate.shape[0]
+    tokens, count = x2.shape[0], gate.shape[0]
+    experts = router.shape[0] if logits is None else logits.shape[-1]
     with jax.named_scope("moe"):
         with jax.named_scope("router"):
-            logits = jnp.dot(x2, router.T, preferred_element_type=jnp.float32)
+            if logits is None:
+                logits = jnp.dot(x2, router.T,
+                                 preferred_element_type=jnp.float32)
+            else:
+                logits = logits.reshape(tokens, experts)
             if scoring == "softmax" and bias is None:
                 prob, chosen = lax.top_k(jax.nn.softmax(logits, axis=-1), top_k)
             else:

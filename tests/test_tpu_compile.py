@@ -645,3 +645,88 @@ def test_kimi_linear_cell_train_step(topo, no_compile_cache, monkeypatch,
     assert "/linear_attention/transpose(jvp(conv))/" in text
     # the scan is its two kernels: no loop over steps of 8 chunks is left
     assert "/linear_attention/scan/while" not in text
+
+
+def test_zaya1_cell_train_step(topo, no_compile_cache, monkeypatch,
+                               record_property):
+    """The FusedTrainStep program of the benchmark's ZAYA1 cell at its
+    published widths and its 1 x 8192 tokens, layers 0 and 1 (of the cell's
+    six alike: the first has no router state to average, the second has):
+    it compiles for the described v5e inside a chip's memory, with the flash
+    kernels at 8 query heads over 2 key/value heads of 128 inside the
+    latent, the grouped products of one assignment a token on both rungs,
+    and the mixer's and the router's op scopes as the owners of their
+    operations, forward and backward (docs/profiler.md)."""
+    import importlib.util
+    import json
+    import os
+    import re
+
+    import numpy as np
+
+    from incubator_mxnet_tpu import nd
+    from incubator_mxnet_tpu.parallel import FusedTrainStep, make_mesh
+
+    configs = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmark", "configs")
+    name = "zaya1_8b_ep2"
+    with open(os.path.join(configs, name + ".json")) as f:
+        doc = json.load(f)
+    del doc["rehearse"]
+    doc.update(num_hidden_layers=2)
+    spec = importlib.util.spec_from_file_location(
+        "zaya1_config", os.path.join(configs, name + ".py"))
+    model = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(model)
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    step = FusedTrainStep(model.net(doc, 1), model.loss(doc),
+                          model.optimizer(doc),
+                          mesh=make_mesh({"dp": 1}, topo.devices[:1]),
+                          sharding="dp")
+    tokens = nd.array(np.zeros((1, 8192), np.int32))
+    compiled = step.lower(tokens, tokens).compile()
+    held = compiled.memory_analysis()
+    record_property("argument_size_in_bytes", held.argument_size_in_bytes)
+    record_property("temp_size_in_bytes", held.temp_size_in_bytes)
+    assert (held.argument_size_in_bytes + held.temp_size_in_bytes
+            < 15 * 2 ** 30)
+    text = compiled.as_text()
+    kernels = {}
+    for line in _custom_calls(text):
+        kernel = re.match(r"\s*(?:ROOT )?%([\w\-]+?)(?:\.\d+)? = ",
+                          line).group(1)
+        kernels[kernel] = kernels.get(kernel, 0) + 1
+        op_name = re.search(r'op_name="([^"]*)"', line).group(1)
+        scope = ("compressed_attention/attention"
+                 if kernel.startswith("flash") else "moe/experts")
+        assert f"/{scope}/" in op_name, line[:160]
+        if kernel.startswith("flash"):
+            # 8 query heads of 8192 x 128; keys and values of 2 heads
+            assert "[8,8192,128]" in line and "[2,8192,128]" in line
+    from incubator_mxnet_tpu.ops import _raw
+    ladder = _raw.row_capacities(8192, 8, 16)
+    assert ladder == (5120, 8192)
+    assert kernels == {"flash_attention_fwd": 2, "flash_attention_bwd": 2,
+                       "gmm": 2 * 8 * len(ladder),
+                       "tgmm": 2 * 3 * len(ladder)}
+    import sys
+    sys.path.insert(0, os.path.dirname(configs))
+    from lib import scopes              # the benchmark's reader of owners
+    op_names = re.findall(r'op_name="([^"]*)"', text)
+    owners = {scopes.owner(op_name) for op_name in op_names}
+    assert not any("(" in owner or ")" in owner for owner in owners)
+    for scope in ("compressed_attention/shift", "compressed_attention/conv",
+                  "compressed_attention/mean", "compressed_attention/norm",
+                  "compressed_attention/rope",
+                  "compressed_attention/attention", "moe/router/down",
+                  "moe/router/average", "moe/router/mlp", "moe/dispatch",
+                  "moe/experts", "moe/combine", "rms_norm"):
+        for phase in ("forward", "backward"):
+            assert any(f"/{scope}/" in scopes.owner(op_name) + "/"
+                       and scopes.phase(op_name) == phase
+                       for op_name in op_names), (scope, phase)
+    # the first layer's router has no state to average
+    assert not any("sparse_experts_0/moe/router/average" in owner
+                   for owner in owners)
+    assert "ragged-dot" not in text
