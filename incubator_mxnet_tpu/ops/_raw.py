@@ -1411,11 +1411,27 @@ def _rung_rows(top_k, capacity, x2, prob, order, sizes):
             live[:, None], rows)
 
 
-def _sum_by_token(rows, picked, top_k, tokens):
-    """(tokens, D) float32: row t sums the `rows` of token t's assignments,
-    `picked`. A row an assignment is a gather token by token (on the chip
-    3.4 ms for the Mellum2 cell's 65536 rows, which cost 7.7 to add); fewer
-    are added where they belong (1.7 ms for 10240: PERF.md, PR 29)."""
+def _sum_by_token(rows, picked, top_k, tokens, count, weight=None,
+                  kernel=False, dtype=jnp.float32):
+    """(tokens, D): row t sums the first `count` of `rows` that are token
+    t's assignments (`picked`), each times its float32 `weight` where one is
+    given, in float32; the rows past `count` are never added. The Pallas
+    kernel (ops/pallas/token_sum.py, where `kernel` says so: ops/select.py
+    `sum_by_token`) reads the live rows alone and writes each sum once, in
+    `dtype`: on the chip 0.27 ms for the Mellum2 cell's 10240 rows, 0.81
+    for 65536 rows with 28406 live. XLA masks every row and adds it where
+    it belongs (1.73 ms), or, with a row an assignment, gathers them token
+    by token (5.24 ms: PERF.md, PR 35), in float32."""
+    if kernel:
+        from .pallas import token_sum
+        return token_sum.sum_by_token(rows, picked // top_k, count, tokens,
+                                      weight, out_dtype=dtype)
+    live = (jnp.arange(rows.shape[0]) < count)[:, None]
+    if weight is None:
+        rows = jnp.where(live, rows, jnp.zeros((), rows.dtype))
+    else:
+        rows = jnp.where(live, rows.astype(jnp.float32) * weight[:, None],
+                         0.0)
     if picked.shape[0] == tokens * top_k:
         mine = rows[jnp.argsort(picked)].reshape(tokens, top_k, -1)
         return jnp.sum(mine, axis=1, dtype=jnp.float32)
@@ -1424,26 +1440,26 @@ def _sum_by_token(rows, picked, top_k, tokens):
 
 
 @jax.named_scope("moe")
-def _rung_fwd(top_k, capacity, kernel, x2, prob, order, sizes, gate, up,
-              down):
+def _rung_fwd(top_k, capacity, kernel, summed, x2, prob, order, sizes, gate,
+              up, down):
     """The held experts' weighted sum a token, on buffers of `capacity`
     rows. Rows beyond the groups hold whatever the kernels left: they are
-    masked with `where`, never multiplied away."""
+    never added (`_sum_by_token`), never multiplied away."""
     product = functools.partial(grouped_matmul, group_sizes=sizes,
                                 kernel=kernel)
-    picked, _, weight, live, rows = _rung_rows(top_k, capacity, x2, prob,
-                                               order, sizes)
+    picked, _, weight, _, rows = _rung_rows(top_k, capacity, x2, prob,
+                                            order, sizes)
     with jax.named_scope("experts"):
         out = product(_gated(product(rows, gate), product(rows, up)), down)
     with jax.named_scope("combine"):
-        mine = jnp.where(live, out.astype(jnp.float32) * weight, 0.0)
-        y = _sum_by_token(mine, picked, top_k, x2.shape[0])
+        y = _sum_by_token(out, picked, top_k, x2.shape[0], jnp.sum(sizes),
+                          weight[:, 0], summed, x2.dtype)
     return y.astype(x2.dtype)
 
 
 @jax.named_scope("moe")
-def _rung_bwd(top_k, capacity, kernel, x2, prob, order, sizes, gate, up,
-              down, g):
+def _rung_bwd(top_k, capacity, kernel, summed, x2, prob, order, sizes, gate,
+              up, down, g):
     """Gradients of `_rung_fwd` for (x2, prob, gate, up, down): each gather
     transposed as a sum by token and each sum as a gather. The rows are
     gathered and the first two products made again: no residual has a
@@ -1475,16 +1491,16 @@ def _rung_bwd(top_k, capacity, kernel, x2, prob, order, sizes, gate, up,
         d_prob = jnp.zeros((prob.size,), prob.dtype).at[picked].set(
             d_weight, unique_indices=True)
     with jax.named_scope("dispatch"):
-        d_x2 = _sum_by_token(jnp.where(live, d_rows, jnp.zeros((), g.dtype)),
-                             picked, top_k, x2.shape[0])
+        d_x2 = _sum_by_token(d_rows, picked, top_k, x2.shape[0],
+                             jnp.sum(sizes), None, summed, x2.dtype)
     return (d_x2.astype(x2.dtype), d_prob.reshape(prob.shape), d_gate, d_up,
             d_down)
 
 
 # the inner `jit`: traced and lowered once for every layer of these shapes
-# and this choice of kernel, not once a layer (the Mellum2 cell has four)
-@functools.partial(jax.jit, static_argnums=(0, 1, 2, 3))
-def _on_rung(body, top_k, ladder, kernel, *operands):
+# and this choice of kernels, not once a layer (the Mellum2 cell has four)
+@functools.partial(jax.jit, static_argnums=(0, 1, 2, 3, 4))
+def _on_rung(body, top_k, ladder, kernel, summed, *operands):
     """`body` at the smallest capacity of `ladder` that holds the live
     rows, chosen on the device."""
     # the barrier keeps XLA from cloning what reads the result into every
@@ -1494,32 +1510,34 @@ def _on_rung(body, top_k, ladder, kernel, *operands):
     # apart, 5.7% without it, limit 5: ROADMAP.md, Queue 3, item 3)
     return lax.optimization_barrier(lax.switch(
         row_capacity(jnp.sum(operands[3]), ladder),
-        [functools.partial(body, top_k, rung, kernel) for rung in ladder],
+        [functools.partial(body, top_k, rung, kernel, summed)
+         for rung in ladder],
         *operands))
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2))
-def _routed(top_k, ladder, kernel, *operands):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2, 3))
+def _routed(top_k, ladder, kernel, summed, *operands):
     """Dispatch, experts and combine of (x2, prob, order, sizes, gate, up,
     down) as ONE differentiable unit: forward and backward each switch on
-    the same rung and run the same product (`kernel`: jax runs a backward
-    rule after the scopes that ops/select.py reads have closed), and what
+    the same rung and run the same product and the same sum by token
+    (`kernel`, `summed`: jax runs a backward rule after the scopes that
+    ops/select.py reads have closed), and what
     passes between them has no rung's shape (autodiff of a `switch` would
     make every branch return every branch's residuals, zero-filled)."""
     # jax traces a backward rule under its operands' abstract mesh and a
     # forward under none: under the same one, megablox's `jit`ted kernels
     # are traced once for both (the backward makes products again)
     with jax.sharding.use_abstract_mesh(jax.typeof(operands[0]).sharding.mesh):
-        return _on_rung(_rung_fwd, top_k, ladder, kernel, *operands)
+        return _on_rung(_rung_fwd, top_k, ladder, kernel, summed, *operands)
 
 
-def _routed_bwd(top_k, ladder, kernel, operands, g):
+def _routed_bwd(top_k, ladder, kernel, summed, operands, g):
     d_x2, d_prob, *d_weights = _on_rung(_rung_bwd, top_k, ladder, kernel,
-                                        *operands, g)
+                                        summed, *operands, g)
     return (d_x2, d_prob, None, None, *d_weights)
 
 
-_routed.defvjp(lambda *args: (_routed(*args), args[3:]), _routed_bwd)
+_routed.defvjp(lambda *args: (_routed(*args), args[4:]), _routed_bwd)
 
 
 def gated_ffn(x, gate, up, down):
@@ -1625,7 +1643,9 @@ def sparse_experts(x, router, gate, up, down, top_k, first=0,
     if len(ladder) == 1:
         y = _every_row(top_k, kernel, *operands[:3], held, *operands[3:])
     else:
-        y = _routed(top_k, ladder, kernel, *operands)
+        summed = _sel.sum_by_token(
+            jax.ShapeDtypeStruct((ladder[-1], d), x.dtype), tokens)
+        y = _routed(top_k, ladder, kernel, summed, *operands)
     return y.reshape(*lead, d), load
 
 

@@ -47,6 +47,10 @@ gated_delta_rule pallas enabled; heads of dk and dv both multiples of
 grouped_matmul   pallas enabled; rows, contraction and columns all
                  multiples of 128 (the Mosaic grouped matmul's tiles);
                  else `jax.lax.ragged_dot`
+sum_by_token     pallas enabled; the rows' width a multiple of 128; rows
+                 bfloat16 or float32; a column block of the tokens'
+                 float32 sums fits VMEM (ops/pallas/token_sum.py `plan`);
+                 else XLA's scatter-add
 layer_norm       pallas enabled; normalized axis is the LAST axis;
                  1-D gamma; on real TPU the width is 128-lane aligned
 scale_shift_act  pallas enabled; channels-last input (the BatchNorm+ReLU
@@ -70,8 +74,8 @@ import numpy as np
 from .. import profiler as _prof
 
 __all__ = ["flash_attention", "gated_delta_rule", "grouped_matmul",
-           "layer_norm", "scale_shift_act", "conv_bn_relu", "capture",
-           "quiet", "partitioned", "selection_table"]
+           "sum_by_token", "layer_norm", "scale_shift_act", "conv_bn_relu",
+           "capture", "quiet", "partitioned", "selection_table"]
 
 _tls = threading.local()
 
@@ -188,6 +192,26 @@ def grouped_matmul(lhs, rhs) -> bool:
     return _decide("grouped_matmul", True, "ok")
 
 
+def sum_by_token(rows, tokens) -> bool:
+    """Qualify the Pallas sum by token (ops/pallas/token_sum.py: a column
+    block of the `tokens` float32 sums resident in VMEM while the live rows
+    stream through) for the sparse experts' rows (capacity, D), the
+    combine in the forward and the rows' gradient in the backward."""
+    if not _open("sum_by_token"):
+        return False
+    d, dtype = rows.shape[1], np.dtype(rows.dtype).name
+    if d % 128:
+        return _decide("sum_by_token", False, f"width {d} not % 128")
+    if dtype not in ("bfloat16", "float32"):
+        return _decide("sum_by_token", False, f"dtype {dtype}")
+    from .pallas import token_sum
+    if token_sum.plan(tokens, rows.shape[0], d, rows.dtype,
+                      rows.dtype) is None:
+        return _decide("sum_by_token", False,
+                       f"{tokens} x 128 sums do not fit VMEM")
+    return _decide("sum_by_token", True, "ok")
+
+
 def gated_delta_rule(dk, dv, dtype) -> bool:
     """Qualify the Pallas kernels of the gated delta rule's chunked scan
     (ops/pallas/gated_delta_rule.py: a chunk's tiles and the state stay in
@@ -273,6 +297,8 @@ def selection_table():
         "gated_delta_rule": ("heads of dk and dv % 128 == 0; bfloat16 or "
                              "float32"),
         "grouped_matmul": "rows, contraction and columns % 128 == 0",
+        "sum_by_token": ("width % 128 == 0; bfloat16 or float32; a column "
+                         "block of the sums fits VMEM"),
         "layer_norm": "last-axis, 1-D gamma; TPU: width % 128 == 0",
         "scale_shift_act": "channels-last; TPU: channels % 128 == 0",
         "conv_bn_relu": ("inference BN, NHWC, ungrouped/undilated; "

@@ -322,11 +322,16 @@ def test_sparse_experts_keeps_no_residual_a_rung(one_chip, no_compile_cache,
                                                  record_property):
     """One expert layer of the Mellum2 cell, forward and backward (8192
     tokens of 2304, top-8 of 64 experts, 8 held): a `conditional` each way
-    with a branch a rung, eleven grouped products a rung, and temporaries
-    that do not grow with the ladder. Autodiff of the `switch` would make
-    every branch return every branch's residuals, zero-filled (3,856,771,072
-    for this layer); what passes from the forward to the backward instead
-    is its operands. The top rung's own buffers set the size."""
+    with a branch a rung, eleven grouped products a rung and two sums by
+    token (`sum_by_token` under `moe/combine` forward, `moe/dispatch`
+    backward, both owned by `moe`, where `moe_ms.train` counts them), and
+    temporaries that do not grow with the ladder. Autodiff of the `switch`
+    would make every branch return every branch's residuals, zero-filled
+    (3,856,771,072 for this layer); what passes from the forward to the
+    backward instead is its operands. The top rung's own buffers set the
+    size."""
+    import re
+
     from incubator_mxnet_tpu.ops import _raw
 
     # the selection asks the platform, and the platform here is the CPU
@@ -349,7 +354,11 @@ def test_sparse_experts_keeps_no_residual_a_rung(one_chip, no_compile_cache,
     assert text.count(" conditional(") == 2
     calls = _custom_calls(text)
     assert sum("tgmm" in c for c in calls) == 3 * len(ladder)
-    assert len(calls) == 11 * len(ladder) and "ragged-dot" not in text
+    assert len(calls) == 13 * len(ladder) and "ragged-dot" not in text
+    # no float32 (tokens, D) sum is a scatter-add any more
+    assert not re.search(r"= f32\[8192,2304\]\S* scatter\(", text)
+    assert _owned_sums(text) == {("forward", "combine"): len(ladder),
+                                 ("backward", "dispatch"): len(ladder)}
     temp = compiled.memory_analysis().temp_size_in_bytes
     record_property("temp_size_in_bytes", temp)
     assert temp < TEMP_OF_ONE_EXPERT_LAYER * 1.15, temp
@@ -357,6 +366,69 @@ def test_sparse_experts_keeps_no_residual_a_rung(one_chip, no_compile_cache,
 
 # as compiled when the ladder was written (AOT, PR 29)
 TEMP_OF_ONE_EXPERT_LAYER = 1_219_587_584
+
+
+def _compiled_and_reserved(lowered, dump):
+    """(the program compiled for the described chip, the HBM its runtime
+    reserves for the temporaries): the final buffer assignment's HBM
+    `preallocated-temp`, which is what the chip reserves to 16 KB (PERF.md,
+    section 7). The benchmark's memory check (benchmark/run.py
+    `reserved_is_filled`) wants it within 5% of `temp_size_in_bytes`."""
+    import glob
+    import os
+    import re
+    compiled = lowered.compile(compiler_options={
+        "xla_dump_to": str(dump), "xla_dump_hlo_as_text": True})
+    path = max(glob.glob(os.path.join(dump, "*buffer-assignment.txt")),
+               key=os.path.getsize)
+    with open(path) as f:
+        hbm = [int(size) for size, color in re.findall(
+            r"allocation \d+: size (\d+)(, color \d+)?, preallocated-temp",
+            f.read()) if not color]
+    return compiled, hbm[0]
+
+
+def _lowered_counting(step, tokens, monkeypatch):
+    """(the step lowered, the `sum_by_token` decisions its trace counted):
+    `FusedTrainStep.lower` keeps the selection quiet, the chip's first call
+    does not."""
+    import contextlib
+
+    from incubator_mxnet_tpu import profiler
+    from incubator_mxnet_tpu.ops import select
+    monkeypatch.setattr(select, "quiet", contextlib.nullcontext)
+    before = dict(profiler.counters())
+    lowered = step.lower(tokens, tokens)
+    return lowered, {k.split("/")[-1]: v - before.get(k, 0)
+                     for k, v in profiler.counters().items()
+                     if "sum_by_token" in k and v != before.get(k, 0)}
+
+
+def _reserved_gap(compiled, reserved):
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    return abs(temp - reserved) / temp
+
+
+def _owned_sums(text):
+    """The sums by token of a compiled step: `sum_by_token` under
+    `moe/combine` in the forward and `moe/dispatch` in the backward, a pair
+    a rung of every layer, each owned by `moe` (so in `moe_ms.train`)."""
+    import os
+    import re
+    import sys
+    sys.path.insert(0, os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmark"))
+    from lib import scopes              # the benchmark's reader of owners
+    found = {}
+    for line in _custom_calls(text):
+        if re.match(r"\s*(?:ROOT )?%sum_by_token[.\d]* = ", line):
+            name = re.search(r'op_name="([^"]*)"', line).group(1)
+            parts = scopes.owner(name).split("/")
+            assert "moe" in parts, name
+            key = (scopes.phase(name), parts[parts.index("moe") + 1])
+            found[key] = found.get(key, 0) + 1
+    return found
+
 
 LN = (((8192, 768), BF16), ((768,), BF16), ((768,), BF16))
 
@@ -456,6 +528,49 @@ def test_lm_train_step(topo, no_compile_cache, monkeypatch, axes, mode):
     assert ("all-reduce(" in text) == (not one_chip)
 
 
+def test_dense_steps_never_ask_for_the_sum_by_token(topo, no_compile_cache,
+                                                    monkeypatch):
+    """The GPT-2 and ResNet-50 cells' steps run no expert layer: the LM's
+    step compiled for the chip and ResNet-50's lowered for it ask ops/
+    select.py's row `sum_by_token` nothing and hold no such kernel, so the
+    row leaves their programs as they were (their lowered steps at full
+    width equal the parent's string for string: PERF.md, PR 35)."""
+    import importlib.util
+    import json
+    import os
+
+    import numpy as np
+
+    from incubator_mxnet_tpu import nd
+    from incubator_mxnet_tpu.ops import select
+    from incubator_mxnet_tpu.parallel import FusedTrainStep, make_mesh
+
+    asked = []
+    monkeypatch.setattr(select, "sum_by_token",
+                        lambda *a: asked.append(a) or False)
+    texts = [_lm_step_text(topo, monkeypatch, {"dp": 1}, "dp")]
+    configs = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmark", "configs")
+    with open(os.path.join(configs, "resnet50_v1.json")) as f:
+        doc = json.load(f)
+    doc.update(doc.pop("rehearse"))
+    spec = importlib.util.spec_from_file_location(
+        "resnet50_config", os.path.join(configs, "resnet50_v1.py"))
+    model = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(model)
+    step = FusedTrainStep(model.net(doc, 1), model.loss(doc),
+                          model.optimizer(doc),
+                          mesh=make_mesh({"dp": 1}, topo.devices[:1]),
+                          sharding="dp")
+    x = nd.array(np.zeros((2, 64, 64, 3), np.float32)).astype("bfloat16")
+    y = nd.array(np.zeros((2,), np.float32))
+    texts.append(step.lower(x, y).as_text())
+    assert not asked
+    # (the compiled text names this test among its stack frames)
+    assert not [line for text in texts for line in text.splitlines()
+                if "custom_call" in line and "sum_by_token" in line]
+
+
 KERNEL_NAMES = {"flash_attention_fwd": "attention",
                 "flash_attention_bwd": "attention",
                 "layer_norm_fwd": "layer_norm"}
@@ -496,7 +611,8 @@ def test_lm_train_step_kernels_are_named_and_owned(topo, no_compile_cache,
                if "flash_attention" in line)
 
 
-def test_mellum2_cell_train_step(topo, no_compile_cache, monkeypatch):
+def test_mellum2_cell_train_step(topo, no_compile_cache, monkeypatch,
+                                 tmp_path):
     """The FusedTrainStep program of the benchmark's Mellum2 cell at its
     published widths and its 1 x 8192 tokens, one sliding and one full layer
     of the period of four (half the cell's depth: the other two repeat the
@@ -531,10 +647,15 @@ def test_mellum2_cell_train_step(topo, no_compile_cache, monkeypatch):
                           mesh=make_mesh({"dp": 1}, topo.devices[:1]),
                           sharding="dp")
     tokens = nd.array(np.zeros((1, 8192), np.int32))
-    compiled = step.lower(tokens, tokens).compile()
+    lowered, asked = _lowered_counting(step, tokens, monkeypatch)
+    # the row is asked once a sparse layer, as on the chip
+    assert asked == {"pallas.selected.sum_by_token": 2}, asked
+    compiled, reserved = _compiled_and_reserved(lowered, tmp_path)
     held = compiled.memory_analysis()
     assert (held.argument_size_in_bytes + held.temp_size_in_bytes
             < 15 * 2 ** 30)
+    # AOT, PR 35: 4.16% (the parent 4.18%)
+    assert _reserved_gap(compiled, reserved) < 0.05
     text = compiled.as_text()
     kernels = {}
     for line in _custom_calls(text):
@@ -542,7 +663,8 @@ def test_mellum2_cell_train_step(topo, no_compile_cache, monkeypatch):
                         line).group(1)
         kernels[name] = kernels.get(name, 0) + 1
         op_name = re.search(r'op_name="([^"]*)"', line).group(1)
-        scope = "attention" if name.startswith("flash") else "moe/experts"
+        scope = ("attention" if name.startswith("flash") else "moe"
+                 if name == "sum_by_token" else "moe/experts")
         assert f"/{scope}/" in op_name, line[:160]
     # a rung of a layer: three products forward; two made again, three for
     # the rows' gradient and three `tgmm` for the weights' in the backward
@@ -550,7 +672,10 @@ def test_mellum2_cell_train_step(topo, no_compile_cache, monkeypatch):
     rungs = len(_raw.row_capacities(8192 * 8, 8, 64))
     assert kernels == {"flash_attention_fwd": 2, "flash_attention_bwd": 2,
                        "gmm": 2 * 8 * rungs,
-                       "tgmm": 2 * 3 * rungs}
+                       "tgmm": 2 * 3 * rungs,
+                       "sum_by_token": 2 * 2 * rungs}
+    assert _owned_sums(text) == {("forward", "combine"): 2 * rungs,
+                                        ("backward", "dispatch"): 2 * rungs}
     assert text.count(" conditional(") == 2 * 2
     for scope in ("rms_norm", "rope", "moe/router", "moe/dispatch",
                   "moe/experts", "moe/combine"):
@@ -559,7 +684,7 @@ def test_mellum2_cell_train_step(topo, no_compile_cache, monkeypatch):
 
 
 def test_kimi_linear_cell_train_step(topo, no_compile_cache, monkeypatch,
-                                     record_property):
+                                     record_property, tmp_path):
     """The FusedTrainStep program of the benchmark's Kimi-Linear cell at its
     published widths and its 1 x 8192 tokens, the leading KDA + dense layer
     and the MLA + experts layer (two of the cell's five: the other three
@@ -599,11 +724,16 @@ def test_kimi_linear_cell_train_step(topo, no_compile_cache, monkeypatch,
                           mesh=make_mesh({"dp": 1}, topo.devices[:1]),
                           sharding="dp")
     tokens = nd.array(np.zeros((1, 8192), np.int32))
-    compiled = step.lower(tokens, tokens).compile()
+    lowered, asked = _lowered_counting(step, tokens, monkeypatch)
+    # the row is asked once a sparse layer, as on the chip
+    assert asked == {"pallas.selected.sum_by_token": 1}, asked
+    compiled, reserved = _compiled_and_reserved(lowered, tmp_path)
     held = compiled.memory_analysis()
     record_property("temp_size_in_bytes", held.temp_size_in_bytes)
     assert (held.argument_size_in_bytes + held.temp_size_in_bytes
             < 15 * 2 ** 30)
+    # AOT, PR 35: 0.35% (the parent 1.05%)
+    assert _reserved_gap(compiled, reserved) < 0.05
     text = compiled.as_text()
     kernels = {}
     for line in _custom_calls(text):
@@ -613,7 +743,7 @@ def test_kimi_linear_cell_train_step(topo, no_compile_cache, monkeypatch,
         op_name = re.search(r'op_name="([^"]*)"', line).group(1)
         scope = ("latent_attention/attention" if kernel.startswith("flash")
                  else "linear_attention/scan" if kernel.startswith("gated")
-                 else "moe/experts")
+                 else "moe" if kernel == "sum_by_token" else "moe/experts")
         assert f"/{scope}/" in op_name, line[:160]
         if kernel.startswith("gated"):
             assert ("transpose(" in op_name) == kernel.endswith("_bwd")
@@ -626,7 +756,10 @@ def test_kimi_linear_cell_train_step(topo, no_compile_cache, monkeypatch,
     assert rungs == 2 and _raw.row_capacities(8192 * 8, 8, 256)[0] == 2560
     assert kernels == {"flash_attention_fwd": 1, "flash_attention_bwd": 1,
                        "gated_delta_rule_fwd": 1, "gated_delta_rule_bwd": 1,
-                       "gmm": 8 * rungs, "tgmm": 3 * rungs}
+                       "gmm": 8 * rungs, "tgmm": 3 * rungs,
+                       "sum_by_token": 2 * rungs}
+    assert _owned_sums(text) == {("forward", "combine"): rungs,
+                                        ("backward", "dispatch"): rungs}
     import sys
     sys.path.insert(0, os.path.dirname(configs))
     from lib import scopes              # the benchmark's reader of owners
@@ -648,7 +781,7 @@ def test_kimi_linear_cell_train_step(topo, no_compile_cache, monkeypatch,
 
 
 def test_zaya1_cell_train_step(topo, no_compile_cache, monkeypatch,
-                               record_property):
+                               record_property, tmp_path):
     """The FusedTrainStep program of the benchmark's ZAYA1 cell at its
     published widths and its 1 x 8192 tokens, layers 0 and 1 (of the cell's
     six alike: the first has no router state to average, the second has):
@@ -685,12 +818,18 @@ def test_zaya1_cell_train_step(topo, no_compile_cache, monkeypatch,
                           mesh=make_mesh({"dp": 1}, topo.devices[:1]),
                           sharding="dp")
     tokens = nd.array(np.zeros((1, 8192), np.int32))
-    compiled = step.lower(tokens, tokens).compile()
+    lowered, asked = _lowered_counting(step, tokens, monkeypatch)
+    # the row is asked once a sparse layer, as on the chip
+    assert asked == {"pallas.selected.sum_by_token": 2}, asked
+    compiled, reserved = _compiled_and_reserved(lowered, tmp_path)
     held = compiled.memory_analysis()
     record_property("argument_size_in_bytes", held.argument_size_in_bytes)
     record_property("temp_size_in_bytes", held.temp_size_in_bytes)
     assert (held.argument_size_in_bytes + held.temp_size_in_bytes
             < 15 * 2 ** 30)
+    # AOT, PR 35: 4.25% (the parent 5.76% at these two layers; the cell's
+    # five read 1.68%)
+    assert _reserved_gap(compiled, reserved) < 0.05
     text = compiled.as_text()
     kernels = {}
     for line in _custom_calls(text):
@@ -699,7 +838,8 @@ def test_zaya1_cell_train_step(topo, no_compile_cache, monkeypatch,
         kernels[kernel] = kernels.get(kernel, 0) + 1
         op_name = re.search(r'op_name="([^"]*)"', line).group(1)
         scope = ("compressed_attention/attention"
-                 if kernel.startswith("flash") else "moe/experts")
+                 if kernel.startswith("flash") else "moe"
+                 if kernel == "sum_by_token" else "moe/experts")
         assert f"/{scope}/" in op_name, line[:160]
         if kernel.startswith("flash"):
             # 8 query heads of 8192 x 128; keys and values of 2 heads
@@ -709,7 +849,11 @@ def test_zaya1_cell_train_step(topo, no_compile_cache, monkeypatch,
     assert ladder == (5120, 8192)
     assert kernels == {"flash_attention_fwd": 2, "flash_attention_bwd": 2,
                        "gmm": 2 * 8 * len(ladder),
-                       "tgmm": 2 * 3 * len(ladder)}
+                       "tgmm": 2 * 3 * len(ladder),
+                       "sum_by_token": 2 * 2 * len(ladder)}
+    assert _owned_sums(text) == {
+        ("forward", "combine"): 2 * len(ladder),
+        ("backward", "dispatch"): 2 * len(ladder)}
     import sys
     sys.path.insert(0, os.path.dirname(configs))
     from lib import scopes              # the benchmark's reader of owners
