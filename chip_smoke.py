@@ -88,35 +88,13 @@ SELECTED_BY_THE_CASES = ("flash_attention", "layer_norm", "scale_shift_act",
 # what every phase line carries
 # ---------------------------------------------------------------------------
 
-class _CompileLog:
-    """Counts what jax reports about compilation: every backend compile
-    request with its seconds, and the persistent cache's hits and writes
-    (a miss is counted when the fresh executable is written)."""
-
-    def __init__(self):
-        self.requests = 0
-        self.seconds = 0.0
-        self.hits = 0
-        self.misses = 0
-        jax.monitoring.register_event_listener(self._on_event)
-        jax.monitoring.register_event_duration_secs_listener(self._on_secs)
-
-    def _on_event(self, event, **_):
-        if event == "/jax/compilation_cache/cache_hits":
-            self.hits += 1
-        elif event == "/jax/compilation_cache/cache_misses":
-            self.misses += 1
-
-    def _on_secs(self, event, secs, **_):
-        if event == "/jax/core/compile/backend_compile_duration":
-            self.requests += 1
-            self.seconds += secs
-
-    def snapshot(self):
-        return (self.requests, self.seconds, self.hits, self.misses)
-
-
-_LOG = None
+def _compiles():
+    """The package's lifetime compile counters (profiler/build.py): backend
+    compile requests with their seconds, and the persistent cache's hits
+    and writes (a miss is counted when the fresh executable is written)."""
+    counted = mx.profiler.counters()
+    return tuple(counted.get("compile/" + name, 0) for name in
+                 ("requests", "seconds", "cache_hits", "cache_misses"))
 
 
 def _peak_bytes():
@@ -128,7 +106,7 @@ def _peak_bytes():
 def phase(name):
     """Run one phase; print its line; a failure ends the process."""
     rec = {"phase": name}
-    before = _LOG.snapshot()
+    before = _compiles()
     t0 = time.perf_counter()
     try:
         yield rec
@@ -136,7 +114,7 @@ def phase(name):
         traceback.print_exc()
         rec["ok"] = False
         raise SystemExit(_finish(ok=False, failed=name, rec=rec))
-    after = _LOG.snapshot()
+    after = _compiles()
     rec.update(ok=True, seconds=round(time.perf_counter() - t0, 3),
                compiles=after[0] - before[0],
                compile_s=round(after[1] - before[1], 3),
@@ -402,7 +380,7 @@ def _train(rec, step, x, y):
     first = float(step(x, y).asscalar())
     rec["first_step_s"] = round(time.perf_counter() - t0, 3)
     step(x, y).wait_to_read()
-    before = _LOG.requests
+    before = _compiles()[0]
     losses, ms = [], []
     for _ in range(STEPS):
         t0 = time.perf_counter()
@@ -410,7 +388,7 @@ def _train(rec, step, x, y):
         loss.wait_to_read()
         ms.append(round((time.perf_counter() - t0) * 1e3, 3))
         losses.append(float(loss.asscalar()))
-    rec["compiles_in_window"] = _LOG.requests - before
+    rec["compiles_in_window"] = _compiles()[0] - before
     rec["ms"] = ms
     rec["loss"] = [round(first, 4)] + [round(v, 4) for v in losses]
     if rec["compiles_in_window"]:
@@ -639,7 +617,6 @@ def phase_multichip(cfg):
 
 
 def main(argv=None):
-    global _LOG
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--tiny", action="store_true",
                     help="toy sizes for the CPU rehearsal; never ok off-TPU")
@@ -647,7 +624,6 @@ def main(argv=None):
                     help="four chips: only the sharded LM steps")
     args = ap.parse_args(argv)
     cfg = TINY if args.tiny else FULL
-    _LOG = _CompileLog()
     np.random.seed(SEED)
     on_tpu = phase_device(args.tiny)
     if not on_tpu:

@@ -110,8 +110,8 @@ class Block:
         return out
 
     def initialize(self, init=None, ctx=None, verbose=False, force_reinit=False):
-        self.collect_params().initialize(init=init, ctx=ctx, verbose=verbose,
-                                         force_reinit=force_reinit)
+        with _prof.build.span("mxtpu.setup.init", "init_s", outermost=True):
+            self.collect_params().initialize(init, ctx, verbose, force_reinit)
 
     def cast(self, dtype):
         # own params only; the child recursion covers descendants exactly once
@@ -379,7 +379,9 @@ class HybridBlock(Block):
         try:
             param_nds = [p.data() for p in params]
         except DeferredInitializationError:
-            with autograd.pause(False):  # one shape-inference pass, no aux drift
+            # one shape-inference pass, no aux drift
+            with autograd.pause(False), \
+                    _prof.build.span("mxtpu.setup.shapes", "shapes_s"):
                 super()._invoke(*args)
             params = list(self.collect_params().values())
             param_nds = [p.data() for p in params]
@@ -392,10 +394,10 @@ class HybridBlock(Block):
                 _flight.record("compile", "jit.compile:" + self.name,
                                {"signature": repr(sig)})
             if _prof._ACTIVE:
-                # jit compile-cache miss: the recorded span covers the
-                # trace/lower work in _build_cache; the device compile
-                # itself happens lazily inside the first dispatch, which
-                # the op hook times as the first `<name>_cachedop` event
+                # jit compile-cache miss: the recorded span covers
+                # _build_cache's abstract trace; the jitted forward's own
+                # trace, lowering and load come in its first dispatch and
+                # land in the `compile/*` counters (profiler/build.py)
                 _prof.counter("jit.cache_miss", "gluon").increment()
                 with _prof.Scope("jit.compile:" + self.name, "jit",
                                  sync=False):

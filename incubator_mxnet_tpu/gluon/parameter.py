@@ -18,6 +18,7 @@ from collections import OrderedDict
 import numpy as np
 
 from .. import autograd, initializer as _initializer
+from .. import profiler as _prof
 from ..context import Context, current_context
 from ..ndarray import NDArray
 from .. import ndarray as nd
@@ -122,6 +123,7 @@ class Parameter:
         from ..ndarray import random as ndrandom
         key = ndrandom._key()
         raw = init_obj(key, self._shape, self.dtype)
+        _prof.build.made(raw)
         self._data = NDArray(raw, ctx=ctx)
         if self.grad_req != "null":
             self._data.attach_grad(self.grad_req)
@@ -133,7 +135,9 @@ class Parameter:
                 raise DeferredInitializationError(
                     f"Parameter {self.name}: shape still unknown {self._shape}")
             init_obj, ctx = self._deferred
-            self._finish_init(init_obj, ctx)
+            with _prof.build.span("mxtpu.setup.init", "init_s",
+                                  outermost=True):
+                self._finish_init(init_obj, ctx)
 
     # -- access -----------------------------------------------------------
     def data(self, ctx=None) -> NDArray:
@@ -273,8 +277,10 @@ class ParameterDict:
         return len(self._params)
 
     def initialize(self, init=None, ctx=None, verbose=False, force_reinit=False):
-        for p in self.values():
-            p.initialize(init=None, ctx=ctx, default_init=init, force_reinit=force_reinit)
+        with _prof.build.span("mxtpu.setup.init", "init_s", outermost=True):
+            for p in self.values():
+                p.initialize(init=None, ctx=ctx, default_init=init,
+                             force_reinit=force_reinit)
 
     def zero_grad(self):
         for p in self.values():

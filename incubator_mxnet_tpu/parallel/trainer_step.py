@@ -204,32 +204,32 @@ class FusedTrainStep:
         # persistent-compile-cache integrity canary (runtime/cache_guard):
         # this jaxlib has mis-deserialized donated fused-step executables
         # written by a previous process (PR 4); the canary validates the
-        # cache READ path once per process and disables the cache on
-        # corruption instead of letting the step train on garbage
+        # cache READ path once per process and disables it on corruption
         from ..runtime import cache_guard as _cg
         _cg.check()
-        # 'auto' defaults (Dense kernels / Embedding tables onto the
-        # model axis) are resolved EPHEMERALLY — the net's own
-        # annotations are never mutated, so a later sharding='dp' build
-        # of the same net stays replicated
+        # 'auto' defaults (Dense kernels / Embedding tables onto the model
+        # axis) are resolved EPHEMERALLY — the net's own annotations are
+        # never mutated: a later sharding='dp' build stays replicated
         self._auto_specs = (_sharding.auto_specs(self.net)
                             if self.sharding == "auto" else {})
         # one eager pass completes deferred shapes
-        try:
-            all_params = list(self.net.collect_params().values())
-            for p in all_params:
-                p.data()
-        except Exception:
-            with autograd.pause(False):
-                self.net(x)
-            all_params = list(self.net.collect_params().values())
+        with _prof.build.span("mxtpu.setup.shapes", "shapes_s"):
+            try:
+                all_params = list(self.net.collect_params().values())
+                for p in all_params:
+                    p.data()
+            except Exception:
+                with autograd.pause(False):
+                    self.net(x)
+                all_params = list(self.net.collect_params().values())
         self.params = all_params
         self.train_idx = [i for i, p in enumerate(all_params) if p.grad_req != "null"]
         self.aux_idx = [i for i, p in enumerate(all_params) if p.grad_req == "null"]
         self.lr_mults = [all_params[i].lr_mult for i in self.train_idx]
         self.wd_mults = [all_params[i].wd_mult for i in self.train_idx]
-        self._states = [self.optimizer.create_state_multi_precision(
-            i, all_params[i].data()._data) for i in self.train_idx]
+        with _prof.build.span("mxtpu.setup.states", "states_s"):
+            self._states = [self.optimizer.create_state_multi_precision(
+                i, all_params[i].data()._data) for i in self.train_idx]
         self._build(x, y)
 
     def _build(self, x, y):
@@ -533,11 +533,11 @@ class FusedTrainStep:
                          rescale, xb, yb),
                         name="fused_step", dtype=xb.dtype, kind="train_step",
                         mesh=self.mesh, mode=self.sharding)
-            # the donating dispatch ENQUEUE is serialized against any
-            # in-flight prefetcher device_put (io.pipeline.TRANSFER_GATE) —
-            # the enqueue-ordering half of the PR 14 flake fix; the other
-            # half is the pipeline's consumer-thread put on XLA:CPU. The
-            # guarded region is the async enqueue, not the step execution.
+            # the donating dispatch ENQUEUE is serialized against in-flight
+            # prefetcher device_puts (io.pipeline.TRANSFER_GATE): one half of
+            # a donation race's fix; the other is the pipeline's put on XLA:CPU
+            began = None if _prof.build.closed else \
+                _prof.build.dispatch_began(self._jitted)
             try:
                 with _prof.Scope("mxtpu.step.enqueue", "trainer",
                                  sync=False), \
@@ -563,6 +563,10 @@ class FusedTrainStep:
             except Exception as e:  # noqa: BLE001 — re-raised unchanged
                 _memscope_oom(e, "fused_step", self._num_update)
                 raise
+            finally:
+                # until set-up's account closes (profiler/build.py)
+                if began is not None:
+                    _prof.build.dispatch_ended(began)
             with _prof.Scope("mxtpu.step.rebind", "trainer", sync=False):
                 self._rebind(new_train, new_aux, new_states)
         # fully-fused path: forward+backward+collective+update is ONE call
@@ -642,6 +646,8 @@ class FusedTrainStep:
                         kind="train_step", extra={"k": k}, mesh=self.mesh,
                         mode=self.sharding)
             # donation-vs-transfer serialization, same contract as __call__
+            began = None if _prof.build.closed else \
+                _prof.build.dispatch_began(self._jitted_k)
             try:
                 with _prof.Scope("mxtpu.step.enqueue", "trainer",
                                  sync=False), \
@@ -658,6 +664,9 @@ class FusedTrainStep:
             except Exception as e:  # noqa: BLE001 — re-raised unchanged
                 _memscope_oom(e, f"fused_step_k{k}", self._num_update)
                 raise
+            finally:
+                if began is not None:
+                    _prof.build.dispatch_ended(began)
             self._num_update += k
             self.optimizer.num_update = self._num_update
             with _prof.Scope("mxtpu.step.rebind", "trainer", sync=False):

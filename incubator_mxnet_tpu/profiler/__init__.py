@@ -372,3 +372,8 @@ def device_memory_stats(device=None):
                 break
     out["available"] = True
     return out
+
+
+# set-up's account and the package's one compile listener (build.py); last,
+# since its spans are Scopes
+from . import build  # noqa: E402
