@@ -19,13 +19,14 @@ TPU-first design decisions:
 """
 from __future__ import annotations
 
+import jax.numpy as jnp
 import numpy as np
 
 from .. import ndarray as nd
 from .. import ops
 from ..gluon import nn
 from ..gluon.block import HybridBlock
-from ..gluon.loss import SoftmaxCrossEntropyLoss
+from ..ops import _raw
 from .bert import MultiHeadAttentionCell, PositionwiseFFN
 
 __all__ = ["TransformerLM", "TransformerLMCell", "CausalSelfAttention",
@@ -269,8 +270,17 @@ def transformer_lm_base(vocab_size=50257, **kwargs):
 
 def lm_loss(logits, targets):
     """Shifted causal-LM loss: per-position CE of logits[:, :-1] vs
-    targets[:, 1:], shape (B*(L-1),) — gluon loss convention; call
-    .mean() for the scalar."""
-    ce = SoftmaxCrossEntropyLoss()
-    v = logits.shape[-1]
-    return ce(logits[:, :-1].reshape(-1, v), targets[:, 1:].reshape(-1))
+    targets[:, 1:], shape (B*(L-1),) in the logits' dtype — gluon loss
+    convention; call .mean() for the scalar.
+
+    The targets move, not the logits: the cross-entropy runs over the
+    (B, L, V) logits as the head wrote them, each position against the
+    next token (the last against its own, a loss never used, so its
+    gradient is exactly zero), and the last column of the small (B, L)
+    result is dropped. Slicing and flattening the logits would copy all of
+    them wherever L - 1 rows do not fill the chip's tiles."""
+    def shifted(x, t):
+        ce = _raw.softmax_cross_entropy(
+            x, jnp.concatenate([t[:, 1:], t[:, -1:]], axis=1))
+        return ce[:, :-1].reshape(-1)
+    return nd._apply(shifted, [logits, nd._as_nd(targets)], name="lm_loss")

@@ -567,12 +567,75 @@ def activation(x, act_type):
 # losses / classification heads
 # ---------------------------------------------------------------------------
 
+@jax.named_scope("cross_entropy")
 def softmax_cross_entropy(logits, labels, axis=-1, sparse_label=True):
-    logp = jax.nn.log_softmax(logits, axis=axis)
-    if sparse_label:
-        lab = labels.astype(jnp.int32)
-        return -jnp.take_along_axis(logp, jnp.expand_dims(lab, axis), axis=axis).squeeze(axis)
-    return -jnp.sum(labels * logp, axis=axis)
+    """-log softmax(logits)[label] over `axis`, or, with `sparse_label`
+    off, -sum(labels * log softmax(logits)) against a distribution of the
+    logits' shape.
+
+    Class ids (`sparse_label`) take a rule of their own, `_sparse_ce`: it
+    writes nothing of the logits' size in the forward and keeps only the
+    logits it was given and a float32 log-sum-exp a row (as its max and the
+    log of the sum beside it, so that logits far from 0 lose nothing).
+    Inside a program that GSPMD partitions over several devices
+    (ops/select.py `partitioned`) class ids keep jax's log-softmax and
+    gather, which GSPMD splits as it always has: how it splits the rule
+    is not measured."""
+    from . import select as _sel
+    if not sparse_label or _sel.meshed():
+        logp = jax.nn.log_softmax(logits, axis=axis)
+        if sparse_label:
+            lab = jnp.expand_dims(labels.astype(jnp.int32), axis)
+            return -jnp.take_along_axis(logp, lab, axis=axis).squeeze(axis)
+        return -jnp.sum(labels * logp, axis=axis)
+    axis = axis % logits.ndim
+    n = logits.shape[axis]
+    label = labels.astype(jnp.int32)
+    # a negative id counts from the end and one outside [-n, n) reads NaN,
+    # as take_along_axis has them
+    label = jnp.where(label < 0, label + n, label)
+    loss = _sparse_ce(axis, logits, label)
+    return jnp.where((label >= 0) & (label < n), loss, jnp.nan)
+
+
+def _sparse_ce_fwd(axis, x, label):
+    """(m - x[label]) + log sum exp(x - m) a row, m the row's max: reductions
+    over x alone, in float32, the label's logit a masked sum beside the sum
+    of exponentials. Not a gather: XLA fuses no producer into a gather's
+    operand, so where x is the head's output widened to float32 it would
+    write that copy out. The loss is rounded to x's dtype at the end."""
+    xf = x.astype(jnp.float32)
+    m = jnp.max(x, axis=axis, keepdims=True).astype(jnp.float32)
+    log_sum = jnp.log(jnp.sum(jnp.exp(xf - m), axis=axis, keepdims=True))
+    picked = jnp.sum(jnp.where(_onehot(x.shape, axis, label), xf, 0.0),
+                     axis=axis, keepdims=True)
+    loss = (m - picked) + log_sum
+    return loss.squeeze(axis).astype(x.dtype), (x, label, m, log_sum)
+
+
+def _onehot(shape, axis, label):
+    """An iota compared with the label, never a scatter."""
+    return (lax.broadcasted_iota(jnp.int32, shape, axis)
+            == jnp.expand_dims(label, axis))
+
+
+def _sparse_ce_bwd(axis, res, g):
+    """g * (softmax(x) - onehot(label)) in one elementwise pass over x, in
+    float32 and rounded to x's dtype."""
+    x, label, m, log_sum = res
+    onehot = _onehot(x.shape, axis, label)
+    grad = (jnp.exp(x.astype(jnp.float32) - m - log_sum)
+            - onehot.astype(jnp.float32))
+    g = jnp.expand_dims(g, axis).astype(jnp.float32)
+    return (g * grad).astype(x.dtype), None
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _sparse_ce(axis, x, label):
+    return _sparse_ce_fwd(axis, x, label)[0]
+
+
+_sparse_ce.defvjp(_sparse_ce_fwd, _sparse_ce_bwd)
 
 
 def smooth_l1(x, scalar=1.0):

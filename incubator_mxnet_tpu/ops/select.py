@@ -75,7 +75,7 @@ from .. import profiler as _prof
 
 __all__ = ["flash_attention", "gated_delta_rule", "grouped_matmul",
            "sum_by_token", "layer_norm", "scale_shift_act", "conv_bn_relu",
-           "capture", "quiet", "partitioned", "selection_table"]
+           "capture", "quiet", "partitioned", "meshed", "selection_table"]
 
 _tls = threading.local()
 
@@ -135,6 +135,12 @@ class partitioned:
         return False
 
 
+def meshed() -> bool:
+    """Is this thread tracing ONE program that GSPMD partitions over more
+    than one device (see :class:`partitioned`)?"""
+    return getattr(_tls, "mesh_size", 1) > 1
+
+
 def _decide(kernel: str, ok: bool, reason: str) -> bool:
     if not getattr(_tls, "quiet", False):
         _prof.counter(
@@ -153,7 +159,7 @@ def _open(kernel: str) -> bool:
     from . import pallas as _pallas
     if not _pallas.enabled():
         return False
-    if getattr(_tls, "mesh_size", 1) > 1:
+    if meshed():
         return _decide(kernel, False, "multi-device GSPMD program")
     return True
 

@@ -1,9 +1,10 @@
 """The names the program compiles into a fused step (docs/profiler.md,
 "Names in a device trace"), held on the compiled program's own text: every
 operation has an owner (Gluon blocks, then the op scopes `attention`,
-`layer_norm`, `batch_norm`, then a Pallas kernel's name) and a phase. The
-grammar is the benchmark reader's, benchmark/lib/scopes.py: what the
-program says and what the reader understands are held together here."""
+`layer_norm`, `batch_norm`, `cross_entropy`, then a Pallas kernel's name)
+and a phase. The grammar is the benchmark reader's, benchmark/lib/
+scopes.py: what the program says and what the reader understands are held
+together here."""
 import os
 import re
 import sys
@@ -114,8 +115,8 @@ def _owners_by_phase(names):
 
 @pytest.mark.parametrize("pallas", ["0", "force"])
 @pytest.mark.parametrize("make,op_scopes", [
-    (_toy_lm, {"attention", "layer_norm"}),
-    (_toy_resnet, {"batch_norm"}),
+    (_toy_lm, {"attention", "layer_norm", "cross_entropy"}),
+    (_toy_resnet, {"batch_norm", "cross_entropy"}),
 ], ids=["transformer_lm", "resnet18_v1"])
 def test_every_operation_of_a_fused_step_has_an_owner(monkeypatch, make,
                                                       op_scopes, pallas):

@@ -571,6 +571,60 @@ def test_dense_steps_never_ask_for_the_sum_by_token(topo, no_compile_cache,
                 if "custom_call" in line and "sum_by_token" in line]
 
 
+def _entry_instructions(text):
+    """(result shapes, op_name) of each instruction of the ENTRY
+    computation that writes memory (fusions whole; no tuple element,
+    bitcast or parameter, which only name a buffer)."""
+    import re
+    body = text[text.index("\nENTRY "):]
+    body = body[:body.index("\n}")]
+    found = []
+    for line in body.splitlines()[1:]:
+        head = re.match(r"\s*(?:ROOT )?%[\w.\-]+ = (.*?) ([a-z][\w\-]*)\(",
+                        line)
+        if head and head.group(2) not in ("get-tuple-element", "bitcast",
+                                          "tuple", "parameter"):
+            shapes = [tuple(int(n) for n in dims.split(",") if n)
+                      for dims in re.findall(r"\[([\d,]*)\]", head.group(1))]
+            op_name = re.search(r'op_name="([^"]*)"', line)
+            found.append((shapes, op_name.group(1) if op_name else ""))
+    return found
+
+
+def test_lm_train_step_takes_the_loss_over_the_logits_as_written(
+        topo, no_compile_cache, monkeypatch):
+    """The shifted loss of the one-chip LM step (`lm_loss`) reads the
+    head's (B, L, V) logits where the head wrote them: no instruction
+    yields the (B (L-1), V) slice of them, and in the forward the loss
+    writes no array of the logits' size, so it keeps none for the backward
+    (its rule keeps the logits and a float32 log-sum-exp a row); the one
+    the head writes is its output. (The blocks' feed-forward is as wide as
+    this vocabulary: their arrays are theirs.)"""
+    import math
+    import os
+    import sys
+    sys.path.insert(0, os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmark"))
+    from lib import scopes              # the benchmark's reader of owners
+    b, length, vocab = 8, 128, 512      # _lm_step_text's batch and net
+    text = _lm_step_text(topo, monkeypatch, {"dp": 1}, "dp")
+    assert f"[{b * (length - 1)},{vocab}]" not in text
+    size = {b * length * vocab, b * (length - 1) * vocab}
+    written = [(scopes.owner(op_name).split("/"), shape)
+               for shapes, op_name in _entry_instructions(text)
+               for shape in shapes
+               if math.prod(shape) in size
+               and scopes.phase(op_name) == "forward"]
+    assert not [shape for owner, shape in written if "loss" in owner]
+    head = [shape for owner, shape in written
+            if len(owner) == 1 and owner[0].startswith("transformer_lm")]
+    assert head == [(b, length, vocab)], written
+    # the loss's forward is there, under its op scope
+    assert any("/cross_entropy/" in op_name
+               and scopes.phase(op_name) == "forward"
+               for _, op_name in _entry_instructions(text))
+
+
 KERNEL_NAMES = {"flash_attention_fwd": "attention",
                 "flash_attention_bwd": "attention",
                 "layer_norm_fwd": "layer_norm"}
