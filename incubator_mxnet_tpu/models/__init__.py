@@ -22,7 +22,7 @@ from .ssd import (SSD, SSDLoss, ssd_512_resnet18_v1, ssd_512_resnet50_v1,
 from .transformer_lm import (TransformerLM, lm_loss, transformer_lm_small,
                              transformer_lm_base)
 from .dlrm import DLRM, dlrm_loss, dlrm_small
-from .moe_lm import (MoeLM, MoeLMCell, GroupedQueryAttentionCell,
+from .moe_lm import (MoeLM, MoeLMCell, MTP, GroupedQueryAttentionCell,
                      LinearAttentionCell, LatentAttentionCell,
                      CompressedAttentionCell)
 

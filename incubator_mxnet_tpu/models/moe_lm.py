@@ -1,12 +1,14 @@
 """Decoder-only language model of the current sparse-expert families: RMS
 norm, a head of its own or the embedding table's, and layer by layer one of
 five sequence mixers (window or full attention over grouped key/value heads
-with rotary positions; gated delta-rule linear attention; latent attention
-without positions; attention inside a compressed latent with convolutions)
-and one of two feed-forwards (sparse experts, with the routers of the three
-families and a shared expert; a dense gated one) — built from a published
-`config.json`'s own keys (`layer_types`, `mlp_layer_types`,
-`rope_parameters`, `sliding_window`, `tie_word_embeddings` and the widths).
+with rotary positions; gated delta-rule linear attention; latent attention,
+with or without a query rank and rotated positions; attention inside a
+compressed latent with convolutions) and one of two feed-forwards (sparse
+experts, with the routers of the three families and a shared expert; a
+dense gated one), and optionally a multi-token-prediction module — built
+from a published `config.json`'s own keys (`layer_types`,
+`mlp_layer_types`, `rope_parameters`, `sliding_window`,
+`tie_word_embeddings`, `num_nextn_predict_layers` and the widths).
 
 A holder of an expert-parallel deployment builds the model with its share:
 `held=(first, count)` of every layer's experts (gluon.nn.SparseExperts
@@ -14,7 +16,8 @@ computes their part of the result and no other) and the rows of the
 vocabulary it holds as `vocab_size`. Training runs through
 `parallel.FusedTrainStep(net, loss, optimizer)` like any other model.
 
-forward(tokens): (B, L) int ids -> (B, L, vocab_size) logits.
+forward(tokens): (B, L) int ids -> (B, L, vocab_size) logits (with an MTP
+module, while training, a pair of them).
 """
 from __future__ import annotations
 
@@ -29,7 +32,7 @@ from ..gluon.block import HybridBlock
 from ..initializer import Initializer
 from ..ops._raw import rope_frequencies
 
-__all__ = ["MoeLM", "MoeLMCell", "GroupedQueryAttentionCell",
+__all__ = ["MoeLM", "MoeLMCell", "MTP", "GroupedQueryAttentionCell",
            "LinearAttentionCell", "LatentAttentionCell",
            "CompressedAttentionCell"]
 
@@ -177,37 +180,78 @@ class LinearAttentionCell(HybridBlock):
 
 
 class LatentAttentionCell(HybridBlock):
-    """Multi-head latent attention WITHOUT positions (`mla_use_nope`): q = h
-    Wq in heads of `nope_dim + rope_dim`; [c ; k_r] = h Wkv_a, a latent of
-    `kv_rank` and one part of `rope_dim` that every head's key shares;
-    [k_n ; v] = rms_norm(c) Wkv_b by head; head h's key is [k_n,h ; k_r],
-    nothing rotated; causal softmax(q k^T / sqrt(nope_dim + rope_dim)) v
-    over values of `v_dim`, then Wo. No bias. The down and up projections
-    run under the op scopes `latent_attention/kv_down` and `/kv_up`."""
+    """Multi-head latent attention (MLA, DeepSeek-V2/V3): q = h Wq in heads
+    of `nope_dim + rope_dim`, or with `q_rank` q = rms_norm(h Wq_a) Wq_b
+    through that rank; [c ; k_r] = h Wkv_a, a latent of `kv_rank` and one
+    part of `rope_dim` that every head's key shares; [k_n ; v] =
+    rms_norm(c) Wkv_b by head; head h's key is [k_n,h ; k_r]; causal
+    softmax(q k^T / sqrt(nope_dim + rope_dim)) v over values of `v_dim`,
+    then Wo. No bias.
+
+    `rope` (a section of a published `rope_parameters`) rotates the last
+    `rope_dim` channels of every query head and the shared k_r by position,
+    adjacent pairs together where `interleaved` (`rope_interleave`); None
+    rotates nothing (`mla_use_nope`). Op scopes: `latent_attention/q_down`
+    (Wq_a and its norm), `/q_up`, `/kv_down`, `/rope`, `/kv_up` (and the
+    keys joined), `/attention`; without a rank q = h Wq is outside them."""
 
     def __init__(self, units, num_heads, kv_rank, nope_dim, rope_dim, v_dim,
-                 epsilon=1e-5, weight_initializer=None, prefix=None,
-                 params=None):
+                 epsilon=1e-5, weight_initializer=None, q_rank=None,
+                 rope=None, interleaved=False, prefix=None, params=None):
         super().__init__(prefix, params)
         self._num_heads = num_heads
         self._dims = (kv_rank, nope_dim, rope_dim, v_dim)
-        init = weight_initializer
-        self.q = _dense(num_heads * (nope_dim + rope_dim), units, init)
+        self._q_rank = q_rank
+        self._rope = (None if rope is None
+                      else rope_frequencies(rope_dim, **rope))
+        self._interleaved = interleaved
+        init, wide = weight_initializer, num_heads * (nope_dim + rope_dim)
+        if q_rank is None:
+            self.q = _dense(wide, units, init)
+        else:
+            self.q_down = _dense(q_rank, units, init)
+            self.q_norm = nn.RMSNorm(epsilon, in_channels=q_rank)
+            self.q_up = _dense(wide, q_rank, init)
         self.kv_down = _dense(kv_rank + rope_dim, units, init)
         self.kv_norm = nn.RMSNorm(epsilon, in_channels=kv_rank)
         self.kv_up = _dense(num_heads * (nope_dim + v_dim), kv_rank, init)
         self.proj = _dense(units, num_heads * v_dim, init)
 
+    def _rotated(self, q, shared):
+        """q with the last `rope_dim` channels of every head rotated, and
+        the shared part (B, L, 1, rope_dim) rotated alike."""
+        _, nope_dim, rope_dim, _ = self._dims
+        heads = self._num_heads
+        b, length = q.shape[:2]
+        inv_freq, factor = self._rope
+        q = q.reshape(b, length, heads, nope_dim + rope_dim)
+        q_rope = ops.rope(
+            q[:, :, :, nope_dim:].reshape(b, length, heads * rope_dim),
+            inv_freq, heads, factor, interleaved=self._interleaved)
+        q = nd.concat(q[:, :, :, :nope_dim],
+                      q_rope.reshape(b, length, heads, rope_dim), dim=3)
+        shared = ops.rope(shared.reshape(b, length, rope_dim), inv_freq, 1,
+                          factor, interleaved=self._interleaved)
+        return (q.reshape(b, length, heads * (nope_dim + rope_dim)),
+                shared.reshape(b, length, 1, rope_dim))
+
     def forward(self, x):
         kv_rank, nope_dim, rope_dim, v_dim = self._dims
         heads = self._num_heads
         b, length = x.shape[:2]
-        q = self.q(x)
+        q = self.q(x) if self._q_rank is None else None
         with jax.named_scope("latent_attention"):
+            if q is None:
+                with jax.named_scope("q_down"):
+                    q = self.q_norm(self.q_down(x))
+                with jax.named_scope("q_up"):
+                    q = self.q_up(q)
             with jax.named_scope("kv_down"):
                 down = self.kv_down(x)
                 latent = self.kv_norm(down[:, :, :kv_rank])
                 shared = down[:, :, kv_rank:].reshape(b, length, 1, rope_dim)
+            if self._rope is not None:
+                q, shared = self._rotated(q, shared)
             with jax.named_scope("kv_up"):
                 up = self.kv_up(latent).reshape(b, length, heads,
                                                 nope_dim + v_dim)
@@ -287,11 +331,41 @@ class MoeLMCell(HybridBlock):
         return x + y
 
 
+class MTP(HybridBlock):
+    """One multi-token-prediction depth (DeepSeek-V3, arXiv:2412.19437
+    section 2.2): from the main stack's output h_i (before its final norm)
+    and the embedding of token t_(i+1), h'_i = eh_proj [enorm(Emb(t_(i+1)))
+    ; hnorm(h_i)], one more `block`, and the model's own head after `norm`:
+    logits for token t_(i+2). The last position has no t_L; id 0 stands in,
+    and causal attention keeps it from every other position. The embedding
+    and the head are the model's, handed to forward, so their gradients sum
+    both uses; every parameter here is shaped at construction."""
+
+    def __init__(self, units, block, epsilon=1e-6, prefix=None, params=None):
+        super().__init__(prefix, params)
+        self.enorm = nn.RMSNorm(epsilon, in_channels=units)
+        self.hnorm = nn.RMSNorm(epsilon, in_channels=units)
+        self.eh_proj = _dense(units, 2 * units, None)
+        self.block = block
+        self.norm = nn.RMSNorm(epsilon, in_channels=units)
+
+    def forward(self, h, tokens, embedding, head):
+        following = nd.concat(tokens[:, 1:], nd.zeros_like(tokens[:, :1]),
+                              dim=1)
+        x = self.eh_proj(nd.concat(self.enorm(embedding(following)),
+                                   self.hnorm(h), dim=2))
+        return head(self.norm(self.block(x)))
+
+
 class MoeLM(HybridBlock):
     """Token embedding (no scale, no position table), one `MoeLMCell` for
     each entry of `layer_types`, a final RMS norm and the vocabulary head:
     a `Dense` of its own, or with `tie_word_embeddings` the embedding table
-    transposed (its gradient then sums both uses).
+    transposed (its gradient then sums both uses). With
+    `num_nextn_predict_layers=1` an `MTP` module (block scope `mtp_N`) whose
+    block is built like the last layer's: forward then returns (logits,
+    the MTP's logits) while training, and the logits alone in predict mode,
+    where the module is not run.
 
     `layer_types[i]` names layer i's sequence mixer: "sliding_attention" or
     "full_attention" (`GroupedQueryAttentionCell`; `rope_parameters` has a
@@ -299,7 +373,9 @@ class MoeLM(HybridBlock):
     built from `linear_attention={num_heads, head_dim,
     short_conv_kernel_size}`, a published `linear_attn_config`),
     "latent_attention" (`LatentAttentionCell`, from `latent_attention=
-    {kv_lora_rank, qk_nope_head_dim, qk_rope_head_dim, v_head_dim}`) or
+    {kv_lora_rank, qk_nope_head_dim, qk_rope_head_dim, v_head_dim}` and,
+    where the family has them, `q_lora_rank` and `rope_interleave`, with
+    rotary positions where `rope_parameters["latent_attention"]` is given) or
     "hybrid" (`CompressedAttentionCell`, from `compressed_attention=
     {cca_time0, cca_time1}` and `rope_parameters["hybrid"]`, whose
     `partial_rotary_factor` says how much of a head is rotated).
@@ -316,8 +392,8 @@ class MoeLM(HybridBlock):
                  rms_norm_eps=1e-6, norm_topk_prob=True, mlp_layer_types=None,
                  hidden_size=None, linear_attention=None,
                  latent_attention=None, compressed_attention=None,
-                 router=None, tie_word_embeddings=False, prefix=None,
-                 params=None):
+                 router=None, tie_word_embeddings=False,
+                 num_nextn_predict_layers=0, prefix=None, params=None):
         super().__init__(prefix, params)
         rope_parameters = rope_parameters or {}
         mlp_layer_types = mlp_layer_types or ["sparse"] * len(layer_types)
@@ -325,9 +401,12 @@ class MoeLM(HybridBlock):
         sized = {"linear_attention": linear_attention,
                  "latent_attention": latent_attention,
                  "hybrid": compressed_attention}
-        self.embedding = nn.Embedding(vocab_size, units)
-        self.layers = []
-        for i, (kind, mlp) in enumerate(zip(layer_types, mlp_layer_types)):
+        if num_nextn_predict_layers not in (0, 1):
+            raise ValueError(f"num_nextn_predict_layers = "
+                             f"{num_nextn_predict_layers}; MoeLM builds one "
+                             f"MTP depth or none")
+
+        def cell(i, kind, mlp):
             if kind in ("sliding_attention", "full_attention"):
                 attention = GroupedQueryAttentionCell(
                     units, num_heads, num_kv_heads, head_dim,
@@ -348,7 +427,11 @@ class MoeLM(HybridBlock):
                     units, num_heads, latent_attention["kv_lora_rank"],
                     latent_attention["qk_nope_head_dim"],
                     latent_attention["qk_rope_head_dim"],
-                    latent_attention["v_head_dim"], rms_norm_eps)
+                    latent_attention["v_head_dim"], rms_norm_eps,
+                    q_rank=latent_attention.get("q_lora_rank"),
+                    rope=rope_parameters.get(kind),
+                    interleaved=latent_attention.get("rope_interleave",
+                                                     False))
             elif kind == "hybrid":
                 attention = CompressedAttentionCell(
                     units, num_heads, num_kv_heads, head_dim,
@@ -372,12 +455,24 @@ class MoeLM(HybridBlock):
                 ffn = nn.GatedFFN(units, hidden_size)
             else:
                 raise ValueError(f"mlp_layer_types[{i}] = {mlp!r}")
-            cell = MoeLMCell(attention, ffn, units, rms_norm_eps)
-            self.register_child(cell, f"layer{i}")
-            self.layers.append(cell)
+            return MoeLMCell(attention, ffn, units, rms_norm_eps)
+
+        self.embedding = nn.Embedding(vocab_size, units)
+        self.layers = []
+        for i, (kind, mlp) in enumerate(zip(layer_types, mlp_layer_types)):
+            self.layers.append(self.register_child(cell(i, kind, mlp),
+                                                   f"layer{i}"))
         self.norm = nn.RMSNorm(rms_norm_eps, in_channels=units)
         self.head = (None if tie_word_embeddings
                      else _dense(vocab_size, units, None))
+        self.mtp = (MTP(units, cell(len(layer_types), layer_types[-1],
+                                    mlp_layer_types[-1]), rms_norm_eps)
+                    if num_nextn_predict_layers else None)
+
+    def _head(self, h):
+        if self.head is None:
+            return nd.dot(h, self.embedding.weight.data(), transpose_b=True)
+        return self.head(h)
 
     def forward(self, tokens):
         h = self.embedding(tokens)
@@ -386,16 +481,17 @@ class MoeLM(HybridBlock):
             h = layer(h) if state is None else layer(h, state)
             if isinstance(h, tuple):
                 h, state = h
-        h = self.norm(h)
-        if self.head is None:
-            return nd.dot(h, self.embedding.weight.data(), transpose_b=True)
-        return self.head(h)
+        logits = self._head(self.norm(h))
+        if self.mtp is None or not autograd.is_training():
+            return logits
+        return logits, self.mtp(h, tokens, self.embedding, self._head)
 
     def read_load(self):
         """`nn.SparseExperts.read_load()` of every expert layer, in order of
-        depth; the counters keep the last layer's."""
-        return [layer.ffn.read_load() for layer in self.layers
-                if isinstance(layer.ffn, nn.SparseExperts)]
+        depth, the MTP block's last; the counters keep the last layer's."""
+        cells = self.layers + ([] if self.mtp is None else [self.mtp.block])
+        return [cell.ffn.read_load() for cell in cells
+                if isinstance(cell.ffn, nn.SparseExperts)]
 
     def read_decay(self):
         """`LinearAttentionCell.read_decay()` of every linear-attention

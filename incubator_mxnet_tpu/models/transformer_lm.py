@@ -268,19 +268,21 @@ def transformer_lm_base(vocab_size=50257, **kwargs):
     return TransformerLM(vocab_size, **kwargs)
 
 
-def lm_loss(logits, targets):
-    """Shifted causal-LM loss: per-position CE of logits[:, :-1] vs
-    targets[:, 1:], shape (B*(L-1),) in the logits' dtype — gluon loss
-    convention; call .mean() for the scalar.
+def lm_loss(logits, targets, shift=1):
+    """Shifted causal-LM loss: per-position CE of logits[:, :-shift] vs
+    targets[:, shift:], shape (B*(L-shift),) in the logits' dtype — gluon
+    loss convention; call .mean() for the scalar. `shift=2` is a
+    multi-token-prediction head's: position i against token i + 2.
 
     The targets move, not the logits: the cross-entropy runs over the
     (B, L, V) logits as the head wrote them, each position against the
-    next token (the last against its own, a loss never used, so its
-    gradient is exactly zero), and the last column of the small (B, L)
-    result is dropped. Slicing and flattening the logits would copy all of
-    them wherever L - 1 rows do not fill the chip's tiles."""
+    token `shift` ahead (the last `shift` against the last tokens, losses
+    never used, so their gradient is exactly zero), and the last `shift`
+    columns of the small (B, L) result are dropped. Slicing and flattening
+    the logits would copy all of them wherever L - shift rows do not fill
+    the chip's tiles."""
     def shifted(x, t):
         ce = _raw.softmax_cross_entropy(
-            x, jnp.concatenate([t[:, 1:], t[:, -1:]], axis=1))
-        return ce[:, :-1].reshape(-1)
+            x, jnp.concatenate([t[:, shift:], t[:, -shift:]], axis=1))
+        return ce[:, :-shift].reshape(-1)
     return nd._apply(shifted, [logits, nd._as_nd(targets)], name="lm_loss")

@@ -337,12 +337,14 @@ def RMSNorm(data, gamma, eps=1e-6):
                   name="RMSNorm")
 
 
-def rope(data, inv_freq, num_heads, factor=1.0, rotary_dim=None):
+def rope(data, inv_freq, num_heads, factor=1.0, rotary_dim=None,
+         interleaved=False):
     """Rotary positions on (B, L, heads * head_dim); `inv_freq` and `factor`
     as `ops._raw.rope_frequencies` gives them; `rotary_dim`: the leading
-    channels of every head that are rotated (default: all of them)."""
+    channels of every head that are rotated (default: all of them);
+    `interleaved`: adjacent channels turn together (whole heads)."""
     return _apply(lambda x: _raw.rope(x, inv_freq, num_heads, factor,
-                                      rotary_dim),
+                                      rotary_dim, interleaved),
                   [data], name="rope")
 
 

@@ -766,7 +766,8 @@ def rope_frequencies(head_dim, rope_type="default", rope_theta=10000.0,
 
 
 @jax.named_scope("rope")
-def rope(x, inv_freq, num_heads, factor=1.0, rotary_dim=None):
+def rope(x, inv_freq, num_heads, factor=1.0, rotary_dim=None,
+         interleaved=False):
     """Rotary positions on (B, L, H * head_dim), HF's rotate-half form on
     each head: x cos + rotate_half(x) sin, with cos and sin of
     position * inv_freq repeated over both halves and times `factor`.
@@ -774,7 +775,12 @@ def rope(x, inv_freq, num_heads, factor=1.0, rotary_dim=None):
 
     `rotary_dim` (a published `partial_rotary_factor` x head_dim): only the
     first `rotary_dim` channels of every head are rotated, by `inv_freq`'s
-    rotary_dim / 2 frequencies; the others pass as they are, bit for bit."""
+    rotary_dim / 2 frequencies; the others pass as they are, bit for bit.
+
+    `interleaved` (a published `rope_interleave`, whole heads): channels
+    (2j, 2j + 1) turn together by frequency j. They are taken apart into
+    the two halves first, and the result stays in that order (HF's
+    DeepSeek-V3 writes it so): the same order for q and k keeps q . k."""
     b, l, d = x.shape
     hd = d // num_heads
     angle = (jnp.arange(l, dtype=jnp.float32)[:, None]
@@ -791,6 +797,9 @@ def rope(x, inv_freq, num_heads, factor=1.0, rotary_dim=None):
              heads[..., rotary_dim:]], -1)
         return out.reshape(b, l, d)
     xf = x.astype(jnp.float32).reshape(b, l, num_heads, hd)
+    if interleaved:
+        xf = xf.reshape(b, l, num_heads, hd // 2, 2).swapaxes(3, 4).reshape(
+            b, l, num_heads, hd)
     x1, x2 = xf[..., :hd // 2], xf[..., hd // 2:]
     out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
     return out.reshape(b, l, d).astype(x.dtype)

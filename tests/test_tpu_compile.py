@@ -928,3 +928,92 @@ def test_zaya1_cell_train_step(topo, no_compile_cache, monkeypatch,
     assert not any("sparse_experts_0/moe/router/average" in owner
                    for owner in owners)
     assert "ragged-dot" not in text
+
+
+def test_joyai_flash_cell_train_step(topo, no_compile_cache, monkeypatch,
+                                     record_property, tmp_path):
+    """The FusedTrainStep program of the benchmark's JoyAI-LLM-Flash cell at
+    its published widths and its 1 x 8192 tokens, the leading dense layer,
+    one expert layer and the MTP module (three of the cell's six latent
+    layers: the other three repeat the expert layer): it compiles for the
+    described v5e inside a chip's memory, with the flash kernels on keys of
+    192 (padded to 256 lanes) beside values of 128 in every latent layer,
+    the MTP block's among them, and the query's rank, the rotation and the
+    MTP module as the owners of their operations, forward and backward
+    (docs/profiler.md)."""
+    import importlib.util
+    import json
+    import os
+    import re
+    import sys
+
+    import numpy as np
+
+    from incubator_mxnet_tpu import nd
+    from incubator_mxnet_tpu.parallel import FusedTrainStep, make_mesh
+
+    configs = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmark", "configs")
+    name = "joyai_llm_flash_ep32"
+    with open(os.path.join(configs, name + ".json")) as f:
+        doc = json.load(f)
+    del doc["rehearse"]
+    doc.update(num_hidden_layers=2)
+    spec = importlib.util.spec_from_file_location(
+        "joyai_config", os.path.join(configs, name + ".py"))
+    model = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(model)
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    step = FusedTrainStep(model.net(doc, 1), model.loss(doc),
+                          model.optimizer(doc),
+                          mesh=make_mesh({"dp": 1}, topo.devices[:1]),
+                          sharding="dp")
+    tokens = nd.array(np.zeros((1, 8192), np.int32))
+    lowered, asked = _lowered_counting(step, tokens, monkeypatch)
+    # the row is asked once an expert layer: layer 1's and the MTP block's
+    assert asked == {"pallas.selected.sum_by_token": 2}, asked
+    compiled, reserved = _compiled_and_reserved(lowered, tmp_path)
+    held = compiled.memory_analysis()
+    record_property("temp_size_in_bytes", held.temp_size_in_bytes)
+    assert (held.argument_size_in_bytes + held.temp_size_in_bytes
+            < 15 * 2 ** 30)
+    # compiled for a described v5e, the cell's five layers and the MTP
+    # module read 1.0%
+    assert _reserved_gap(compiled, reserved) < 0.05
+    text = compiled.as_text()
+    kernels = {}
+    for line in _custom_calls(text):
+        kernel = re.match(r"\s*(?:ROOT )?%([\w\-]+?)(?:\.\d+)? = ",
+                          line).group(1)
+        kernels[kernel] = kernels.get(kernel, 0) + 1
+        if kernel.startswith("flash"):
+            assert "/latent_attention/attention/" in line, line[:160]
+            assert "[32,8192,256]" in line and "[32,8192,128]" in line
+    from incubator_mxnet_tpu.ops import _raw
+    rungs = len(_raw.row_capacities(8192 * 8, 8, 256))
+    assert kernels == {"flash_attention_fwd": 3, "flash_attention_bwd": 3,
+                       "gmm": 2 * 8 * rungs, "tgmm": 2 * 3 * rungs,
+                       "sum_by_token": 2 * 2 * rungs}
+    sys.path.insert(0, os.path.dirname(configs))
+    from lib import scopes              # the benchmark's reader of owners
+    op_names = re.findall(r'op_name="([^"]*)"', text)
+    owners = {scopes.owner(op_name) for op_name in op_names}
+    assert not any("(" in owner or ")" in owner for owner in owners)
+    for scope in ("latent_attention/q_down", "latent_attention/q_up",
+                  "latent_attention/kv_down", "latent_attention/rope",
+                  "latent_attention/kv_up", "latent_attention/attention"):
+        for phase in ("forward", "backward"):
+            assert any(f"/{scope}/" in scopes.owner(op_name) + "/"
+                       and scopes.phase(op_name) == phase
+                       for op_name in op_names), (scope, phase)
+    mtp = [op_name for op_name in op_names
+           if "mtp" in scopes.owner_class(scopes.owner(op_name)).split("/")]
+    for phase in ("forward", "backward"):
+        assert any(scopes.phase(op_name) == phase for op_name in mtp), phase
+    # the MTP block's own attention and experts, and the shared head's
+    # second product, are the module's
+    assert any("/latent_attention/attention/" in scopes.owner(op_name) + "/"
+               for op_name in mtp)
+    assert any("/moe/experts" in scopes.owner(op_name) for op_name in mtp)
+    assert "ragged-dot" not in text
