@@ -191,9 +191,21 @@ class LatentAttentionCell(HybridBlock):
     `rope` (a section of a published `rope_parameters`) rotates the last
     `rope_dim` channels of every query head and the shared k_r by position,
     adjacent pairs together where `interleaved` (`rope_interleave`); None
-    rotates nothing (`mla_use_nope`). Op scopes: `latent_attention/q_down`
-    (Wq_a and its norm), `/q_up`, `/kv_down`, `/rope`, `/kv_up` (and the
-    keys joined), `/attention`; without a rank q = h Wq is outside them."""
+    rotates nothing (`mla_use_nope`).
+
+    The parameters keep their published shapes and order; the step reads
+    the query product's weight (Wq_b, or Wq without a rank) as two: every
+    head's `nope_dim` rows, then every head's `rope_dim` rows, those in
+    rotate-half order (channel pairs (2j, 2j + 1) as j and j + rope_dim /
+    2) where `interleaved`. So the queries come out of two products as the
+    parts `ops.latent_attention` takes, q_n (B, L, H nope_dim) and q_r (B,
+    L, H rope_dim), and the rotation is a plain rotate-half; k_r is taken
+    apart in the rotation as before, the same order on both sides of q .
+    k. The keys are never assembled: the key/value product (B, L, H
+    (nope_dim + v_dim)) and k_r go to the op as they are. Op scopes:
+    `latent_attention/q_down` (Wq_a and its norm), `/q_up`, `/kv_down`,
+    `/rope`, `/kv_up`, `/attention`; without a rank the two query products
+    are outside them."""
 
     def __init__(self, units, num_heads, kv_rank, nope_dim, rope_dim, v_dim,
                  epsilon=1e-5, weight_initializer=None, q_rank=None,
@@ -217,50 +229,46 @@ class LatentAttentionCell(HybridBlock):
         self.kv_up = _dense(num_heads * (nope_dim + v_dim), kv_rank, init)
         self.proj = _dense(units, num_heads * v_dim, init)
 
-    def _rotated(self, q, shared):
-        """q with the last `rope_dim` channels of every head rotated, and
-        the shared part (B, L, 1, rope_dim) rotated alike."""
+    def _queries(self, x, dense):
+        """(q_n, q_r): x through the rows of `dense`'s weight that give
+        every head's part without positions, then through those that give
+        every head's rotated part (pairs taken apart where
+        `interleaved`)."""
         _, nope_dim, rope_dim, _ = self._dims
         heads = self._num_heads
-        b, length = q.shape[:2]
-        inv_freq, factor = self._rope
-        q = q.reshape(b, length, heads, nope_dim + rope_dim)
-        q_rope = ops.rope(
-            q[:, :, :, nope_dim:].reshape(b, length, heads * rope_dim),
-            inv_freq, heads, factor, interleaved=self._interleaved)
-        q = nd.concat(q[:, :, :, :nope_dim],
-                      q_rope.reshape(b, length, heads, rope_dim), dim=3)
-        shared = ops.rope(shared.reshape(b, length, rope_dim), inv_freq, 1,
-                          factor, interleaved=self._interleaved)
-        return (q.reshape(b, length, heads * (nope_dim + rope_dim)),
-                shared.reshape(b, length, 1, rope_dim))
+        weight = dense.weight.data().reshape(heads, nope_dim + rope_dim, -1)
+        w_r = weight[:, nope_dim:]
+        if self._interleaved and self._rope is not None:
+            w_r = w_r.reshape(heads, rope_dim // 2, 2, -1).swapaxes(1, 2)
+        return tuple(ops.FullyConnected(x, w.reshape(heads * width, -1),
+                                        no_bias=True, flatten=False)
+                     for w, width in ((weight[:, :nope_dim], nope_dim),
+                                      (w_r, rope_dim)))
 
     def forward(self, x):
         kv_rank, nope_dim, rope_dim, v_dim = self._dims
         heads = self._num_heads
-        b, length = x.shape[:2]
-        q = self.q(x) if self._q_rank is None else None
+        if self._q_rank is None:
+            q_n, q_r = self._queries(x, self.q)
         with jax.named_scope("latent_attention"):
-            if q is None:
+            if self._q_rank is not None:
                 with jax.named_scope("q_down"):
                     q = self.q_norm(self.q_down(x))
                 with jax.named_scope("q_up"):
-                    q = self.q_up(q)
+                    q_n, q_r = self._queries(q, self.q_up)
             with jax.named_scope("kv_down"):
                 down = self.kv_down(x)
                 latent = self.kv_norm(down[:, :, :kv_rank])
-                shared = down[:, :, kv_rank:].reshape(b, length, 1, rope_dim)
+                shared = down[:, :, kv_rank:]
             if self._rope is not None:
-                q, shared = self._rotated(q, shared)
+                inv_freq, factor = self._rope
+                # q_r's pairs were taken apart by its weight's rows
+                q_r = ops.rope(q_r, inv_freq, heads, factor)
+                shared = ops.rope(shared, inv_freq, 1, factor,
+                                  interleaved=self._interleaved)
             with jax.named_scope("kv_up"):
-                up = self.kv_up(latent).reshape(b, length, heads,
-                                                nope_dim + v_dim)
-                k = nd.concat(
-                    up[:, :, :, :nope_dim],
-                    shared.broadcast_to((b, length, heads, rope_dim)),
-                    dim=3).reshape(b, length, heads * (nope_dim + rope_dim))
-                v = up[:, :, :, nope_dim:].reshape(b, length, heads * v_dim)
-            out = ops.multihead_attention(q, k, v, heads, causal=True)
+                kv = self.kv_up(latent)
+            out = ops.latent_attention(q_n, q_r, kv, shared, heads)
         return self.proj(out)
 
 

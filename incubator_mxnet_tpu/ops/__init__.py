@@ -23,7 +23,8 @@ __all__ = ["FullyConnected", "Convolution", "Deconvolution", "Pooling",
            "ConvBNReLU",
            "BatchNorm", "LayerNorm", "InstanceNorm", "GroupNorm", "Activation",
            "Dropout", "L2Normalization", "softmax_cross_entropy", "smooth_l1",
-           "UpSampling", "multihead_attention", "RMSNorm", "rope",
+           "UpSampling", "multihead_attention", "latent_attention",
+           "RMSNorm", "rope",
            "sparse_experts", "router_mlp", "compressed_attention",
            "gated_ffn", "linear_attention", "box_iou",
            "box_nms",
@@ -328,6 +329,16 @@ def multihead_attention(q, k, v, num_heads, mask=None, dropout_rate=0.0,
                                         key, training, scale, causal,
                                         num_kv_heads, window)
     return _apply(f, inputs, name="multihead_attention")
+
+
+def latent_attention(q_n, q_r, kv, k_r, num_heads):
+    """Causal attention of a latent layer on its parts as the products
+    wrote them (ops/_raw.py `latent_attention`): q_n (B, L, H dn), q_r (B,
+    L, H dr), kv (B, L, H (dn + dv)) with head h's key part and values side
+    by side, and k_r (B, L, dr), which every head shares; returns (B, L, H
+    dv)."""
+    return _apply(lambda *a: _raw.latent_attention(*a, num_heads),
+                  [q_n, q_r, kv, k_r], name="latent_attention")
 
 
 def RMSNorm(data, gamma, eps=1e-6):

@@ -718,6 +718,38 @@ def multihead_attention(q, k, v, num_heads, mask=None, dropout_rate=0.0,
     return out.transpose(0, 2, 1, 3).reshape(b, lq, -1)
 
 
+def latent_attention(q_n, q_r, kv, k_r, num_heads):
+    """Causal attention of a latent layer (MLA) on its parts as the
+    products wrote them: q_n (B, L, H dn) and q_r (B, L, H dr), a head's
+    queries without and with positions; kv (B, L, H (dn + dv)), head h's
+    key part k_n,h and values v_h side by side; k_r (B, L, dr), the part
+    of the keys every head shares. Head h's score is (q_n,h k_n,h^T +
+    q_r,h k_r^T) / sqrt(dn + dr); returns (B, L, H dv).
+
+    ops/select.py's row `latent_attention` (decided here, once a layer)
+    runs the flash kernels on the parts under the op scope `attention`
+    (ops/pallas/flash_attention.py `latent_flash_attention`). Elsewhere
+    the keys are assembled a head at a time, [k_n,h ; k_r], and so are the
+    queries, and `multihead_attention` runs on them."""
+    from . import select as _sel
+    b, length = q_n.shape[:2]
+    dn, dr = q_n.shape[2] // num_heads, k_r.shape[2]
+    dv = kv.shape[2] // num_heads - dn
+    if _sel.latent_attention(dn, dr, dv):
+        from . import pallas as _pallas
+        with jax.named_scope("attention"):
+            return _pallas.latent_flash_attention(q_n, q_r, kv, k_r,
+                                                  num_heads)
+    q = jnp.concatenate([q_n.reshape(b, length, num_heads, dn),
+                         q_r.reshape(b, length, num_heads, dr)], -1)
+    kv = kv.reshape(b, -1, num_heads, dn + dv)
+    k = jnp.concatenate([kv[..., :dn], jnp.broadcast_to(
+        k_r[:, :, None], kv.shape[:3] + (dr,))], -1)
+    return multihead_attention(
+        q.reshape(b, length, -1), k.reshape(b, k.shape[1], -1),
+        kv[..., dn:].reshape(b, kv.shape[1], -1), num_heads, causal=True)
+
+
 # ---------------------------------------------------------------------------
 # RMS norm, rotary positions
 # ---------------------------------------------------------------------------

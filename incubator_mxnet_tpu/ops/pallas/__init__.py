@@ -8,16 +8,16 @@ start-up self-test and no silent switch to the XLA path
 (``python chip_smoke.py`` checks every kernel on the chip, and
 tests/test_tpu_compile.py compiles them for a described v5e).
 """
-from .flash_attention import flash_attention
+from .flash_attention import flash_attention, latent_flash_attention
 from .layer_norm import layer_norm
 from .conv_bn_relu import conv_bn_relu, scale_shift_act, fold_bn
 from .grouped_matmul import grouped_matmul
 
 import jax
 
-__all__ = ["flash_attention", "layer_norm", "conv_bn_relu",
-           "scale_shift_act", "fold_bn", "grouped_matmul", "enabled",
-           "is_tpu"]
+__all__ = ["flash_attention", "latent_flash_attention", "layer_norm",
+           "conv_bn_relu", "scale_shift_act", "fold_bn", "grouped_matmul",
+           "enabled", "is_tpu"]
 
 
 def enabled() -> bool:

@@ -60,6 +60,12 @@ in VMEM.
 - grouped heads: K and V may hold fewer heads than Q (`group` query heads
   to one). Forward and dq read them through the index map (head b // group);
   dk/dv are written per QUERY head and summed over the group outside.
+- keys in two parts (latent attention, `latent_flash_attention`): the
+  score is q_n k_n^T + q_r k_r^T, two float32 products summed, where k_r
+  is ONE part every head shares; the backward gives dq_n, dq_r, dk_n and dv,
+  and k_r's gradient a head in float32, summed over the heads outside.
+  The operands' shapes decide which form runs (`_In`), never a flag: one
+  algorithm, the same loops, masks and plan.
 
 `_plan` derives every block and every grant from (lq, lk, d, dtype) under
 the budget; `block_q=` / `block_k=` / `vmem_budget=` override it for the
@@ -69,7 +75,17 @@ Layout (what Mosaic accepted, tests/test_tpu_compile.py):
 - q/k/v/o are (batch*heads, seq, head_dim). A block's last dimension is the
   array's FULL last dimension, so head_dim is left as it is when it is a
   multiple of 128 or one of 64 / 32 / 16 / 8 (an even split of the 128
-  lanes), and padded to 128 lanes otherwise (d = 80);
+  lanes), and padded to 128 lanes otherwise (d = 80; keys of 192 in one
+  part to 256);
+- in two parts nothing is padded to the lanes or transposed: q_n, o and
+  dO are (batch, seq, heads x width) as the products wrote them and head h
+  is lane block h (`_by_head`); kv is the key/value product's (batch, seq,
+  heads x (dn + dv)), a head's block split in VMEM at lane dn, and dk_n and
+  dv go back into ONE array of that layout; k_r is (batch, seq, dr) under
+  an index map that a batch's heads share, so it is fetched once (`_shared`);
+  q_r and dq_r are (batch*heads, seq, dr), a head a row, since a block of
+  64 lanes of a wider array is not one Mosaic takes. With the queries
+  resident the backward makes delta itself from dO and o (`_own_delta`);
 - the sequences are padded to the block in use only: a multiple of 128 on
   the chip (lane-dense score tiles, 128-aligned lane slices), of 16 when
   interpreted; a long sequence to the largest block that wastes under an
@@ -90,7 +106,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-__all__ = ["flash_attention"]
+__all__ = ["flash_attention", "latent_flash_attention"]
 
 _NEG = -1e30
 _LANES = 128
@@ -238,6 +254,126 @@ class _Cfg(NamedTuple):
     plan: _Plan
     window: int | None = None   # ... and > r + offset - window
     group: int = 1              # query heads to one key/value head
+    heads: int = 0              # the latent layout's heads side by side
+    #                             along the lanes; 0: one part (below)
+
+
+class _In(NamedTuple):
+    """The refs a kernel reads the attention's operands from, by the
+    operands' layout. One part: q, k and v, a head a row of the first
+    axis. Two (the latent layout): the queries as (q_n, q_r), the keys as
+    (kv, k_r), where a head's block of kv holds its key part in the first
+    `split` lanes and its values after them, and k_r is the one part every
+    head shares; the score is q_n k_n^T + q_r k_r^T."""
+    q: tuple
+    k: tuple
+    v: object
+    split: int | None = None
+
+    def queries(self, rows=None):
+        if rows is None:
+            return [ref[0] for ref in self.q]
+        return [ref[0, rows, :] for ref in self.q]
+
+    def keys(self, rows=None):
+        if self.split is None:
+            k, = self.k
+            return [k[0] if rows is None else k[0, rows, :]]
+        kv, k_r = self.k
+        rows = slice(None) if rows is None else rows
+        return [kv[0, rows, :self.split], k_r[0, rows, :]]
+
+    def values(self, rows=None):
+        if self.split is None:
+            return self.v[0] if rows is None else self.v[0, rows, :]
+        return self.v[0, slice(None) if rows is None else rows, self.split:]
+
+    @property
+    def dv(self):
+        return self.v.shape[2] - (self.split or 0)
+
+    def store_keys(self, outs, dk, dv, scale):
+        """Write a key block's gradients: dk and dv, or the latent layout's
+        dk_n and dv side by side in ONE block of kv's layout and k_r's
+        gradient (this head's share, float32) beside it."""
+        if self.split is None:
+            dk_ref, dv_ref = outs
+            dk_ref[0] = (dk[0] * scale).astype(dk_ref.dtype)
+            dv_ref[0] = dv.astype(dv_ref.dtype)
+            return
+        dkv_ref, dkr_ref = outs
+        dkv_ref[0, :, :self.split] = (dk[0] * scale).astype(dkv_ref.dtype)
+        dkv_ref[0, :, self.split:] = dv.astype(dkv_ref.dtype)
+        dkr_ref[0] = (dk[1] * scale).astype(dkr_ref.dtype)
+
+
+def _ins(cfg, refs):
+    """(the operands' `_In`, the refs after them)."""
+    if cfg.heads:
+        (q_n, q_r, kv, k_r), rest = refs[:4], refs[4:]
+        return _In((q_n, q_r), (kv, k_r), kv, q_n.shape[2]), rest
+    (q, k, v), rest = refs[:3], refs[3:]
+    return _In((q,), (k,), v), rest
+
+
+def _dot_parts(xs, ys, dims):
+    """sum_i xs[i] . ys[i] over `dims`, in float32: a score tile or its
+    transpose from the parts of the queries and of the keys."""
+    s = jax.lax.dot_general(xs[0], ys[0], dims,
+                            preferred_element_type=jnp.float32)
+    for x, y in zip(xs[1:], ys[1:]):
+        s = s + jax.lax.dot_general(x, y, dims,
+                                    preferred_element_type=jnp.float32)
+    return s
+
+
+def _by_head(cfg, index_map):
+    """index_map for an operand of the latent layout (batch, seq, heads x
+    width): grid row b is head b % heads of batch b // heads, one block of
+    the lanes."""
+    heads = cfg.heads
+
+    def by_head(b, *axes):
+        _, row, _ = index_map(b, *axes)
+        return b // heads, row, b % heads
+    return by_head
+
+
+def _shared(cfg, index_map):
+    """index_map for the part every head shares, (batch, seq, width): the
+    same block for all of a batch's heads, so it is fetched once."""
+    heads = cfg.heads
+
+    def shared(b, *axes):
+        _, row, _ = index_map(b, *axes)
+        return b // heads, row, 0
+    return shared
+
+
+def _in_specs(cfg, q, k, v, q_rows, k_rows, q_map, kv_map):
+    """BlockSpecs of the operands: queries of `q_rows` rows at q_map, keys
+    and values of `k_rows` at kv_map, in either layout."""
+    if not cfg.heads:
+        d = q.shape[2]
+        return [_vspec((1, q_rows, d), q_map),
+                _vspec((1, k_rows, d), kv_map),
+                _vspec((1, k_rows, v.shape[2]), kv_map)]
+    (q_n, q_r), (kv, k_r) = q, k
+    heads = cfg.heads
+    return [_vspec((1, q_rows, q_n.shape[2] // heads), _by_head(cfg, q_map)),
+            _vspec((1, q_rows, q_r.shape[2]), q_map),
+            _vspec((1, k_rows, kv.shape[2] // heads), _by_head(cfg, kv_map)),
+            _vspec((1, k_rows, k_r.shape[2]), _shared(cfg, kv_map))]
+
+
+def _shapes(cfg, q, k, v):
+    """(grid rows, lq, lk, the query parts' widths, the values' width)."""
+    if not cfg.heads:
+        return q.shape[0], q.shape[1], k.shape[1], (q.shape[2],), v.shape[2]
+    (q_n, q_r), (kv, _) = q, k
+    dn = q_n.shape[2] // cfg.heads
+    return (q_r.shape[0], q_r.shape[1], kv.shape[1], (dn, q_r.shape[2]),
+            kv.shape[2] // cfg.heads - dn)
 
 
 def _vspec(shape, index_map):
@@ -404,21 +540,21 @@ def _paired(cfg):
             and cfg.kv_len == p_.lkp == p_.k_major)
 
 
-def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch, cfg):
+def _fwd_kernel(*refs, cfg):
+    ins, (o_ref, lse_ref, *scratch) = _ins(cfg, refs)
     p_ = cfg.plan
     bq, bk = p_.bq, p_.bk
     qi, kj = pl.program_id(1), pl.program_id(2)
-    q = q_ref[0]
+    q = ins.queries()
     edges = _key_range(cfg, qi, kj, bq, bk, p_.k_major)
     mask = _masker(cfg, (bq, bk), 0)
 
     def tile(kb):
         """Scores and values of key sub-block kb, and its first key."""
         start = pl.multiple_of(kb * bk, bk)
-        k = k_ref[0, pl.ds(start, bk), :]
-        v = v_ref[0, pl.ds(start, bk), :]
-        s = jax.lax.dot_general(
-            q, k, _NT, preferred_element_type=jnp.float32) * cfg.scale
+        k = ins.keys(pl.ds(start, bk))
+        v = ins.values(pl.ds(start, bk))
+        s = _dot_parts(q, k, _NT) * cfg.scale
         return s, v, start
 
     def online(carry, s, parts):
@@ -467,7 +603,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch, cfg):
 
     init = (jnp.full((bq, 1), _NEG, jnp.float32),
             jnp.zeros((bq, 1), jnp.float32),
-            jnp.zeros((bq, v_ref.shape[2]), jnp.float32))
+            jnp.zeros((bq, ins.dv), jnp.float32))
     if _paired(cfg):
         m, l, acc = paired(init)
     else:
@@ -487,35 +623,39 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch, cfg):
 
 def _fwd(q, k, v, cfg):
     p_ = cfg.plan
-    bh, lq, d = q.shape
-    dv = v.shape[2]         # the values' head size, and the output's
+    bh, lq, lk, _, dv = _shapes(cfg, q, k, v)   # dv: the output's head size
     bq, km = p_.bq, p_.k_major
-    num_q, num_k = lq // bq, k.shape[1] // km
+    num_q, num_k = lq // bq, lk // km
     kv_map = _kv_map(cfg, num_k)
+    q_map = lambda b, i, j: (b, i, 0)       # noqa: E731
+    if cfg.heads:           # o as (batch, lq, heads x dv), what W_o reads
+        o_spec = _vspec((1, bq, dv), _by_head(cfg, q_map))
+        o_shape = (bh // cfg.heads, lq, cfg.heads * dv)
+    else:
+        o_spec, o_shape = _vspec((1, bq, dv), q_map), (bh, lq, dv)
     return _call(
         functools.partial(_fwd_kernel, cfg=cfg), cfg, "flash_attention_fwd",
         p_.fwd_vmem, grid=(bh, num_q, num_k),
-        in_specs=[_vspec((1, bq, d), lambda b, i, j: (b, i, 0)),
-                  _vspec((1, km, d), kv_map),
-                  _vspec((1, km, dv), kv_map)],
-        out_specs=[_vspec((1, bq, dv), lambda b, i, j: (b, i, 0)),
+        in_specs=_in_specs(cfg, q, k, v, bq, km, q_map, kv_map),
+        out_specs=[o_spec,
                    _vspec((1, 1, bq), lambda b, i, j: (b, 0, i))],
-        out_shape=[jax.ShapeDtypeStruct((bh, lq, dv), q.dtype),
+        out_shape=[jax.ShapeDtypeStruct(o_shape, jax.tree.leaves(q)[0].dtype),
                    jax.ShapeDtypeStruct((bh, 1, lq), jnp.float32)],
         scratch_shapes=_scratch(num_k, (bq, 1), (bq, 1), (bq, dv)),
-    )(q, k, v)
+    )(*jax.tree.leaves((q, k, v)))
 
 
 # ---------------------------------------------------------------------------
 # backward
 # ---------------------------------------------------------------------------
 
-def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref, dq_ref, *scratch,
-               cfg):
+def _dq_kernel(*refs, cfg):
+    ins, (do_ref, lse_ref, dl_ref, *rest) = _ins(cfg, refs)
+    dq_refs, scratch = rest[:len(ins.q)], rest[len(ins.q):]
     p_ = cfg.plan
     bq, bk = p_.bq, p_.bk
     qi, kj = pl.program_id(1), pl.program_id(2)
-    q, do = q_ref[0], do_ref[0]
+    q, do = ins.queries(), do_ref[0]
     lse = lse_ref[0].reshape(bq, 1)
     dl = dl_ref[0].reshape(bq, 1)
     edges = _key_range(cfg, qi, kj, bq, bk, p_.k_major)
@@ -523,28 +663,28 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref, dq_ref, *scratch,
 
     def body(masked):
         def step(kb, carry):
-            dq, = carry
             start = pl.multiple_of(kb * bk, bk)
-            k = k_ref[0, pl.ds(start, bk), :]
-            v = v_ref[0, pl.ds(start, bk), :]
-            s = jax.lax.dot_general(
-                q, k, _NT, preferred_element_type=jnp.float32) * cfg.scale
+            k = ins.keys(pl.ds(start, bk))
+            v = ins.values(pl.ds(start, bk))
+            s = _dot_parts(q, k, _NT) * cfg.scale
             p = jnp.exp(s - lse)
             if masked:
                 p = _where(mask(qi * bq, kj * p_.k_major + start), p, 0.0)
             dp = jax.lax.dot_general(do, v, _NT,
                                      preferred_element_type=jnp.float32)
-            ds = p * (dp - dl)       # x scale: once, on the sum
-            return dq + jax.lax.dot(ds.astype(k.dtype), k,
-                                    preferred_element_type=jnp.float32),
+            ds = (p * (dp - dl)).astype(k[0].dtype)  # x scale: on the sum
+            return tuple(dq + jax.lax.dot(ds, part,
+                                          preferred_element_type=jnp.float32)
+                         for dq, part in zip(carry, k))
         return step
 
-    dq, = _carry(scratch, (jnp.zeros(q.shape, jnp.float32),), kj,
-                 lambda carry: _loops(edges, body, carry,
-                                       cfg.window is not None))
+    dq = _carry(scratch, tuple(jnp.zeros(x.shape, jnp.float32) for x in q),
+                kj, lambda carry: _loops(edges, body, carry,
+                                         cfg.window is not None))
 
     def store():
-        dq_ref[0] = (dq * cfg.scale).astype(dq_ref.dtype)
+        for ref, part in zip(dq_refs, dq):
+            ref[0] = (part * cfg.scale).astype(ref.dtype)
 
     _at_last(p_.lkp // p_.k_major, kj, store)
 
@@ -555,24 +695,49 @@ def _merged(plan):
     return plan.q_major == plan.lqp
 
 
-def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref, dk_ref, dv_ref,
-                *rest, cfg):
+def _own_delta(cfg):
+    """Whether the backward kernel makes delta = rowsum(dO o) itself, from
+    the output where the forward wrote it: in the latent layout, with a
+    head's dO and o resident. XLA's sum over each head's 128 lanes of
+    (batch, lq, heads x dv) writes the float32 product twice (a layout
+    change) where the kernel reads o once."""
+    return bool(cfg.heads) and _merged(cfg.plan)
+
+
+def _dkv_kernel(*refs, cfg):
     """dk and dv of one key block. With Q and dO resident (`_merged`) the
     same pass gives dq as well: every tile adds its ds k to the head's
     float32 dq, kept in VMEM across the key blocks, so the scores and
     their exp are recomputed once in backward, not twice."""
+    ins, (do_ref, lse_ref, dl_ref, *rest) = _ins(cfg, refs)
+    outs, scratch = rest[:2], rest[2:]
     p_ = cfg.plan
     bk, bq = p_.dkv_bk, p_.dkv_bq
     subs, num_q = p_.q_major // bq, p_.lqp // bq
     kj, qm = pl.program_id(1), pl.program_id(2)
-    k, v = k_ref[0], v_ref[0]
-    scratch = rest
+    k, v = ins.keys(), ins.values()
     if _merged(p_):
-        (dq_ref, dq_scr), scratch = rest, ()
+        parts = len(ins.q)
+        dq_refs, dq_scr, scratch = (scratch[:parts],
+                                    scratch[parts:2 * parts], ())
+        if _own_delta(cfg):     # dl_ref is o; delta's row is made below
+            o_ref, dl_ref = dl_ref, rest[-1]
 
         @pl.when(kj == 0)
         def _():
-            dq_scr[...] = jnp.zeros_like(dq_scr)
+            for ref in dq_scr:
+                ref[...] = jnp.zeros_like(ref)
+            if _own_delta(cfg):
+                def row(qb, carry):
+                    rows = pl.ds(pl.multiple_of(qb * bq, bq), bq)
+                    dl = jnp.sum(do_ref[0, rows, :].astype(jnp.float32)
+                                 * o_ref[0, rows, :].astype(jnp.float32),
+                                 axis=1, keepdims=True)
+                    # the column as a row, as the forward stores lse
+                    dl_ref[0, :, rows] = jnp.broadcast_to(
+                        dl, (bq, _LANES)).T[:1]
+                    return carry
+                jax.lax.fori_loop(0, num_q, row, 0)
     # query sub-blocks this key block meets: [first, last); the diagonal
     # crosses [first, full), the window's edge [inside, last), the padding
     # of the keys every one of them
@@ -600,15 +765,14 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref, dk_ref, dv_ref,
 
     def body(masked):
         def step(qb, carry):
-            dk, dv = carry
+            *dk, dv = carry
             start = pl.multiple_of(qb * bq, bq)
-            q = q_ref[0, pl.ds(start, bq), :]
+            q = ins.queries(pl.ds(start, bq))
             do = do_ref[0, pl.ds(start, bq), :]
             lse = lse_ref[0, :, pl.ds(start, bq)]
             dl = dl_ref[0, :, pl.ds(start, bq)]
             # transposed tiles: keys along rows, queries along lanes
-            s = jax.lax.dot_general(
-                k, q, _NT, preferred_element_type=jnp.float32) * cfg.scale
+            s = _dot_parts(k, q, _NT) * cfg.scale
             p = jnp.exp(s - lse)
             if masked:
                 p = _where(mask(qm * p_.q_major + start, kj * bk), p, 0.0)
@@ -616,57 +780,81 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref, dk_ref, dv_ref,
                                   preferred_element_type=jnp.float32)
             dp = jax.lax.dot_general(v, do, _NT,
                                      preferred_element_type=jnp.float32)
-            ds = (p * (dp - dl)).astype(q.dtype)  # x scale: once, on the sum
-            dk = dk + jax.lax.dot(ds, q, preferred_element_type=jnp.float32)
+            ds = (p * (dp - dl)).astype(q[0].dtype)  # x scale: on the sum
+            dk = [acc + jax.lax.dot(ds, part,
+                                    preferred_element_type=jnp.float32)
+                  for acc, part in zip(dk, q)]
             if _merged(p_):
-                dq_scr[pl.ds(start, bq), :] += jax.lax.dot_general(
-                    ds, k, _TN, preferred_element_type=jnp.float32)
-            return dk, dv
+                for ref, part in zip(dq_scr, k):
+                    ref[pl.ds(start, bq), :] += jax.lax.dot_general(
+                        ds, part, _TN, preferred_element_type=jnp.float32)
+            return (*dk, dv)
         return step
 
-    zeros = jnp.zeros(k.shape, jnp.float32)
-    dk, dv = _carry(
-        scratch, (zeros, zeros if v.shape == k.shape
-                  else jnp.zeros(v.shape, jnp.float32)), qm,
+    zeros = {}      # one array of zeros a shape
+
+    def zero(shape):
+        if shape not in zeros:
+            zeros[shape] = jnp.zeros(shape, jnp.float32)
+        return zeros[shape]
+
+    *dk, dv = _carry(
+        scratch, tuple(zero(x.shape) for x in k) + (zero(v.shape),), qm,
         lambda carry: _loops(edges, body, carry, True))
-
-    def store():
-        dk_ref[0] = (dk * cfg.scale).astype(dk_ref.dtype)
-        dv_ref[0] = dv.astype(dv_ref.dtype)
-
-    _at_last(p_.lqp // p_.q_major, qm, store)
+    _at_last(p_.lqp // p_.q_major, qm,
+             lambda: ins.store_keys(outs, dk, dv, cfg.scale))
     if _merged(p_):
         @pl.when(kj == p_.lkp // bk - 1)
         def _():
-            dq_ref[0] = (dq_scr[...] * cfg.scale).astype(dq_ref.dtype)
+            for ref, part in zip(dq_refs, dq_scr):
+                ref[0] = (part[...] * cfg.scale).astype(ref.dtype)
 
 
 def _bwd(cfg, res, dout):
     p_ = cfg.plan
     q, k, v, out, lse = res
     do, _ = dout
-    bh, lq, d = q.shape
-    lk, dv_ = k.shape[1], v.shape[2]
-    delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32),
-                    axis=-1).reshape(bh, 1, lq)
+    bh, lq, lk, widths, dv_ = _shapes(cfg, q, k, v)
+    heads = cfg.heads
+    if _own_delta(cfg):
+        delta = out             # the kernel makes delta's rows from it
+    elif heads:     # do and out as (batch, lq, heads x dv)
+        delta = jnp.sum((do.astype(jnp.float32) * out.astype(jnp.float32))
+                        .reshape(bh // heads, lq, heads, dv_), axis=-1)
+        delta = delta.transpose(0, 2, 1).reshape(bh, 1, lq)
+    else:
+        delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32),
+                        axis=-1).reshape(bh, 1, lq)
 
     bq, km = p_.bq, p_.k_major
     num_q, num_k = lq // bq, lk // km
     kv_map = _kv_map(cfg, num_k)
     merged = _merged(p_)
-    dq = None if merged else _call(
-        functools.partial(_dq_kernel, cfg=cfg), cfg, "flash_attention_dq",
-        p_.dq_vmem, grid=(bh, num_q, num_k),
-        in_specs=[_vspec((1, bq, d), lambda b, i, j: (b, i, 0)),
-                  _vspec((1, km, d), kv_map),
-                  _vspec((1, km, dv_), kv_map),
-                  _vspec((1, bq, dv_), lambda b, i, j: (b, i, 0)),
-                  _vspec((1, 1, bq), lambda b, i, j: (b, 0, i)),
-                  _vspec((1, 1, bq), lambda b, i, j: (b, 0, i))],
-        out_specs=_vspec((1, bq, d), lambda b, i, j: (b, i, 0)),
-        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
-        scratch_shapes=_scratch(num_k, (bq, d)),
-    )(q, k, v, do, lse, delta)
+
+    def by_head(index_map):
+        return _by_head(cfg, index_map) if heads else index_map
+
+    if merged:
+        dq = None
+    else:
+        q_map = lambda b, i, j: (b, i, 0)       # noqa: E731
+        if heads:
+            dq_specs = [_vspec((1, bq, w), m) for w, m in
+                        zip(widths, (_by_head(cfg, q_map), q_map))]
+            dq_shapes = [jax.ShapeDtypeStruct(x.shape, x.dtype) for x in q]
+        else:
+            dq_specs = _vspec((1, bq, widths[0]), q_map)
+            dq_shapes = jax.ShapeDtypeStruct(q.shape, q.dtype)
+        dq = _call(
+            functools.partial(_dq_kernel, cfg=cfg), cfg, "flash_attention_dq",
+            p_.dq_vmem, grid=(bh, num_q, num_k),
+            in_specs=_in_specs(cfg, q, k, v, bq, km, q_map, kv_map)
+            + [_vspec((1, bq, dv_), by_head(q_map)),
+               _vspec((1, 1, bq), lambda b, i, j: (b, 0, i)),
+               _vspec((1, 1, bq), lambda b, i, j: (b, 0, i))],
+            out_specs=dq_specs, out_shape=dq_shapes,
+            scratch_shapes=_scratch(num_k, *((bq, w) for w in widths)),
+        )(*jax.tree.leaves((q, k, v)), do, lse, delta)
 
     bk, qm = p_.dkv_bk, p_.q_major
     num_qm = lq // qm
@@ -691,33 +879,56 @@ def _bwd(cfg, res, dout):
     def kv_head(b, j, i):
         return (b // cfg.group if cfg.group > 1 else b, j, 0)
 
-    whole = [_vspec((1, lq, d), lambda b, j, i: (b, 0, 0))]
+    if heads:
+        # dk_n and dv in ONE array of kv's layout, what kv_up's backward
+        # reads; k_r's gradient a head in float32, summed below
+        (kv, k_r), key_map = k, (lambda b, j, i: (b, j, 0))
+        whole = [_vspec((1, lq, w), m) for w, m in zip(
+            widths, (_by_head(cfg, lambda b, j, i: (b, 0, 0)),
+                     lambda b, j, i: (b, 0, 0)))]
+        dkv_specs = [_vspec((1, bk, kv.shape[2] // heads),
+                            _by_head(cfg, key_map)),
+                     _vspec((1, bk, widths[1]), key_map)]
+        dkv_shapes = [jax.ShapeDtypeStruct(kv.shape, kv.dtype),
+                      jax.ShapeDtypeStruct((bh, lk, widths[1]), jnp.float32)]
+        dq_shapes = [jax.ShapeDtypeStruct(x.shape, x.dtype) for x in q]
+    else:
+        whole = [_vspec((1, lq, widths[0]), lambda b, j, i: (b, 0, 0))]
+        dkv_specs = [_vspec((1, bk, widths[0]), lambda b, j, i: (b, j, 0)),
+                     _vspec((1, bk, dv_), lambda b, j, i: (b, j, 0))]
+        # a group's dk and dv are summed from float32, rounded once
+        dkv_shapes = [jax.ShapeDtypeStruct(
+            (bh, lk, x.shape[2]), x.dtype if cfg.group == 1 else jnp.float32)
+            for x in (k, v)]
+        dq_shapes = [jax.ShapeDtypeStruct(q.shape, q.dtype)]
+    # dk, dv; or in two parts kv's gradient and k_r's a head in float32
     dk, dv, *dq_merged = _call(
         functools.partial(_dkv_kernel, cfg=cfg), cfg,
         "flash_attention_bwd" if merged else "flash_attention_dkv",
         p_.dkv_vmem, carried=(1, 2) if merged else (2,),
         grid=(bh, lk // bk, num_qm),
-        in_specs=[_vspec((1, qm, d), q_map),
-                  _vspec((1, bk, d), kv_head),
-                  _vspec((1, bk, dv_), kv_head),
-                  _vspec((1, qm, dv_), q_map),
-                  _vspec((1, 1, qm), row_map),
-                  _vspec((1, 1, qm), row_map)],
-        out_specs=[_vspec((1, bk, d), lambda b, j, i: (b, j, 0)),
-                   _vspec((1, bk, dv_), lambda b, j, i: (b, j, 0))]
-        + whole * merged,
-        # a group's dk and dv are summed from float32, rounded once
-        out_shape=[jax.ShapeDtypeStruct(
-            (bh, lk, x.shape[2]), x.dtype if cfg.group == 1 else jnp.float32)
-            for x in (k, v)]
-        + [jax.ShapeDtypeStruct(q.shape, q.dtype)] * merged,
-        scratch_shapes=([pltpu.VMEM((lq, d), jnp.float32)] if merged else
-                        _scratch(num_qm, (bk, d), (bk, dv_))),
-    )(q, k, v, do, lse, delta)
+        in_specs=_in_specs(cfg, q, k, v, qm, bk, q_map, kv_head)
+        + [_vspec((1, qm, dv_), by_head(q_map)),
+           _vspec((1, 1, qm), row_map),
+           _vspec((1, qm, dv_), by_head(q_map)) if _own_delta(cfg)
+           else _vspec((1, 1, qm), row_map)],
+        out_specs=dkv_specs + whole * merged,
+        out_shape=dkv_shapes + dq_shapes * merged,
+        scratch_shapes=([pltpu.VMEM((lq, w), jnp.float32) for w in widths]
+                        + [pltpu.VMEM((1, 1, lq), jnp.float32)]  # delta
+                        * _own_delta(cfg)
+                        if merged else _scratch(
+                            num_qm, *((bk, w) for w in widths), (bk, dv_))),
+    )(*jax.tree.leaves((q, k, v)), do, lse, delta)
+    if merged:
+        dq = tuple(dq_merged) if heads else dq_merged[0]
+    if heads:       # (dq_n, dq_r), (dkv, k_r's gradient summed over heads)
+        return tuple(dq), (dk, dv.reshape(-1, heads, lk, widths[1]).sum(
+            1).astype(k_r.dtype)), None
     if cfg.group > 1:       # one dk, dv a query head: sum each group's
         dk, dv = (x.reshape(-1, cfg.group, lk, x.shape[2]).sum(1).astype(
             k.dtype) for x in (dk, dv))
-    return (dq_merged[0] if merged else dq), dk, dv
+    return dq, dk, dv
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
@@ -784,7 +995,8 @@ def flash_attention(q, k, v, *, causal=False, window=None, scale=None,
     `window=w` (causal only): a row sees the w keys up to its own. K and V
     may hold fewer heads than Q, (B, H / group, Lk, D): query head h reads
     key/value head h // group. V's head size may differ from Q's and K's
-    (latent attention: 192 beside 128); the result has V's.
+    (keys of 192 beside values of 128); the result has V's. A latent
+    layer's parts go to `latent_flash_attention` instead.
 
     Differentiable (custom VJP with blockwise recompute). The block sizes
     come from the shape (`_plan`); `block_q` / `block_k` override them for
@@ -797,3 +1009,56 @@ def flash_attention(q, k, v, *, causal=False, window=None, scale=None,
         interpret = not is_tpu()
     return _attention(q, k, v, causal, scale, block_q, block_k, interpret,
                       window=window)
+
+
+def _latent(q_n, q_r, kv, k_r, heads, block_q, block_k, interpret,
+            vmem_budget=_VMEM_BUDGET):
+    b, lq, _ = q_n.shape
+    lk, dr = kv.shape[1], k_r.shape[2]
+    dn = q_n.shape[2] // heads
+    dv = kv.shape[2] // heads - dn
+    if (q_n.shape[2] % heads or kv.shape[2] % heads or dv <= 0
+            or q_r.shape != (b, lq, heads * dr) or lq > lk
+            or k_r.shape[:2] != kv.shape[:2] or kv.shape[0] != b):
+        raise ValueError(
+            f"latent_flash_attention: {heads} causal heads over q_n "
+            f"{q_n.shape}, q_r {q_r.shape}, kv {kv.shape}, k_r {k_r.shape}")
+    # what the kernels hold of a head is what one part of dn + dr would
+    # take in lanes (256 at 128 + 64): the one-part plan reckons it
+    plan = _plan(lq, lk, max(dn + dr, dv), q_n.dtype.itemsize, interpret,
+                 block_q, block_k, vmem_budget)
+    cfg = _Cfg((dn + dr) ** -0.5, True, lk, lk - lq, bool(interpret), plan,
+               heads=heads)
+    # a head of q_r a row: its block is the array's whole last dimension
+    q_r = q_r.reshape(b, lq, heads, dr).transpose(0, 2, 1, 3).reshape(
+        b * heads, lq, dr)
+
+    def prep(x, lp):        # the sequence padded to the blocks, if need be
+        if lp == x.shape[1]:
+            return x
+        return jnp.pad(x, ((0, 0), (0, lp - x.shape[1]), (0, 0)))
+
+    out, _ = _flash((prep(q_n, plan.lqp), prep(q_r, plan.lqp)),
+                    (prep(kv, plan.lkp), prep(k_r, plan.lkp)), None, cfg)
+    return out if out.shape[1] == lq else out[:, :lq]
+
+
+def latent_flash_attention(q_n, q_r, kv, k_r, num_heads, *, block_q=None,
+                           block_k=None, interpret=None):
+    """Causal attention of a latent layer (MLA) on its parts as the
+    products wrote them, by the same kernels: q_n (B, Lq, H dn) and q_r
+    (B, Lq, H dr), a head's queries without and with positions; kv (B, Lk,
+    H (dn + dv)), head h's key part k_n,h and its values side by side; k_r
+    (B, Lk, dr), the part of the keys every head shares. Head h's score is
+    (q_n,h k_n,h^T + q_r,h k_r^T) / sqrt(dn + dr); returns (B, Lq, H dv),
+    what the output projection reads.
+
+    Nothing is assembled, padded to 128 lanes or transposed on the way in
+    but q_r (a head a row, whole); the gradients come back in the operands'
+    own layouts (dk_n and dv as one array of kv's), k_r's as the sum over
+    the heads of their float32 shares. `block_q`, `block_k` and `interpret`
+    as for `flash_attention`."""
+    if interpret is None:
+        from . import is_tpu
+        interpret = not is_tpu()
+    return _latent(q_n, q_r, kv, k_r, num_heads, block_q, block_k, interpret)

@@ -40,6 +40,14 @@ flash_attention  pallas enabled; no explicit mask; no attention-weight
                  (lq, lk, d), keeps K/V resident while a head fits VMEM
                  and streams them beyond, and leaves a head size of 64
                  unpadded
+latent_attention pallas enabled (the op takes no mask); parts of the
+                 keys and values whose widths the kernels read where the
+                 products wrote them: nope_dim and v_dim multiples of
+                 128 (a head is whole 128-lane blocks of (B, L, H x w)),
+                 rope_dim a divisor of 128 (q_r a head a row, its whole
+                 last dimension). Else the keys are assembled a head at
+                 a time and `multihead_attention` runs (its own row
+                 decides there)
 gated_delta_rule pallas enabled; heads of dk and dv both multiples of
                  128 (a head is a row of whole 128-lane tiles of the (B,
                  L, H, d) arrays, read with no copy); q's dtype bfloat16
@@ -73,8 +81,8 @@ import numpy as np
 
 from .. import profiler as _prof
 
-__all__ = ["flash_attention", "gated_delta_rule", "grouped_matmul",
-           "sum_by_token", "layer_norm", "scale_shift_act", "conv_bn_relu",
+__all__ = ["flash_attention", "latent_attention", "gated_delta_rule",
+           "grouped_matmul", "sum_by_token", "layer_norm", "scale_shift_act", "conv_bn_relu",
            "capture", "quiet", "partitioned", "meshed", "selection_table"]
 
 _tls = threading.local()
@@ -183,6 +191,23 @@ def flash_attention(mask, dropout_active: bool) -> bool:
         return _decide("flash_attention", False,
                        "attention-weight dropout in training")
     return _decide("flash_attention", True, "ok")
+
+
+def latent_attention(nope_dim, rope_dim, v_dim) -> bool:
+    """Qualify the flash kernels on a latent layer's parts (ops/pallas/
+    flash_attention.py `latent_flash_attention`): the queries as (q_n,
+    q_r), the keys as the key/value product's (B, L, H x (nope_dim +
+    v_dim)) and the one part of `rope_dim` every head shares, read where
+    the products wrote them. Decided once a layer; the op takes no mask."""
+    if not _open("latent_attention"):
+        return False
+    if nope_dim % 128 or v_dim % 128:
+        return _decide("latent_attention", False,
+                       f"nope_dim {nope_dim} or v_dim {v_dim} not % 128")
+    if 128 % rope_dim:
+        return _decide("latent_attention", False,
+                       f"rope_dim {rope_dim} does not divide 128")
+    return _decide("latent_attention", True, "ok")
 
 
 def grouped_matmul(lhs, rhs) -> bool:
@@ -300,6 +325,8 @@ def selection_table():
         "flash_attention": ("no explicit mask, no attention-weight "
                             "dropout; causal, window and grouped heads "
                             "stay"),
+        "latent_attention": ("nope_dim and v_dim % 128 == 0; "
+                             "128 % rope_dim == 0"),
         "gated_delta_rule": ("heads of dk and dv % 128 == 0; bfloat16 or "
                              "float32"),
         "grouped_matmul": "rows, contraction and columns % 128 == 0",

@@ -249,6 +249,61 @@ def test_flash_attention_compiles_inside_its_reckoned_need(
     assert fa._merged(plan) == (kernels == 2)
 
 
+# the JoyAI cell's latent layer: 1 x 8192 tokens, 32 heads, queries and
+# keys of 128 + 64 (the 64 one part every head shares), values of 128
+LATENT = {"q_n": ((1, 8192, 32 * 128), BF16), "q_r": ((1, 8192, 32 * 64), BF16),
+          "kv": ((1, 8192, 32 * 256), BF16), "k_r": ((1, 8192, 64), BF16)}
+
+
+def test_latent_attention_reads_its_parts_at_the_cells_shape(
+        compile_for_chip, monkeypatch):
+    """`ops.latent_attention` forward and backward at the JoyAI cell's
+    shape, selected as on the chip: the two kernels compile with
+    `vmem_limit_bytes` set to what `_plan` reckons (without `_grant`'s
+    quarter), read q_n, kv and k_r as the products wrote them and q_r a
+    head a row under the op scope `attention`, nothing in the program pads,
+    and no buffer of a head of 192 keys, or of one padded to 256 lanes, is
+    left in it."""
+    import importlib
+    import re
+
+    from incubator_mxnet_tpu import profiler
+    from incubator_mxnet_tpu.ops import _raw
+    fa = importlib.import_module(
+        "incubator_mxnet_tpu.ops.pallas.flash_attention")
+    asked = []
+
+    def bare(vmem):
+        asked.append(vmem)
+        return {"vmem_limit_bytes": vmem}
+    monkeypatch.setattr(fa, "_grant", bare)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    before = profiler.counters().get("ops/pallas.selected.latent_attention",
+                                     0)
+    text = compile_for_chip(
+        _with_grads(lambda *a: _raw.latent_attention(*a, 32), 4),
+        *LATENT.values())
+    assert profiler.counters()[
+        "ops/pallas.selected.latent_attention"] == before + 1
+    calls = _custom_calls(text)
+    assert [c.split(" = ")[0].strip().lstrip("%").split(".")[0]
+            for c in calls] == ["flash_attention_fwd", "flash_attention_bwd"]
+    plan = fa._plan(8192, 8192, 192, 2, False)
+    assert fa._merged(plan) and sorted(set(asked)) == sorted(
+        {plan.fwd_vmem, plan.dkv_vmem})
+    for line in calls:
+        head = line.split("metadata=")[0]
+        # kv as kv_up wrote it, k_r shared, q_r a head a row
+        assert "bf16[1,8192,8192]" in head and "bf16[1,8192,64]" in head
+        assert "bf16[32,8192,64]" in head
+        assert re.search(r'op_name="[^"]*\(attention\)+/flash_attention_'
+                         r'(fwd|bwd)/pallas_call"', line), line[-300:]
+    assert " pad(" not in text
+    for shape in ("bf16[32,8192,256]", "bf16[32,8192,192]",
+                  "bf16[1,8192,32,192]"):
+        assert shape not in text, shape
+
+
 # the Kimi-Linear cell's mixer: 1 x 8192 tokens, 32 heads of 128
 KDA = ((1, 8192, 32, 128), BF16)
 KDA_BETA = ((1, 8192, 32), jnp.float32)
@@ -389,9 +444,9 @@ def _compiled_and_reserved(lowered, dump):
 
 
 def _lowered_counting(step, tokens, monkeypatch):
-    """(the step lowered, the `sum_by_token` decisions its trace counted):
-    `FusedTrainStep.lower` keeps the selection quiet, the chip's first call
-    does not."""
+    """(the step lowered, the `sum_by_token` and `latent_attention`
+    decisions its trace counted): `FusedTrainStep.lower` keeps the
+    selection quiet, the chip's first call does not."""
     import contextlib
 
     from incubator_mxnet_tpu import profiler
@@ -401,7 +456,8 @@ def _lowered_counting(step, tokens, monkeypatch):
     lowered = step.lower(tokens, tokens)
     return lowered, {k.split("/")[-1]: v - before.get(k, 0)
                      for k, v in profiler.counters().items()
-                     if "sum_by_token" in k and v != before.get(k, 0)}
+                     if k.endswith((".sum_by_token", ".latent_attention"))
+                     and v != before.get(k, 0)}
 
 
 def _reserved_gap(compiled, reserved):
@@ -743,8 +799,9 @@ def test_kimi_linear_cell_train_step(topo, no_compile_cache, monkeypatch,
     published widths and its 1 x 8192 tokens, the leading KDA + dense layer
     and the MLA + experts layer (two of the cell's five: the other three
     repeat the KDA mixer and the expert layer): it compiles for the
-    described v5e inside a chip's memory, with the flash kernels on keys of
-    192 (padded to 256 lanes) beside values of 128, the chunked delta rule
+    described v5e inside a chip's memory, with the flash kernels on the
+    latent layer's parts (keys of 128 + a shared 64, read where the products
+    wrote them) beside values of 128, the chunked delta rule
     as two Pallas kernels (forward, and its own backward) and no `while`,
     the grouped products and every new op scope as the owners of their
     operations (docs/profiler.md)."""
@@ -779,8 +836,9 @@ def test_kimi_linear_cell_train_step(topo, no_compile_cache, monkeypatch,
                           sharding="dp")
     tokens = nd.array(np.zeros((1, 8192), np.int32))
     lowered, asked = _lowered_counting(step, tokens, monkeypatch)
-    # the row is asked once a sparse layer, as on the chip
-    assert asked == {"pallas.selected.sum_by_token": 1}, asked
+    # each row is asked once a layer it serves, as on the chip
+    assert asked == {"pallas.selected.sum_by_token": 1,
+                     "pallas.selected.latent_attention": 1}, asked
     compiled, reserved = _compiled_and_reserved(lowered, tmp_path)
     held = compiled.memory_analysis()
     record_property("temp_size_in_bytes", held.temp_size_in_bytes)
@@ -803,8 +861,10 @@ def test_kimi_linear_cell_train_step(topo, no_compile_cache, monkeypatch,
             assert ("transpose(" in op_name) == kernel.endswith("_bwd")
             assert op_name.endswith(f"/{kernel}/pallas_call")
         if kernel.startswith("flash"):
-            # 32 heads of 8192: queries and keys of 256 lanes, values of 128
-            assert "[32,8192,256]" in line and "[32,8192,128]" in line
+            # 32 heads of 8192: q_r a head a row of 64; kv as the product
+            # wrote it, (1, 8192, 32 x 256); k_r shared; nothing of 256 lanes
+            assert "[32,8192,64]" in line and "[1,8192,8192]" in line
+            assert "[1,8192,64]" in line and "[32,8192,256]" not in line
     from incubator_mxnet_tpu.ops import _raw
     rungs = len(_raw.row_capacities(8192 * 8, 8, 256))
     assert rungs == 2 and _raw.row_capacities(8192 * 8, 8, 256)[0] == 2560
@@ -936,8 +996,9 @@ def test_joyai_flash_cell_train_step(topo, no_compile_cache, monkeypatch,
     its published widths and its 1 x 8192 tokens, the leading dense layer,
     one expert layer and the MTP module (three of the cell's six latent
     layers: the other three repeat the expert layer): it compiles for the
-    described v5e inside a chip's memory, with the flash kernels on keys of
-    192 (padded to 256 lanes) beside values of 128 in every latent layer,
+    described v5e inside a chip's memory, with the flash kernels on the
+    latent parts (keys of 128 + a shared 64, read where the products wrote
+    them) beside values of 128 in every latent layer,
     the MTP block's among them, and the query's rank, the rotation and the
     MTP module as the owners of their operations, forward and backward
     (docs/profiler.md)."""
@@ -971,8 +1032,10 @@ def test_joyai_flash_cell_train_step(topo, no_compile_cache, monkeypatch,
                           sharding="dp")
     tokens = nd.array(np.zeros((1, 8192), np.int32))
     lowered, asked = _lowered_counting(step, tokens, monkeypatch)
-    # the row is asked once an expert layer: layer 1's and the MTP block's
-    assert asked == {"pallas.selected.sum_by_token": 2}, asked
+    # the sum by token once an expert layer, layer 1's and the MTP block's;
+    # the kernels on the latent parts once a latent layer, the MTP block's too
+    assert asked == {"pallas.selected.sum_by_token": 2,
+                     "pallas.selected.latent_attention": 3}, asked
     compiled, reserved = _compiled_and_reserved(lowered, tmp_path)
     held = compiled.memory_analysis()
     record_property("temp_size_in_bytes", held.temp_size_in_bytes)
@@ -989,7 +1052,8 @@ def test_joyai_flash_cell_train_step(topo, no_compile_cache, monkeypatch,
         kernels[kernel] = kernels.get(kernel, 0) + 1
         if kernel.startswith("flash"):
             assert "/latent_attention/attention/" in line, line[:160]
-            assert "[32,8192,256]" in line and "[32,8192,128]" in line
+            assert "[32,8192,64]" in line and "[1,8192,8192]" in line
+            assert "[1,8192,64]" in line and "[32,8192,256]" not in line
     from incubator_mxnet_tpu.ops import _raw
     rungs = len(_raw.row_capacities(8192 * 8, 8, 256))
     assert kernels == {"flash_attention_fwd": 3, "flash_attention_bwd": 3,
